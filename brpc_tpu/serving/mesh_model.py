@@ -21,24 +21,21 @@ onto the full device mesh (``tpu/mesh.serving_mesh``) against a
   sequence-parallel path over this mesh's ``sp`` axis (``tpu/ring.py``),
   scattering into the owner's slice of the stacked pools.
 
-jax-0.4.37: shard_map comes through ``tpu/collective.py``'s
-version-guarded shim (``shard_map_norep`` keeps the ``check_rep`` /
-``check_vma`` spelling inside the shim module); weights are replicated
-across the mesh and the stacked KV pools are sharded over ``dp`` by
-``named_sharding`` — jit follows the input shardings, which is the pjit
-lowering on this jax line.
+Weights are replicated across the mesh and the stacked KV pools are
+sharded over ``dp`` by ``named_sharding``; jit follows the input
+shardings.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import List, Sequence
 
 import numpy as np
 
 from brpc_tpu.serving.kv_cache import ShardedKVCache
 from brpc_tpu.serving.model import (ModelConfig, TinyTransformer,
-                                    _decode_body, _next_pow2, _rms)
+                                    _decode_body, _next_pow2,
+                                    _prefill_attention, _rms)
 
 
 class MeshTransformer(TinyTransformer):
@@ -68,14 +65,11 @@ class MeshTransformer(TinyTransformer):
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from brpc_tpu.tpu import pallas_ops
         from brpc_tpu.tpu.collective import shard_map_norep
 
         cfg = self.config
         H, hd = cfg.n_heads, cfg.head_dim
         Hl = H // self.tp
-        kernel = (pallas_ops.flash_attention if use_flash
-                  else pallas_ops.attention_reference)
 
         def local(params, kpools, vpools, tokens, slots, length, owner):
             # every device traces the same prompt SPMD-style; tp shards
@@ -98,8 +92,7 @@ class MeshTransformer(TinyTransformer):
                 qh = lax.dynamic_slice_in_dim(qh, tp_i * Hl, Hl, 1)
                 kh = lax.dynamic_slice_in_dim(kh, tp_i * Hl, Hl, 1)
                 vh = lax.dynamic_slice_in_dim(vh, tp_i * Hl, Hl, 1)
-                attn = jax.vmap(functools.partial(kernel, causal=True),
-                                in_axes=1, out_axes=1)(qh, kh, vh)
+                attn = _prefill_attention(qh, kh, vh, use_flash)
                 # gather heads back before the projection: the matmul then
                 # contracts the same (S, H*hd) operand as single-device,
                 # keeping greedy decode bit-identical across mesh shapes

@@ -6,8 +6,9 @@ The control message (:class:`MigrateRequest`, the manifest: tokens so
 far, chain geometry, refcount-audited length) rides a normal RPC; the
 raw block bytes do NOT — they stream over the existing STREAM→HBM record
 lane (``tpu/device_stream.py``): one 16-byte ``(handle, nbytes)`` record
-per block, credit-windowed on staged HBM bytes, the same lane the bench
-drives at 158.5 GB/s (BENCH_r05).
+per block, credit-windowed on staged HBM bytes, the same lane bench.py's
+device phase drives (rate not measured on the current machine; see
+PERF.md).
 
 Ownership is a two-phase handshake with **no window where the chain is
 owned by nobody or by both sides**:

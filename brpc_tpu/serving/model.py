@@ -3,10 +3,10 @@
 Small enough to decode on the CPU test substrate, shaped enough that every
 device-side mechanism in the repo carries weight on the request path:
 
-- **Weights by handle** — parameters are packed into one flat buffer and
-  staged into HBM through ``DeviceStore.put`` (the device lane's single
-  host→device crossing); compute looks them up by handle and unpacks
-  device-side, so the serving plane owns no host-resident copy.
+- **Weights by handle** — parameters are packed into one flat float32
+  buffer, crossed host→device once and registered in the ``DeviceStore``
+  (``adopt``); compute looks them up by handle and unpacks device-side,
+  so the serving plane owns no host-resident copy.
 - **Paged KV** — prefill scatters K/V into the :class:`PagedKVCache`
   pools at block-table slots; decode gathers context pages and appends
   the new token's K/V, all inside ONE jitted program per engine step
@@ -18,10 +18,6 @@ device-side mechanism in the repo carries weight on the request path:
   the O(S²) reference as the numerics oracle; long prompts route through
   the ring-attention path (``tpu/ring.py``) which shard_maps across the
   ``sp`` mesh axis.
-- **jax-0.4.37 shims** — shard_map comes through the same version-guarded
-  import ``tpu/collective.py`` uses; sharded placement goes through
-  ``tpu/mesh.named_sharding`` (jit follows input shardings — the pjit
-  lowering on this jax line).
 
 Shapes are bucketed (batch to powers of two, sequence to block-size
 multiples) so the jit cache stays bounded across traffic mixes.
@@ -79,6 +75,23 @@ def _rms(x):
 
     return x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
+def _prefill_attention(qh, kh, vh, use_flash: bool):
+    """Causal self-attention over one prompt's (S, H, hd) rows — the one
+    spelling the single-device and mesh prefill programs share. Heads
+    lead into the kernel: Mosaic tiles the LAST TWO block dims, so the
+    single-head (S, hd) kernel vmaps over a leading head axis but not
+    over the middle axis of (S, H, hd)."""
+    import jax
+
+    from brpc_tpu.tpu import pallas_ops
+
+    kernel = (pallas_ops.flash_attention if use_flash
+              else pallas_ops.attention_reference)
+    out = jax.vmap(functools.partial(kernel, causal=True))(
+        qh.transpose(1, 0, 2), kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
+    return out.transpose(1, 0, 2)
 
 
 def _decode_body(cfg: ModelConfig, params, kpool, vpool, tokens, positions,
@@ -142,14 +155,13 @@ class TinyTransformer:
         self._lock = threading.Lock()
         self._prefill_cache = {}
         self._decode_cache = {}
-        self._on_tpu = jax.default_backend() == "tpu"
 
         # ---- weights: pack host-side once, stream into HBM by handle
         flat, self._offsets = self._init_weights(config)
-        self.param_handle, self.param_nbytes = self.store.put(
-            flat.tobytes())
-        params_u8 = self.store.lookup(self.param_handle)
-        self._params = self._unpack_params(params_u8)
+        self.param_handle, self.param_nbytes = self.store.adopt(
+            jax.device_put(flat, self.store.device))
+        self._params = self._unpack_params(
+            self.store.lookup(self.param_handle))
         if mesh is not None:
             # replicate params across the mesh; jit follows the placement
             from brpc_tpu.tpu.mesh import named_sharding
@@ -176,20 +188,19 @@ class TinyTransformer:
             pos += n
         return np.concatenate(parts), offsets
 
-    def _unpack_params(self, params_u8):
-        """Device-side: reinterpret the staged byte buffer as the weight
-        pytree (one bitcast + views, no host copy)."""
+    def _unpack_params(self, flat):
+        """Device-side: slice the staged float32 buffer into the weight
+        pytree (no host copy). The buffer is staged as float32, not
+        bytes: a ``u8.reshape(-1, 4)`` bitcast has a minor dimension of
+        4, which a TPU layout pads to a full lane tile."""
         import jax
-        import jax.numpy as jnp
 
         @jax.jit
-        def unpack(u8):
-            f32 = jax.lax.bitcast_convert_type(
-                u8.reshape(-1, 4), jnp.float32).reshape(-1)
+        def unpack(f32):
             return {name: f32[pos:pos + int(np.prod(shape))].reshape(shape)
                     for name, pos, shape in self._offsets}
 
-        return jax.tree_util.tree_map(lambda x: x, unpack(params_u8))
+        return unpack(flat)
 
     # ----------------------------------------------------------- attention
     def _use_flash(self) -> bool:
@@ -197,14 +208,14 @@ class TinyTransformer:
             return True
         if self.config.attn == "reference":
             return False
-        return self._on_tpu
+        from brpc_tpu.tpu.pallas_ops import _on_tpu
+
+        return _on_tpu()
 
     # ------------------------------------------------------------- prefill
     def _prefill_fn(self, s_bucket: int, use_flash: bool):
         import jax
         import jax.numpy as jnp
-
-        from brpc_tpu.tpu import pallas_ops
 
         cfg = self.config
         H, hd = cfg.n_heads, cfg.head_dim
@@ -224,16 +235,7 @@ class TinyTransformer:
                 qh = q.reshape(s_bucket, H, hd)
                 kh = k.reshape(s_bucket, H, hd)
                 vh = vv.reshape(s_bucket, H, hd)
-                if use_flash:
-                    attn = jax.vmap(
-                        functools.partial(pallas_ops.flash_attention,
-                                          causal=True),
-                        in_axes=1, out_axes=1)(qh, kh, vh)
-                else:
-                    attn = jax.vmap(
-                        functools.partial(pallas_ops.attention_reference,
-                                          causal=True),
-                        in_axes=1, out_axes=1)(qh, kh, vh)
+                attn = _prefill_attention(qh, kh, vh, use_flash)
                 x = x + attn.reshape(s_bucket, -1) @ params[f"wo{l}"]
                 h2 = rms(x)
                 x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]

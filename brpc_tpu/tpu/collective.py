@@ -23,28 +23,16 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax (0.4.x): experimental home
-    from jax.experimental.shard_map import shard_map
 
 
 def shard_map_norep(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with the replication/varying-axes checker OFF — for
-    bodies that write their collectives by hand (manual psum/all_gather,
-    interpreted-Pallas kernels the checker rejects). Keeps the
-    version-fragile kwarg spelling (``check_rep`` on 0.4.x,
-    ``check_vma`` on newer jax) inside this shim module, per the
-    version-guard lint rule."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    """shard_map with the varying-axes checker OFF — for bodies that
+    write their collectives by hand (manual psum/all_gather,
+    interpreted-Pallas kernels the checker rejects)."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------------ wrappers

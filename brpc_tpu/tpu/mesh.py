@@ -36,6 +36,14 @@ def device_count() -> int:
     return len(devices())
 
 
+def describe_devices() -> str:
+    """``platform=... device_kind=... count=...`` as JAX reports it — the
+    tag every bench and smoke line carries to name the device it ran on."""
+    devs = devices()
+    return (f"platform={devs[0].platform} "
+            f"device_kind={devs[0].device_kind!r} count={len(devs)}")
+
+
 def list_device_endpoints(host: str = "localhost") -> List[EndPoint]:
     """The tpu:// naming view of the local process (one EndPoint per chip)."""
     return [

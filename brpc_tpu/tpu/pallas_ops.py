@@ -15,24 +15,12 @@ import jax.numpy as jnp
 
 EPS = 1e-6
 
-try:  # jax >= 0.7 types out_shape with varying mesh axes
-    jax.ShapeDtypeStruct((), jnp.float32, vma=None)
-    _SDS_HAS_VMA = True
-except TypeError:  # jax 0.4.x: no varying-axes types, drop the annotation
-    _SDS_HAS_VMA = False
-
-
-def _sds(shape, dtype, vma=None):
-    if _SDS_HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """The one place that picks compiled-vs-interpreted Pallas: a backend
+    that fails to initialise raises here instead of answering "not a
+    TPU" and silently selecting interpret=True."""
+    return jax.default_backend() == "tpu"
 
 
 def rmsnorm_reference(x, w, eps: float = EPS):
@@ -446,7 +434,8 @@ def _fit_block(s: int, want: int) -> int:
 
 
 def _pick_blocks(sq, sk, block_q, block_k, interpret, causal=False):
-    """Swept on v5e (docs/round4-notes.md): causal peaks at 1024x1024
+    """Swept on a v5e (docs/round4-notes.md §1; not re-measured on the
+    current machine): causal peaks at 1024x1024
     (smaller k-tiles keep the block-granular skip tight), non-causal at
     512x2048 (deepest k-stream per q residency). Explicit block sizes are
     honored exactly (and rejected if they don't divide); defaults fall
@@ -824,7 +813,7 @@ def _flash_bwd_bhsd(q, k, v, lse, do, delta, q_start, k_start,
             pl.BlockSpec((1, bq, 1), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=_sds((n, sq, d), q.dtype, vma=vset),
+        out_shape=jax.ShapeDtypeStruct((n, sq, d), q.dtype, vma=vset),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
@@ -848,8 +837,8 @@ def _flash_bwd_bhsd(q, k, v, lse, do, delta, q_start, k_start,
             pl.BlockSpec((1, bk, d), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            _sds((n, sk, d), k.dtype, vma=vset),
-            _sds((n, sk, d), v.dtype, vma=vset),
+            jax.ShapeDtypeStruct((n, sk, d), k.dtype, vma=vset),
+            jax.ShapeDtypeStruct((n, sk, d), v.dtype, vma=vset),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -1011,6 +1000,7 @@ def flash_attention_carry(q, k, v, m, l, acc, q_start, k_start,
                      jnp.asarray(k_start, jnp.int32)])[None, :]
     kernel = functools.partial(_flash_carry_kernel, causal=causal, bq=bq,
                                bk=bk, nk=nk)
+    vset = set(vma) if vma else None
     return pl.pallas_call(
         kernel,
         grid=(nq, nk),
@@ -1029,12 +1019,9 @@ def flash_attention_carry(q, k, v, m, l, acc, q_start, k_start,
             pl.BlockSpec((bq, d), lambda qi, ki: (qi, 0)),
         ],
         out_shape=[
-            _sds((sq, 1), jnp.float32,
-                 vma=set(vma) if vma else None),
-            _sds((sq, 1), jnp.float32,
-                 vma=set(vma) if vma else None),
-            _sds((sq, d), jnp.float32,
-                 vma=set(vma) if vma else None),
+            jax.ShapeDtypeStruct((sq, 1), jnp.float32, vma=vset),
+            jax.ShapeDtypeStruct((sq, 1), jnp.float32, vma=vset),
+            jax.ShapeDtypeStruct((sq, d), jnp.float32, vma=vset),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -1057,6 +1044,9 @@ def softmax_xent_reference(logits, targets):
     return -jnp.mean(ll)
 
 
+_XENT_BLOCK_BYTES = 2 << 20   # one (rows, V) float32 logits block in VMEM
+
+
 def _xent_kernel(logits_ref, targets_ref, o_ref):
     x = logits_ref[:].astype(jnp.float32)          # [bn, V]
     t = targets_ref[:]                             # [bn, 1]
@@ -1075,6 +1065,11 @@ def _xent_forward_rows(logits, targets, block_rows: int, interpret: bool):
 
     n, v = logits.shape
     bn = min(block_rows, max(n, 1))
+    if not interpret:
+        # a (bn, V) block is double-buffered and the kernel body holds a
+        # few block-sized temporaries; Mosaic's scoped VMEM limit is
+        # 16 MiB, which 256 rows of a 32k vocabulary exceed fourfold
+        bn = min(bn, max(8, _XENT_BLOCK_BYTES // (4 * v) // 8 * 8))
     n2 = ((n + bn - 1) // bn) * bn
     if n2 != n:
         logits = jnp.pad(logits, ((0, n2 - n), (0, 0)))

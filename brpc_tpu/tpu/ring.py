@@ -19,29 +19,17 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax (0.4.x): experimental home + old kwarg name
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, /, *, check_vma=True, **kw):
-        return _exp_shard_map(f, check_rep=check_vma, **kw)
+from brpc_tpu.tpu.pallas_ops import _on_tpu
 
 NEG_INF = -1e30
 
 
 def _pvary(x, axes):
-    """Mark x varying over mesh axes. jax >= 0.9 renamed lax.pvary to
-    lax.pcast(..., to='varying'); support both without a deprecation
-    warning (VERDICT r4 weak #7)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x  # jax 0.4.x: no varying-axes types, marking is a no-op
+    """Mark x varying over mesh axes (shard_map's varying-axes types)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def _block_attend(q, k, v, o, m, l, mask):
@@ -161,9 +149,12 @@ def _make_ring_flash(axis, n, fwd, causal, block_q, block_k, vaxes,
 
             if causal:
                 # fully-future KV block: dq/dk/dv contributions are
-                # identically zero — skip both backward kernels
-                zero_q = jnp.zeros((B * H, sq, D), qb.dtype)
-                zero_kv = jnp.zeros((B * H, sk, D), kb.dtype)
+                # identically zero — skip both backward kernels. The
+                # zeros carry the kernel outputs' varying-axes type:
+                # both cond branches must agree under check_vma
+                zero_q = _pvary(jnp.zeros((B * H, sq, D), qb.dtype), vaxes)
+                zero_kv = _pvary(jnp.zeros((B * H, sk, D), kb.dtype),
+                                 vaxes)
                 dq_b, dk_b, dv_b = lax.cond(
                     k_start <= q_start + sq - 1, run_bwd,
                     lambda ops: (zero_q, zero_kv, zero_kv),
@@ -230,9 +221,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
     # ops whose internal constants are unvarying — shard_map's varying-axes
     # checker rejects that mix; compiled TPU lowering types the outputs via
     # the kernel's vma= annotation and keeps the check
-    check_vma = not (use_flash and jax.default_backend() != "tpu")
-
-    interp = jax.default_backend() != "tpu"
+    interp = not _on_tpu()
+    check_vma = not (use_flash and interp)
 
     @partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
              out_specs=spec, check_vma=check_vma)

@@ -180,8 +180,16 @@ def sgd_train_step(params, batch, cfg: ModelConfig, mesh: Mesh = None,
     return params, loss
 
 
-def make_train_step(cfg: ModelConfig, mesh: Mesh, lr: float = 1e-3):
-    """Jitted sharded train step + the shardings for params and batch."""
+def make_train_step(cfg: ModelConfig, mesh: Mesh = None, lr: float = 1e-3):
+    """Jitted train step + the shardings for params and batch. Without a
+    mesh it is the single-device step (batched flash kernels, fused
+    cross-entropy) and both shardings are None."""
+    if mesh is None:
+        @partial(jax.jit, donate_argnums=(0,))
+        def step1(params, batch):
+            return sgd_train_step(params, batch, cfg, None, lr)
+
+        return step1, None, None
     pshard = param_shardings(cfg, mesh)
     batch_shard = (
         NamedSharding(mesh, P("dp", "sp")),

@@ -56,6 +56,9 @@ def main(argv=None):
     ap.add_argument("--run_seconds", type=float, default=0)
     args = ap.parse_args(argv)
 
+    from brpc_tpu.tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # one program per shape bucket: keep them
     engine = build_engine(args)
     server = Server().add_service(LlmServingService(engine))
     server.start(f"0.0.0.0:{args.port}")

@@ -2,7 +2,7 @@
 hardware-only coverage that the CPU-mesh suite permanently skips).
 
 Every test here states a CORRECTNESS property; timing lives in
-tools/kernel_bench.py (bench.py runs it for BENCH_r03).
+tools/kernel_bench.py.
 """
 
 import numpy as np
@@ -127,6 +127,61 @@ class TestKernelsOnChip:
         np.testing.assert_allclose(np.asarray(got, dtype=np.float32),
                                    np.asarray(want, dtype=np.float32),
                                    rtol=0.05, atol=0.05)
+
+
+class TestServingAndRingOnChip:
+    """The two paths no hardware test had, and the chip refused (PR 21)."""
+
+    @pytest.mark.parametrize("S", [16, 128, 384])
+    def test_serving_prefill_attention_layout(self, tpu_device, S):
+        # serving's layout and dtype: (S, H, hd) float32, heads in the
+        # MIDDLE — through the one spelling both prefill programs share
+        from brpc_tpu.serving.model import _prefill_attention
+
+        rng = np.random.default_rng(S)
+        H, hd = 16, 128
+        q, k, v = (jnp.asarray(rng.normal(size=(S, H, hd)) * 0.5,
+                               dtype=jnp.float32) for _ in range(3))
+        flash = jax.jit(lambda q, k, v: _prefill_attention(q, k, v, True))
+        ref = jax.jit(lambda q, k, v: _prefill_attention(q, k, v, False))
+        out = flash(q, k, v)
+        assert out.shape == (S, H, hd) and out.dtype == jnp.float32
+        # the reference einsum runs at the TPU's default matmul precision
+        # (bf16 passes); the kernel's float32 dots do not
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_ring_flash_causal_under_shard_map(self, tpu_device):
+        # ring attention with the carry-form kernel and the Pallas ring
+        # backward under a REAL sp=2 shard_map (varying-axes checker on),
+        # forward and gradients, against the lax path
+        from brpc_tpu.tpu.mesh import make_mesh
+        from brpc_tpu.tpu.ring import ring_attention
+
+        if len(jax.devices()) < 2:
+            pytest.skip("ring over sp=2 needs 2 devices (the lane's one "
+                        "permitted skip on a one-chip machine)")
+        mesh = make_mesh({"sp": 2}, devices_list=jax.devices()[:2])
+        rng = np.random.default_rng(11)
+        B, S, H, D = 2, 512, 4, 128
+        q, k, v = (jnp.asarray(rng.normal(size=(B, S, H, D)) * 0.5,
+                               dtype=jnp.float32) for _ in range(3))
+
+        def loss(use_flash):
+            def f(q, k, v):
+                out = ring_attention(q, k, v, mesh, "sp", causal=True,
+                                     use_flash=use_flash)
+                return jnp.sum(jnp.sin(out)), out
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                              has_aux=True))
+
+        (_, out_f), g_f = loss(True)(q, k, v)
+        (_, out_l), g_l = loss(False)(q, k, v)
+        np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_l),
+                                   rtol=2e-2, atol=2e-2)
+        for a, b in zip(g_f, g_l):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-2, atol=2e-2)
 
 
 class TestDeviceLanesOnChip:

@@ -4,7 +4,9 @@ Run as a child process so client and server do not share a GIL — the
 reference benchmarks likewise run client and server as separate binaries
 (/root/reference/example/multi_threaded_echo_c++/server.cpp). Prints
 ``LISTEN <endpoint>`` once the listener is up, then serves until stdin
-closes (the parent holds the pipe).
+closes (the parent holds the pipe). A mode that computes with JAX
+(--batch/--device/--serving) owns the chip for its lifetime and prints
+``DEVICE platform=... device_kind=... count=...`` before LISTEN.
 
     python tools/bench_server.py --listen 127.0.0.1:0
     python tools/bench_server.py --listen tpu://127.0.0.1:0/0
@@ -204,6 +206,11 @@ def main(argv=None):
         from brpc_tpu import flags
 
         flags.set_flag("tpu_shard_workers", args.shard_workers)
+    owns_device = args.batch or args.device or args.serving
+    if owns_device:
+        from brpc_tpu.tpu.compile_cache import enable_compile_cache
+
+        enable_compile_cache()  # before the first compile below
     server = Server(ServerOptions(
         native_dataplane=args.native, usercode_inline=args.inline,
         shard_factory="brpc_tpu.shard.testing:echo_services"))
@@ -237,6 +244,10 @@ def main(argv=None):
         # don't print LISTEN until the workers can take traffic — the
         # sweep must measure the plane, not worker interpreter boot
         server._shard_plane.wait_ready(30.0)
+    if owns_device:
+        from brpc_tpu.tpu.mesh import describe_devices
+
+        print(f"DEVICE {describe_devices()}", flush=True)
     print(f"LISTEN {server.listen_endpoint()}", flush=True)
     try:
         sys.stdin.read()  # parent closing the pipe is the stop signal

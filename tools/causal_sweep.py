@@ -4,9 +4,9 @@ Measures the flash forward at the flagship shape (B=4 H=8 S=2048 D=128)
 across (block_q, block_k, bn, causal) configs. Causal rows report % of
 v5e bf16 peak with the CAUSAL flop count (lower-triangular useful MACs).
 
-Methodology: the relay environment drifts by up to +-10 points across
-minutes (docs/round5-notes.md), so a single pass per config is useless
-for A/B decisions. This sweep interleaves: every config's marginal slope
+Methodology: a single pass per config is useless for A/B decisions when
+the machine drifts between passes (how much the current machine drifts is
+not measured). This sweep interleaves: every config's marginal slope
 is measured once per OUTER pass, 3 passes round-robin over the whole
 config list, and the reported number is the MEDIAN of the 3 passes (all
 within one process, compile cache warm after pass 1).
