@@ -150,7 +150,7 @@ def run_batch(queue: BatchQueue, items: List[BatchItem], reason: str) -> None:
                            queue=queue.name)
                 spans.append(span)
     t_exec = time.monotonic_ns()
-    prev_ph = _prof.set_phase("execute")
+    prev_ph = _prof.set_phase("rpc.execute")
     try:
         responses = queue.vector_fn(ctx)
     except Exception as e:

@@ -663,7 +663,8 @@ def fault_service(server, http: HttpMessage):
 # ------------------------------------------------------------------- serving
 def serving_service(server, http: HttpMessage):
     """Serving-plane engines: batch occupancy, KV pool watermark, queue
-    depth and step timings. ``?format=json`` for the structured view."""
+    depth and wait, step timings and the loop thread's time by span.
+    ``?format=json`` for the structured view."""
     try:
         from brpc_tpu.serving.engine import active_engines
     except ImportError:
@@ -689,7 +690,15 @@ def serving_service(server, http: HttpMessage):
                    f"last={s['last_step_us']:.0f}")
         out.append(f"  ttft_us p50={s['ttft_us_p50']:.0f} "
                    f"p99={s['ttft_us_p99']:.0f} "
-                   f"itl_us p50={s['itl_us_p50']:.0f}")
+                   f"itl_us p50={s['itl_us_p50']:.0f} "
+                   f"queue_wait_us mean={s['queue_wait_us_mean']:.0f} "
+                   f"(admitted={s['admitted']})")
+        # where the loop thread's time went since start, by span (self
+        # time): idle = nothing to run; sync = waiting for the device
+        if s["loop_share"]:
+            out.append("  loop: " + " ".join(
+                f"{name}={share:.1%}" for name, share in sorted(
+                    s["loop_share"].items(), key=lambda kv: -kv[1])))
         out.append(f"  kv: {kv['blocks_used']}/{kv['blocks_total']} blocks "
                    f"used ({kv['used_ratio']:.0%}), "
                    f"watermark={kv['watermark']:.0%}, "

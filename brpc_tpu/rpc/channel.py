@@ -19,6 +19,7 @@ from brpc_tpu.fiber import call_id as _cid
 from brpc_tpu.trace import span as _span
 from brpc_tpu.metrics.latency_recorder import LatencyRecorder
 from brpc_tpu.policy import compress as _compress
+from brpc_tpu.profiling import registry as _prof
 from brpc_tpu.rpc import errors
 from brpc_tpu.rpc.controller import Controller
 from brpc_tpu.rpc.protocol import find_protocol
@@ -168,18 +169,19 @@ class Channel:
         if cntl.compress_type == _compress.COMPRESS_NONE:
             cntl.compress_type = self.options.compress_type
         cid = cntl._begin_call(self, method, request, response, done)
-        try:
-            _cid.id_lock(cid)
-        except _cid.IdGone:
-            pass  # a tiny timeout already fired and finished the RPC
-        else:
+        with _prof.span("rpc.call", cid=cid):
             try:
-                cntl._issue_rpc()
-            finally:
-                try:  # never leave the id locked (join would hang forever)
-                    _cid.id_unlock(cid)
-                except _cid.IdGone:
-                    pass
+                _cid.id_lock(cid)
+            except _cid.IdGone:
+                pass  # a tiny timeout already fired and finished the RPC
+            else:
+                try:
+                    cntl._issue_rpc()
+                finally:
+                    try:  # never leave the id locked (join would hang)
+                        _cid.id_unlock(cid)
+                    except _cid.IdGone:
+                        pass
         if done is not None:
             return cntl
         cntl.join()
@@ -692,11 +694,12 @@ class _AsyncFastCall:
         # capture sizes BEFORE the send: the GIL is released inside the
         # ctypes call, so completion may run before this thread resumes
         nbytes = len(self.payload) + len(self.att)
-        rc = sock._dp.call2(sock.conn_id, self.svc_b, self.meth_b, cid,
-                            self.log_id, self.timeout_ms, self.payload,
-                            self.att, on_flusher_thread(),
-                            span.trace_id if span else 0,
-                            span.span_id if span else 0)
+        with _prof.span("rpc.call", cid=cid):
+            rc = sock._dp.call2(sock.conn_id, self.svc_b, self.meth_b, cid,
+                                self.log_id, self.timeout_ms, self.payload,
+                                self.att, on_flusher_thread(),
+                                span.trace_id if span else 0,
+                                span.span_id if span else 0)
         if rc != 0:
             if sock._fast_calls.pop(cid, None) is None:
                 return True  # concurrent failure fan-out owns completion
@@ -751,6 +754,10 @@ class _AsyncFastCall:
         return ev.wait(timeout)
 
     def _complete(self, rec) -> None:
+        with _prof.span("rpc.on_response"):
+            self._on_complete(rec)
+
+    def _on_complete(self, rec) -> None:
         if rec.code != errors.OK:
             self._retry_or_finalize(rec.code, rec.text)
             return

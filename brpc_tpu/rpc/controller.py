@@ -18,6 +18,7 @@ from brpc_tpu.butil.iobuf import IOBuf
 from brpc_tpu.fiber import call_id as _cid
 from brpc_tpu.fiber.timer import timer_add, timer_del
 from brpc_tpu.policy import compress as _compress
+from brpc_tpu.profiling import registry as _prof
 from brpc_tpu.proto import rpc_meta_pb2
 from brpc_tpu.rpc import errors
 from brpc_tpu.trace import span as _span
@@ -434,6 +435,11 @@ def handle_response_message(msg) -> None:
     ``msg.meta`` (trpc_std natively; http by header synthesis) funnels
     through the same attempt-version verification and completion path.
     """
+    with _prof.span("rpc.on_response", cid=msg.meta.correlation_id):
+        _handle_response(msg)
+
+
+def _handle_response(msg) -> None:
     meta = msg.meta
     cid = meta.correlation_id
     try:

@@ -129,7 +129,7 @@ class InputMessenger:
         # profiler phase marker: cutting/framing cost on this thread is
         # "parse"; inline (run-to-completion) dispatch re-stamps its own
         # phases and restores back here
-        prev_ph = _prof.set_phase("parse")
+        prev_ph = _prof.set_phase("rpc.parse")
         # transports that defer flow-control credits (the tpu tunnel's
         # borrowed registered blocks) bracket the cut loop so every credit
         # released while this batch parses coalesces into one ACK frame

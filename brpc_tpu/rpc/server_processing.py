@@ -23,8 +23,10 @@ from brpc_tpu.profiling import registry as _prof
 from brpc_tpu.rpc.controller import Controller
 from brpc_tpu.trace import span as _tspan
 
-# per-thread phase marker for the statistical profiler: the sampler reads
-# it from outside this thread to attribute CPU samples to span phases
+# the span primitive's fast-path form (profiling/registry.py): phase marker
+# for the sampler, a brpc.rpc.* annotation on the profiler's clock and the
+# thread's counters. A request's three phases lie side by side, tied by the
+# correlation id; each path hands the marker it found back when it is done.
 _set_phase = _prof.set_phase
 
 # requests rejected because their client timeout budget was already spent
@@ -215,7 +217,7 @@ def process_rpc_request(protocol, msg, server) -> None:
         if responded[0]:
             return
         responded[0] = True
-        prev_ph = _set_phase("respond")
+        prev_ph = _set_phase("rpc.respond", cid=meta.correlation_id)
         t_resp = time.perf_counter_ns()
         payload_out = b""
         if response is not None and not cntl.failed():
@@ -253,8 +255,8 @@ def process_rpc_request(protocol, msg, server) -> None:
         _set_phase(prev_ph)
         _settle(cntl.error_code)
 
+    ph0 = _set_phase("rpc.parse", cid=meta.correlation_id)
     try:
-        _set_phase("parse")
         t_split = time.perf_counter_ns() if cntl.span is not None else 0
         payload, attachment = protocol.split_attachment(msg)
         if cntl.span is not None:
@@ -291,7 +293,7 @@ def process_rpc_request(protocol, msg, server) -> None:
         # USER CODE (reference svc->CallMethod, :838-854); the server span
         # is "current" while it runs so downstream calls stitch the trace
         prev_span = _span.set_current(cntl.span)
-        _set_phase("execute")
+        _set_phase("rpc.execute", cid=meta.correlation_id)
         t_exec = time.perf_counter_ns()
         ex0 = _other_marks(cntl.span)
         try:
@@ -320,7 +322,7 @@ def process_rpc_request(protocol, msg, server) -> None:
         _settle(errors.EINTERNAL)
         raise
     finally:
-        _set_phase(None)
+        _set_phase(ph0)
 
 
 # ===================================================================== slim
@@ -365,7 +367,7 @@ class _SlimDone:
         if self.responded:
             return
         self.responded = True
-        prev_ph = _set_phase("respond")
+        prev_ph = _set_phase("rpc.respond", cid=self.meta.correlation_id)
         cntl = self.cntl
         span = cntl.span
         t_resp = time.perf_counter_ns() if span is not None else 0
@@ -496,8 +498,8 @@ def _process_request_slim(protocol, msg, server, meta) -> bool:
         cntl.deadline_mono = deadline_mono
     done = _SlimDone(protocol, sock, meta, cntl, entry, server, start_us)
 
+    ph0 = _set_phase("rpc.parse", cid=meta.correlation_id)
     try:
-        _set_phase("parse")
         t_parse = time.perf_counter_ns() if span is not None else 0
         body = msg.body
         if span is not None:
@@ -515,7 +517,7 @@ def _process_request_slim(protocol, msg, server, meta) -> bool:
             span.add_phase(
                 "parse_us", (time.perf_counter_ns() - t_parse) / 1000.0)
         prev_span = _tspan.set_current(span)
-        _set_phase("execute")
+        _set_phase("rpc.execute", cid=meta.correlation_id)
         t_exec = time.perf_counter_ns() if span is not None else 0
         ex0 = _other_marks(span)
         try:
@@ -541,7 +543,7 @@ def _process_request_slim(protocol, msg, server, meta) -> bool:
         done.settle(errors.EINTERNAL)
         raise
     finally:
-        _set_phase(None)
+        _set_phase(ph0)
     return True
 
 
@@ -758,8 +760,8 @@ def fast_process_request(item) -> None:
     done.pending_dump = pending_dump
     done.pending_tail = pending_tail
 
+    ph0 = _set_phase("rpc.parse", cid=cid)
     try:
-        _set_phase("parse")
         t_parse = time.perf_counter_ns() if span is not None else 0
         try:
             request = entry.request_class()
@@ -772,7 +774,7 @@ def fast_process_request(item) -> None:
             span.add_phase(
                 "parse_us", (time.perf_counter_ns() - t_parse) / 1000.0)
         prev_span = _span.set_current(span)
-        _set_phase("execute")
+        _set_phase("rpc.execute", cid=cid)
         t_exec = time.perf_counter_ns() if span is not None else 0
         ex0 = _other_marks(span)
         try:
@@ -797,7 +799,7 @@ def fast_process_request(item) -> None:
         done.settle(errors.EINTERNAL)
         raise
     finally:
-        _set_phase(None)
+        _set_phase(ph0)
 
 
 class _FastDone:
@@ -828,7 +830,7 @@ class _FastDone:
         if self.responded:
             return
         self.responded = True
-        prev_ph = _set_phase("respond")
+        prev_ph = _set_phase("rpc.respond", cid=self.cid)
         cntl = self.cntl
         span = cntl.span
         t_resp = time.perf_counter_ns() if span is not None else 0

@@ -29,6 +29,7 @@ from brpc_tpu.butil.resource_pool import VersionedPool
 from brpc_tpu.fiber.butex import Butex
 from brpc_tpu.fiber.execution_queue import ExecutionQueue
 from brpc_tpu.proto import rpc_meta_pb2
+from brpc_tpu.profiling import registry as _prof
 from brpc_tpu.rpc import errors
 
 FRAME_DATA = 1
@@ -182,10 +183,11 @@ class Stream:
                                if self.options.measure is None
                                else self.options.measure(payload))
         if self.options.on_received is not None:
-            try:
-                self.options.on_received(self.stream_id, msgs)
-            except Exception:
-                pass
+            with _prof.span("rpc.on_response", stream=self.stream_id):
+                try:
+                    self.options.on_received(self.stream_id, msgs)
+                except Exception:
+                    pass
         self._maybe_feedback()
 
     def _maybe_feedback(self) -> None:
@@ -261,10 +263,11 @@ def stream_accept(cntl, options: Optional[StreamOptions] = None) -> int:
 
 def stream_write(stream_id: int, data: bytes,
                  timeout: Optional[float] = None) -> int:
-    stream = _stream_pool.address(stream_id)
-    if stream is None:
-        return errors.ESTREAMCLOSED
-    return stream.write(data, timeout=timeout)
+    with _prof.span("rpc.stream_write", stream=stream_id):
+        stream = _stream_pool.address(stream_id)
+        if stream is None:
+            return errors.ESTREAMCLOSED
+        return stream.write(data, timeout=timeout)
 
 
 def stream_close(stream_id: int) -> None:

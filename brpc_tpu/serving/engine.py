@@ -52,6 +52,7 @@ from brpc_tpu.metrics.latency_recorder import LatencyRecorder
 from brpc_tpu.metrics.reducer import Adder
 from brpc_tpu.metrics.status import PassiveStatus
 from brpc_tpu.profiling import registry as _prof
+from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.rpc import errors
 from brpc_tpu.serving import qos as _qos
 from brpc_tpu.serving import speculative as _spec
@@ -182,6 +183,7 @@ class Sequence:
         # prefill runs only the suffix past this point
         self.prefix_len = 0
         self.t_submit = time.monotonic()
+        self.t_admit = 0.0   # when admission moved it to running
         self.t_first_token = 0.0
         self.t_last_token = 0.0
         self.finish_reason = ""
@@ -230,6 +232,12 @@ class ServingEngine:
         self.tokens_generated = 0
         self.last_step_us = 0.0
         self._occupancy_sum = 0
+        # time between submit and admission, over the sequences admitted
+        self.admitted = 0
+        self.queue_wait_us_sum = 0.0
+        # the loop thread's span counters (profiling/registry.py), kept
+        # here so snapshot() reads them from any thread
+        self._spans: Dict[str, List[int]] = {}
         # disaggregation plumbing: the migrator ships chains OUT (set via
         # set_migrator), the receiver (installed by LlmServingService)
         # adopts chains IN; _adopted parks migrated-in sequences until a
@@ -351,6 +359,20 @@ class ServingEngine:
         when no controller carries them. ``_synthetic`` marks burst
         clones fabricated by the serving.qos.burst fault point so they
         cannot re-trigger it."""
+        # the link between a request's RPC spans (correlation id) and its
+        # engine spans (seq): this span lies inside the caller's rpc.execute
+        with _span("engine.submit") as sp:
+            code, seq = self._submit(
+                prompt, max_new_tokens, stop_token, cntl, done, stream_id,
+                resume_seq_id, tenant_id, priority, _synthetic)
+            if seq is not None:
+                meta = getattr(cntl, "_srv_meta", None)
+                sp.note(seq=seq.seq_id,
+                        cid=meta.correlation_id if meta is not None else 0)
+        return code, seq
+
+    def _submit(self, prompt, max_new_tokens, stop_token, cntl, done,
+                stream_id, resume_seq_id, tenant_id, priority, _synthetic):
         if resume_seq_id:
             with self._cv:
                 seq = self._adopted.get(resume_seq_id)
@@ -567,34 +589,51 @@ class ServingEngine:
 
     # ------------------------------------------------------------ step loop
     def _loop(self) -> None:
+        """Every instant of the loop lies in a span: its leaf spans (the
+        innermost open one) partition the thread's time, which is how a
+        profile attributes the device's idle gaps to the host."""
         _prof.register_current_thread("serving")
+        self._spans = _prof.thread_spans()
         try:
             while True:
-                with self._cv:
-                    while (self.running and not self._waiting
-                           and not self._running
-                           and not self._adopted_pending
-                           and (self.qos is None
-                                or self.qos.total_depth() == 0)):
-                        self._cv.wait(self.config.idle_wait_s)
-                    if not self.running:
-                        return
-                    admitted = self._admit_locked()
+                # admit's own time: the wait for the lock (an RPC thread
+                # in submit holds it), prefix match, block allocation
+                with _span("engine.admit") as sp:
+                    with self._cv:
+                        # a span a wait: a profiler session that starts
+                        # mid-span never sees it, so none is long
+                        while self.running and not self._has_work():
+                            with _span("engine.idle"):
+                                self._cv.wait(self.config.idle_wait_s)
+                        if not self.running:
+                            return
+                        admitted = self._admit_locked()
+                    sp.note(admitted=len(admitted))
                 if not admitted and not self._running:
                     # waiting work exists but the pool is full — let
                     # in-flight frees land instead of spinning the step
-                    time.sleep(0.002)
+                    with _span("engine.pool_wait"):
+                        time.sleep(0.002)
                     continue
-                try:
-                    with self.pool_gate:
-                        self._step(admitted)
-                except Exception as e:  # engine must survive a bad step
-                    for seq in list(self._running):
-                        self._finish(seq, errors.EINTERNAL,
-                                     f"step failed: {e}")
-                    self._running = []
+                with _span("engine.step", step=self.steps,
+                           batch=len(self._running)) as sp:
+                    try:
+                        with self.pool_gate:
+                            self._step(admitted)
+                    except Exception as e:  # engine must survive a bad step
+                        for seq in list(self._running):
+                            self._finish(seq, errors.EINTERNAL,
+                                         f"step failed: {e}")
+                        self._running = []
+                self.last_step_us = sp.elapsed_ns / 1000.0
+                g_serving_step.record(self.last_step_us)
         finally:
             _prof.unregister_current_thread()
+
+    def _has_work(self) -> bool:
+        """Something to admit or to step (lock held)."""
+        return bool(self._waiting or self._running or self._adopted_pending
+                    or (self.qos is not None and self.qos.total_depth()))
 
     def _admit_locked(self) -> List[Sequence]:
         """Pull waiting sequences into the running set — called between
@@ -644,11 +683,23 @@ class ServingEngine:
                     break
             self._waiting.popleft()
             budget -= self._prefill_cost(seq)
-            seq.state = STATE_RUNNING
-            self._running.append(seq)
-            admitted.append(seq)
-            g_serving_admitted.put(1)
+            self._mark_admitted(seq, admitted)
         return admitted
+
+    def _mark_admitted(self, seq: Sequence, admitted: List[Sequence]) -> None:
+        """``seq`` leaves the queue for the running set: its queue wait
+        ends here (engine counters, and the request's rpcz span)."""
+        seq.t_admit = time.monotonic()
+        wait_us = (seq.t_admit - seq.t_submit) * 1e6
+        self.admitted += 1
+        self.queue_wait_us_sum += wait_us
+        rspan = getattr(seq.cntl, "span", None)
+        if rspan is not None:
+            rspan.add_phase("serving_queue_us", wait_us)
+        seq.state = STATE_RUNNING
+        self._running.append(seq)
+        admitted.append(seq)
+        g_serving_admitted.put(1)
 
     def _admit_qos_locked(self, admitted: List[Sequence],
                           budget: int) -> List[Sequence]:
@@ -684,10 +735,7 @@ class ServingEngine:
             cost = self._prefill_cost(seq)
             self.qos.commit(seq, cost)
             budget -= cost
-            seq.state = STATE_RUNNING
-            self._running.append(seq)
-            admitted.append(seq)
-            g_serving_admitted.put(1)
+            self._mark_admitted(seq, admitted)
         return admitted
 
     def _decode_cost(self, seq: Sequence) -> int:
@@ -736,40 +784,12 @@ class ServingEngine:
             self.kv.alloc_sequence(seq.seq_id, seq.context_len())
 
     def _step(self, admitted: List[Sequence]) -> None:
-        t0 = time.perf_counter_ns()
-        # ---- prefill phase: one fused program per new sequence
-        if admitted:
-            prev = _prof.set_phase("prefill")
-            try:
-                for seq in admitted:
-                    if seq.adopted:
-                        continue  # chain arrived prefilled — decode only
-                    tp0 = time.perf_counter_ns()
-                    if seq.prefix_len:
-                        # forked chain: cow-split the divergence block if
-                        # shared, then run only the suffix — hit TTFT is
-                        # one decode-shaped launch, not O(prompt) prefill
-                        self.kv.ensure_writable(seq.seq_id, seq.prefix_len)
-                        table = self.kv.block_table(seq.seq_id)
-                        first = self.model.prefill_suffix(
-                            seq.prompt, table, seq.prefix_len)
-                        g_serving_prefill_tokens.put(
-                            len(seq.prompt) - seq.prefix_len)
-                        self.prefill_tokens += (len(seq.prompt)
-                                                - seq.prefix_len)
-                    else:
-                        table = self.kv.block_table(seq.seq_id)
-                        first = self.model.prefill(seq.prompt, table)
-                        g_serving_prefill_tokens.put(len(seq.prompt))
-                        self.prefill_tokens += len(seq.prompt)
-                    self._append_token(seq, first)
-                    span = getattr(seq.cntl, "span", None)
-                    if span is not None:
-                        span.add_phase(
-                            "prefill_us",
-                            (time.perf_counter_ns() - tp0) / 1000.0)
-            finally:
-                _prof.set_phase(prev)
+        """One iteration inside the loop's ``engine.step`` span: a fused
+        prefill per new sequence, then ONE fused program for the whole
+        decode batch."""
+        for seq in admitted:
+            if not seq.adopted:   # an adopted chain arrived prefilled
+                self._prefill_one(seq)
         self._reap_finished()
         # ---- disaggregated handoff: a prefill-role engine ships every
         # live chain to the decode shard right after its first token; a
@@ -777,93 +797,10 @@ class ServingEngine:
         # fallback), retried next step
         if self.config.role == ROLE_PREFILL and self.migrator is not None:
             self._migrate_handoff()
-        # ---- decode phase: ONE fused program for the whole batch
         batch = list(self._running)
         if batch:
-            prev = _prof.set_phase("decode")
             try:
-                _fault.maybe_sleep(_fault.hit("serving.decode.stall"))
-                td0 = time.perf_counter_ns()
-                cfg = self.config
-                spec_on = (cfg.spec_k > 0
-                           and hasattr(self.model, "verify_step"))
-                tokens = np.array([s.out_tokens[-1] for s in batch],
-                                  dtype=np.int32)
-                # the step's input token (last sampled) is written at the
-                # end of the current context, so capacity must cover
-                # context_len() and the write position is context_len()-1
-                positions = np.array([s.pos for s in batch],
-                                     dtype=np.int32)
-                if spec_on:
-                    # draft lane: host-side prompt-lookup over committed
-                    # history — zero device work before the one verify
-                    # launch. k is capped at remaining-1 (a full accept
-                    # plus bonus lands exactly on max_new_tokens) so the
-                    # chain never outgrows the admitted KV bound.
-                    vocab = getattr(self.model.config, "vocab", 0)
-                    drafts = []
-                    for s in batch:
-                        if s.spec is None:
-                            s.spec = _spec.AdaptiveK(
-                                cfg.spec_k, cfg.spec_collapse_after)
-                        k = min(s.spec.k,
-                                max(0, s.max_new_tokens
-                                    - len(s.out_tokens) - 1))
-                        drafts.append(_spec.draft_tokens(
-                            list(s.prompt) + s.out_tokens, k,
-                            cfg.spec_ngram, vocab) if k > 0 else [])
-                    tables = []
-                    for s, d in zip(batch, drafts):
-                        tables.append(self.kv.extend_sequence(
-                            s.seq_id, s.context_len() + len(d)))
-                else:
-                    tables = []
-                    for s in batch:
-                        tables.append(self.kv.extend_sequence(
-                            s.seq_id, s.context_len()))
-                # dispatch-count invariant: under an armed ledger, the
-                # whole decode batch — across every mesh shard, and all
-                # k+1 verify rows per sequence — must cost exactly ONE
-                # fused launch + ONE host sync
-                audit = (getattr(self.model, "FUSED_STEP", False)
-                         and getattr(self.kv, "_check", False))
-                if audit:
-                    from brpc_tpu.tpu.device_lane import step_dispatch
-                    d_before = step_dispatch.snapshot()
-                if spec_on:
-                    outs = self.model.verify_step(tokens, positions,
-                                                  tables, drafts)
-                else:
-                    nxt = self.model.decode_step(tokens, positions,
-                                                 tables)
-                if audit:
-                    launches, _, syncs = step_dispatch.delta(
-                        d_before, step_dispatch.snapshot())
-                    assert (launches, syncs) == (1, 1), (
-                        f"decode step dispatched {launches} launches / "
-                        f"{syncs} host syncs for {len(batch)} seqs; the "
-                        f"step contract is exactly (1, 1)")
-                decode_us = (time.perf_counter_ns() - td0) / 1000.0
-                shards_live: Dict[int, int] = {}
-                for tbl in tables:
-                    sh = getattr(tbl, "shard", 0)
-                    shards_live[sh] = shards_live.get(sh, 0) + 1
-                for sh, n_live in shards_live.items():
-                    st = self._shard_step.setdefault(sh, [0, 0.0, 0.0, 0])
-                    st[0] += 1
-                    st[1] += decode_us
-                    st[2] = decode_us
-                    st[3] += n_live
-                if spec_on:
-                    self._commit_speculative(batch, drafts, outs)
-                else:
-                    for s, tok in zip(batch, nxt):
-                        self._append_token(s, int(tok))
-                for s in batch:
-                    span = getattr(s.cntl, "span", None)
-                    if span is not None:
-                        span.add_phase("decode_us",
-                                       decode_us / len(batch))
+                self._decode_batch(batch)
             except KVCacheFull:
                 # mid-decode exhaustion: shed the youngest sequences until
                 # the pool has headroom again — admission watermark should
@@ -877,17 +814,117 @@ class ServingEngine:
                                                       s.context_len())
                         except KeyError:
                             pass
-                victim = batch[-1]
-                self._finish(victim, errors.EOVERCROWDED,
+                self._finish(batch[-1], errors.EOVERCROWDED,
                              "kv pool exhausted mid-decode")
-            finally:
-                _prof.set_phase(prev)
         self._reap_finished()
         self.steps += 1
         self._occupancy_sum += len(batch)
         g_serving_steps.put(1)
-        self.last_step_us = (time.perf_counter_ns() - t0) / 1000.0
-        g_serving_step.record(self.last_step_us)
+
+    def _prefill_one(self, seq: Sequence) -> None:
+        with _span("engine.prefill", seq=seq.seq_id,
+                   n=len(seq.prompt)) as sp:
+            if seq.prefix_len:
+                # forked chain: cow-split the divergence block if shared,
+                # then run only the suffix — hit TTFT is one decode-shaped
+                # launch, not O(prompt) prefill
+                self.kv.ensure_writable(seq.seq_id, seq.prefix_len)
+                first = self.model.prefill_suffix(
+                    seq.prompt, self.kv.block_table(seq.seq_id),
+                    seq.prefix_len)
+            else:
+                first = self.model.prefill(
+                    seq.prompt, self.kv.block_table(seq.seq_id))
+            n_new = len(seq.prompt) - seq.prefix_len
+            g_serving_prefill_tokens.put(n_new)
+            self.prefill_tokens += n_new
+            with _span("engine.commit", batch=1):
+                self._append_token(seq, first)
+        rspan = getattr(seq.cntl, "span", None)
+        if rspan is not None:
+            rspan.add_phase("prefill_us", sp.elapsed_ns / 1000.0)
+
+    def _decode_batch(self, batch: List[Sequence]) -> None:
+        cfg = self.config
+        spec_on = cfg.spec_k > 0 and hasattr(self.model, "verify_step")
+        drafts: List[List[int]] = []
+        _fault.maybe_sleep(_fault.hit("serving.decode.stall"))
+        with _span("engine.decode_prep", batch=len(batch)) as prep:
+            tokens = np.array([s.out_tokens[-1] for s in batch],
+                              dtype=np.int32)
+            # the step's input token (last sampled) is written at the end
+            # of the current context, so capacity must cover context_len()
+            # and the write position is context_len()-1
+            positions = np.array([s.pos for s in batch], dtype=np.int32)
+            if spec_on:
+                # draft lane: host-side prompt-lookup over committed
+                # history — zero device work before the one verify launch.
+                # k is capped at remaining-1 (a full accept plus bonus
+                # lands exactly on max_new_tokens) so the chain never
+                # outgrows the admitted KV bound.
+                vocab = getattr(self.model.config, "vocab", 0)
+                for s in batch:
+                    if s.spec is None:
+                        s.spec = _spec.AdaptiveK(
+                            cfg.spec_k, cfg.spec_collapse_after)
+                    k = min(s.spec.k, max(0, s.max_new_tokens
+                                          - len(s.out_tokens) - 1))
+                    drafts.append(_spec.draft_tokens(
+                        list(s.prompt) + s.out_tokens, k,
+                        cfg.spec_ngram, vocab) if k > 0 else [])
+            else:
+                drafts = [[]] * len(batch)
+            tables = [self.kv.extend_sequence(s.seq_id,
+                                              s.context_len() + len(d))
+                      for s, d in zip(batch, drafts)]
+        # dispatch-count invariant: under an armed ledger, the whole
+        # decode batch — across every mesh shard, and all k+1 verify rows
+        # per sequence — must cost exactly ONE fused launch + ONE host sync
+        audit = (getattr(self.model, "FUSED_STEP", False)
+                 and getattr(self.kv, "_check", False))
+        if audit:
+            from brpc_tpu.tpu.device_lane import step_dispatch
+            d_before = step_dispatch.snapshot()
+        model_ns = self._span_ns("model.decode")
+        if spec_on:
+            outs = self.model.verify_step(tokens, positions, tables, drafts)
+        else:
+            nxt = self.model.decode_step(tokens, positions, tables)
+        # the step's decode time, from the spans that covered it
+        decode_us = (prep.elapsed_ns + self._span_ns("model.decode")
+                     - model_ns) / 1000.0
+        if audit:
+            launches, _, syncs = step_dispatch.delta(
+                d_before, step_dispatch.snapshot())
+            assert (launches, syncs) == (1, 1), (
+                f"decode step dispatched {launches} launches / "
+                f"{syncs} host syncs for {len(batch)} seqs; the "
+                f"step contract is exactly (1, 1)")
+        shards_live: Dict[int, int] = {}
+        for tbl in tables:
+            sh = getattr(tbl, "shard", 0)
+            shards_live[sh] = shards_live.get(sh, 0) + 1
+        for sh, n_live in shards_live.items():
+            st = self._shard_step.setdefault(sh, [0, 0.0, 0.0, 0])
+            st[0] += 1
+            st[1] += decode_us
+            st[2] = decode_us
+            st[3] += n_live
+        with _span("engine.commit", batch=len(batch)):
+            if spec_on:
+                self._commit_speculative(batch, drafts, outs)
+            else:
+                for s, tok in zip(batch, nxt):
+                    self._append_token(s, int(tok))
+            for s in batch:
+                rspan = getattr(s.cntl, "span", None)
+                if rspan is not None:
+                    rspan.add_phase("decode_us", decode_us / len(batch))
+
+    def _span_ns(self, name: str) -> int:
+        """Total time so far of the loop thread's spans called ``name``."""
+        rec = self._spans.get(name)
+        return rec[1] if rec else 0
 
     def _commit_speculative(self, batch: List[Sequence],
                             drafts: List[List[int]],
@@ -987,17 +1024,19 @@ class ServingEngine:
 
     def _reap_finished(self) -> None:
         still: List[Sequence] = []
-        for seq in self._running:
-            sock = getattr(seq.cntl, "_srv_socket", None)
-            if sock is not None and getattr(sock, "failed", False):
-                # tunnel/connection died mid-generation: retriable error
-                # to the sequence, blocks back to the pool
-                self._finish(seq, errors.EFAILEDSOCKET,
-                             "connection failed mid-generation")
-            elif seq.state == STATE_DONE:
-                self._finish(seq, 0, "")
-            else:
-                still.append(seq)
+        with _span("engine.reap") as sp:
+            for seq in self._running:
+                sock = getattr(seq.cntl, "_srv_socket", None)
+                if sock is not None and getattr(sock, "failed", False):
+                    # tunnel/connection died mid-generation: retriable
+                    # error to the sequence, blocks back to the pool
+                    self._finish(seq, errors.EFAILEDSOCKET,
+                                 "connection failed mid-generation")
+                elif seq.state == STATE_DONE:
+                    self._finish(seq, 0, "")
+                else:
+                    still.append(seq)
+            sp.note(finished=len(self._running) - len(still))
         self._running = still
 
     # ------------------------------------------------------------- handoff
@@ -1070,9 +1109,10 @@ class ServingEngine:
             # commit the fully-written blocks back into the radix tree
             # (insert-or-share) before the table drops; the last sampled
             # token's K/V was never written, hence the -1 valid length
-            self.prefix.commit(
-                seq.seq_id, list(seq.prompt) + seq.out_tokens,
-                len(seq.prompt) + len(seq.out_tokens) - 1)
+            with _span("engine.prefix_commit", seq=seq.seq_id):
+                self.prefix.commit(
+                    seq.seq_id, list(seq.prompt) + seq.out_tokens,
+                    len(seq.prompt) + len(seq.out_tokens) - 1)
         self.kv.free_sequence(seq.seq_id)
         if seq.state != STATE_DONE:
             seq.state = STATE_DONE
@@ -1138,6 +1178,8 @@ class ServingEngine:
     def snapshot(self) -> Dict[str, object]:
         kv = self.kv.snapshot()
         occ = (self._occupancy_sum / self.steps) if self.steps else 0.0
+        spans = sorted(list(self._spans.items()))
+        loop_ns = sum(own for _n, (_c, _t, own) in spans) or 1
         migration = None
         if self.migrator is not None or self._migration_rx is not None:
             migration = {"parked": len(self._adopted)}
@@ -1157,6 +1199,18 @@ class ServingEngine:
             "tokens_generated": self.tokens_generated,
             "batch_occupancy_avg": round(occ, 3),
             "last_step_us": round(self.last_step_us, 1),
+            "admitted": self.admitted,
+            "queue_wait_us_sum": self.queue_wait_us_sum,
+            "queue_wait_us_mean": round(
+                self.queue_wait_us_sum / max(1, self.admitted), 1),
+            # the loop thread's spans since start: [count, total_us,
+            # self_us]; self times partition the loop's time, so their
+            # shares say where the loop spends it
+            "span_us": {name: [n, round(tot / 1000.0, 1),
+                               round(own / 1000.0, 1)]
+                        for name, (n, tot, own) in spans},
+            "loop_share": {name: round(own / loop_ns, 4)
+                           for name, (_n, _tot, own) in spans},
             "step_us_p50": g_serving_step.latency_percentile(0.5),
             "step_us_p99": g_serving_step.latency_percentile(0.99),
             "ttft_us_p50": g_serving_ttft.latency_percentile(0.5),

@@ -32,10 +32,12 @@ from typing import List, Sequence
 
 import numpy as np
 
+from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.serving.kv_cache import ShardedKVCache
 from brpc_tpu.serving.model import (ModelConfig, TinyTransformer,
-                                    _decode_body, _next_pow2,
-                                    _prefill_attention, _rms)
+                                    _decode_body, _decode_buckets,
+                                    _prefill_attention, _prefill_bucket,
+                                    _rms)
 
 
 class MeshTransformer(TinyTransformer):
@@ -112,34 +114,35 @@ class MeshTransformer(TinyTransformer):
         return jax.jit(sm, donate_argnums=(1, 2))
 
     def prefill(self, tokens: np.ndarray, table: Sequence[int]) -> int:
-        cfg = self.config
         s = len(tokens)
-        if s >= cfg.ring_threshold:
-            return self._prefill_ring(tokens, table)
-        self.kv.assert_writable(table, 0, s)
-        shard = getattr(table, "shard", 0)
-        bucket = max(16, _next_pow2(s))
-        if bucket > 128:
-            bucket = ((s + 127) // 128) * 128  # flash wants S % 128 == 0
-        use_flash = self._use_flash()
-        key = (bucket, use_flash)
-        with self._lock:
-            fn = self._prefill_cache.get(key)
-            if fn is None:
-                fn = self._mesh_prefill_fn(bucket, use_flash)
-                self._prefill_cache[key] = fn
-        toks = np.zeros(bucket, dtype=np.int32)
-        toks[:s] = tokens
-        slots = self._slots_for(table, s, bucket)
-        from brpc_tpu.tpu.device_lane import step_dispatch
-        step_dispatch.note_launch(1)
-        kpools, vpools, nxt = fn(self._params, self.kv.k_pools,
-                                 self.kv.v_pools, toks, slots,
-                                 np.int32(s), np.int32(shard))
-        self.kv.update_pools(kpools, vpools)
-        first = int(nxt)
-        step_dispatch.note_host_sync()
-        return first
+        bucket = _prefill_bucket(s)
+        with _span("model.prefill", n=s, bucket=bucket):
+            if s >= self.config.ring_threshold:
+                return self._prefill_ring(tokens, table)
+            self.kv.assert_writable(table, 0, s)
+            with _span("model.prep"):
+                shard = getattr(table, "shard", 0)
+                use_flash = self._use_flash()
+                key = (bucket, use_flash)
+                with self._lock:
+                    fn = self._prefill_cache.get(key)
+                    if fn is None:
+                        fn = self._mesh_prefill_fn(bucket, use_flash)
+                        self._prefill_cache[key] = fn
+                toks = np.zeros(bucket, dtype=np.int32)
+                toks[:s] = tokens
+                slots = self._slots_for(table, s, bucket)
+            from brpc_tpu.tpu.device_lane import step_dispatch
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                kpools, vpools, nxt = fn(self._params, self.kv.k_pools,
+                                         self.kv.v_pools, toks, slots,
+                                         np.int32(s), np.int32(shard))
+                self.kv.update_pools(kpools, vpools)
+            with _span("model.sync"):
+                first = int(nxt)
+                step_dispatch.note_host_sync()
+            return first
 
     def _prefill_ring(self, tokens: np.ndarray,
                       table: Sequence[int]) -> int:
@@ -160,30 +163,32 @@ class MeshTransformer(TinyTransformer):
         self.kv.assert_writable(table, 0, s)
         pad = ((s + n - 1) // n) * n
         p = self._params
-        toks = np.zeros(pad, dtype=np.int32)
-        toks[:s] = tokens
-        x = p["embed"][jnp.asarray(toks)]
-        kpools, vpools = self.kv.k_pools, self.kv.v_pools
-        slots = jnp.asarray(self._slots_for(table, s, pad))
-        for l in range(cfg.n_layers):
-            h = _rms(x)
-            qkv = h @ p[f"wqkv{l}"]
-            q, k, vv = jnp.split(qkv, 3, axis=-1)
-            kpools = kpools.at[shard, l, slots].set(k)
-            vpools = vpools.at[shard, l, slots].set(vv)
-            qh = q.reshape(1, pad, H, hd)
-            kh = k.reshape(1, pad, H, hd)
-            vh = vv.reshape(1, pad, H, hd)
-            step_dispatch.note_launch(1)
-            attn = ring.ring_attention(qh, kh, vh, self.mesh, "sp",
-                                       causal=True)
-            x = x + attn.reshape(pad, -1) @ p[f"wo{l}"]
-            h2 = _rms(x)
-            x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
-        self.kv.update_pools(kpools, vpools)
-        logits = _rms(x[s - 1]) @ p["embed"].T
-        first = int(jnp.argmax(logits))
-        step_dispatch.note_host_sync()
+        with _span("model.launch"):   # one launch a layer, none waited for
+            toks = np.zeros(pad, dtype=np.int32)
+            toks[:s] = tokens
+            x = p["embed"][jnp.asarray(toks)]
+            kpools, vpools = self.kv.k_pools, self.kv.v_pools
+            slots = jnp.asarray(self._slots_for(table, s, pad))
+            for l in range(cfg.n_layers):
+                h = _rms(x)
+                qkv = h @ p[f"wqkv{l}"]
+                q, k, vv = jnp.split(qkv, 3, axis=-1)
+                kpools = kpools.at[shard, l, slots].set(k)
+                vpools = vpools.at[shard, l, slots].set(vv)
+                qh = q.reshape(1, pad, H, hd)
+                kh = k.reshape(1, pad, H, hd)
+                vh = vv.reshape(1, pad, H, hd)
+                step_dispatch.note_launch(1)
+                attn = ring.ring_attention(qh, kh, vh, self.mesh, "sp",
+                                           causal=True)
+                x = x + attn.reshape(pad, -1) @ p[f"wo{l}"]
+                h2 = _rms(x)
+                x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
+            self.kv.update_pools(kpools, vpools)
+            logits = _rms(x[s - 1]) @ p["embed"].T
+        with _span("model.sync"):
+            first = int(jnp.argmax(logits))
+            step_dispatch.note_host_sync()
         return first
 
     # -------------------------------------------------------------- decode
@@ -222,46 +227,50 @@ class MeshTransformer(TinyTransformer):
         the per-shard ``_decode_body`` sees exactly the single-pool row
         layout and the verify lowering stays bit-identical across tp/dp
         splits, still one launch and one sync for the whole mesh."""
-        bs = self.kv.block_size
         B = len(tokens)
-        self.kv.assert_writable_batch(tables, positions)
         dp = self.dp
-        groups: List[List[int]] = [[] for _ in range(dp)]
-        for i, t in enumerate(tables):
-            groups[getattr(t, "shard", 0)].append(i)
         # bucket by TOTAL batch, not the max per-shard group: the shard
         # split depends on seq-id hashing, so group-derived buckets churn
         # the jit cache across otherwise-identical workloads (a cold
         # compile mid-serving is a multi-hundred-ms step); total-batch
         # buckets cost a little padding and make the combo set a pure
         # function of the workload
-        b_bucket = max(2, _next_pow2(B))
-        max_blocks = max(len(t) for t in tables)
-        l_bucket = max(2, _next_pow2(max_blocks)) * bs
-        key = (b_bucket, l_bucket)
-        with self._lock:
-            fn = self._decode_cache.get(key)
-            if fn is None:
-                fn = self._decode_fn(b_bucket, l_bucket)
-                self._decode_cache[key] = fn
-        toks = np.zeros((dp, b_bucket), dtype=np.int32)
-        pos = np.zeros((dp, b_bucket), dtype=np.int32)
-        slot_tables = np.zeros((dp, b_bucket, l_bucket), dtype=np.int32)
-        for shard, g in enumerate(groups):
-            for j, i in enumerate(g):
-                toks[shard, j] = tokens[i]
-                pos[shard, j] = positions[i]
-                slot_tables[shard, j] = self._slots_for(
-                    tables[i], positions[i] + 1, l_bucket)
-        from brpc_tpu.tpu.device_lane import step_dispatch
-        step_dispatch.note_launch(1)
-        kpools, vpools, nxt = fn(self._params, self.kv.k_pools,
-                                 self.kv.v_pools, toks, pos, slot_tables)
-        self.kv.update_pools(kpools, vpools)
-        flat = np.asarray(nxt)
-        step_dispatch.note_host_sync()
-        out = np.zeros(B, dtype=np.int32)
-        for shard, g in enumerate(groups):
-            for j, i in enumerate(g):
-                out[i] = flat[shard, j]
-        return out
+        b_bucket, l_bucket = _decode_buckets(B, tables, self.kv.block_size)
+        with _span("model.decode", B=B, b_bucket=b_bucket,
+                   l_bucket=l_bucket):
+            self.kv.assert_writable_batch(tables, positions)
+            with _span("model.prep"):
+                groups: List[List[int]] = [[] for _ in range(dp)]
+                for i, t in enumerate(tables):
+                    groups[getattr(t, "shard", 0)].append(i)
+                key = (b_bucket, l_bucket)
+                with self._lock:
+                    fn = self._decode_cache.get(key)
+                    if fn is None:
+                        fn = self._decode_fn(b_bucket, l_bucket)
+                        self._decode_cache[key] = fn
+                toks = np.zeros((dp, b_bucket), dtype=np.int32)
+                pos = np.zeros((dp, b_bucket), dtype=np.int32)
+                slot_tables = np.zeros((dp, b_bucket, l_bucket),
+                                       dtype=np.int32)
+                for shard, g in enumerate(groups):
+                    for j, i in enumerate(g):
+                        toks[shard, j] = tokens[i]
+                        pos[shard, j] = positions[i]
+                        slot_tables[shard, j] = self._slots_for(
+                            tables[i], positions[i] + 1, l_bucket)
+            from brpc_tpu.tpu.device_lane import step_dispatch
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                kpools, vpools, nxt = fn(self._params, self.kv.k_pools,
+                                         self.kv.v_pools, toks, pos,
+                                         slot_tables)
+                self.kv.update_pools(kpools, vpools)
+            with _span("model.sync"):
+                flat = np.asarray(nxt)
+                step_dispatch.note_host_sync()
+                out = np.zeros(B, dtype=np.int32)
+                for shard, g in enumerate(groups):
+                    for j, i in enumerate(g):
+                        out[i] = flat[shard, j]
+            return out

@@ -31,6 +31,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.serving.kv_cache import PagedKVCache
 
 
@@ -67,6 +68,21 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p <<= 1
     return p
+
+
+def _prefill_bucket(s: int) -> int:
+    """The prefill program's padded length for a prompt of ``s``."""
+    bucket = max(16, _next_pow2(s))
+    if bucket > 128:
+        bucket = ((s + 127) // 128) * 128  # flash wants S % 128 == 0
+    return bucket
+
+
+def _decode_buckets(n_rows: int, tables, block_size: int):
+    """The decode program's padded (rows, context) for a batch."""
+    max_blocks = max(len(t) for t in tables)
+    return (max(2, _next_pow2(n_rows)),
+            max(2, _next_pow2(max_blocks)) * block_size)
 
 
 def _rms(x):
@@ -107,30 +123,36 @@ def _decode_body(cfg: ModelConfig, params, kpool, vpool, tokens, positions,
     import jax.numpy as jnp
 
     H, hd = cfg.n_heads, cfg.head_dim
+    scope = jax.named_scope   # metadata only: names a device op's part
     x = params["embed"][tokens]                       # (B, D)
     write = slot_tables[jnp.arange(B), positions]     # (B,)
     mask = (jnp.arange(L)[None, :]
             <= positions[:, None])                    # (B, L)
     for l in range(cfg.n_layers):
-        h = _rms(x)
-        qkv = h @ params[f"wqkv{l}"]
-        q, k, vv = jnp.split(qkv, 3, axis=-1)
-        kpool = kpool.at[l, write].set(k)
-        vpool = vpool.at[l, write].set(vv)
-        ks = kpool[l][slot_tables]                    # (B, L, D)
-        vs = vpool[l][slot_tables]
-        qh = q.reshape(B, H, hd)
-        kh = ks.reshape(B, L, H, hd)
-        vh = vs.reshape(B, L, H, hd)
-        s = jnp.einsum("bhd,blhd->bhl", qh, kh) / np.sqrt(hd)
-        s = jnp.where(mask[:, None, :], s, -1e30)
-        patt = jax.nn.softmax(s, axis=-1)
-        attn = jnp.einsum("bhl,blhd->bhd", patt, vh)
-        x = x + attn.reshape(B, -1) @ params[f"wo{l}"]
-        h2 = _rms(x)
-        x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]
-    logits = _rms(x) @ params["embed"].T              # (B, V)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with scope("kv_write"):
+            h = _rms(x)
+            qkv = h @ params[f"wqkv{l}"]
+            q, k, vv = jnp.split(qkv, 3, axis=-1)
+            kpool = kpool.at[l, write].set(k)
+            vpool = vpool.at[l, write].set(vv)
+        with scope("kv_gather"):
+            ks = kpool[l][slot_tables]                # (B, L, D)
+            vs = vpool[l][slot_tables]
+        with scope("attention"):
+            qh = q.reshape(B, H, hd)
+            kh = ks.reshape(B, L, H, hd)
+            vh = vs.reshape(B, L, H, hd)
+            s = jnp.einsum("bhd,blhd->bhl", qh, kh) / np.sqrt(hd)
+            s = jnp.where(mask[:, None, :], s, -1e30)
+            patt = jax.nn.softmax(s, axis=-1)
+            attn = jnp.einsum("bhl,blhd->bhd", patt, vh)
+            x = x + attn.reshape(B, -1) @ params[f"wo{l}"]
+        with scope("mlp"):
+            h2 = _rms(x)
+            x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]
+    with scope("head"):
+        logits = _rms(x) @ params["embed"].T          # (B, V)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return kpool, vpool, nxt
 
 
@@ -224,24 +246,32 @@ class TinyTransformer:
             return x * jax.lax.rsqrt(
                 jnp.mean(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
+        scope = jax.named_scope   # as in _decode_body
+
         def impl(params, kpool, vpool, tokens, slots, length):
             x = params["embed"][tokens]                      # (S, D)
             for l in range(cfg.n_layers):
-                h = rms(x)
-                qkv = h @ params[f"wqkv{l}"]
-                q, k, vv = jnp.split(qkv, 3, axis=-1)
-                kpool = kpool.at[l, slots].set(k)
-                vpool = vpool.at[l, slots].set(vv)
-                qh = q.reshape(s_bucket, H, hd)
-                kh = k.reshape(s_bucket, H, hd)
-                vh = vv.reshape(s_bucket, H, hd)
-                attn = _prefill_attention(qh, kh, vh, use_flash)
-                x = x + attn.reshape(s_bucket, -1) @ params[f"wo{l}"]
-                h2 = rms(x)
-                x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]
-            last = rms(x[length - 1])
-            logits = last @ params["embed"].T
-            return kpool, vpool, jnp.argmax(logits).astype(jnp.int32)
+                with scope("kv_write"):
+                    h = rms(x)
+                    qkv = h @ params[f"wqkv{l}"]
+                    q, k, vv = jnp.split(qkv, 3, axis=-1)
+                    kpool = kpool.at[l, slots].set(k)
+                    vpool = vpool.at[l, slots].set(vv)
+                with scope("attention"):
+                    qh = q.reshape(s_bucket, H, hd)
+                    kh = k.reshape(s_bucket, H, hd)
+                    vh = vv.reshape(s_bucket, H, hd)
+                    attn = _prefill_attention(qh, kh, vh, use_flash)
+                    x = x + attn.reshape(s_bucket, -1) @ params[f"wo{l}"]
+                with scope("mlp"):
+                    h2 = rms(x)
+                    x = x + (jax.nn.relu(h2 @ params[f"w1{l}"])
+                             @ params[f"w2{l}"])
+            with scope("head"):
+                last = rms(x[length - 1])
+                logits = last @ params["embed"].T
+                nxt = jnp.argmax(logits).astype(jnp.int32)
+            return kpool, vpool, nxt
 
         return jax.jit(impl, donate_argnums=(1, 2))
 
@@ -261,32 +291,33 @@ class TinyTransformer:
         """Run prompt prefill for ONE sequence: scatter its K/V pages into
         the pool and return the first generated token (greedy). Long
         prompts take the ring-attention path."""
-        cfg = self.config
         s = len(tokens)
-        if s >= cfg.ring_threshold:
-            return self._prefill_ring(tokens, table)
-        self.kv.assert_writable(table, 0, s)
-        bucket = max(16, _next_pow2(s))
-        if bucket > 128:
-            bucket = ((s + 127) // 128) * 128  # flash wants S % 128 == 0
-        use_flash = self._use_flash()
-        key = (bucket, use_flash)
-        with self._lock:
-            fn = self._prefill_cache.get(key)
-            if fn is None:
-                fn = self._prefill_fn(bucket, use_flash)
-                self._prefill_cache[key] = fn
-        toks = np.zeros(bucket, dtype=np.int32)
-        toks[:s] = tokens
-        slots = self._slots_for(table, s, bucket)
-        from brpc_tpu.tpu.device_lane import step_dispatch
-        step_dispatch.note_launch(1)
-        kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
-                               self.kv.v_pool, toks, slots, s)
-        self.kv.update_pools(kpool, vpool)
-        first = int(nxt)
-        step_dispatch.note_host_sync()
-        return first
+        bucket = _prefill_bucket(s)
+        with _span("model.prefill", n=s, bucket=bucket):
+            if s >= self.config.ring_threshold:
+                return self._prefill_ring(tokens, table)
+            self.kv.assert_writable(table, 0, s)
+            with _span("model.prep"):
+                use_flash = self._use_flash()
+                key = (bucket, use_flash)
+                with self._lock:
+                    fn = self._prefill_cache.get(key)
+                    if fn is None:
+                        fn = self._prefill_fn(bucket, use_flash)
+                        self._prefill_cache[key] = fn
+                toks = np.zeros(bucket, dtype=np.int32)
+                toks[:s] = tokens
+                slots = self._slots_for(table, s, bucket)
+            from brpc_tpu.tpu.device_lane import step_dispatch
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
+                                       self.kv.v_pool, toks, slots, s)
+                self.kv.update_pools(kpool, vpool)
+            with _span("model.sync"):
+                first = int(nxt)
+                step_dispatch.note_host_sync()
+            return first
 
     def prefill_suffix(self, tokens: np.ndarray, table: Sequence[int],
                        start: int) -> int:
@@ -301,10 +332,12 @@ class TinyTransformer:
         s = len(tokens)
         if not 0 < start < s:
             raise ValueError(f"suffix start {start} outside (0, {s})")
-        suffix = np.asarray(tokens[start:], dtype=np.int32)
-        positions = np.arange(start, s, dtype=np.int32)
-        out = self.decode_step(suffix, positions, [table] * (s - start))
-        return int(out[-1])
+        with _span("model.prefill", n=s, start=start):
+            suffix = np.asarray(tokens[start:], dtype=np.int32)
+            positions = np.arange(start, s, dtype=np.int32)
+            out = self.decode_step(suffix, positions,
+                                   [table] * (s - start))
+            return int(out[-1])
 
     def _prefill_ring(self, tokens: np.ndarray,
                       table: Sequence[int]) -> int:
@@ -333,30 +366,33 @@ class TinyTransformer:
             return x * jax.lax.rsqrt(
                 jnp.mean(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
-        toks = np.zeros(pad, dtype=np.int32)
-        toks[:s] = tokens
-        x = p["embed"][jnp.asarray(toks)]
-        kpool, vpool = self.kv.k_pool, self.kv.v_pool
-        slots = jnp.asarray(self._slots_for(table, s, pad))
         from brpc_tpu.tpu.device_lane import step_dispatch
-        for l in range(cfg.n_layers):
-            h = rms(x)
-            qkv = h @ p[f"wqkv{l}"]
-            q, k, vv = jnp.split(qkv, 3, axis=-1)
-            kpool = kpool.at[l, slots].set(k)
-            vpool = vpool.at[l, slots].set(vv)
-            qh = q.reshape(1, pad, H, hd)
-            kh = k.reshape(1, pad, H, hd)
-            vh = vv.reshape(1, pad, H, hd)
-            step_dispatch.note_launch(1)
-            attn = ring.ring_attention(qh, kh, vh, mesh, "sp", causal=True)
-            x = x + attn.reshape(pad, -1) @ p[f"wo{l}"]
-            h2 = rms(x)
-            x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
-        self.kv.update_pools(kpool, vpool)
-        logits = rms(x[s - 1]) @ p["embed"].T
-        first = int(jnp.argmax(logits))
-        step_dispatch.note_host_sync()
+        with _span("model.launch"):   # one launch a layer, none waited for
+            toks = np.zeros(pad, dtype=np.int32)
+            toks[:s] = tokens
+            x = p["embed"][jnp.asarray(toks)]
+            kpool, vpool = self.kv.k_pool, self.kv.v_pool
+            slots = jnp.asarray(self._slots_for(table, s, pad))
+            for l in range(cfg.n_layers):
+                h = rms(x)
+                qkv = h @ p[f"wqkv{l}"]
+                q, k, vv = jnp.split(qkv, 3, axis=-1)
+                kpool = kpool.at[l, slots].set(k)
+                vpool = vpool.at[l, slots].set(vv)
+                qh = q.reshape(1, pad, H, hd)
+                kh = k.reshape(1, pad, H, hd)
+                vh = vv.reshape(1, pad, H, hd)
+                step_dispatch.note_launch(1)
+                attn = ring.ring_attention(qh, kh, vh, mesh, "sp",
+                                           causal=True)
+                x = x + attn.reshape(pad, -1) @ p[f"wo{l}"]
+                h2 = rms(x)
+                x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
+            self.kv.update_pools(kpool, vpool)
+            logits = rms(x[s - 1]) @ p["embed"].T
+        with _span("model.sync"):
+            first = int(jnp.argmax(logits))
+            step_dispatch.note_host_sync()
         return first
 
     # -------------------------------------------------------------- decode
@@ -377,34 +413,37 @@ class TinyTransformer:
         each sequence's token at its position, gather paged context, and
         return the next token per sequence (host-materialized once, here,
         not per token)."""
-        bs = self.kv.block_size
         B = len(tokens)
-        self.kv.assert_writable_batch(tables, positions)
-        b_bucket = max(2, _next_pow2(B))
-        max_blocks = max(len(t) for t in tables)
-        l_bucket = max(2, _next_pow2(max_blocks)) * bs
-        key = (b_bucket, l_bucket)
-        with self._lock:
-            fn = self._decode_cache.get(key)
-            if fn is None:
-                fn = self._decode_fn(b_bucket, l_bucket)
-                self._decode_cache[key] = fn
-        toks = np.zeros(b_bucket, dtype=np.int32)
-        toks[:B] = tokens
-        pos = np.zeros(b_bucket, dtype=np.int32)
-        pos[:B] = positions
-        slot_tables = np.zeros((b_bucket, l_bucket), dtype=np.int32)
-        for i, table in enumerate(tables):
-            slot_tables[i] = self._slots_for(table, positions[i] + 1,
-                                             l_bucket)
-        from brpc_tpu.tpu.device_lane import step_dispatch
-        step_dispatch.note_launch(1)
-        kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
-                               self.kv.v_pool, toks, pos, slot_tables)
-        self.kv.update_pools(kpool, vpool)
-        out = np.asarray(nxt[:B])
-        step_dispatch.note_host_sync()
-        return out
+        b_bucket, l_bucket = _decode_buckets(B, tables, self.kv.block_size)
+        with _span("model.decode", B=B, b_bucket=b_bucket,
+                   l_bucket=l_bucket):
+            self.kv.assert_writable_batch(tables, positions)
+            with _span("model.prep"):
+                key = (b_bucket, l_bucket)
+                with self._lock:
+                    fn = self._decode_cache.get(key)
+                    if fn is None:
+                        fn = self._decode_fn(b_bucket, l_bucket)
+                        self._decode_cache[key] = fn
+                toks = np.zeros(b_bucket, dtype=np.int32)
+                toks[:B] = tokens
+                pos = np.zeros(b_bucket, dtype=np.int32)
+                pos[:B] = positions
+                slot_tables = np.zeros((b_bucket, l_bucket), dtype=np.int32)
+                for i, table in enumerate(tables):
+                    slot_tables[i] = self._slots_for(
+                        table, positions[i] + 1, l_bucket)
+            from brpc_tpu.tpu.device_lane import step_dispatch
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
+                                       self.kv.v_pool, toks, pos,
+                                       slot_tables)
+                self.kv.update_pools(kpool, vpool)
+            with _span("model.sync"):
+                out = np.asarray(nxt[:B])
+                step_dispatch.note_host_sync()
+            return out
 
     def verify_step(self, last_tokens: Sequence[int],
                     positions: Sequence[int], tables: List[Sequence[int]],
@@ -429,13 +468,15 @@ class TinyTransformer:
         flat_pos: List[int] = []
         flat_tables: List[Sequence[int]] = []
         counts: List[int] = []
-        for t0, p0, table, d in zip(last_tokens, positions, tables, drafts):
-            row_toks = [int(t0)] + [int(x) for x in d]
-            for j, tok in enumerate(row_toks):
-                flat_tokens.append(tok)
-                flat_pos.append(int(p0) + j)
-                flat_tables.append(table)
-            counts.append(len(row_toks))
+        with _span("model.prep"):   # the rows; decode_step is the span
+            for t0, p0, table, d in zip(last_tokens, positions, tables,
+                                        drafts):
+                row_toks = [int(t0)] + [int(x) for x in d]
+                for j, tok in enumerate(row_toks):
+                    flat_tokens.append(tok)
+                    flat_pos.append(int(p0) + j)
+                    flat_tables.append(table)
+                counts.append(len(row_toks))
         out = self.decode_step(np.asarray(flat_tokens, dtype=np.int32),
                                np.asarray(flat_pos, dtype=np.int32),
                                flat_tables)

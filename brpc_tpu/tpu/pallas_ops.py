@@ -67,6 +67,7 @@ def _rmsnorm_fwd_call(x, w, eps: float = EPS, interpret: bool = None,
         out_specs=pl.BlockSpec((rows, D), lambda i: (i, 0)),
         compiler_params=params,
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w)
     return out[:N].reshape(orig_shape)
 
@@ -249,6 +250,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -496,6 +498,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, bq: int, bk: int,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_fwd_bhsd",
     )(q, k, v)
 
 
@@ -655,6 +658,7 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_fwd_folded",
     )(q, k, v)
 
 
@@ -767,6 +771,7 @@ def _flash_fwd_qgrid(q, k, v, causal: bool, bq: int, bkc: int,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_fwd_qgrid",
     )(q, k, v)
 
 
@@ -817,6 +822,7 @@ def _flash_bwd_bhsd(q, k, v, lse, do, delta, q_start, k_start,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
+        name="flash_dq",
     )(pos, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -846,6 +852,7 @@ def _flash_bwd_bhsd(q, k, v, lse, do, delta, q_start, k_start,
         ],
         compiler_params=params,
         interpret=interpret,
+        name="flash_dkv",
     )(pos, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1029,6 +1036,7 @@ def flash_attention_carry(q, k, v, m, l, acc, q_start, k_start,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_carry",
     )(pos, q, k, v, m, l, acc)
 
 
@@ -1084,6 +1092,7 @@ def _xent_forward_rows(logits, targets, block_rows: int, interpret: bool):
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n2, 1), jnp.float32),
         interpret=interpret,
+        name="softmax_xent",
     )(logits, targets.astype(jnp.int32)[:, None])
     return nll[:n, 0]
 

@@ -81,6 +81,7 @@ def _make_ring_flash(axis, n, fwd, causal, block_q, block_k, vaxes,
         l0 = _pvary(jnp.zeros((B, H, sq, 1), jnp.float32), vaxes)
         a0 = _pvary(jnp.zeros((B, H, sq, D), jnp.float32), vaxes)
 
+        @jax.named_scope("ring_fwd_hop")
         def step(i, carry):
             k_cur, v_cur, at, mt, lt = carry
             src = (my - i) % n
@@ -109,8 +110,10 @@ def _make_ring_flash(axis, n, fwd, causal, block_q, block_k, vaxes,
             else:
                 mt, lt, at = jax.vmap(jax.vmap(one_head))(qt, kt, vt, mt,
                                                           lt, at)
-            return (lax.ppermute(k_cur, axis, fwd),
-                    lax.ppermute(v_cur, axis, fwd), at, mt, lt)
+            with jax.named_scope("ring_kv_ppermute"):
+                k_nxt = lax.ppermute(k_cur, axis, fwd)
+                v_nxt = lax.ppermute(v_cur, axis, fwd)
+            return (k_nxt, v_nxt, at, mt, lt)
 
         (_, _, at, mt, lt) = lax.fori_loop(0, n, step, (k, v, a0, m0, l0))
         l_safe = jnp.where(lt == 0, 1.0, lt)
@@ -132,6 +135,7 @@ def _make_ring_flash(axis, n, fwd, causal, block_q, block_k, vaxes,
         dk0 = _pvary(jnp.zeros((B, sk0, H, D), jnp.float32), vaxes)
         dv0 = _pvary(jnp.zeros((B, sk0, H, D), jnp.float32), vaxes)
 
+        @jax.named_scope("ring_bwd_hop")
         def step(i, carry):
             k_cur, v_cur, dk_cur, dv_cur, dq_acc = carry
             src = (my - i) % n
@@ -168,10 +172,13 @@ def _make_ring_flash(axis, n, fwd, causal, block_q, block_k, vaxes,
                 0, 2, 1, 3).astype(jnp.float32)
             # the kv block AND its gradient accumulators rotate together;
             # after n hops both are home
-            return (lax.ppermute(k_cur, axis, fwd),
-                    lax.ppermute(v_cur, axis, fwd),
-                    lax.ppermute(dk_cur, axis, fwd),
-                    lax.ppermute(dv_cur, axis, fwd), dq_acc)
+            with jax.named_scope("ring_kv_ppermute"):
+                k_nxt = lax.ppermute(k_cur, axis, fwd)
+                v_nxt = lax.ppermute(v_cur, axis, fwd)
+            with jax.named_scope("ring_dkv_ppermute"):
+                dk_nxt = lax.ppermute(dk_cur, axis, fwd)
+                dv_nxt = lax.ppermute(dv_cur, axis, fwd)
+            return (k_nxt, v_nxt, dk_nxt, dv_nxt, dq_acc)
 
         (_, _, dk, dv, dq) = lax.fori_loop(0, n, step,
                                            (k, v, dk0, dv0, dq0))
@@ -245,6 +252,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
         l = _pvary(jnp.zeros((B, H, sq), dtype=jnp.float32), vaxes)
         qf = q.astype(jnp.float32)
 
+        @jax.named_scope("ring_fwd_hop")
         def step(i, carry):
             k_cur, v_cur, o, m, l = carry
             # the block visiting at hop i originated on device (my - i) % n
@@ -261,8 +269,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
                 v_cur.astype(jnp.float32), o, m, l, mask,
             )
             # rotate kv to the next neighbor (overlappable with compute)
-            k_nxt = lax.ppermute(k_cur, axis, fwd)
-            v_nxt = lax.ppermute(v_cur, axis, fwd)
+            with jax.named_scope("ring_kv_ppermute"):
+                k_nxt = lax.ppermute(k_cur, axis, fwd)
+                v_nxt = lax.ppermute(v_cur, axis, fwd)
             return (k_nxt, v_nxt, o, m, l)
 
         (_, _, o, m, l) = lax.fori_loop(0, n, step, (k, v, o, m, l))

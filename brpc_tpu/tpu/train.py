@@ -123,51 +123,55 @@ def forward(params, tokens, cfg: ModelConfig, mesh: Mesh = None,
     x = params["embed"][tokens].astype(cfg.dtype)  # [B,S,D]
     x = constrain(x, "dp", "sp", None)
     for layer in params["layers"]:
-        h = _norm(x, layer["ln1"], cfg)
-        qkv = h @ layer["wqkv"]                    # [B,S,3D]
-        qkv = qkv.reshape(B, S, 3, H, Dh)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if mesh is not None:
-            q = constrain(q, "dp", "sp", "tp", None)
-            k = constrain(k, "dp", "sp", "tp", None)
-            v = constrain(v, "dp", "sp", "tp", None)
-            att = ring_attention(q, k, v, mesh, axis="sp", causal=causal,
-                                 batch_axis="dp", head_axis="tp",
-                                 use_flash=cfg.use_flash_attention)
-        elif cfg.use_flash_attention:
-            from brpc_tpu.tpu.pallas_ops import flash_attention_mha
+        with jax.named_scope("layer"):   # metadata only
+            h = _norm(x, layer["ln1"], cfg)
+            qkv = h @ layer["wqkv"]                    # [B,S,3D]
+            qkv = qkv.reshape(B, S, 3, H, Dh)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            if mesh is not None:
+                q = constrain(q, "dp", "sp", "tp", None)
+                k = constrain(k, "dp", "sp", "tp", None)
+                v = constrain(v, "dp", "sp", "tp", None)
+                att = ring_attention(q, k, v, mesh, axis="sp", causal=causal,
+                                     batch_axis="dp", head_axis="tp",
+                                     use_flash=cfg.use_flash_attention)
+            elif cfg.use_flash_attention:
+                from brpc_tpu.tpu.pallas_ops import flash_attention_mha
 
-            # [B,S,H,Dh] -> [B,H,S,Dh] for the per-head kernel
-            att = flash_attention_mha(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=causal,
-            ).transpose(0, 2, 1, 3).astype(cfg.dtype)
-        else:
-            from brpc_tpu.tpu.ring import full_attention_reference
+                # [B,S,H,Dh] -> [B,H,S,Dh] for the per-head kernel
+                att = flash_attention_mha(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), causal=causal,
+                ).transpose(0, 2, 1, 3).astype(cfg.dtype)
+            else:
+                from brpc_tpu.tpu.ring import full_attention_reference
 
-            att = full_attention_reference(q, k, v, causal=causal)
-        att = att.reshape(B, S, cfg.d_model)
-        x = x + att @ layer["wo"]
-        x = constrain(x, "dp", "sp", None)
-        h = _norm(x, layer["ln2"], cfg)
-        x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
-        x = constrain(x, "dp", "sp", None)
-    x = _norm(x, params["ln_f"], cfg)
-    logits = x @ params["head"]
-    return constrain(logits, "dp", "sp", None)
+                att = full_attention_reference(q, k, v, causal=causal)
+            att = att.reshape(B, S, cfg.d_model)
+            x = x + att @ layer["wo"]
+            x = constrain(x, "dp", "sp", None)
+            h = _norm(x, layer["ln2"], cfg)
+            x = x + jax.nn.gelu(h @ layer["w1"]) @ layer["w2"]
+            x = constrain(x, "dp", "sp", None)
+    with jax.named_scope("head"):
+        x = _norm(x, params["ln_f"], cfg)
+        logits = x @ params["head"]
+        return constrain(logits, "dp", "sp", None)
 
 
 def loss_fn(params, batch, cfg: ModelConfig, mesh: Mesh = None):
     tokens, targets = batch
     logits = forward(params, tokens, cfg, mesh).astype(jnp.float32)
-    if cfg.use_fused_xent and mesh is None:
-        from brpc_tpu.tpu.pallas_ops import softmax_xent
+    with jax.named_scope("loss"):
+        if cfg.use_fused_xent and mesh is None:
+            from brpc_tpu.tpu.pallas_ops import softmax_xent
 
-        B, S, V = logits.shape
-        return softmax_xent(logits.reshape(B * S, V), targets.reshape(-1))
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+            B, S, V = logits.shape
+            return softmax_xent(logits.reshape(B * S, V),
+                                targets.reshape(-1))
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def sgd_train_step(params, batch, cfg: ModelConfig, mesh: Mesh = None,
@@ -175,8 +179,9 @@ def sgd_train_step(params, batch, cfg: ModelConfig, mesh: Mesh = None,
     """One full training step (fwd+bwd+update). GSPMD inserts the dp-psum
     for gradients and tp-psums for row-parallel matmuls automatically."""
     loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg, mesh)
-    params = jax.tree_util.tree_map(
-        lambda p, g: p - lr * g.astype(p.dtype), params, grads)
+    with jax.named_scope("update"):
+        params = jax.tree_util.tree_map(
+            lambda p, g: p - lr * g.astype(p.dtype), params, grads)
     return params, loss
 
 

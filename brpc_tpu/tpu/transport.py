@@ -419,7 +419,7 @@ class PeerWindow:
             # adaptive spin before the locked park: under streaming-parse
             # credit return the refill usually lands within the spin
             # budget, and winning here skips the full park/notify round
-            prev_ph = _prof.set_phase("credit_wait")
+            prev_ph = _prof.set_phase("rpc.credit_wait")
             try:
                 _window_spin.spin(lambda: bool(self._free) or self._closed)
             finally:
@@ -430,7 +430,7 @@ class PeerWindow:
                 left = deadline - _time.monotonic()
                 if left <= 0:
                     return None
-                prev_ph = _prof.set_phase("credit_wait")
+                prev_ph = _prof.set_phase("rpc.credit_wait")
                 try:
                     self._cond.wait(left)
                 finally:
@@ -866,7 +866,7 @@ class TpuEndpoint:
             self._send_lock.acquire()
         # profiler phase marker: samples landing in the copy/frame loops
         # attribute to "send"; credit stalls re-stamp "credit_wait" inside
-        prev_ph = _prof.set_phase("send")
+        prev_ph = _prof.set_phase("rpc.send")
         if on_main_lane:
             try:
                 if self._failed:
@@ -1376,7 +1376,7 @@ class TpuEndpoint:
             return errors.EFAILEDSOCKET
         total = sum(ln for _, ln in segs)
         with self._send_lock:
-            prev_ph = _prof.set_phase("send")
+            prev_ph = _prof.set_phase("rpc.send")
             try:
                 if self._failed:
                     return errors.EFAILEDSOCKET

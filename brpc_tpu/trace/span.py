@@ -9,7 +9,8 @@ ring — the /rpcz surface is identical, the storage budget explicit).
 
 Beyond the reference, spans carry a **phase timeline**: typed duration
 marks (:data:`PHASE_NAMES` — queue/parse/credit_wait/send/batch_wait/
-execute/respond) accumulated by the layers a request crosses, plus a
+execute/respond, and the serving plane's serving_queue/prefill/decode)
+accumulated by the layers a request crosses, plus a
 bounded list of structured **events** (credit stalls, send quanta, healer
 dials, epoch restarts, batch flushes). Durations are measured on the
 monotonic clock (``time.monotonic_ns``); the wall clock is kept only for
@@ -44,7 +45,9 @@ PHASE_NAMES = ("queue_us", "parse_us", "credit_wait_us", "send_us",
                "batch_wait_us", "execute_us", "respond_us",
                # serving plane: prompt prefill and the request's share of
                # each fused decode step, stamped by the engine's step loop
-               "prefill_us", "decode_us")
+               "prefill_us", "decode_us",
+               # submit to admission in the serving engine's queue
+               "serving_queue_us")
 
 # Hard cap on structured events per span: a 16MB streaming send emits one
 # event per pipeline quantum, which is bounded, but a pathological retry

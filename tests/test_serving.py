@@ -559,9 +559,17 @@ def test_serving_corpus_replays_and_phases_hold(tmp_path):
         with_phases = [s for s in spans
                        if "prefill_us" in s.phases and "decode_us" in s.phases]
         assert with_phases, "no replayed span carries the engine phases"
+        # the wait in the engine's queue at twice the recorded rate IS the
+        # open-loop queueing noise that must not flake the gate (on a
+        # loaded CPU it passes the floor), and the recorded corpus predates
+        # the phase; tests/test_serving_spans.py holds it to the engine's
+        # own counters
+        docs = [s.to_dict() for s in _span.recent_spans(200)]
+        assert any("serving_queue_us" in d["phases"] for d in docs)
+        for d in docs:
+            d["phases"].pop("serving_queue_us", None)
         replayed = tmp_path / "replayed.json"
-        replayed.write_text(json.dumps(
-            {"spans": [s.to_dict() for s in _span.recent_spans(200)]}))
+        replayed.write_text(json.dumps({"spans": docs}))
         # p50 + 50ms floor: open-loop queueing noise must not flake the gate
         rc = trace_diff.main([CORPUS, str(replayed),
                               "--percentile", "50",
