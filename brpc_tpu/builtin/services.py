@@ -712,6 +712,7 @@ def serving_service(server, http: HttpMessage):
                 f"tokens={pfx['hit_tokens']} "
                 f"inserted={pfx['inserted_blocks']} "
                 f"evicted={pfx['evicted_blocks']} "
+                f"evict_scanned={pfx['evict_scanned']} "
                 f"hit_ratio={pfx['hit_ratio']:.2f}"
                 + ("" if pfx.get("enabled", True) else " (disabled)"))
         # speculative decoding: draft/verify economics — how many tokens

@@ -32,6 +32,18 @@ reject with EOVERCROWDED first asks the tree to give blocks back
 tree only ever *releases* holds, it cannot defer a rejection the
 watermark would still make.
 
+The victim is always the evictable leaf with the lowest stamp. Finding it
+never walks the tree: the tree keeps an index of its leaves, ordered by
+stamp, up to date where it changes (commit's insert, fork's and commit's
+restamp, eviction exposing a parent, clear). Every stamping stamps one
+root-to-node path with a fresh tick, so a node's stamp never exceeds its
+parent's and no two leaves share one: the order is total, and a stamped
+leaf always moves to the index's end. Whether a leaf is evictable
+(``kv.block_ref == 1``) changes outside the tree, when a forked sequence
+frees its chain, so it is tested when a victim is chosen: the scan runs
+from the oldest leaf past the shared ones, and ``evict_scanned`` counts
+the leaves it looked at (one an evicted block where nothing is shared).
+
 **Sharded mode** (:class:`ShardedPrefixCache`): one tree per dp shard,
 each over its shard's ledger pool. Placement is prefix-hash routed —
 ``prefix_route_key`` folds the first cached-block-aligned window of
@@ -44,6 +56,7 @@ shard client-side.
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left, insort
 from typing import Dict, List, Optional, Tuple
 
 from brpc_tpu import fault as _fault
@@ -119,16 +132,34 @@ class PrefixCache:
         self._root = _Node((), -1, None)
         self._tick = 0  # monotonic LRU clock (stamps, not wall time)
         self._nodes = 0
+        # the leaves, oldest first: their stamps sorted (unique among
+        # leaves, see the module docstring) and the leaf of each stamp
+        self._leaf_stamps: List[int] = []
+        self._leaf_of: Dict[int, _Node] = {}
         self.hit_seqs = 0
         self.miss_seqs = 0
         self.hit_blocks = 0
         self.hit_tokens = 0
         self.inserted_blocks = 0
         self.evicted_blocks = 0
+        self.evict_scanned = 0  # leaves examined while choosing victims
 
     @staticmethod
     def enabled() -> bool:
         return bool(_flags.get("serving_prefix_cache_enabled"))
+
+    # ---------------------------------------------------------- leaf index
+    def _index_leaf_locked(self, node: _Node) -> None:
+        """``node`` is a leaf now: it enters the index at its stamp (the
+        end, where it has just been stamped with the current tick)."""
+        insort(self._leaf_stamps, node.stamp)
+        self._leaf_of[node.stamp] = node
+
+    def _unindex_leaf_locked(self, node: _Node) -> None:
+        """``node`` stops being the leaf of its stamp: it is evicted, or
+        about to be stamped again or to get a child."""
+        del self._leaf_stamps[bisect_left(self._leaf_stamps, node.stamp)]
+        del self._leaf_of[node.stamp]
 
     # ------------------------------------------------------------- matching
     def _walk_locked(self, tokens) -> List[_Node]:
@@ -171,8 +202,13 @@ class PrefixCache:
                 g_serving_prefix_miss_seqs.put(1)
                 return 0
             self._tick += 1
+            last = chain[-1]
+            if not last.children:
+                self._unindex_leaf_locked(last)
             for n in chain:
                 n.stamp = self._tick
+            if not last.children:
+                self._index_leaf_locked(last)
             blocks = [n.block for n in chain]
             matched = len(blocks) * self.kv.config.block_size
             self.kv.adopt_sequence(seq_id, blocks, matched)
@@ -211,8 +247,15 @@ class PrefixCache:
                     node.children[key] = child
                     self._nodes += 1
                     inserted += 1
+                elif not child.children:
+                    # the one indexed leaf a path can hold: restamped
+                    # here, and a parent from the next block on
+                    self._unindex_leaf_locked(child)
                 child.stamp = self._tick
                 node = child
+            # the deepest node of the path is its only leaf, if it is one
+            if node is not self._root and not node.children:
+                self._index_leaf_locked(node)
         if inserted:
             self.inserted_blocks += inserted
             g_serving_prefix_inserted_blocks.put(inserted)
@@ -220,30 +263,30 @@ class PrefixCache:
         return inserted
 
     # ------------------------------------------------------------ eviction
-    def _evictable_leaves_locked(self) -> List[_Node]:
-        """Leaves whose block the tree is the SOLE owner of (refcount 1):
-        chains a live sequence still shares are never stolen from."""
-        out: List[_Node] = []
-        stack = list(self._root.children.values())
-        while stack:
-            n = stack.pop()
-            if n.children:
-                stack.extend(n.children.values())
-            elif self.kv.block_ref(n.block) == 1:
-                out.append(n)
-        return out
+    def _take_victim_locked(self) -> Optional[_Node]:
+        """Take the oldest leaf whose block the tree is the SOLE owner of
+        (refcount 1) out of the index: chains a live sequence still
+        shares are passed over, never stolen from."""
+        for stamp in self._leaf_stamps:
+            self.evict_scanned += 1
+            node = self._leaf_of[stamp]
+            if self.kv.block_ref(node.block) == 1:
+                self._unindex_leaf_locked(node)
+                return node
+        return None
 
     def _evict_locked(self, nblocks: int) -> int:
         """LRU-evict up to ``nblocks`` leaf blocks; freeing a leaf can
-        expose its parent, so the candidate set is recomputed as the
-        walk unwinds."""
+        expose its parent, which enters the index at its own stamp."""
         evicted = 0
         while evicted < nblocks:
-            leaves = self._evictable_leaves_locked()
-            if not leaves:
+            victim = self._take_victim_locked()
+            if victim is None:
                 break
-            victim = min(leaves, key=lambda n: n.stamp)
-            del victim.parent.children[victim.key]
+            parent = victim.parent
+            del parent.children[victim.key]
+            if not parent.children and parent is not self._root:
+                self._index_leaf_locked(parent)
             self._nodes -= 1
             self.kv.release_block(victim.block)
             evicted += 1
@@ -298,6 +341,8 @@ class PrefixCache:
                 released += 1
             self._root.children.clear()
             self._nodes = 0
+            self._leaf_stamps.clear()
+            self._leaf_of.clear()
         return released
 
     def snapshot(self) -> Dict[str, object]:
@@ -315,6 +360,7 @@ class PrefixCache:
             "hit_tokens": self.hit_tokens,
             "inserted_blocks": self.inserted_blocks,
             "evicted_blocks": self.evicted_blocks,
+            "evict_scanned": self.evict_scanned,
             "hit_ratio": hits / total if total else 0.0,
         }
 
@@ -388,7 +434,7 @@ class ShardedPrefixCache:
         agg = {k: sum(s[k] for s in shards)
                for k in ("nodes", "blocks", "hit_seqs", "miss_seqs",
                          "hit_blocks", "hit_tokens", "inserted_blocks",
-                         "evicted_blocks")}
+                         "evicted_blocks", "evict_scanned")}
         total = agg["hit_seqs"] + agg["miss_seqs"]
         agg["hit_ratio"] = agg["hit_seqs"] / total if total else 0.0
         agg["enabled"] = self.enabled()

@@ -1,6 +1,6 @@
 """Radix prefix cache: copy-on-write KV reuse across the serving plane.
 
-Four layers, cheapest first:
+Five layers, cheapest first:
 
 * the ledger's cache-hold surface — retain/release/adopt, copy-on-write
   block splits, the armed ``assert_writable`` range audit, and
@@ -9,6 +9,10 @@ Four layers, cheapest first:
   (insert-or-share), block-aligned matching capped at a proper prefix,
   LRU eviction over refcount-1 chains ONLY, watermark trim, the
   admission-pressure release valve, and the kill-switch flag;
+* the evictable-leaf index against the plain reference it replaced (a
+  walk of the whole tree for every evicted block): the same victims in
+  the same order over random interleavings, and a cost counted in leaves
+  examined, not read from a clock;
 * prefix-hash routing — ``prefix_route_key`` semantics and the fleet
   contract that client-side :class:`GenerateRouter` and server-side
   :class:`ShardedPrefixCache` place the same prompt on the same shard;
@@ -19,6 +23,8 @@ Four layers, cheapest first:
   proving zero leaked blocks under an armed ledger.
 """
 
+import itertools
+import random
 import threading
 import types
 
@@ -39,6 +45,7 @@ from brpc_tpu.serving import (
     build_prefix_cache,
     prefix_route_key,
 )
+from brpc_tpu.serving.kv_cache import KVCacheFull
 from brpc_tpu.shard.plane import shard_for
 
 # the committed replay corpus's schedule: synth prompts are arange(1, n+1),
@@ -310,6 +317,237 @@ class TestPrefixRadixTree:
     def test_evict_fault_point_is_registered(self):
         points = {p["point"] for p in fault.snapshot()}
         assert "serving.prefix.evict" in points
+
+
+# ------------------------------------------- the leaf index against the walk
+class _WalkingPrefixCache(PrefixCache):
+    """The plain reference: eviction as it was before the leaf index (PR
+    26), a depth-first walk of the whole tree for every evicted block and
+    ``min`` over the refcount-1 leaves it finds. It keeps no index."""
+
+    def _index_leaf_locked(self, node):
+        pass
+
+    def _unindex_leaf_locked(self, node):
+        pass
+
+    def _evictable_leaves_locked(self):
+        out = []
+        stack = list(self._root.children.values())
+        while stack:
+            n = stack.pop()
+            if n.children:
+                stack.extend(n.children.values())
+            elif self.kv.block_ref(n.block) == 1:
+                out.append(n)
+        return out
+
+    def _evict_locked(self, nblocks):
+        evicted = 0
+        while evicted < nblocks:
+            leaves = self._evictable_leaves_locked()
+            if not leaves:
+                break
+            victim = min(leaves, key=lambda n: n.stamp)
+            del victim.parent.children[victim.key]
+            self._nodes -= 1
+            self.kv.release_block(victim.block)
+            evicted += 1
+        if evicted:
+            self.evicted_blocks += evicted
+        return evicted
+
+
+def _log_releases(pool, log, shard=0):
+    """Every tree hold ``pool`` drops from here on, in order."""
+    inner = pool.release_block
+
+    def release_block(block):
+        log.append((shard, block))
+        return inner(block)
+
+    pool.release_block = release_block
+
+
+def _tree_leaves(tree):
+    """The tree's leaves by a walk, oldest first (what the index holds)."""
+    out, stack = [], list(tree._root.children.values())
+    while stack:
+        n = stack.pop()
+        stack.extend(n.children.values())
+        if not n.children:
+            out.append(n)
+    return sorted(out, key=lambda n: n.stamp)
+
+
+def _assert_index_whole(tree):
+    leaves = _tree_leaves(tree)
+    assert tree._leaf_stamps == [n.stamp for n in leaves]
+    assert [tree._leaf_of[s] for s in tree._leaf_stamps] == leaves
+
+
+def _counters(snap):
+    """A snapshot without ``evict_scanned``, the one counter that tells
+    the index from the walk."""
+    out = {k: v for k, v in snap.items() if k != "evict_scanned"}
+    if "shards" in out:
+        out["shards"] = [_counters(s) for s in out["shards"]]
+    return out
+
+
+def _drive(kv, cache, seed, steps, bs, whole=()):
+    """One random interleaving of what an engine does to its prefix cache
+    (admission that may evict, fork or cold allocation, commit, abort)
+    plus the eviction fault, a bare ``evict_for_admission`` and ``clear``,
+    drawn from ``seed`` alone: two pools that evict alike see the same
+    calls. Prompts share blocks level by level, so the tree branches and
+    chains are forked while they are cached. Ends with every sequence
+    finished; returns the snapshot before the last ``clear``."""
+    rng = random.Random(seed)
+    live, ids = [], itertools.count(1)
+
+    def prompt():
+        toks = []
+        for level in range(rng.randint(1, 9)):
+            toks += [10 * level + min(int(rng.expovariate(1.0)), 4)] * bs
+        return toks + [7] * rng.randint(1, bs)
+
+    def request():
+        sid, toks = next(ids), prompt()
+        shard = cache.route_shard(toks)
+        need = len(toks) - cache.match_len(toks)
+        if not kv.can_admit(need, route_key=sid, shard=shard) \
+                and not cache.evict_for_admission(need, shard=shard,
+                                                  route_key=sid):
+            return
+        try:
+            if cache.fork(sid, toks):
+                kv.extend_sequence(sid, len(toks))
+            elif shard is not None:
+                kv.alloc_sequence(sid, len(toks), shard=shard)
+            else:
+                kv.alloc_sequence(sid, len(toks))
+        except KVCacheFull:
+            kv.free_sequence(sid)
+            return
+        live.append((sid, toks))
+
+    def finish(commit):
+        sid, toks = live.pop(rng.randrange(len(live)))
+        if commit:
+            cache.commit(sid, toks, len(toks))
+        kv.free_sequence(sid)
+
+    for _ in range(steps):
+        op = rng.random()
+        if op < 0.50 or not live:
+            request()
+        elif op < 0.85:
+            finish(commit=op < 0.80)
+        elif op < 0.92:
+            fault.arm("serving.prefix.evict", mode="oneshot",
+                      blocks=rng.randint(1, 6))
+            request()
+            fault.disarm_all()
+        elif op < 0.99:
+            cache.evict_for_admission(bs * rng.randint(1, 40))
+        else:
+            cache.clear()
+        for tree in whole:
+            _assert_index_whole(tree)
+    while live:
+        finish(commit=True)
+    snap = cache.snapshot()
+    cache.clear()
+    kv.assert_idle("after the drive's clear")
+    return snap
+
+
+def _ledger(num_blocks, bs):
+    kv = PagedKVCache(KVCacheConfig(block_size=bs, num_blocks=num_blocks),
+                      1, 8, device_pools=False)
+    kv._check = True
+    return kv
+
+
+class TestLeafIndexAgainstTheWalk:
+    @pytest.mark.parametrize("num_blocks", [12, 40, 160])
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_same_victims_in_the_same_order(self, seed, num_blocks,
+                                            fault_enabled):
+        bs, steps = 4, 1200
+        logs, snaps = [], []
+        for cls in (PrefixCache, _WalkingPrefixCache):
+            kv, log = _ledger(num_blocks, bs), []
+            _log_releases(kv, log)
+            tree = cls(kv)
+            snaps.append(_drive(kv, tree, seed, steps, bs,
+                                whole=[tree] if cls is PrefixCache else []))
+            logs.append(log)
+        assert logs[0] == logs[1]
+        assert _counters(snaps[0]) == _counters(snaps[1])
+        assert snaps[0]["evicted_blocks"] > 5 * num_blocks  # it did churn
+        # leaves were passed over while a forked sequence shared them
+        assert snaps[0]["evict_scanned"] > snaps[0]["evicted_blocks"]
+
+    def test_same_victims_through_the_sharded_cache(self, fault_enabled):
+        bs, logs, snaps = 4, [], []
+        for cls in (PrefixCache, _WalkingPrefixCache):
+            kv = ShardedKVCache(KVCacheConfig(block_size=bs, num_blocks=24),
+                                1, 8)
+            kv._check = True
+            try:
+                log = []
+                for i, pool in enumerate(kv.pools):
+                    _log_releases(pool, log, shard=i)
+                spc = ShardedPrefixCache(kv)
+                spc.trees = [cls(pool, shard=i)
+                             for i, pool in enumerate(kv.pools)]
+                snaps.append(_drive(kv, spc, 5, 1500, bs))
+                logs.append(log)
+            finally:
+                kv.close()
+        assert logs[0] == logs[1]
+        assert _counters(snaps[0]) == _counters(snaps[1])
+        assert {shard for shard, _b in logs[0]} == {0, 1}
+        assert snaps[0]["evict_scanned"] == sum(
+            s["evict_scanned"] for s in snaps[0]["shards"]) > 0
+
+    def test_an_eviction_costs_in_leaves_not_in_blocks(self):
+        """The engine's finish path (commit, then free) on the benchmark's
+        pool and prompt lengths, nothing shared: the cost of choosing a
+        victim is counted in leaves examined, so no clock is read."""
+        rng = random.Random(27)
+        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=2048),
+                          1, 8, device_pools=False)
+        tree = PrefixCache(kv)
+        mark = float(_flags.get("serving_prefix_evict_watermark"))
+
+        def finish_one(sid):
+            n = rng.randint(384, 1536)
+            _commit_chain(kv, tree, sid,
+                          [rng.randrange(1, 49152) for _ in range(n)])
+
+        sid = 0
+        while not tree.evicted_blocks:   # fill the pool to the watermark
+            sid += 1
+            finish_one(sid)
+        before = tree.snapshot()
+        for _ in range(50):
+            sid += 1
+            finish_one(sid)
+        after = tree.snapshot()
+        chains = len(_tree_leaves(tree))
+        assert after["nodes"] > 1500 and 20 < chains < 40
+        assert kv.used_ratio() <= mark
+        evicted = after["evicted_blocks"] - before["evicted_blocks"]
+        scanned = after["evict_scanned"] - before["evict_scanned"]
+        assert evicted > 2000
+        assert scanned / evicted < chains + 1    # the walk: about 1640
+        assert scanned == evicted   # ordered, nothing shared: one each
+        tree.clear()
+        kv.assert_idle()
+
 
 
 # --------------------------------------------------- prefix-hash routing
