@@ -704,6 +704,18 @@ def serving_service(server, http: HttpMessage):
                    f"watermark={kv['watermark']:.0%}, "
                    f"block_size={kv['block_size']}, "
                    f"sequences={kv['sequences']}")
+        if "slots" in kv:
+            # a hybrid manager: the line above counts the full layer's
+            # pages; here each further kind of per-sequence state
+            win, slots = kv["window"], kv["slots"]
+            out.append(
+                f"  kv window: {win['used']}/{win['total']} blocks in rings "
+                f"of {win['ring_blocks']} "
+                f"(recycled={kv['window_blocks_recycled']}), "
+                f"slots: {slots['used']}/{slots['total']} recurrent, "
+                f"cache_bytes={kv['cache_bytes']} "
+                f"(peak {kv['cache_bytes_peak']} at "
+                f"{kv['tokens_at_peak']} tokens)")
         pfx = s.get("prefix")
         if pfx:
             out.append(

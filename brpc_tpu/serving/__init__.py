@@ -42,6 +42,10 @@ mechanism:
   gradient ceiling driven by the queue-wait series ring, shedding
   best-effort lanes first so a protected tenant survives an overload
   wave EOVERCROWDED-retriable instead of everyone drowning together.
+- :mod:`brpc_tpu.serving.hybrid_model` + :mod:`brpc_tpu.serving.hybrid_cache`
+  — a SambaY decoder-hybrid-decoder (Mamba-1, window and full differential
+  attention, gated memory units over one shared K/V) and its cache manager:
+  full-layer pages, window rings and recurrent slots under one ledger.
 - :mod:`brpc_tpu.serving.speculative` — the speculative-decoding draft
   lane: host-side prompt-lookup drafting (zero weights, zero device
   work, lint-pinned) feeding the model's one fused ``verify_step``
@@ -81,6 +85,13 @@ def __getattr__(name):
     if name == "MigrationReceiver":
         from brpc_tpu.serving.migration import MigrationReceiver
         return MigrationReceiver
+    # the hybrid (state-space + window + full attention) lane, as lazily
+    if name in ("HybridCacheConfig", "HybridStateCache", "HybridTable"):
+        from brpc_tpu.serving import hybrid_cache
+        return getattr(hybrid_cache, name)
+    if name in ("SambaYConfig", "SambaYModel"):
+        from brpc_tpu.serving import hybrid_model
+        return getattr(hybrid_model, name)
     raise AttributeError(name)
 
 
@@ -92,6 +103,8 @@ __all__ = [
     "prefix_route_key",
     "LlmServingService", "ShardedLlmChannel",
     "KVMigrator", "MigrationReceiver",
+    "HybridCacheConfig", "HybridStateCache", "HybridTable",
+    "SambaYConfig", "SambaYModel",
     "AdaptiveK", "accept_longest_prefix", "draft_tokens",
     "QosConfig", "QosGovernor", "QosLimiter", "TenantScheduler",
 ]

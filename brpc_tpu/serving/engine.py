@@ -216,6 +216,17 @@ class ServingEngine:
         self.model = model
         self.kv = kv if kv is not None else model.kv
         self.config = config or EngineConfig()
+        if getattr(self.kv, "recurrent_state", False):
+            # a state overwritten every token cannot be rolled back past a
+            # rejected draft, and nothing moves it between engines yet
+            if self.config.spec_k > 0:
+                raise ValueError(
+                    "speculative decoding (spec_k > 0) over a cache manager "
+                    "with recurrent state is not supported")
+            if self.config.role != ROLE_BOTH:
+                raise ValueError(
+                    f"role {self.config.role!r} migrates sequences, which a "
+                    f"cache manager with recurrent state does not support")
         # radix prefix cache: None auto-builds over the pool (gated per
         # admission by the serving_prefix_cache_enabled flag), False
         # disables outright (cold A/B lanes, oracle reference engines)
@@ -302,6 +313,9 @@ class ServingEngine:
         prefill-role engine hands every prefilled chain to it from the
         step loop; ANY engine with one drains live sequences to the
         destination on stop() instead of aborting them from scratch."""
+        if getattr(self.kv, "recurrent_state", False):
+            raise ValueError("migration over a cache manager with recurrent "
+                             "state is not supported")
         self.migrator = migrator
         return self
 

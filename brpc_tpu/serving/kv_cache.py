@@ -42,6 +42,7 @@ free stay device-local: they only ever touch the owning shard's ledger.
 
 from __future__ import annotations
 
+import collections
 import threading
 import weakref
 from typing import Dict, List, Optional, Tuple
@@ -120,7 +121,10 @@ class PagedKVCache:
             self.v_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype)
             self.k_handle, _ = self.store.adopt(self.k_pool)
             self.v_handle, _ = self.store.adopt(self.v_pool)
-        self._free: List[int] = list(range(config.num_blocks, 0, -1))
+        # the block that has lain free longest goes out first, so a freed
+        # sequence's rows stay readable for as long as the pool's slack
+        # allows (serving/hybrid_cache.py: ``retired``)
+        self._free = collections.deque(range(1, config.num_blocks + 1))
         self._ref: Dict[int, int] = {}
         self._tables: Dict[int, List[int]] = {}
         self._seq_len: Dict[int, int] = {}
@@ -193,7 +197,7 @@ class PagedKVCache:
         if not self._free:
             raise KVCacheFull(
                 f"kv pool exhausted ({self.config.num_blocks} blocks)")
-        b = self._free.pop()
+        b = self._free.popleft()
         self._ref[b] = 1
         return b
 

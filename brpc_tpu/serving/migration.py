@@ -385,6 +385,9 @@ class MigrationReceiver:
             sid = meta.stream_settings.stream_id
         if not sid:
             return reject("migration needs a record stream")
+        if getattr(kv, "recurrent_state", False):
+            return reject("this shard's cache manager holds recurrent "
+                          "state: no migration into it")
         if (request.block_size != kv.block_size
                 or request.layers != kv.layers
                 or request.kv_dim != kv.kv_dim):
