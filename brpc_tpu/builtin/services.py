@@ -727,6 +727,17 @@ def serving_service(server, http: HttpMessage):
                 f"evict_scanned={pfx['evict_scanned']} "
                 f"hit_ratio={pfx['hit_ratio']:.2f}"
                 + ("" if pfx.get("enabled", True) else " (disabled)"))
+        # decode attention: which path the launches took, and how much of
+        # the padded bucket (what the gather copies) the rows' lengths
+        # cover (what the paged kernel reads)
+        dec = s.get("decode")
+        if dec:
+            out.append(
+                f"  decode: launches paged={dec['decode_launches_paged']} "
+                f"gather={dec['decode_launches_gather']} "
+                f"pages live={dec['decode_pages_live']} "
+                f"bucket={dec['decode_pages_bucket']} "
+                f"live_share={dec['live_share']:.2f}")
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

@@ -1189,6 +1189,19 @@ class ServingEngine:
             self._finish(seq, code, reason)
 
     # ------------------------------------------------------------ visibility
+    def _decode_snapshot(self) -> Optional[Dict[str, object]]:
+        """The model's decode-attention counters (serving/model.py), for
+        a model that keeps them: launches by path, and pages the rows'
+        lengths covered against pages of the padded buckets."""
+        counters = getattr(self.model, "decode_counters", None)
+        if counters is None:
+            return None
+        out = dict(counters)
+        out["live_share"] = round(
+            out["decode_pages_live"] / max(1, out["decode_pages_bucket"]),
+            4)
+        return out
+
     def snapshot(self) -> Dict[str, object]:
         kv = self.kv.snapshot()
         occ = (self._occupancy_sum / self.steps) if self.steps else 0.0
@@ -1240,6 +1253,7 @@ class ServingEngine:
             "kv": kv,
             "prefix": (self.prefix.snapshot()
                        if self.prefix is not None else None),
+            "decode": self._decode_snapshot(),
             "spec": (dict(self.spec_stats.snapshot(),
                           k_max=self.config.spec_k)
                      if self.spec_stats is not None else None),

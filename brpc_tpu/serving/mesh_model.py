@@ -35,7 +35,8 @@ import numpy as np
 from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.serving.kv_cache import ShardedKVCache
 from brpc_tpu.serving.model import (ModelConfig, TinyTransformer,
-                                    _decode_body, _decode_buckets,
+                                    _block_tables, _decode_body,
+                                    _decode_buckets,
                                     _prefill_attention, _prefill_bucket,
                                     _rms)
 
@@ -192,7 +193,7 @@ class MeshTransformer(TinyTransformer):
         return first
 
     # -------------------------------------------------------------- decode
-    def _decode_fn(self, b_bucket: int, l_bucket: int):
+    def _decode_fn(self, b_bucket: int, l_table: int, paged: bool):
         import jax
         from jax.sharding import PartitionSpec as P
 
@@ -200,13 +201,13 @@ class MeshTransformer(TinyTransformer):
 
         cfg = self.config
 
-        def local(params, kpools, vpools, tokens, positions, slot_tables):
+        def local(params, kpools, vpools, tokens, positions, block_tables):
             # each dp group decodes its own sub-batch from its own pool
             # slice; sp/tp devices in the group replicate the compute so
             # the whole mesh stays inside ONE program launch
             kp, vp, nxt = _decode_body(
                 cfg, params, kpools[0], vpools[0], tokens[0], positions[0],
-                slot_tables[0], b_bucket, l_bucket)
+                block_tables[0], b_bucket, l_table, paged)
             return kp[None], vp[None], nxt[None]
 
         sm = shard_map_norep(
@@ -243,28 +244,23 @@ class MeshTransformer(TinyTransformer):
                 groups: List[List[int]] = [[] for _ in range(dp)]
                 for i, t in enumerate(tables):
                     groups[getattr(t, "shard", 0)].append(i)
-                key = (b_bucket, l_bucket)
-                with self._lock:
-                    fn = self._decode_cache.get(key)
-                    if fn is None:
-                        fn = self._decode_fn(b_bucket, l_bucket)
-                        self._decode_cache[key] = fn
+                fn, width = self._decode_program(b_bucket, l_bucket,
+                                                 positions, groups=dp)
                 toks = np.zeros((dp, b_bucket), dtype=np.int32)
                 pos = np.zeros((dp, b_bucket), dtype=np.int32)
-                slot_tables = np.zeros((dp, b_bucket, l_bucket),
-                                       dtype=np.int32)
                 for shard, g in enumerate(groups):
                     for j, i in enumerate(g):
                         toks[shard, j] = tokens[i]
                         pos[shard, j] = positions[i]
-                        slot_tables[shard, j] = self._slots_for(
-                            tables[i], positions[i] + 1, l_bucket)
+                block_tables = np.stack([
+                    _block_tables([tables[i] for i in g], b_bucket, width)
+                    for g in groups])
             from brpc_tpu.tpu.device_lane import step_dispatch
             with _span("model.launch"):
                 step_dispatch.note_launch(1)
                 kpools, vpools, nxt = fn(self._params, self.kv.k_pools,
                                          self.kv.v_pools, toks, pos,
-                                         slot_tables)
+                                         block_tables)
                 self.kv.update_pools(kpools, vpools)
             with _span("model.sync"):
                 flat = np.asarray(nxt)

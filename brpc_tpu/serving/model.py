@@ -8,11 +8,15 @@ device-side mechanism in the repo carries weight on the request path:
   (``adopt``); compute looks them up by handle and unpacks device-side,
   so the serving plane owns no host-resident copy.
 - **Paged KV** — prefill scatters K/V into the :class:`PagedKVCache`
-  pools at block-table slots; decode gathers context pages and appends
-  the new token's K/V, all inside ONE jitted program per engine step
-  (donated pools → in-place updates, one dispatch for the whole mixed
-  batch — the op-coalescing trick the device lane's dispatch thread plays,
-  applied to the decode path).
+  pools at block-table slots; decode appends the new token's K/V and
+  attends over the context's pages, all inside ONE jitted program per
+  engine step (donated pools → in-place updates, one dispatch for the
+  whole mixed batch — the op-coalescing trick the device lane's dispatch
+  thread plays, applied to the decode path). On a TPU the attention is
+  the paged kernel of ``tpu/pallas_ops.py``: it reads the pages where
+  they lie, through the block table, as far as each row's length; on the
+  CPU substrate (and as the kernel's oracle) it gathers the context
+  padded to the program's bucket.
 - **Flash-attention prefill** — prompt self-attention runs the Pallas
   flash kernel from ``tpu/pallas_ops.py`` (interpret-mode on CPU), with
   the O(S²) reference as the numerics oracle; long prompts route through
@@ -110,24 +114,82 @@ def _prefill_attention(qh, kh, vh, use_flash: bool):
     return out.transpose(1, 0, 2)
 
 
-def _decode_body(cfg: ModelConfig, params, kpool, vpool, tokens, positions,
-                 slot_tables, B: int, L: int):
-    """The fused decode math for ONE device's pool slice — shared verbatim
-    by the single-device jit and the mesh shard_map body
-    (serving/mesh_model.py), so sharded greedy decode is token-identical
-    to single-device by construction.
+def _block_tables(tables, rows: int, n_pages: int) -> np.ndarray:
+    """Host-side: each row's physical block ids, padded with scratch
+    block 0 to the program's table width."""
+    out = np.zeros((rows, n_pages), dtype=np.int32)
+    for i, table in enumerate(tables):
+        out[i, :len(table)] = table
+    return out
 
-    tokens (B,), positions (B,), slot_tables (B, L): flat pool slot for
-    every context position (pads -> scratch block 0)."""
+
+def _gather_attention(q, kpool_l, vpool_l, slot_tables, mask, n_heads: int):
+    """One query row (B, D) over its padded context, copied out of one
+    layer's pool: (B, L, D) of K and of V whatever the rows' lengths."""
     import jax
     import jax.numpy as jnp
 
-    H, hd = cfg.n_heads, cfg.head_dim
+    B, L = slot_tables.shape
+    hd = q.shape[-1] // n_heads
+    with jax.named_scope("kv_gather"):
+        ks = kpool_l[slot_tables]                     # (B, L, D)
+        vs = vpool_l[slot_tables]
+    qh = q.reshape(B, n_heads, hd)
+    kh = ks.reshape(B, L, n_heads, hd)
+    vh = vs.reshape(B, L, n_heads, hd)
+    s = jnp.einsum("bhd,blhd->bhl", qh, kh) / np.sqrt(hd)
+    s = jnp.where(mask[:, None, :], s, -1e30)
+    patt = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhl,blhd->bhd", patt, vh).reshape(B, -1)
+
+
+def _decode_body(cfg: ModelConfig, params, kpool, vpool, tokens, positions,
+                 block_tables, B: int, L: int, paged: bool):
+    """The fused decode math for ONE device's pool slice — shared verbatim
+    by the single-device jit and the mesh shard_map body
+    (serving/mesh_model.py), so sharded greedy decode is token-identical
+    to single-device by construction. Returns the pools and each row's
+    greedy next token."""
+    import jax
+    import jax.numpy as jnp
+
+    kpool, vpool, logits = _decode_logits(cfg, params, kpool, vpool, tokens,
+                                          positions, block_tables, B, L,
+                                          paged)
+    with jax.named_scope("head"):
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return kpool, vpool, nxt
+
+
+def _decode_logits(cfg: ModelConfig, params, kpool, vpool, tokens,
+                   positions, block_tables, B: int, L: int, paged: bool):
+    """``_decode_body`` up to the logits (B, V), which the hardware lane
+    compares between the two attention paths.
+
+    tokens (B,), positions (B,), block_tables (B, L / block_size): the
+    physical block of every page of a row's context (pads -> scratch
+    block 0). Row b attends to positions 0 .. positions[b] of its table,
+    its own included: every row's K/V is written before any row's
+    attention of the same layer. ``paged``: attention reads the pages in
+    place through the kernel; otherwise it gathers the padded context
+    (the CPU path, and the oracle the kernel is tested against)."""
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.tpu import pallas_ops
+
+    H = cfg.n_heads
+    bs = L // block_tables.shape[1]
     scope = jax.named_scope   # metadata only: names a device op's part
     x = params["embed"][tokens]                       # (B, D)
-    write = slot_tables[jnp.arange(B), positions]     # (B,)
-    mask = (jnp.arange(L)[None, :]
-            <= positions[:, None])                    # (B, L)
+    page = jnp.take_along_axis(block_tables, positions[:, None] // bs,
+                               axis=1)[:, 0]
+    write = page * bs + positions % bs                # (B,)
+    if not paged:
+        slot_tables = (block_tables[:, :, None] * bs
+                       + jnp.arange(bs)).reshape(B, L)
+        mask = (jnp.arange(L)[None, :]
+                <= positions[:, None])                # (B, L)
     for l in range(cfg.n_layers):
         with scope("kv_write"):
             h = _rms(x)
@@ -135,25 +197,21 @@ def _decode_body(cfg: ModelConfig, params, kpool, vpool, tokens, positions,
             q, k, vv = jnp.split(qkv, 3, axis=-1)
             kpool = kpool.at[l, write].set(k)
             vpool = vpool.at[l, write].set(vv)
-        with scope("kv_gather"):
-            ks = kpool[l][slot_tables]                # (B, L, D)
-            vs = vpool[l][slot_tables]
         with scope("attention"):
-            qh = q.reshape(B, H, hd)
-            kh = ks.reshape(B, L, H, hd)
-            vh = vs.reshape(B, L, H, hd)
-            s = jnp.einsum("bhd,blhd->bhl", qh, kh) / np.sqrt(hd)
-            s = jnp.where(mask[:, None, :], s, -1e30)
-            patt = jax.nn.softmax(s, axis=-1)
-            attn = jnp.einsum("bhl,blhd->bhd", patt, vh)
-            x = x + attn.reshape(B, -1) @ params[f"wo{l}"]
+            if paged:
+                attn = pallas_ops.paged_decode_attention(
+                    q, kpool, vpool, l, block_tables, positions + 1,
+                    n_heads=H, block_size=bs)
+            else:
+                attn = _gather_attention(q, kpool[l], vpool[l],
+                                         slot_tables, mask, H)
+            x = x + attn @ params[f"wo{l}"]
         with scope("mlp"):
             h2 = _rms(x)
             x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]
     with scope("head"):
         logits = _rms(x) @ params["embed"].T          # (B, V)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return kpool, vpool, nxt
+    return kpool, vpool, logits
 
 
 class TinyTransformer:
@@ -176,7 +234,15 @@ class TinyTransformer:
         self.mesh = mesh
         self._lock = threading.Lock()
         self._prefill_cache = {}
-        self._decode_cache = {}
+        self._decode_cache = {}   # (b_bucket, l_bucket) -> program, path
+        self._decode_fns = {}     # the jitted programs those keys share
+        # what the decode launches of this instance ran and read: pages
+        # the rows' lengths cover against pages of the padded bucket (what
+        # the gather copies); ServingEngine.snapshot() and /serving show it
+        self.decode_counters = {"decode_launches_paged": 0,
+                                "decode_launches_gather": 0,
+                                "decode_pages_live": 0,
+                                "decode_pages_bucket": 0}
 
         # ---- weights: pack host-side once, stream into HBM by handle
         flat, self._offsets = self._init_weights(config)
@@ -233,6 +299,17 @@ class TinyTransformer:
         from brpc_tpu.tpu.pallas_ops import _on_tpu
 
         return _on_tpu()
+
+    def _decode_paged(self, b_bucket: int, n_pages: int) -> bool:
+        """Which attention a decode program of this shape runs, from what
+        the code can observe: the paged kernel on a TPU (``_use_flash``'s
+        rule for prefill) while the block table fits the kernel's scalar
+        memory, the gather of the padded context elsewhere."""
+        from brpc_tpu.tpu import pallas_ops
+
+        return (pallas_ops._on_tpu() and
+                b_bucket * pallas_ops.paged_table_pages(n_pages) * 4
+                <= pallas_ops.PAGED_TABLE_BYTES)
 
     # ------------------------------------------------------------- prefill
     def _prefill_fn(self, s_bucket: int, use_flash: bool):
@@ -324,8 +401,8 @@ class TinyTransformer:
         """Prefill only ``tokens[start:]`` against a table whose first
         ``start`` positions already hold committed K/V (a forked prefix
         chain). Runs through the SAME fused decode program as steady-state
-        decode — one row per suffix token, each gathering the full paged
-        context — so a cache hit costs one decode-shaped launch and the
+        decode — one row per suffix token, each attending over the paged
+        context up to itself — so a cache hit costs one decode-shaped launch and the
         written K/V (and the sampled token, row ``s - 1``'s argmax) are
         bit-identical to what cold prefill produces. Inherits to the mesh
         model unchanged: decode_step places rows by ``table.shard``."""
@@ -396,49 +473,83 @@ class TinyTransformer:
         return first
 
     # -------------------------------------------------------------- decode
-    def _decode_fn(self, b_bucket: int, l_bucket: int):
+    def _decode_fn(self, b_bucket: int, l_table: int, paged: bool):
+        """The jitted decode program for ``b_bucket`` rows under block
+        tables that span ``l_table`` positions."""
         import jax
 
         cfg = self.config
 
-        def impl(params, kpool, vpool, tokens, positions, slot_tables):
+        def impl(params, kpool, vpool, tokens, positions, block_tables):
             return _decode_body(cfg, params, kpool, vpool, tokens,
-                                positions, slot_tables, b_bucket, l_bucket)
+                                positions, block_tables, b_bucket, l_table,
+                                paged)
 
         return jax.jit(impl, donate_argnums=(1, 2))
+
+    def _decode_program(self, b_bucket: int, l_bucket: int, positions,
+                        groups: int = 1):
+        """The jitted program of one (rows, context) bucket and the width
+        of its block tables in pages, built on its first use, with this
+        launch counted (``groups``: how many pool slices each run a
+        bucket of rows). The gather body's table is the bucket's: it
+        copies every column. The kernel reads a row's pages only as far
+        as its length, so a column costs it a skipped grid step and no
+        more: its tables are widened (``pallas_ops.paged_table_pages``)
+        and the context buckets under one width
+        share ONE compiled program a row bucket (a program is seconds of
+        set-up in every process, from the compile cache or not)."""
+        from brpc_tpu.tpu.pallas_ops import paged_table_pages
+
+        bs = self.kv.block_size
+        n_pages = l_bucket // bs
+        key = (b_bucket, l_bucket)
+        with self._lock:
+            hit = self._decode_cache.get(key)
+            if hit is None:
+                paged = self._decode_paged(b_bucket, n_pages)
+                width = paged_table_pages(n_pages) if paged else n_pages
+                fn_key = (b_bucket, width, paged)
+                fn = self._decode_fns.get(fn_key)
+                if fn is None:
+                    fn = self._decode_fn(b_bucket, width * bs, paged)
+                    self._decode_fns[fn_key] = fn
+                hit = (fn, paged, width)
+                self._decode_cache[key] = hit
+            fn, paged, width = hit
+            c = self.decode_counters
+            c["decode_launches_paged" if paged
+              else "decode_launches_gather"] += 1
+            c["decode_pages_live"] += int(
+                ((np.asarray(positions, dtype=np.int64) + bs) // bs).sum())
+            c["decode_pages_bucket"] += groups * b_bucket * n_pages
+        return fn, width
 
     def decode_step(self, tokens: np.ndarray, positions: np.ndarray,
                     tables: List[Sequence[int]]) -> np.ndarray:
         """ONE fused device dispatch for the whole decode batch: append
-        each sequence's token at its position, gather paged context, and
-        return the next token per sequence (host-materialized once, here,
-        not per token)."""
+        each sequence's token at its position, attend over its paged
+        context, and return the next token per sequence
+        (host-materialized once, here, not per token)."""
         B = len(tokens)
         b_bucket, l_bucket = _decode_buckets(B, tables, self.kv.block_size)
         with _span("model.decode", B=B, b_bucket=b_bucket,
                    l_bucket=l_bucket):
             self.kv.assert_writable_batch(tables, positions)
             with _span("model.prep"):
-                key = (b_bucket, l_bucket)
-                with self._lock:
-                    fn = self._decode_cache.get(key)
-                    if fn is None:
-                        fn = self._decode_fn(b_bucket, l_bucket)
-                        self._decode_cache[key] = fn
+                fn, width = self._decode_program(b_bucket, l_bucket,
+                                                 positions)
                 toks = np.zeros(b_bucket, dtype=np.int32)
                 toks[:B] = tokens
                 pos = np.zeros(b_bucket, dtype=np.int32)
                 pos[:B] = positions
-                slot_tables = np.zeros((b_bucket, l_bucket), dtype=np.int32)
-                for i, table in enumerate(tables):
-                    slot_tables[i] = self._slots_for(
-                        table, positions[i] + 1, l_bucket)
+                block_tables = _block_tables(tables, b_bucket, width)
             from brpc_tpu.tpu.device_lane import step_dispatch
             with _span("model.launch"):
                 step_dispatch.note_launch(1)
                 kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
                                        self.kv.v_pool, toks, pos,
-                                       slot_tables)
+                                       block_tables)
                 self.kv.update_pools(kpool, vpool)
             with _span("model.sync"):
                 out = np.asarray(nxt[:B])
@@ -452,8 +563,8 @@ class TinyTransformer:
         last committed token plus its k drafted tokens — k+1 rows per
         sequence flattened into the same fused decode program steady-state
         decode uses (the ``prefill_suffix`` trick, batched). Inside one
-        launch every row's K/V write lands before any row's gather and
-        the causal mask limits row j to positions ≤ its own, so row j
+        launch every row's K/V write lands before any row's attention and
+        a row's length limits row j to positions ≤ its own, so row j
         attends over rows 0..j-1's *same-launch* writes: the returned
         argmax per row is exactly what k+1 sequential decode steps would
         produce. One launch, one host materialization — the (1,1)

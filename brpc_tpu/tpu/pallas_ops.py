@@ -1041,6 +1041,170 @@ def flash_attention_carry(q, k, v, m, l, acc, q_start, k_start,
 
 
 # ---------------------------------------------------------------------------
+# Paged decode attention — one query row per sequence against the K/V pages
+# where they lie in the pools. The block table and the rows' lengths are
+# scalar-prefetched; each grid step takes `pages` pages of one row, every
+# page through a BlockSpec of its own over the SAME pool operand whose
+# index_map reads the table, so Mosaic's pipeline fetches a page straight
+# from the pool (128 KB contiguous at block 16 x kv_dim 2048 float32) and
+# prefetches the next row's first pages behind the current row's last. A
+# page past the row's length keeps the index it had one step earlier: the
+# pipeline fetches nothing for an unchanged index, and `pl.when` skips the
+# chunk. Scores for all heads come from ONE MXU product against the
+# block-diagonal query (H, D) (one cross-lane reduction a head and
+# position otherwise); the P V product yields (H, D) whose diagonal blocks
+# are the heads' outputs. Matmul operands are rounded as the backend's
+# default precision rounds the gather body's einsums: to bfloat16 on the
+# TPU (one MXU pass), not at all in the interpreted kernel on the CPU;
+# sums and the online softmax are float32 everywhere.
+# ---------------------------------------------------------------------------
+_PAGED_CHUNK = 128   # positions a grid step: the MXU's tile of keys
+# the scalar-prefetched block table (rows x pages, int32) lives in SMEM,
+# 1 MiB on a v5e with the kernel's own scalars beside it
+PAGED_TABLE_BYTES = 512 << 10
+# a caller's tables come in multiples of this many pages (2048 positions at
+# block 16): a dead column costs one skipped grid step a chunk (~0.35 us),
+# a table width of its own costs a compiled program
+PAGED_TABLE_PAGES = 128
+
+
+def paged_table_pages(n_pages: int) -> int:
+    """The width of the block table a caller hands the kernel for rows of
+    up to ``n_pages`` pages."""
+    return -(-n_pages // PAGED_TABLE_PAGES) * PAGED_TABLE_PAGES
+
+
+def _paged_decode_kernel(layer_ref, table_ref, len_ref, q_ref, *refs,
+                         pages: int, bs: int, n_heads: int, nj: int):
+    import jax.experimental.pallas as pl
+
+    del layer_ref, table_ref   # read by the index maps
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, kbuf, vbuf, m_scr, l_scr, acc_scr = refs[2 * pages:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+    H, D = acc_scr.shape
+    hd = D // n_heads
+    T = pages * bs
+    own = (jax.lax.broadcasted_iota(jnp.int32, (H, D), 1) // hd
+           == jax.lax.broadcasted_iota(jnp.int32, (H, D), 0))
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * T < length)
+    def _chunk():
+        for i in range(pages):
+            kbuf[i * bs:(i + 1) * bs, :] = k_refs[i][:].astype(kbuf.dtype)
+            vbuf[i * bs:(i + 1) * bs, :] = v_refs[i][:].astype(vbuf.dtype)
+        q = jnp.broadcast_to(q_ref[0], (H, D))
+        qbd = jnp.where(own, q, 0.0).astype(kbuf.dtype)
+        s = _dot_f32(qbd, kbuf[:], trans_b=True) / float(hd) ** 0.5
+        pos = j * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+        # the first chunk always holds position 0, so m is finite from
+        # there on and a masked score's exp underflows to the correct 0
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + _dot_f32(p.astype(vbuf.dtype),
+                                                   vbuf[:])
+        m_scr[:] = m_new
+
+    @pl.when(j == nj - 1)
+    def _finish():
+        out = jnp.where(own, acc_scr[:] / l_scr[:], 0.0)
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_heads", "block_size", "interpret",
+                                    "operand_dtype"))
+def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
+                           n_heads: int, block_size: int,
+                           interpret: bool = None, operand_dtype=None):
+    """Attention of ONE query row per sequence over its own paged prefix.
+
+    q: (B, D) float32, heads side by side (D = n_heads * head_dim);
+    k_pool, v_pool: the WHOLE pools, (layers, slots, D), a page being
+    ``block_size`` consecutive slots; layer: int32 scalar (an operand, so
+    every layer of a program runs the same kernel); block_tables:
+    (B, pages) int32 physical block ids; lengths: (B,) int32, each >= 1,
+    the positions ``0 .. length - 1`` a row attends to (pages past it are
+    neither fetched nor computed). ``operand_dtype``: what the two
+    products' operands are rounded to; by default the backend's own
+    default precision (see above). Returns (B, D)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    if operand_dtype is None:
+        operand_dtype = jnp.float32 if interpret else jnp.bfloat16
+    B, D = q.shape
+    n_layers, slots, _ = k_pool.shape
+    bs = block_size
+    n_pages = block_tables.shape[1]
+    pages = min(max(1, _PAGED_CHUNK // bs), n_pages)
+    if n_pages % pages:
+        raise ValueError(f"table width {n_pages} must divide into chunks "
+                         f"of {pages} pages")
+    nj = n_pages // pages
+    # (layers, blocks, bs, D): a bitcast where bs is a multiple of the
+    # float32 sublane tile (8)
+    k4 = k_pool.reshape(n_layers, slots // bs, bs, D)
+    v4 = v_pool.reshape(n_layers, slots // bs, bs, D)
+
+    def page_spec(i):
+        def index(b, j, layer_ref, table_ref, len_ref):
+            live = (len_ref[b] + bs - 1) // bs
+            # the last step at which page j * pages + i is live; later
+            # steps repeat its index so nothing is fetched for them
+            last = jnp.maximum(live - 1 - i, 0) // pages
+            page = jnp.minimum(j, last) * pages + i
+            return (layer_ref[0], table_ref[b * n_pages + page], 0, 0)
+        return pl.BlockSpec((None, None, bs, D), index)
+
+    def row(b, j, *_):
+        return (b, 0, 0)
+
+    kernel = functools.partial(_paged_decode_kernel, pages=pages, bs=bs,
+                               n_heads=n_heads, nj=nj)
+    params = (None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary")))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, nj),
+            in_specs=([pl.BlockSpec((1, 1, D), row)]
+                      + [page_spec(i) for i in range(pages)] * 2),
+            out_specs=pl.BlockSpec((1, 1, D), row),
+            scratch_shapes=[
+                pltpu.VMEM((pages * bs, D), operand_dtype),
+                pltpu.VMEM((pages * bs, D), operand_dtype),
+                pltpu.VMEM((n_heads, 1), jnp.float32),
+                pltpu.VMEM((n_heads, 1), jnp.float32),
+                pltpu.VMEM((n_heads, D), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), q.dtype),
+        compiler_params=params,
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_tables.astype(jnp.int32).reshape(-1),
+      lengths.astype(jnp.int32),
+      q[:, None, :], *([k4] * pages), *([v4] * pages))
+    return out[:, 0, :]
+
+
+# ---------------------------------------------------------------------------
 # Fused softmax cross-entropy — the other canonical memory-bound fusion:
 # per row, one VMEM pass computes max / logsumexp / target logit without
 # materializing the [rows, V] log-softmax in HBM.

@@ -228,6 +228,17 @@ def serving_phase(sizes: Sizes, mesh, native_lane: bool) -> None:
               f"shared a decode batch of more than 2")
         say(f"serving: 4 concurrent Generate prompts={sizes.concurrent} OK "
             f"({toks} tokens in {steps} engine steps)")
+        dec = engine.snapshot()["decode"]
+        # on the chip decode attention reads the pages in place; the
+        # rehearsal's CPU takes the gather body
+        ran, other = (("paged", "gather") if jax.default_backend() == "tpu"
+                      else ("gather", "paged"))
+        check(dec[f"decode_launches_{ran}"] > 0
+              and dec[f"decode_launches_{other}"] == 0,
+              f"decode launches took the wrong attention path: {dec}")
+        say(f"serving: decode launches paged={dec['decode_launches_paged']} "
+            f"gather={dec['decode_launches_gather']}, the rows' lengths "
+            f"cover {dec['live_share']:.0%} of the padded buckets' pages")
     finally:
         server.stop()
         server.join()

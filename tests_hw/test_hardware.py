@@ -184,6 +184,116 @@ class TestServingAndRingOnChip:
                                        rtol=5e-2, atol=2e-2)
 
 
+class TestPagedDecodeOnChip:
+    """Decode attention over the pages in place (ISSUE 30): the compiled
+    kernel against the gather body at the `chat-steady` cell's widths."""
+
+    @pytest.mark.parametrize("rows,context", [(8, 1024), (16, 1024)])
+    def test_decode_program_paged_against_gather(self, tpu_device, rows,
+                                                 context):
+        # the whole decode program both ways on one pool: d_model 2048,
+        # 16 heads, block 16; 4 of the cell's 12 layers so that both
+        # programs' pools fit beside each other (nothing is donated)
+        from brpc_tpu.serving.model import ModelConfig, _decode_logits
+
+        cfg = ModelConfig(vocab=49152, d_model=2048, n_heads=16, n_layers=4,
+                          max_context=2048)
+        bs, blocks, d = 16, 2048, cfg.d_model
+        rng = np.random.default_rng(rows + context)
+        key = jax.random.key(rows)
+
+        def draw(i, shape, scale):
+            return jax.random.normal(jax.random.fold_in(key, i), shape,
+                                     jnp.float32) * scale
+
+        params = {"embed": draw(0, (cfg.vocab, d), 0.5 / np.sqrt(cfg.vocab))}
+        for l in range(cfg.n_layers):
+            for j, (name, shape) in enumerate(
+                    [("wqkv", (d, 3 * d)), ("wo", (d, d)),
+                     ("w1", (d, 2 * d)), ("w2", (2 * d, d))]):
+                params[f"{name}{l}"] = draw(
+                    1 + 4 * l + j, shape, 0.5 / np.sqrt(shape[0]))
+        pool_shape = (cfg.n_layers, (blocks + 1) * bs, d)
+        kpool, vpool = draw(90, pool_shape, 0.7), draw(91, pool_shape, 0.7)
+        # ragged rows as the cell has them: 50 .. context positions, two
+        # padded rows, tables shuffled over the pool
+        live = rows - 2
+        positions = np.zeros(rows, np.int32)
+        positions[:live] = rng.integers(49, context, size=live)
+        positions[0], positions[1] = context - 1, bs - 1
+        ids = rng.permutation(np.arange(1, blocks + 1))
+        tables = np.zeros((rows, context // bs), np.int32)
+        used = 0
+        for b in range(live):
+            n = positions[b] // bs + 1
+            tables[b, :n] = ids[used:used + n]
+            used += n
+        tokens = rng.integers(1, cfg.vocab, size=rows).astype(np.int32)
+
+        def program(paged):
+            return jax.jit(lambda p, k, v, t, pos, bt: _decode_logits(
+                cfg, p, k, v, t, pos, bt, rows, context, paged))
+
+        args = (params, kpool, vpool, tokens, positions, tables)
+        k_p, v_p, logits_p = program(True)(*args)
+        k_g, v_g, logits_g = program(False)(*args)
+        logit_gap = float(jnp.max(jnp.abs(logits_p - logits_g)[:live]))
+        spread = float(jnp.std(logits_g[:live]))
+        same = int(jnp.sum(jnp.argmax(logits_p, -1)[:live]
+                           == jnp.argmax(logits_g, -1)[:live]))
+        slots = (tables[np.arange(live), positions[:live] // bs] * bs
+                 + positions[:live] % bs)
+        kv_gap = max(float(jnp.max(jnp.abs(a[-1, slots] - b[-1, slots])))
+                     for a, b in ((k_p, k_g), (v_p, v_g)))
+        print(f"\npaged decode {rows} x {context}: worst logit difference "
+              f"{logit_gap:.3e} (logits' deviation {spread:.3e}), last "
+              f"layer's written K/V rows {kv_gap:.3e}, greedy tokens equal "
+              f"{same}/{live}")
+        # both paths round their operands to bfloat16, in different places
+        assert logit_gap < 0.05 * spread + 1e-3, (logit_gap, spread)
+        assert kv_gap < 5e-2
+        # layer 0's rows are written before any attention: equal
+        assert bool(jnp.all(k_p[0, slots] == k_g[0, slots]))
+
+    def test_generate_counts_paged_launches(self, tpu_device):
+        from brpc_tpu.proto import serving_pb2
+        from brpc_tpu.rpc import Channel, ChannelOptions, Server, Stub
+        from brpc_tpu.serving import (EngineConfig, KVCacheConfig,
+                                      LlmServingService, ModelConfig,
+                                      PagedKVCache, ServingEngine,
+                                      TinyTransformer)
+
+        cfg = ModelConfig(vocab=512, d_model=256, n_heads=2, n_layers=2,
+                          max_context=512)
+        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=128),
+                          cfg.n_layers, cfg.kv_dim)
+        model = TinyTransformer(cfg, kv)
+        engine = ServingEngine(model, kv, EngineConfig(
+            max_batch=4, token_budget=1024)).start()
+        server = Server().add_service(LlmServingService(engine)) \
+            .start("127.0.0.1:0")
+        try:
+            ch = Channel(ChannelOptions(protocol="trpc_std",
+                                        timeout_ms=300000))
+            ch.init(str(server.listen_endpoint()))
+            stub = Stub(ch, serving_pb2.DESCRIPTOR
+                        .services_by_name["LlmService"])
+            resp = stub.Generate(serving_pb2.GenerateRequest(
+                prompt_len=40, max_new_tokens=24))
+            assert len(resp.tokens) == 24
+            dec = engine.snapshot()["decode"]
+            print(f"\ndecode counters after a Generate: {dec}")
+            assert dec["decode_launches_paged"] > 0
+            assert dec["decode_launches_gather"] == 0
+            assert 0 < dec["decode_pages_live"] <= dec["decode_pages_bucket"]
+        finally:
+            server.stop()
+            server.join(timeout=2)
+            engine.stop()
+        kv.assert_idle()
+        model.close()
+
+
 class TestDeviceLanesOnChip:
     def test_tpusocket_device_echo(self, tpu_device):
         from brpc_tpu.proto import echo_pb2

@@ -3,6 +3,7 @@
 Run standalone (owns the chip):
 
     python tools/kernel_bench.py            # prints one line per metric
+    python tools/kernel_bench.py paged_decode   # only the named benches
 
 Timing methodology: marginal cost between two round counts inside ONE
 compiled loop. Every measurement ends in a dependent fetch, and the slope
@@ -355,6 +356,90 @@ def bench_rmsnorm(peak: dict):
     return gbps
 
 
+def bench_paged_decode(peak: dict):
+    """Decode attention over the pages in place, at the widths of the
+    `chat-steady` cell (d_model 2048, 16 heads, 12 layers x 2048 blocks of
+    16 float32 rows): the kernel alone, chained through its own output,
+    against the bytes of the pages the rows' lengths cover; the gather
+    body beside it on the same rows."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.serving.model import _gather_attention
+    from brpc_tpu.tpu.pallas_ops import paged_decode_attention
+
+    H, D, bs, layers, blocks = 16, 2048, 16, 12, 2048
+    key = jax.random.key(0)
+    shape = (layers, (blocks + 1) * bs, D)
+    kpool = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.float32)
+    vpool = jax.random.normal(jax.random.fold_in(key, 2), shape, jnp.float32)
+    rng = np.random.default_rng(0)
+    hbm = peak["hbm_bytes_per_s"]
+
+    def case(label, rows, context, lengths, table_pages=None):
+        # ``table_pages``: the width serving hands the kernel (its tables
+        # come in multiples of PAGED_TABLE_PAGES); the gather body always
+        # copies the context bucket
+        n_pages = table_pages or context // bs
+        lengths = np.asarray(lengths, np.int32)
+        pad = np.ones(rows - len(lengths), np.int32)
+        lens = jnp.asarray(np.concatenate([lengths, pad]))
+        ids = rng.permutation(np.arange(1, blocks + 1))
+        tables = np.zeros((rows, n_pages), np.int32)
+        used = 0
+        for b, n in enumerate(lengths):
+            live = -(-int(n) // bs)
+            tables[b, :live] = ids[used:used + live]
+            used += live
+        tables = jnp.asarray(tables)
+        slots = (tables[:, :context // bs, None] * bs
+                 + jnp.arange(bs)).reshape(rows, -1)
+        mask = jnp.arange(context)[None, :] < lens[:, None]
+        q0 = jax.random.normal(jax.random.fold_in(key, 3), (rows, D),
+                               jnp.float32)
+
+        def chained(call):
+            @functools.partial(jax.jit, static_argnames=("n",))
+            def loop(q, kp, vp, n: int):
+                def body(i, qc):
+                    return call(qc, kp, vp, i % layers)
+                return jax.lax.fori_loop(0, n, body, q)
+
+            def run(n):
+                jax.device_get(loop(q0, kpool, vpool, n)[0, :1])
+
+            return _marginal(run, 24, 240)
+
+        paged = chained(lambda q, kp, vp, l: paged_decode_attention(
+            q, kp, vp, l, tables, lens, n_heads=H, block_size=bs,
+            interpret=False))
+        gather = chained(lambda q, kp, vp, l: _gather_attention(
+            q, kp[l], vp[l], slots, mask, H))
+        live_bytes = 2 * 4 * D * float(np.sum(-(-lengths // bs)) * bs)
+        print(f"# kernel paged_decode_attention {label} ({rows} x {context}"
+              f" bucket, table {n_pages} pages, {len(lengths)} live rows, "
+              f"{int(lengths.sum())} positions): {paged * 1e6:8.1f} us a "
+              f"layer = {live_bytes / paged / 1e9:6.1f} GB/s of live pages "
+              f"({live_bytes / paged / hbm * 100:.0f}% of the HBM peak); "
+              f"the gather body on the same rows {gather * 1e6:8.1f} us",
+              flush=True)
+
+    chat = [957, 612, 402, 301, 222, 131, 57]   # ~7 rows of 50-960
+    case("chat-steady rows", 8, 1024, chat)
+    case("chat-steady rows, upper bucket", 16, 1024,
+         chat + [880, 45, 512])
+    case("every row full", 8, 1024, [1024] * 8)
+    case("two short rows", 2, 512, [80, 300])
+    # as served: the same rows under tables of 128 pages
+    case("chat-steady rows as served", 8, 1024, chat, table_pages=128)
+    case("upper bucket as served", 16, 1024, chat + [880, 45, 512],
+         table_pages=128)
+    case("two short rows as served", 2, 512, [80, 300], table_pages=128)
+
+
 def bench_train_step_mfu(peak: dict):
     """Single-chip train step of the flagship LM, reported BOTH ways:
     kernels ON (Pallas flash fwd+bwd, Pallas norm, fused xent — the
@@ -450,10 +535,13 @@ def main():
     print(f"# kernel bench on {describe_devices()} jax={jax.__version__} "
           f"(peaks: {peak['bf16_flops'] / 1e12:.0f} TFLOP/s bf16, "
           f"{peak['hbm_bytes_per_s'] / 1e9:.0f} GB/s HBM)", flush=True)
-    bench_flash_attention(peak)
-    bench_ring_path(peak)
-    bench_rmsnorm(peak)
-    bench_train_step_mfu(peak)
+    benches = {"flash_attention": bench_flash_attention,
+               "ring_path": bench_ring_path,
+               "rmsnorm": bench_rmsnorm,
+               "paged_decode": bench_paged_decode,
+               "train_step_mfu": bench_train_step_mfu}
+    for name in sys.argv[1:] or list(benches):   # all, or the named ones
+        benches[name](peak)
     return 0
 
 
