@@ -21,19 +21,20 @@ Channel -> tpu:// transport -> Server stack, vs the reference's 2.3 GB/s
 loopback plateau (/root/reference/docs/cn/benchmark.md:104).
 
 One process per chip: a chip belongs to one process at a time, so every
-child that needs it (the --batch/--device/--serving servers, the kernel
-bench) runs and exits BEFORE this process initialises a JAX backend for
-its in-process lanes; main() orders the phases that way and _BenchServer
-refuses to start such a child once this process holds the chip. With the
-device phase on, a device lane that fails or finds no TPU fails the run.
+child that needs it (the --batch/--device servers, the kernel bench) runs
+and exits BEFORE this process initialises a JAX backend for the device
+probe; main() orders the phases that way and _BenchServer refuses to start
+such a child once this process holds the chip. With the device phase on, a
+device lane that fails or finds no TPU fails the run.
+
+This is the transport's yardstick (BASELINE.json). The serving plane's
+speed is read by benchmark/run.py on the chip, and by nothing here.
 
 Env knobs: BENCH_QUICK=1 shortens every phase (CI smoke); BENCH_SKIP_DEVICE=1
-skips the device phase; BENCH_PHASES=shm,qps,native,hybrid,batch,serving,spec,
-qos,device runs only the named phases (default: all) — e.g. BENCH_PHASES=shm
-is the CPU-only tier-1 smoke lane, whose headline is then the Python tpu://
-sweep; batch is the adaptive-batching vs per-request dispatch comparison
-(also CPU-only); spec is the speculative-decoding draft+verify A/B; qos is
-the multi-tenant overload A/B (protected p99 + shed rate).
+skips the device phase; BENCH_PHASES=shm,qps,native,hybrid,batch,device runs
+only the named phases (default: all) — e.g. BENCH_PHASES=shm is the CPU-only
+tier-1 smoke lane, whose headline is then the Python tpu:// sweep; batch is
+the adaptive-batching vs per-request dispatch comparison (also CPU-only).
 """
 
 from __future__ import annotations
@@ -59,9 +60,6 @@ HEADLINE_SIZE = 1 << 20
 # (pre fastpath-stack; record deleted in PR 21) — the qps the latency work
 # was measured against; not measured on the current machine
 BASELINE_64B_QPS = 1692.0
-# isolated per-RPC device dispatch rate from the last pre-PR-1 chip record
-# (record deleted in PR 21; not measured on the current machine)
-BASELINE_DEVICE_OPS = 7222.0
 
 # (payload bytes, threads, calls per thread)
 SWEEP = [
@@ -102,10 +100,10 @@ def _assert_chip_free(child: str) -> None:
 
 class _BenchServer:
     """Child echo server; LISTEN line gives the bound endpoint. A server
-    that owns a JAX device (--batch/--device/--serving) names it on a
-    DEVICE line first."""
+    that owns a JAX device (--batch/--device) names it on a DEVICE line
+    first."""
 
-    JAX_MODES = ("--batch", "--device", "--serving")
+    JAX_MODES = ("--batch", "--device")
 
     def __init__(self, listen: str, *extra_args: str):
         if any(a in self.JAX_MODES for a in extra_args):
@@ -415,759 +413,6 @@ def bench_batch_lane():
         srv.close()
 
 
-def _serving_engine_qps(scheduling: str, n_requests: int,
-                        sharded: bool = False):
-    """In-process half of the serving lane: one engine, one mixed-length
-    workload (mostly short 4-token generations with a long 64-token one
-    every 4th request — each static gang carries exactly one straggler;
-    all submitted up front); returns (requests/sec, tokens/sec). Static
-    gang scheduling drains a whole batch before admitting the next, so
-    every short request waits out the longest gang member; continuous
-    batching refills freed slots between decode steps
-    (brpc_tpu/serving/engine.py). Identical model/engine configs, so the
-    ratio isolates the scheduler. ``sharded=True`` runs the mesh stack
-    (MeshTransformer + ShardedKVCache over the dp/sp/tp serving mesh) —
-    on one device the mesh degenerates to 1x1x1, so the lane works under
-    any XLA_FLAGS device count."""
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, ServingEngine,
-                                  TinyTransformer)
-
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2)
-    if sharded:
-        from brpc_tpu.serving import MeshTransformer, ShardedKVCache
-
-        kv = ShardedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                            cfg.n_layers, cfg.kv_dim)
-        model = MeshTransformer(cfg, kv)
-    else:
-        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                          cfg.n_layers, cfg.kv_dim)
-        model = TinyTransformer(cfg, kv)
-    # prefix_cache=False: this A/B isolates the SCHEDULER — cached-prefix
-    # reuse would shrink exactly the prefill work the static gang stalls
-    # behind (the prefix cache gets its own hit-TTFT lane below)
-    engine = ServingEngine(model, kv, EngineConfig(
-        max_batch=4, token_budget=256, scheduling=scheduling,
-        idle_wait_s=0.005), prefix_cache=False).start()
-    tokens = sum(64 if i % 4 == 3 else 4 for i in range(n_requests))
-
-    def run(n):
-        evs = []
-        t0 = time.perf_counter()
-        for i in range(n):
-            ev = threading.Event()
-            code, _ = engine.submit(model.synth_prompt(16),
-                                    64 if i % 4 == 3 else 4,
-                                    done=lambda _r, ev=ev: ev.set())
-            if code != 0:
-                raise RuntimeError(f"serving submit rejected: {code}")
-            evs.append(ev)
-        for ev in evs:
-            if not ev.wait(300):
-                raise RuntimeError(f"serving A/B stalled ({scheduling})")
-        wall = time.perf_counter() - t0
-        return n / wall, tokens / wall
-
-    try:
-        # two warmup rounds of the EXACT timed workload: the queue-depth
-        # profile decides which (batch, context) buckets the decode hits,
-        # so a smaller warmup misses combos (e.g. full batch at long
-        # context) and their compiles would land in the timed run; the
-        # second round covers the donated-pool second jit signature
-        run(n_requests)
-        run(n_requests)
-        return run(n_requests)
-    finally:
-        engine.stop()
-        model.close()
-
-
-def _device_op_rate() -> tuple:
-    """Coalesced per-step device dispatch rate, measured in-process on
-    the sim lane: one small HBM-resident buffer, transient copies queued
-    through DeviceStore.copy_coalesced (the per-step batch API the
-    serving engine rides) so the dispatcher thread fuses them into O(1)
-    compiled programs instead of per-op isolated dispatches. Returns
-    (op_rate, ops)."""
-    from brpc_tpu.tpu.device_lane import (DispatchCounter, global_store,
-                                          step_dispatch)
-
-    store = global_store()
-    handle, _ = store.put(b"\x00" * 1024)
-    try:
-        store.copy_coalesced(handle, 64)  # warmup: dispatcher + jit cache
-        store.fence()
-        total_ops = 2048 if QUICK else 16384
-        batch = 256  # one "step" worth of device ops per Python dispatch
-        before = step_dispatch.snapshot()
-        t0 = time.perf_counter()
-        for _ in range(total_ops // batch):
-            store.copy_coalesced(handle, batch)
-        store.fence()
-        wall = time.perf_counter() - t0
-        _, ops, _ = DispatchCounter.delta(before, step_dispatch.snapshot())
-        return ops / wall, ops
-    finally:
-        store.free(handle)
-
-
-def _bench_prefix_ttft():
-    """Prefix-cache hit-TTFT A/B: two identical engines — one with the
-    radix cache disabled (cold reference), one with it on (warm) — driven
-    with a shared-prefix corpus (same synth prompt, one distinct tail
-    token per request, the system-prompt traffic shape). After the warm
-    engine's first request commits the shared chain, every later request
-    forks it and prefills ONE suffix token — hit TTFT collapses from
-    O(prompt) reference-attention prefill to one decode-shaped launch.
-    Returns (hit_ttft_ms, cold_ttft_ms, hit_ratio)."""
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, ServingEngine,
-                                  TinyTransformer)
-
-    plen = 256 if QUICK else 512
-    reqs = 4 if QUICK else 8
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2,
-                      max_context=4 * plen)
-    ecfg = dict(max_batch=4, token_budget=4 * plen, idle_wait_s=0.002)
-
-    def build(prefix_cache):
-        kv = PagedKVCache(KVCacheConfig(block_size=16,
-                                        num_blocks=2 * (4 * plen) // 16),
-                          cfg.n_layers, cfg.kv_dim)
-        model = TinyTransformer(cfg, kv)
-        return ServingEngine(model, kv, EngineConfig(**ecfg),
-                             prefix_cache=prefix_cache).start()
-
-    base = None  # shared-prefix corpus: common first blocks, unique tail
-
-    def prompt(i):
-        p = base.copy()
-        p[-1] = 1 + (7 * i + 3) % (cfg.vocab - 1)
-        return p
-
-    def one(engine, i):
-        ev = threading.Event()
-        box = {}
-        code, _ = engine.submit(prompt(i), 4,
-                                done=lambda r, ev=ev: (box.update(r=r),
-                                                       ev.set()))
-        if code != 0:
-            raise RuntimeError(f"prefix bench submit rejected: {code}")
-        if not ev.wait(300):
-            raise RuntimeError("prefix bench stalled")
-        return box["r"].ttft_us / 1000.0
-
-    cold = build(prefix_cache=False)
-    warm = build(prefix_cache=None)
-    base = cold.model.synth_prompt(plen + 1)
-    try:
-        # warmup: compile every bucket both lanes touch (cold prefill,
-        # warm suffix decode-shape), twice for the donated-pool second
-        # jit signature; the warm engine's warmup also PRIMES the tree —
-        # the first commit is the corpus the timed hits fork
-        for _ in range(2):
-            for i in range(reqs):
-                one(cold, i)
-                one(warm, i)
-        cold_ms = _percentile(sorted(one(cold, i) for i in range(reqs)), 0.5)
-        hit_ms = _percentile(sorted(one(warm, i) for i in range(reqs)), 0.5)
-        snap = warm.snapshot()["prefix"]
-        hit_ratio = snap["hit_ratio"]
-    finally:
-        warm.stop()
-        cold.stop()
-        warm.model.close()
-        cold.model.close()
-    return hit_ms, cold_ms, hit_ratio
-
-
-def _bench_disagg_interference():
-    """Disaggregated prefill/decode interference A/B: the same 3:1 mixed
-    corpus (three short decode-heavy requests, then one long prefill)
-    through (a) ONE co-located engine, where every long prefill launch
-    stalls the decode steps sharing its loop, and (b) a prefill engine
-    that hands each just-prefilled sequence to a separate decode engine
-    over the tpu:// record lane (KVMigrator -> loopback LlmService ->
-    adopt). The decode engine then runs NOTHING but (1,1) decode steps,
-    so its inter-token jitter (p99-p50 of per-engine ITL samples) must
-    come in below the co-located engine's — that spread IS the
-    interference the disaggregation removes. Returns
-    (coloc_jitter_ms, disagg_jitter_ms, coloc_ttft_ms, disagg_ttft_ms,
-    migrator_snapshot)."""
-    import numpy as np
-
-    from brpc_tpu.rpc.server import Server
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, ServingEngine,
-                                  TinyTransformer)
-    from brpc_tpu.serving.migration import KVMigrator
-    from brpc_tpu.serving.service import LlmServingService
-
-    n = 16 if QUICK else 32
-    corpus = [(160, 4) if i % 4 == 3 else (16, 24) for i in range(n)]
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2,
-                      max_context=256)
-
-    def build(role):
-        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                          cfg.n_layers, cfg.kv_dim)
-        model = TinyTransformer(cfg, kv)
-        # prefix_cache=False: this A/B isolates scheduling interference —
-        # cached-prefix reuse would shrink exactly the prefill launches
-        # the co-located decode steps stall behind
-        return ServingEngine(model, kv, EngineConfig(
-            max_batch=4, token_budget=256, idle_wait_s=0.002, role=role),
-            prefix_cache=False).start()
-
-    def submit(eng, plen, max_new, resume=0):
-        ev = threading.Event()
-        box = {}
-        prompt = (np.zeros(0, dtype=np.int32) if resume
-                  else eng.model.synth_prompt(plen))
-        code, _ = eng.submit(
-            prompt, 0 if resume else max_new,
-            done=lambda r, box=box, ev=ev: (box.update(r=r), ev.set()),
-            resume_seq_id=resume)
-        if code != 0:
-            raise RuntimeError(f"disagg bench submit rejected: {code}")
-        return ev, box
-
-    def run_coloc(eng):
-        pend = [submit(eng, p, m) for p, m in corpus]
-        for ev, _ in pend:
-            if not ev.wait(300):
-                raise RuntimeError("disagg bench: co-located run stalled")
-
-    def run_disagg(pre, dec):
-        stage1 = [submit(pre, p, m) for p, m in corpus]
-        for ev, box in stage1:
-            if not ev.wait(300):
-                raise RuntimeError("disagg bench: prefill stage stalled")
-            r = box["r"]
-            if r is None or r.finish_reason != "handoff":
-                raise RuntimeError(
-                    f"disagg bench: expected handoff, got "
-                    f"{getattr(r, 'finish_reason', None)!r}")
-        stage2 = [submit(dec, 0, 0, resume=box["r"].seq_id)
-                  for _, box in stage1]
-        for ev, _ in stage2:
-            if not ev.wait(300):
-                raise RuntimeError("disagg bench: decode stage stalled")
-
-    def jitter_ms(samples):
-        s = sorted(samples)
-        if not s:
-            return 0.0
-        return (_percentile(s, 0.99) - _percentile(s, 0.5)) / 1e3
-
-    def ttft_ms(samples):
-        s = sorted(samples)
-        return (_percentile(s, 0.5) / 1e3) if s else 0.0
-
-    def warm_buckets(eng):
-        # deterministically compile every (batch, context) decode bucket
-        # the timed reps can hit — a mid-run jit trace (hundreds of ms)
-        # would otherwise masquerade as scheduling jitter in a p99 drawn
-        # from a few hundred samples
-        for group in ([(160, 4)] * 4, [(16, 4)] * 4, [(160, 4)],
-                      [(16, 4)]):
-            pend = [submit(eng, p, m) for p, m in group]
-            for ev, _ in pend:
-                if not ev.wait(300):
-                    raise RuntimeError(
-                        "disagg bench: bucket warmup stalled")
-
-    REPS = 3  # min-of-reps: p99 from ~300 samples is one GC pause from
-    #           flipping the A/B, so each mode keeps its best draw
-
-    coloc = build("both")
-    try:
-        # warmup covers every (batch, context) bucket the timed run hits,
-        # twice for the donated-pool second jit signature
-        run_coloc(coloc)
-        run_coloc(coloc)
-        warm_buckets(coloc)
-        coloc_j = coloc_t = float("inf")
-        for _ in range(REPS):
-            coloc.itl_samples.clear()
-            coloc.ttft_samples.clear()
-            run_coloc(coloc)
-            coloc_j = min(coloc_j, jitter_ms(coloc.itl_samples))
-            coloc_t = min(coloc_t, ttft_ms(coloc.ttft_samples))
-    finally:
-        coloc.stop()
-        coloc.model.close()
-
-    dec = build("decode")
-    srv = Server().add_service(LlmServingService(dec)).start("127.0.0.1:0")
-    pre = build("prefill")
-    pre.set_migrator(KVMigrator(f"{srv.listen_endpoint()}"))
-    try:
-        run_disagg(pre, dec)
-        run_disagg(pre, dec)
-        warm_buckets(dec)
-        dis_j = dis_t = float("inf")
-        for _ in range(REPS):
-            pre.ttft_samples.clear()
-            dec.itl_samples.clear()
-            run_disagg(pre, dec)
-            dis_j = min(dis_j, jitter_ms(dec.itl_samples))
-            dis_t = min(dis_t, ttft_ms(pre.ttft_samples))
-        mig = pre.migrator.snapshot()
-    finally:
-        pre.stop()
-        srv.stop()
-        srv.join(timeout=2)
-        dec.stop()
-        pre.model.close()
-        dec.model.close()
-    return coloc_j, dis_j, coloc_t, dis_t, mig
-
-
-def bench_serving_lane():
-    """Serving plane (brpc_tpu/serving/): streamed generations over the
-    RPC path against a pre-warmed child server — aggregate tokens/sec and
-    TTFT percentiles measured at stream-frame arrival — then the
-    in-process continuous-vs-static scheduling A/B on mixed-length
-    traffic over the SHARDED mesh stack, the prefix-cache hit-TTFT A/B,
-    the disaggregated prefill/decode interference A/B, plus the coalesced
-    device dispatch-rate probe. Emits the ten serving JSON metric
-    lines."""
-    from brpc_tpu.proto import serving_pb2
-    from brpc_tpu.rpc import Channel, ChannelOptions, Controller, Stub
-    from brpc_tpu.rpc.stream import (StreamOptions, stream_close,
-                                     stream_create)
-
-    threads = 4 if QUICK else 8
-    calls = 3 if QUICK else 8
-    srv = _BenchServer("127.0.0.1:0", "--serving")
-    srv_device = srv.device
-    try:
-        ch = Channel(ChannelOptions(protocol="trpc_std", timeout_ms=120000))
-        ch.init(srv.endpoint)
-        stub = Stub(ch,
-                    serving_pb2.DESCRIPTOR.services_by_name["LlmService"])
-
-        def generate(prompt_len, max_new):
-            t_first = [0.0]
-
-            def on_received(sid, msgs):
-                if not t_first[0]:
-                    t_first[0] = time.perf_counter()
-
-            sid = stream_create(StreamOptions(on_received=on_received))
-            cntl = Controller()
-            cntl.stream_id = sid
-            cntl.timeout_ms = 120000
-            t0 = time.perf_counter()
-            resp = stub.Generate(
-                serving_pb2.GenerateRequest(prompt_len=prompt_len,
-                                            max_new_tokens=max_new),
-                controller=cntl)
-            total = time.perf_counter() - t0
-            stream_close(sid)
-            if cntl.failed():
-                raise RuntimeError(f"Generate failed: {cntl.error_text()}")
-            ttft = (t_first[0] - t0) if t_first[0] else total
-            return len(resp.tokens), ttft
-
-        generate(16, 2)  # warmup: connection + client codepaths
-        tok_count = [0] * threads
-        ttfts = [[] for _ in range(threads)]
-        failures = []
-        barrier = threading.Barrier(threads + 1)
-
-        def worker(idx):
-            barrier.wait()
-            try:
-                for c in range(calls):
-                    n, ttft = generate(16 + 16 * (idx % 2),
-                                       4 if (idx + c) % 2 else 24)
-                    tok_count[idx] += n
-                    ttfts[idx].append(ttft)
-            except BaseException as e:
-                failures.append(e)
-
-        ts = [threading.Thread(target=worker, args=(i,))
-              for i in range(threads)]
-        for t in ts:
-            t.start()
-        barrier.wait()
-        t0 = time.perf_counter()
-        for t in ts:
-            t.join()
-        wall = time.perf_counter() - t0
-        if failures:
-            raise RuntimeError(f"serving bench worker failed: "
-                               f"{failures[0]!r}") from failures[0]
-        tps = sum(tok_count) / wall
-        lat = sorted(x for l in ttfts for x in l)
-    finally:
-        srv.close()
-
-    # the scheduling A/B runs on the SHARDED stack (mesh prefill/decode +
-    # per-device KV pools): the 1.5x continuous-vs-static floor must hold
-    # with sharding on, or the mesh lowering broke iteration-level refill
-    n_ab = 16 if QUICK else 32
-    cont_qps, cont_tps = _serving_engine_qps("continuous", n_ab,
-                                             sharded=True)
-    stat_qps, _ = _serving_engine_qps("static", n_ab, sharded=True)
-    ratio = cont_qps / max(stat_qps, 1e-9)
-    hit_ms, cold_ms, hit_ratio = _bench_prefix_ttft()
-    pfx_ratio = hit_ms / max(cold_ms, 1e-9)
-    coloc_j, dis_j, coloc_t, dis_t, mig = _bench_disagg_interference()
-    op_rate, n_ops = _device_op_rate()
-    import jax as _jax
-    n_dev = len(_jax.devices())
-    from brpc_tpu.tpu.mesh import describe_devices
-    dev = describe_devices()
-    p50 = _percentile(lat, 0.5) * 1e3
-    p99 = _percentile(lat, 0.99) * 1e3
-    print(f"# serving lane: [server child on {srv_device}; in-process "
-          f"lanes on {dev}] {threads}x{calls} streamed generations "
-          f"tokens/s={tps:,.0f} ttft p50={p50:.1f}ms p99={p99:.1f}ms | "
-          f"sharded A/B ({n_dev} dev) {n_ab} mixed-length reqs: "
-          f"continuous={cont_qps:.1f} req/s "
-          f"static={stat_qps:.1f} req/s ratio={ratio:.2f}x "
-          f"({'OK' if ratio >= 1.5 else 'BELOW'} 1.5x floor) | "
-          f"coalesced device dispatch: {n_ops} ops at {op_rate:,.0f} op/s "
-          f"(isolated-dispatch baseline {BASELINE_DEVICE_OPS:,.0f})",
-          file=sys.stderr)
-    print(f"# serving prefix: [{dev}] shared-prefix hit "
-          f"ttft={hit_ms:.2f}ms "
-          f"cold={cold_ms:.2f}ms ratio={pfx_ratio:.3f} "
-          f"({'OK' if pfx_ratio <= 0.5 else 'ABOVE'} 0.5x ceiling) "
-          f"hit_ratio={hit_ratio:.2f}", file=sys.stderr)
-    print(f"# serving disagg: [{dev}] 3:1 mixed corpus decode jitter "
-          f"coloc={coloc_j:.3f}ms disagg={dis_j:.3f}ms "
-          f"({'OK' if dis_j < coloc_j else 'ABOVE'} interference floor) "
-          f"ttft coloc={coloc_t:.2f}ms disagg={dis_t:.2f}ms | "
-          f"migrated seqs={mig['seqs']} blocks={mig['blocks']} "
-          f"at {mig['gbps']:.3f} GB/s", file=sys.stderr)
-    print(json.dumps({
-        "metric": "serving_tokens_per_sec",
-        "value": round(tps, 1),
-        "unit": "tokens/s",
-    }))
-    print(json.dumps({
-        "metric": "serving_ttft_ms",
-        "value": round(p50, 2),
-        "unit": "ms",
-        "p99": round(p99, 2),
-    }))
-    print(json.dumps({
-        "metric": "serving_continuous_vs_static",
-        "value": round(ratio, 3),
-        "unit": "x",
-        "continuous_qps": round(cont_qps, 1),
-        "static_qps": round(stat_qps, 1),
-    }))
-    print(json.dumps({
-        "metric": "serving_sharded_tokens_per_s",
-        "value": round(cont_tps, 1),
-        "unit": "tokens/s",
-        "devices": n_dev,
-    }))
-    print(json.dumps({
-        "metric": "serving_prefix_hit_ttft_ms",
-        "value": round(hit_ms, 3),
-        "unit": "ms",
-        "cold_ms": round(cold_ms, 3),
-        "ratio": round(pfx_ratio, 4),
-    }))
-    print(json.dumps({
-        "metric": "serving_prefix_hit_ratio",
-        "value": round(hit_ratio, 4),
-        "unit": "ratio",
-    }))
-    print(json.dumps({
-        "metric": "serving_disagg_decode_jitter",
-        "value": round(dis_j, 4),
-        "unit": "ms",
-        "coloc_ms": round(coloc_j, 4),
-    }))
-    print(json.dumps({
-        "metric": "serving_disagg_ttft_ms",
-        "value": round(dis_t, 3),
-        "unit": "ms",
-        "coloc_ms": round(coloc_t, 3),
-    }))
-    print(json.dumps({
-        "metric": "serving_migrate_gbps",
-        "value": round(mig["gbps"], 4),
-        "unit": "GB/s",
-        "seqs": mig["seqs"],
-        "blocks": mig["blocks"],
-    }))
-    print(json.dumps({
-        "metric": "device_op_rate",
-        "value": round(op_rate, 1),
-        "unit": "op/s",
-        "ops": n_ops,
-        "vs_baseline": BASELINE_DEVICE_OPS,
-    }))
-    return ratio
-
-
-def bench_spec_lane():
-    """Speculative decoding A/B: two identical engines — one plain
-    (spec_k=0), one running the prompt-lookup draft + one fused verify
-    lane (spec_k=4) — driven with the same repetition-heavy corpus the
-    committed spec replay corpus records (templated motif prompts whose
-    greedy continuations the n-gram matcher predicts). Greedy acceptance
-    makes the lanes bit-identical (raised on here, gated exactly in
-    tests/test_serving_spec.py), so the only delta is steps: the spec
-    lane commits up to k+1 tokens per fused launch. Emits tokens/s for
-    both lanes (1.3x floor), the run's accept rate, and the per-user
-    decode latency (request wall minus TTFT over tokens after the first
-    — the per-token latency one client observes)."""
-    import numpy as np
-
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, ServingEngine,
-                                  TinyTransformer)
-    from tools.record_serving_corpus_spec import SCHEDULE, SPEC_K, spec_prompt
-
-    # no QUICK trim — doubled instead: the 8-request schedule is only
-    # ~256 decode tokens, and a pass that short puts OS-scheduler noise
-    # on the same scale as the A/B delta; 16 requests keep a pass in the
-    # hundreds of milliseconds, and the longer generations amortize the
-    # prefill share out of the tokens/s ratio
-    sched = SCHEDULE * 2
-    n_tokens = sum(mn for _, mn, _ in sched)
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2)
-
-    def build(spec_k):
-        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                          cfg.n_layers, cfg.kv_dim)
-        model = TinyTransformer(cfg, kv)
-        # prefix_cache off: repeated warmups of the same motif prompts
-        # would otherwise fold prefill into the A/B, which is about the
-        # decode loop only. max_batch=1: speculation's win is fewer
-        # LAUNCHES per committed token, so the A/B runs where launch
-        # overhead dominates — a verify over k+1 rows costs ~one decode
-        # dispatch but commits up to k+1 tokens; at large batch the CPU
-        # sim's row compute scales linearly and hides exactly the
-        # dispatch overhead a real accelerator step is bound by (the
-        # batched-throughput story is the serving phase's A/B)
-        return ServingEngine(model, kv, EngineConfig(
-            max_batch=1, token_budget=512, idle_wait_s=0.002,
-            spec_k=spec_k), prefix_cache=False).start()
-
-    def run(engine, itls=None):
-        """One open-loop pass over the schedule; returns (wall_s, outputs)
-        and appends per-request mean decode ITL seconds to ``itls``."""
-        pend = []
-        t0 = time.perf_counter()
-        for plen, max_new, motif in sched:
-            ev = threading.Event()
-            box = {}
-            code, _ = engine.submit(
-                np.asarray(spec_prompt(plen, motif), dtype=np.int32),
-                max_new,
-                done=lambda r, box=box, ev=ev: (box.update(r=r,
-                                                           t=time.perf_counter()),
-                                                ev.set()))
-            if code != 0:
-                raise RuntimeError(f"spec bench submit rejected: {code}")
-            pend.append((ev, box))
-        outs = []
-        for ev, box in pend:
-            if not ev.wait(300):
-                raise RuntimeError("spec bench stalled")
-            r = box["r"]
-            outs.append(list(r.tokens))
-            if itls is not None and len(r.tokens) > 1:
-                decode_s = (box["t"] - t0) - r.ttft_us / 1e6
-                itls.append(max(0.0, decode_s) / (len(r.tokens) - 1))
-        return time.perf_counter() - t0, outs
-
-    REPS = 5  # best-of: one GC pause must not flip the A/B
-    base = build(0)
-    sp = build(SPEC_K)
-    try:
-        for _ in range(2):  # compile every bucket (2nd donated signature)
-            run(base)
-            run(sp)
-        base_wall, base_itl = float("inf"), []
-        sp_wall, sp_itl = float("inf"), []
-        base_outs = sp_outs = None
-        for _ in range(REPS):
-            w, base_outs = run(base, base_itl)
-            base_wall = min(base_wall, w)
-            w, sp_outs = run(sp, sp_itl)
-            sp_wall = min(sp_wall, w)
-        if sp_outs != base_outs:
-            raise RuntimeError(
-                "speculative lane diverged from baseline: greedy "
-                "acceptance must be bit-identical")
-        st = sp.spec_stats.snapshot()
-    finally:
-        sp.stop()
-        base.stop()
-        sp.model.close()
-        base.model.close()
-    tps = n_tokens / sp_wall
-    base_tps = n_tokens / base_wall
-    ratio = tps / max(base_tps, 1e-9)
-    itl_ms = 1e3 * sorted(sp_itl)[len(sp_itl) // 2] if sp_itl else 0.0
-    base_itl_ms = 1e3 * sorted(base_itl)[len(base_itl) // 2] \
-        if base_itl else 0.0
-    print(f"# serving spec: {len(sched)} reqs ({n_tokens} tokens) "
-          f"draft+verify k={SPEC_K}: spec={tps:,.0f} tok/s "
-          f"baseline={base_tps:,.0f} tok/s ratio={ratio:.2f}x "
-          f"({'OK' if ratio >= 1.3 else 'BELOW'} 1.3x floor) | "
-          f"accept_rate={st['accept_rate']:.2f} "
-          f"(drafted={st['drafted']} accepted={st['accepted']} "
-          f"bonus={st['bonus']}) | per-user decode itl p50 "
-          f"spec={itl_ms:.2f}ms baseline={base_itl_ms:.2f}ms",
-          file=sys.stderr)
-    print(json.dumps({
-        "metric": "serving_spec_tokens_per_s",
-        "value": round(tps, 1),
-        "unit": "tokens/s",
-        "baseline": round(base_tps, 1),
-        "ratio": round(ratio, 3),
-    }))
-    print(json.dumps({
-        "metric": "serving_spec_accept_rate",
-        "value": st["accept_rate"],
-        "unit": "ratio",
-        "drafted": st["drafted"],
-        "accepted": st["accepted"],
-        "bonus": st["bonus"],
-    }))
-    print(json.dumps({
-        "metric": "serving_spec_itl_ms",
-        "value": round(itl_ms, 3),
-        "unit": "ms",
-        "baseline_ms": round(base_itl_ms, 3),
-    }))
-    return ratio
-
-
-def bench_qos_lane():
-    """Multi-tenant QoS A/B under a best-effort flood: two engines see
-    the same offered load — a ``batch`` tenant (priority 0) dumping a
-    saturating wave, then a ``prod`` tenant (priority 1, weight 4)
-    submitting its steady work. The QoS engine meters admission by
-    weighted fair share and sheds batch past its queue cap
-    (EOVERCROWDED, retriable); the control engine is the plain FIFO
-    path, where prod queues behind the entire flood. Emits the
-    protected tenant's p99 (vs its unloaded p99 and the FIFO engine's
-    flooded p99) and the shed rate — the overload-survival headline
-    tests/test_bench_quick.py floor-gates."""
-    import numpy as np
-
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, QosConfig, ServingEngine,
-                                  TinyTransformer)
-
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2)
-    FLOOD, PROD_REQS = 32, 8
-    PLEN, MAX_NEW = 16, 8
-    qos_cfg = QosConfig(tenants={"prod": 4.0, "batch": 1.0},
-                        queue_cap=12, protected_priority=1)
-
-    def build(qos):
-        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                          cfg.n_layers, cfg.kv_dim)
-        model = TinyTransformer(cfg, kv)
-        # max_batch=2 + a tight budget keeps the flood saturating for
-        # many steps — the regime where admission ORDER is the outcome
-        return ServingEngine(model, kv, EngineConfig(
-            max_batch=2, token_budget=64, max_queue=256,
-            idle_wait_s=0.002, qos=qos), prefix_cache=False).start()
-
-    def submit(engine, tenant, priority, lats, sheds, pend):
-        t0 = time.perf_counter()
-        ev = threading.Event()
-        code, _ = engine.submit(
-            engine.model.synth_prompt(PLEN), MAX_NEW,
-            tenant_id=tenant, priority=priority,
-            done=lambda r, ev=ev, t0=t0: (
-                lats.append(time.perf_counter() - t0), ev.set()))
-        if code != 0:
-            sheds.append(code)
-        else:
-            pend.append(ev)
-
-    def drain(pend):
-        for ev in pend:
-            if not ev.wait(300):
-                raise RuntimeError("qos bench stalled")
-
-    def flood_run(engine):
-        """The overload wave: batch floods, then prod submits its work.
-        Returns (prod_p99_s, batch_shed, batch_sent)."""
-        prod_lats, batch_lats = [], []
-        prod_shed, batch_shed = [], []
-        pend = []
-        for _ in range(FLOOD):
-            submit(engine, "batch", 0, batch_lats, batch_shed, pend)
-        for _ in range(PROD_REQS):
-            submit(engine, "prod", 1, prod_lats, prod_shed, pend)
-        drain(pend)
-        if prod_shed:
-            raise RuntimeError("protected tenant was shed")
-        return (sorted(prod_lats)[max(0, int(len(prod_lats) * 0.99) - 1)],
-                len(batch_shed), FLOOD)
-
-    qos_eng = build(qos_cfg)
-    fifo = build(None)
-    try:
-        # compile both buckets on both engines (2nd donated signature)
-        for eng in (qos_eng, fifo):
-            for _ in range(2):
-                lats, sheds, pend = [], [], []
-                submit(eng, "prod", 1, lats, sheds, pend)
-                drain(pend)
-        # unloaded: the protected tenant alone, sequentially
-        unloaded = []
-        for _ in range(PROD_REQS):
-            lats, sheds, pend = [], [], []
-            submit(qos_eng, "prod", 1, lats, sheds, pend)
-            drain(pend)
-            unloaded.extend(lats)
-        unloaded_p99 = sorted(unloaded)[max(0,
-                                            int(len(unloaded) * 0.99) - 1)]
-        qos_p99, shed, sent = flood_run(qos_eng)
-        fifo_p99, fifo_shed, _ = flood_run(fifo)
-    finally:
-        qos_eng.stop()
-        fifo.stop()
-        qos_eng.model.close()
-        fifo.model.close()
-    ratio = qos_p99 / max(unloaded_p99, 1e-9)
-    vs_fifo = fifo_p99 / max(qos_p99, 1e-9)
-    shed_rate = shed / sent
-    print(f"# serving qos: flood={FLOOD} batch + {PROD_REQS} prod: "
-          f"protected p99 {qos_p99 * 1e3:.1f}ms "
-          f"(unloaded {unloaded_p99 * 1e3:.1f}ms, {ratio:.1f}x; "
-          f"fifo {fifo_p99 * 1e3:.1f}ms, qos {vs_fifo:.1f}x better) | "
-          f"batch shed {shed}/{sent} ({shed_rate:.0%}) "
-          f"fifo shed {fifo_shed}", file=sys.stderr)
-    print(json.dumps({
-        "metric": "serving_qos_protected_p99_ms",
-        "value": round(qos_p99 * 1e3, 3),
-        "unit": "ms",
-        "unloaded_ms": round(unloaded_p99 * 1e3, 3),
-        "ratio_vs_unloaded": round(ratio, 3),
-        "fifo_ms": round(fifo_p99 * 1e3, 3),
-        "fifo_ratio": round(vs_fifo, 3),
-    }))
-    print(json.dumps({
-        "metric": "serving_qos_shed_rate",
-        "value": round(shed_rate, 3),
-        "unit": "ratio",
-        "shed": shed,
-        "sent": sent,
-        "fifo_shed": fifo_shed,
-    }))
-    return vs_fifo
-
-
 def bench_native_lane():
     """The framework's native lane end to end: C++ bench client (the analog
     of the reference's C++ client binaries) against the C++ engine serving
@@ -1355,21 +600,6 @@ def bench_hybrid_native():
                   file=sys.stderr)
         finally:
             srv0.close()
-        # VERDICT r4 #2b lever, on the record: subinterpreter dispatch
-        # cost on this box (nproc=1 -> any dispatch is pure loss)
-        import subprocess as _sp
-
-        try:
-            out = _sp.run([sys.executable,
-                           os.path.join(REPO, "tools",
-                                        "subinterp_probe.py")],
-                          capture_output=True, text=True, timeout=120)
-            for line in out.stdout.splitlines():
-                if line.startswith("#"):
-                    print(line, file=sys.stderr)
-        except _sp.SubprocessError as e:
-            print(f"# subinterp probe failed: {type(e).__name__}",
-                  file=sys.stderr)
         ch = Channel(ChannelOptions(protocol="trpc_std", timeout_ms=30000,
                                     native_transport=True))
         ch.init(srv.endpoint)
@@ -1872,10 +1102,10 @@ def main() -> None:
         bench_batch_lane()
     # ---- one process per chip. Up to here every JAX user was a child
     # that came and went. The device lane's server and the kernel bench
-    # are the last such children; they run BEFORE the serving/spec/qos
-    # lanes and the probe initialise a backend in THIS process (after
-    # which _BenchServer refuses chip-owning children). A failure here
-    # fails the run: nothing is caught and reported as skipped.
+    # are the last such children; they run BEFORE the probe initialises
+    # a backend in THIS process (after which _BenchServer refuses
+    # chip-owning children). A failure here fails the run: nothing is
+    # caught and reported as skipped.
     if device_on:
         bench_device_lane()
         if not QUICK:
@@ -1892,12 +1122,6 @@ def main() -> None:
                 tail = (r.stderr or "").strip().splitlines()[-3:]
                 raise SystemExit(f"kernel bench failed rc={r.returncode}: "
                                  f"{' | '.join(tail)}")
-    if _phase_enabled("serving"):
-        bench_serving_lane()
-    if _phase_enabled("spec"):
-        bench_spec_lane()
-    if _phase_enabled("qos"):
-        bench_qos_lane()
     py_1mb = py_64b_qps = series_pct = None
     if _phase_enabled("shm"):
         py_1mb, py_64b_qps = bench_tpu_sweep()
@@ -1909,7 +1133,7 @@ def main() -> None:
         bench_device_probe()
     # headline: the framework's fastest supported lane (native when built,
     # like the reference's C++ stack; Python tpu:// sweep otherwise);
-    # omitted when neither lane ran (e.g. BENCH_PHASES=batch|serving)
+    # omitted when neither lane ran (e.g. BENCH_PHASES=batch)
     headline = native_1mb if native_1mb is not None else py_1mb
     if headline is not None:
         print(json.dumps({

@@ -1,5 +1,5 @@
 """Profiler builtin services — /hotspots/{cpu,heap,growth,contention,
-flame,continuous}, /pprof/{profile,heap,symbol,cmdline}, /vlog.
+flame,continuous}, /pprof/{profile,heap,symbol,cmdline}.
 
 Counterpart of the reference's ``builtin/hotspots_service.cpp`` (gperftools
 ProfilerStart / MallocExtension) and ``builtin/pprof_service.cpp`` (the
@@ -20,7 +20,6 @@ from __future__ import annotations
 import cProfile
 import io
 import json
-import logging
 import pstats
 import sys
 import threading
@@ -396,32 +395,6 @@ def pprof_cmdline_service(server, http: HttpMessage):
     return 200, CONTENT_TEXT, "\x00".join(sys.argv) + "\n"
 
 
-# ------------------------------------------------------------------ vlog
-def vlog_service(server, http: HttpMessage):
-    """/vlog — list logger levels; /vlog?logger=name&level=DEBUG sets one
-    (the reference's VLOG site toggling)."""
-    q = http.query
-    if q.get("logger") is not None:
-        name = q.get("logger") or None
-        level = (q.get("level") or "INFO").upper()
-        if level not in ("DEBUG", "INFO", "WARNING", "ERROR", "CRITICAL"):
-            return 400, CONTENT_TEXT, f"bad level {level!r}\n"
-        logging.getLogger(name).setLevel(level)
-        return 200, CONTENT_TEXT, f"{name or 'root'} -> {level}\n"
-    lines = ["# loggers (set with /vlog?logger=<name>&level=<LEVEL>)"]
-    all_loggers = [logging.getLogger()] + [
-        logging.getLogger(n)
-        for n in sorted(logging.root.manager.loggerDict)
-    ]
-    for lg in all_loggers:
-        if isinstance(lg, logging.PlaceHolder):
-            continue
-        eff = logging.getLevelName(lg.getEffectiveLevel())
-        own = (logging.getLevelName(lg.level) if lg.level else "-")
-        lines.append(f"{lg.name or 'root':<50} level={own:<8} eff={eff}")
-    return 200, CONTENT_TEXT, "\n".join(lines) + "\n"
-
-
 def _sub(http: HttpMessage) -> str:
     parts = http.path.strip("/").split("/", 1)
     return parts[1] if len(parts) > 1 else ""
@@ -455,4 +428,3 @@ def pprof_service(server, http: HttpMessage):
 register_builtin("hotspots", hotspots_service,
                  "cpu/heap/growth/contention/continuous profilers")
 register_builtin("pprof", pprof_service, "pprof-compatible endpoints")
-register_builtin("vlog", vlog_service, "list/set logger levels")

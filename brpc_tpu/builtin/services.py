@@ -678,8 +678,7 @@ def serving_service(server, http: HttpMessage):
     out = []
     for i, s in enumerate(snaps):
         kv = s["kv"]
-        out.append(f"[engine {i}] scheduling={s['scheduling']} "
-                   f"max_batch={s['max_batch']} "
+        out.append(f"[engine {i}] max_batch={s['max_batch']} "
                    f"token_budget={s['token_budget']}")
         out.append(f"  queue_depth={s['queue_depth']} "
                    f"running={s['running']} steps={s['steps']} "

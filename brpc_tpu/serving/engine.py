@@ -4,9 +4,7 @@ Orca-style scheduling mapped onto this repo's server: the engine thread
 runs a step loop where each step is a mixed prefill+decode batch under a
 token budget, and new requests are admitted *between* decode steps —
 a long generation never blocks a short one behind it (continuous
-batching). ``scheduling="static"`` keeps the classic gang behavior (admit
-a batch, drain it fully, admit the next) purely as the bench comparison
-lane.
+batching).
 
 Admission is where policy concentrates, mirroring the server's own
 front door:
@@ -99,10 +97,6 @@ g_serving_running = PassiveStatus(
 g_serving_running.prometheus_type = "gauge"
 
 
-SCHED_CONTINUOUS = "continuous"
-SCHED_STATIC = "static"
-
-
 ROLE_PREFILL = "prefill"
 ROLE_DECODE = "decode"
 ROLE_BOTH = "both"
@@ -111,12 +105,9 @@ ROLE_BOTH = "both"
 class EngineConfig:
     def __init__(self, max_batch: int = 8, token_budget: int = 512,
                  max_queue: int = 64, max_new_tokens_cap: int = 512,
-                 scheduling: str = SCHED_CONTINUOUS,
                  idle_wait_s: float = 0.05, role: str = ROLE_BOTH,
                  spec_k: int = 0, spec_ngram: int = 3,
                  spec_collapse_after: int = 4, qos=None):
-        if scheduling not in (SCHED_CONTINUOUS, SCHED_STATIC):
-            raise ValueError(f"unknown scheduling {scheduling!r}")
         if role not in (ROLE_PREFILL, ROLE_DECODE, ROLE_BOTH):
             raise ValueError(f"unknown role {role!r}")
         if spec_k < 0:
@@ -127,7 +118,6 @@ class EngineConfig:
         self.token_budget = token_budget
         self.max_queue = max_queue
         self.max_new_tokens_cap = max_new_tokens_cap
-        self.scheduling = scheduling
         self.idle_wait_s = idle_wait_s
         # disaggregated serving: a "prefill" engine runs prefill then
         # migrates each chain to its KVMigrator's destination (falling
@@ -265,13 +255,11 @@ class ServingEngine:
         # scatter — concurrent writers see deleted/donated buffers
         self.pool_gate = threading.Lock()
         self._recover_index: Dict[tuple, Deque[int]] = {}
-        # per-engine counters the disaggregation oracle and bench need
-        # (the g_serving_* fleet vars cannot isolate one engine)
+        # per-engine counter the disaggregation oracle needs (the
+        # g_serving_* fleet vars cannot isolate one engine)
         self.prefill_tokens = 0
-        self.ttft_samples: List[float] = []  # us, bounded
-        self.itl_samples: List[float] = []   # us, bounded
-        # speculative decoding: per-engine counters (the A/B bench and
-        # the oracle need per-lane isolation, like the fields above)
+        # speculative decoding: per-engine counters (the oracle needs
+        # per-lane isolation, like the field above)
         self.spec_stats = (_spec.SpecStats()
                            if self.config.spec_k > 0 else None)
         # multi-tenant QoS: the fair-share scheduler replaces _waiting
@@ -651,11 +639,9 @@ class ServingEngine:
 
     def _admit_locked(self) -> List[Sequence]:
         """Pull waiting sequences into the running set — called between
-        steps with the lock held. Continuous mode refills whenever a slot
-        and budget exist; static mode only when the gang drained."""
+        steps with the lock held: refills whenever a slot and budget
+        exist."""
         cfg = self.config
-        if cfg.scheduling == SCHED_STATIC and self._running:
-            return []
         admitted: List[Sequence] = []
         # migrated-in chains first (already prefilled, zero prefill
         # cost) — capped by max_batch so the decode batch never exceeds
@@ -1002,12 +988,8 @@ class ServingEngine:
         if not seq.out_tokens:
             seq.t_first_token = now
             g_serving_ttft.record((now - seq.t_submit) * 1e6)
-            if len(self.ttft_samples) < 65536:
-                self.ttft_samples.append((now - seq.t_submit) * 1e6)
         elif seq.t_last_token:
             g_serving_itl.record((now - seq.t_last_token) * 1e6)
-            if len(self.itl_samples) < 65536:
-                self.itl_samples.append((now - seq.t_last_token) * 1e6)
         seq.t_last_token = now
         seq.out_tokens.append(tok)
         self.tokens_generated += 1
@@ -1217,7 +1199,6 @@ class ServingEngine:
         return {
             "role": self.config.role,
             "migration": migration,
-            "scheduling": self.config.scheduling,
             "max_batch": self.config.max_batch,
             "token_budget": self.config.token_budget,
             "queue_depth": self.queue_depth,

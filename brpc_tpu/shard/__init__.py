@@ -4,8 +4,8 @@ PR 9 (docs/small-message-fastpath.md) measured the single-core ceiling:
 ~323µs of irreducible Python CPU per call, all latency tricks applied. The
 reference escapes this with bthread's M:N scheduler spreading work over
 every core (PAPER.md, runtime layer); CPython cannot — one GIL per
-process — and ``tools/subinterp_probe.py`` recorded the negative result
-for same-process subinterpreter dispatch. So our idiomatic analog is OS
+process — and same-process subinterpreter dispatch was a recorded
+negative result (docs/round5-notes.md). So our idiomatic analog is OS
 processes: a parent keeps owning the tunnel's control plane (handshake,
 epochs, credit window, healer) while the CPU-heavy middle — TRPC frame
 parse, method dispatch, response pack — runs in worker processes.

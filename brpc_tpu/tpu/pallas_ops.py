@@ -662,119 +662,6 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
     )(q, k, v)
 
 
-# ---------------------------------------------------------------------------
-# q-grid flash forward — the causal-first variant (round 5, VERDICT r4 #1).
-#
-# This substrate charges ~1.5-2 µs of pipeline overhead per grid step
-# (tools/causal_sweep.py, docs/round5-notes.md), so the (qi, ki) grid pays
-# a k-tile's overhead even for skipped tiles, and causal utilization x
-# per-tile-throughput caps near 37%. Here the grid is (batch, q-tile) ONLY:
-# the whole K/V row sits in VMEM (index map ignores qi, so Mosaic fetches
-# K/V once per head, not once per q-tile), and the kernel walks k-chunks
-# with an in-kernel fori_loop whose trip counts are EXACT for causal —
-# nfull mask-free chunks strictly below the diagonal, then the masked
-# diagonal band, nothing else. No skipped-tile fetch, no per-k-step
-# overhead, no wasted MXU work beyond the diagonal chunk interiors.
-# ---------------------------------------------------------------------------
-def _flash_fwd_qgrid_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                            causal: bool, bq: int, bkc: int, sk: int,
-                            bn: int):
-    import jax.experimental.pallas as pl
-
-    qi = pl.program_id(1)
-    nkc = sk // bkc
-
-    for j in range(bn):
-        scale = 1.0 / float(q_ref.shape[-1]) ** 0.5
-        q = q_ref[j]
-
-        def chunk(c, carry, masked):
-            m_prev, l_prev, acc_prev = carry
-            k = k_ref[j, pl.ds(c * bkc, bkc)]
-            v = v_ref[j, pl.ds(c * bkc, bkc)]
-            s = _dot_f32(q, k, trans_b=True) * scale
-            if masked:
-                q_pos = qi * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bkc), 0)
-                k_pos = c * bkc + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bkc), 1)
-                s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            if masked:
-                alive = m_new > NEG_INF / 2
-                p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
-                alpha = jnp.where(alive, jnp.exp(m_prev - m_new), 0.0)
-            else:
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc_prev * alpha + _dot_f32(p.astype(v.dtype), v)
-            return m_new, l_new, acc_new
-
-        init = (jnp.full((bq, 1), NEG_INF, jnp.float32),
-                jnp.zeros((bq, 1), jnp.float32),
-                jnp.zeros((bq, q.shape[-1]), jnp.float32))
-        if causal:
-            # chunks [0, nfull) are strictly below the diagonal; the band
-            # [nfull, nlive) holds the diagonal and is masked
-            nfull = (qi * bq) // bkc
-            nlive = jax.lax.div(qi * bq + bq + bkc - 1, bkc)
-            carry = jax.lax.fori_loop(
-                0, nfull, lambda c, cr: chunk(c, cr, False), init)
-            m, l, acc = jax.lax.fori_loop(
-                nfull, nlive, lambda c, cr: chunk(c, cr, True), carry)
-        else:
-            m, l, acc = jax.lax.fori_loop(
-                0, nkc, lambda c, cr: chunk(c, cr, False), init)
-
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[j] = (acc / safe).astype(o_ref.dtype)
-        lse_ref[j] = jnp.where(l == 0.0, NEG_INF, m + jnp.log(safe))
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "bq", "bkc",
-                                             "interpret", "bn"))
-def _flash_fwd_qgrid(q, k, v, causal: bool, bq: int, bkc: int,
-                     interpret: bool, bn: int = 1):
-    """q-grid forward over [N, S, D]: returns (o, lse). K/V rows resident
-    in VMEM — requires sk*d*(2 dtypes)*bn*2(double-buffer) well under the
-    ~16MB VMEM budget; callers gate on shape."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n, sq, d = q.shape
-    sk = k.shape[1]
-    nq = sq // bq
-    if n % bn or sq % bq or sk % bkc:
-        raise ValueError(f"shape ({n},{sq},{sk}) vs blocks "
-                         f"({bn},{bq},{bkc})")
-    kernel = functools.partial(_flash_fwd_qgrid_kernel, causal=causal,
-                               bq=bq, bkc=bkc, sk=sk, bn=bn)
-    params = (None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary")))
-    return pl.pallas_call(
-        kernel,
-        grid=(n // bn, nq),
-        in_specs=[
-            pl.BlockSpec((bn, bq, d), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((bn, sk, d), lambda b, qi: (b, 0, 0)),
-            pl.BlockSpec((bn, sk, d), lambda b, qi: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn, bq, d), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((bn, bq, 1), lambda b, qi: (b, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((n, sq, 1), jnp.float32),
-        ],
-        compiler_params=params,
-        interpret=interpret,
-        name="flash_fwd_qgrid",
-    )(q, k, v)
-
-
 def _flash_delta(o, do):
     """delta = rowsum(dO * O) — loop-invariant in the ring backward, so
     it is computed ONCE by the caller, not per hop."""
@@ -858,11 +745,9 @@ def _flash_bwd_bhsd(q, k, v, lse, do, delta, q_start, k_start,
 
 
 def _flash_fwd_best(q, k, v, causal, bq, bk, interpret):
-    """Forward dispatch (round-5 sweeps, docs/round5-notes.md): causal
-    self-attention takes the folded triangular grid (no skipped steps,
-    ~9% over the rectangular grid); everything else takes the (qi, ki)
-    grid with bn=2 heads per step when the batch divides (74.8% vs 60.8%
-    of peak at the flagship shape)."""
+    """Forward dispatch: causal self-attention takes the folded
+    triangular grid (no skipped steps); everything else takes the (qi, ki)
+    grid with bn=2 heads per step when the batch divides."""
     n = q.shape[0]
     if causal and bq == bk and q.shape[1] == k.shape[1]:
         return _flash_fwd_folded(q, k, v, bq, interpret)

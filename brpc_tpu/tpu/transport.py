@@ -695,6 +695,25 @@ class TpuEndpoint:
         }
 
     # --------------------------------------------------------------- handshake
+    def _data_hdr(self, inline_len: int, nsegs: int) -> bytes:
+        """Head of a DATA body. A v1 peer (the native lane,
+        dataplane.cpp TFT_DATA / TFT_ACK) frames DATA and ACK bodies
+        without the leading epoch word, and ``on_data`` / ``on_ack`` take
+        this endpoint's epoch for the frame's. An endpoint with a v1 peer
+        never changes generation, so there is no stale frame to tell: a
+        v1 HELLO carries no ``gen`` and a repeat one is dropped as stale
+        (``on_hello``), and a dialer heals on a new connection with a new
+        endpoint (``TunnelHealer._dial_once``)."""
+        if self.peer_version == 1:
+            return struct.pack("!II", inline_len, nsegs)
+        return struct.pack(DATA_BODY_HDR, self.epoch, inline_len, nsegs)
+
+    def _ack_body(self, acks) -> bytes:
+        if self.peer_version == 1:
+            return struct.pack(f"!{len(acks) + 1}I", len(acks), *acks)
+        return struct.pack(f"!{len(acks) + 2}I", self.epoch, len(acks),
+                           *acks)
+
     def _hello_body(self, ordinal: int, err: str = "") -> bytes:
         pool = self.recv_pool
         body = {
@@ -942,11 +961,11 @@ class TpuEndpoint:
             # single-frame case: build one contiguous bytes object instead
             # of an IOBuf — a small echo pays this framing cost twice per
             # RPC and bytes.join beats block-list assembly at these sizes
+            hdr = self._data_hdr(total, 0)
             frame = b"".join(
                 (struct.pack(CTRL_HDR, CTRL_MAGIC, FT_DATA,
-                             DATA_BODY_HDR_SIZE + total),
-                 struct.pack(DATA_BODY_HDR, self.epoch, total, 0),
-                 *views))
+                             len(hdr) + total),
+                 hdr, *views))
             rc = self._write_data_frame(frame)
             if rc != 0:
                 return rc, False
@@ -970,9 +989,10 @@ class TpuEndpoint:
                     vi += 1
                     voff = 0
             frame = IOBuf()
+            hdr = self._data_hdr(part_len, 0)
             frame.append(struct.pack(CTRL_HDR, CTRL_MAGIC, FT_DATA,
-                                     DATA_BODY_HDR_SIZE + part_len))
-            frame.append(struct.pack(DATA_BODY_HDR, self.epoch, part_len, 0))
+                                     len(hdr) + part_len))
+            frame.append(hdr)
             for p in parts:
                 frame.append(p)
             rc = self._write_data_frame(frame)
@@ -1047,7 +1067,7 @@ class TpuEndpoint:
                     segs.append((idx, blk_off))
                     if sent >= total:
                         break
-                body = struct.pack(DATA_BODY_HDR, self.epoch, 0, len(segs))
+                body = self._data_hdr(0, len(segs))
                 body += b"".join(struct.pack(SEG_FMT, i, ln)
                                  for i, ln in segs)
                 rc = self._write_data_frame(_pack_frame(FT_DATA, body))
@@ -1107,12 +1127,15 @@ class TpuEndpoint:
         parser (the eager-copy behavior this path replaced)."""
         if self._failed:
             return
-        if len(body) < DATA_BODY_HDR_SIZE:
+        v1 = self.peer_version == 1      # no epoch word: _data_hdr
+        hdr_size = 8 if v1 else DATA_BODY_HDR_SIZE
+        if len(body) < hdr_size:
             self.fail(errors.EREQUEST, "short DATA frame")
             return
-        epoch, inline_len, nsegs = struct.unpack(
-            DATA_BODY_HDR, body.fetch(DATA_BODY_HDR_SIZE))
-        body.pop_front(DATA_BODY_HDR_SIZE)
+        *gen, inline_len, nsegs = struct.unpack(
+            "!II" if v1 else DATA_BODY_HDR, body.fetch(hdr_size))
+        epoch = self.epoch if v1 else gen[0]
+        body.pop_front(hdr_size)
         if epoch != self.epoch:
             # a frame from a previous window generation (in flight across
             # a re-handshake): its block refs point into the torn-down
@@ -1264,8 +1287,7 @@ class TpuEndpoint:
         _fault.maybe_sleep(_fault.hit("tpu.ack.stall"))  # tpulint: disable=no-blocking-in-poller
         if _fault.hit("tpu.ack.drop") is not None:
             return  # credits vanish: the peer's window wedges until heal
-        body = struct.pack(f"!{len(acks) + 2}I", self.epoch, len(acks),
-                           *acks)
+        body = self._ack_body(acks)
         g_tunnel_ack_frames.put(1)
         g_tunnel_ack_credits.put(len(acks))
         if self.ctrl.write(_pack_frame(FT_ACK, body)) != 0:
@@ -1382,8 +1404,7 @@ class TpuEndpoint:
                     return errors.EFAILEDSOCKET
                 for k in range(0, len(segs), MAX_SEGS_PER_FRAME):
                     chunk = segs[k:k + MAX_SEGS_PER_FRAME]
-                    body = struct.pack(DATA_BODY_HDR, self.epoch, 0,
-                                       len(chunk))
+                    body = self._data_hdr(0, len(chunk))
                     body += b"".join(struct.pack(SEG_FMT, i, ln)
                                      for i, ln in chunk)
                     rc = self._write_data_frame(_pack_frame(FT_DATA, body))
@@ -1419,6 +1440,8 @@ class TpuEndpoint:
     @poller_context
     def on_ack(self, body: bytes) -> None:
         vals = struct.unpack(f"!{len(body) // 4}I", body[:len(body) & ~3])
+        if self.peer_version == 1:
+            vals = (self.epoch,) + vals
         if len(vals) < 2:
             return
         epoch, n = vals[0], vals[1]

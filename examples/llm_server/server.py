@@ -5,7 +5,7 @@ scheduling) behind LlmService, with token streaming over the Stream API.
 Browse http://<host>:<port>/serving while the client runs to watch batch
 occupancy and the KV watermark.
 
-    python examples/llm_server/server.py [--port 8011] [--scheduling continuous]
+    python examples/llm_server/server.py [--port 8011]
 """
 
 import argparse
@@ -34,16 +34,13 @@ def build_engine(args) -> ServingEngine:
         model_cfg.n_layers, model_cfg.kv_dim)
     model = TinyTransformer(model_cfg, kv)
     engine = ServingEngine(model, kv, EngineConfig(
-        max_batch=args.max_batch, token_budget=args.token_budget,
-        scheduling=args.scheduling))
+        max_batch=args.max_batch, token_budget=args.token_budget))
     return engine.start()
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--port", type=int, default=8011)
-    ap.add_argument("--scheduling", choices=("continuous", "static"),
-                    default="continuous")
     ap.add_argument("--max_batch", type=int, default=8)
     ap.add_argument("--token_budget", type=int, default=512)
     ap.add_argument("--block_size", type=int, default=16)
@@ -63,7 +60,7 @@ def main(argv=None):
     server = Server().add_service(LlmServingService(engine))
     server.start(f"0.0.0.0:{args.port}")
     print(f"LlmServer on {server.listen_endpoint()} "
-          f"({args.scheduling} batching, "
+          f"(continuous batching, "
           f"{args.num_blocks}x{args.block_size}-token KV blocks) — "
           f"see /serving", flush=True)
     try:

@@ -53,3 +53,31 @@ def _brpc_tpu_check_ledger():
     except Exception:
         _drain = None
     _rc.ledger.assert_balanced(drain=_drain)
+
+
+@pytest.fixture()
+def empty_registry():
+    """An empty variable registry for one test. Afterwards the registry
+    holds what it held before and what a module still owns: a module
+    imported for the first time during the test exposed its ``g_*``
+    variables then (``serving.engine`` in ``test_fleet.py``, the tunnel in
+    ``test_tail_dump.py``), and they belong to every file that runs later
+    in this worker. What only the test owned is gone."""
+    import gc
+    import weakref
+
+    from brpc_tpu.metrics.variable import (Variable, clear_registry,
+                                           exposed_variables, get_exposed)
+
+    saved = exposed_variables()
+    clear_registry()
+    yield
+    fresh = [(name, weakref.ref(var)) for name, var in exposed_variables()]
+    clear_registry()
+    gc.collect()        # a recorder and its exposed parts are a cycle
+    for name, var in saved:
+        Variable.expose(var, name)
+    for name, ref in fresh:
+        var = ref()
+        if var is not None and get_exposed(name) is None:
+            Variable.expose(var, name)

@@ -149,271 +149,6 @@ def test_batch_phase_skips_others(batch_bench_run):
     assert "# device lane" not in err
 
 
-@pytest.fixture(scope="module")
-def serving_bench_run():
-    # 8 virtual CPU devices so the sharded A/B runs the real dp=2/sp=2/tp=2
-    # serving mesh (matches tests/conftest.py) instead of the 1x1x1
-    # degenerate
-    env = dict(os.environ,
-               BENCH_QUICK="1",
-               BENCH_PHASES="serving",
-               BENCH_SKIP_DEVICE="1",
-               JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          capture_output=True, text=True, timeout=420,
-                          cwd=REPO, env=env)
-    assert proc.returncode == 0, \
-        f"bench.py failed rc={proc.returncode}:\n{proc.stderr[-2000:]}"
-    return proc
-
-
-def test_serving_lane_json_metrics(serving_bench_run):
-    """The serving phase emits exactly its ten machine-readable lines:
-    streamed tokens/sec, TTFT percentiles measured at stream-frame
-    arrival, the continuous-vs-static scheduling ratio (sharded stack),
-    the sharded engine's tokens/sec, the prefix-cache hit-TTFT A/B pair,
-    the disaggregated prefill/decode interference A/B pair plus the
-    migration lane's GB/s, and the coalesced device dispatch rate vs the
-    BENCH_r05 isolated-dispatch baseline."""
-    rows = [json.loads(l) for l in serving_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    by = {r["metric"]: r for r in rows}
-    assert set(by) == {"serving_tokens_per_sec", "serving_ttft_ms",
-                       "serving_continuous_vs_static",
-                       "serving_sharded_tokens_per_s",
-                       "serving_prefix_hit_ttft_ms",
-                       "serving_prefix_hit_ratio",
-                       "serving_disagg_decode_jitter",
-                       "serving_disagg_ttft_ms",
-                       "serving_migrate_gbps",
-                       "device_op_rate"}, \
-        serving_bench_run.stdout
-    assert by["serving_tokens_per_sec"]["unit"] == "tokens/s"
-    assert by["serving_tokens_per_sec"]["value"] > 0
-    ttft = by["serving_ttft_ms"]
-    assert ttft["unit"] == "ms" and ttft["value"] > 0
-    assert ttft["p99"] >= ttft["value"], ttft
-    sharded = by["serving_sharded_tokens_per_s"]
-    assert sharded["unit"] == "tokens/s" and sharded["value"] > 0, sharded
-    # the fixture forces 8 virtual devices -> the dp=2/sp=2/tp=2 mesh
-    assert sharded["devices"] == 8, sharded
-    ops = by["device_op_rate"]
-    assert ops["unit"] == "op/s" and ops["value"] > 0, ops
-    assert ops["vs_baseline"] == 7222.0, ops
-    # coalesced dispatch must beat the isolated per-RPC baseline even on
-    # the CPU sim (the fused-program path skips per-op Python dispatch)
-    assert ops["value"] > ops["vs_baseline"], ops
-
-
-def test_serving_continuous_beats_static_by_1_5x(serving_bench_run):
-    """The acceptance floor: iteration-level admission must clear 1.5x the
-    static-gang QPS on the mixed-length A/B (3:1 short:long, so every
-    static gang drains behind one straggler) — with sharding on: the A/B
-    runs MeshTransformer + ShardedKVCache over the 8-virtual-device
-    mesh."""
-    rows = [json.loads(l) for l in serving_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    ab = [r for r in rows
-          if r["metric"] == "serving_continuous_vs_static"][0]
-    assert ab["continuous_qps"] > 0 and ab["static_qps"] > 0, ab
-    assert ab["value"] >= 1.5, ab
-    lane = [l for l in serving_bench_run.stderr.splitlines()
-            if l.startswith("# serving lane:")]
-    assert lane and "OK 1.5x floor" in lane[0], \
-        serving_bench_run.stderr[-2000:]
-
-
-def test_serving_prefix_hit_ttft_floor(serving_bench_run):
-    """The prefix-cache acceptance floor: on the shared-prefix corpus a
-    warm (cache-hit) generation's TTFT must come in at no more than half
-    the cold engine's — the radix fork replaces O(prompt) prefill with
-    one decode-shaped suffix launch."""
-    rows = [json.loads(l) for l in serving_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    hit = [r for r in rows
-           if r["metric"] == "serving_prefix_hit_ttft_ms"][0]
-    assert hit["unit"] == "ms" and hit["value"] > 0, hit
-    assert hit["cold_ms"] > 0, hit
-    assert hit["value"] <= 0.5 * hit["cold_ms"], hit
-    assert hit["ratio"] <= 0.5, hit
-    ratio = [r for r in rows
-             if r["metric"] == "serving_prefix_hit_ratio"][0]
-    # warmup primes the tree: all but the very first request hit
-    assert ratio["unit"] == "ratio" and ratio["value"] >= 0.5, ratio
-    lane = [l for l in serving_bench_run.stderr.splitlines()
-            if l.startswith("# serving prefix:")]
-    assert lane and "OK 0.5x ceiling" in lane[0], \
-        serving_bench_run.stderr[-2000:]
-
-
-def test_serving_disagg_interference_floor(serving_bench_run):
-    """The disaggregation acceptance floor: on the 3:1 mixed corpus the
-    decode engine of the disaggregated pair must show strictly less
-    inter-token jitter (p99-p50 ITL) than the co-located engine whose
-    decode steps share a loop with the long prefill launches — and the
-    migration lane must have actually moved bytes (GB/s > 0)."""
-    rows = [json.loads(l) for l in serving_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    jit = [r for r in rows
-           if r["metric"] == "serving_disagg_decode_jitter"][0]
-    assert jit["unit"] == "ms", jit
-    assert jit["coloc_ms"] > 0, jit
-    assert jit["value"] < jit["coloc_ms"], jit
-    ttft = [r for r in rows if r["metric"] == "serving_disagg_ttft_ms"][0]
-    assert ttft["value"] > 0 and ttft["coloc_ms"] > 0, ttft
-    gbps = [r for r in rows if r["metric"] == "serving_migrate_gbps"][0]
-    assert gbps["unit"] == "GB/s" and gbps["value"] > 0, gbps
-    assert gbps["seqs"] > 0 and gbps["blocks"] > 0, gbps
-    lane = [l for l in serving_bench_run.stderr.splitlines()
-            if l.startswith("# serving disagg:")]
-    assert lane and "OK interference floor" in lane[0], \
-        serving_bench_run.stderr[-2000:]
-
-
-def test_serving_phase_skips_others(serving_bench_run):
-    err = serving_bench_run.stderr
-    assert "# tpu:// sweep" not in err
-    assert "# batch lane (" not in err
-    assert "# device lane" not in err
-    assert "# serving spec:" not in err
-
-
-@pytest.fixture(scope="module")
-def spec_bench_run():
-    env = dict(os.environ,
-               BENCH_QUICK="1",
-               BENCH_PHASES="spec",
-               BENCH_SKIP_DEVICE="1",
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          capture_output=True, text=True, timeout=300,
-                          cwd=REPO, env=env)
-    assert proc.returncode == 0, \
-        f"bench.py failed rc={proc.returncode}:\n{proc.stderr[-2000:]}"
-    return proc
-
-
-def test_spec_lane_json_metrics(spec_bench_run):
-    """The spec phase emits exactly its three machine-readable lines:
-    the speculative-vs-baseline tokens/s A/B, the run's accept rate, and
-    the per-user decode latency pair."""
-    rows = [json.loads(l) for l in spec_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    by = {r["metric"]: r for r in rows}
-    assert set(by) == {"serving_spec_tokens_per_s",
-                       "serving_spec_accept_rate",
-                       "serving_spec_itl_ms"}, spec_bench_run.stdout
-    tps = by["serving_spec_tokens_per_s"]
-    assert tps["unit"] == "tokens/s" and tps["value"] > 0, tps
-    assert tps["baseline"] > 0, tps
-    itl = by["serving_spec_itl_ms"]
-    assert itl["unit"] == "ms" and itl["value"] > 0, itl
-    assert itl["baseline_ms"] > 0, itl
-
-
-def test_spec_beats_baseline_by_1_3x(spec_bench_run):
-    """The acceptance floor: on the repetition-heavy corpus the
-    draft+verify lane must clear 1.3x the non-speculative engine's
-    tokens/s — k accepted drafts plus the bonus token ride one fused
-    verify launch, so committed tokens per dispatch goes up while the
-    bit-identity oracle (checked inside the lane, gated exactly in
-    test_serving_spec.py) pins correctness."""
-    rows = [json.loads(l) for l in spec_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    tps = [r for r in rows if r["metric"] == "serving_spec_tokens_per_s"][0]
-    assert tps["ratio"] >= 1.3, tps
-    lane = [l for l in spec_bench_run.stderr.splitlines()
-            if l.startswith("# serving spec:")]
-    assert lane and "OK 1.3x floor" in lane[0], \
-        spec_bench_run.stderr[-2000:]
-
-
-def test_spec_accept_rate_on_repetitive_corpus(spec_bench_run):
-    """Prompt-lookup must actually hit on the motif corpus — an accept
-    rate near zero means the lane is winning (or losing) for the wrong
-    reason."""
-    rows = [json.loads(l) for l in spec_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    ar = [r for r in rows if r["metric"] == "serving_spec_accept_rate"][0]
-    assert ar["unit"] == "ratio", ar
-    assert ar["drafted"] > 0 and ar["accepted"] > 0, ar
-    assert ar["value"] >= 0.5, ar
-
-
-def test_spec_phase_skips_others(spec_bench_run):
-    err = spec_bench_run.stderr
-    assert "# serving lane:" not in err
-    assert "# tpu:// sweep" not in err
-    assert "# batch lane (" not in err
-    assert "# device lane" not in err
-
-
-@pytest.fixture(scope="module")
-def qos_bench_run():
-    env = dict(os.environ,
-               BENCH_QUICK="1",
-               BENCH_PHASES="qos",
-               BENCH_SKIP_DEVICE="1",
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          capture_output=True, text=True, timeout=300,
-                          cwd=REPO, env=env)
-    assert proc.returncode == 0, \
-        f"bench.py failed rc={proc.returncode}:\n{proc.stderr[-2000:]}"
-    return proc
-
-
-def test_qos_lane_json_metrics(qos_bench_run):
-    """The qos phase emits exactly its two machine-readable lines: the
-    protected tenant's p99 under the best-effort flood (with its
-    unloaded and FIFO-engine comparators) and the flood's shed rate."""
-    rows = [json.loads(l) for l in qos_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    by = {r["metric"]: r for r in rows}
-    assert set(by) == {"serving_qos_protected_p99_ms",
-                       "serving_qos_shed_rate"}, qos_bench_run.stdout
-    p99 = by["serving_qos_protected_p99_ms"]
-    assert p99["unit"] == "ms" and p99["value"] > 0, p99
-    assert p99["unloaded_ms"] > 0 and p99["fifo_ms"] > 0, p99
-
-
-def test_qos_protects_p99_vs_fifo(qos_bench_run):
-    """The acceptance floor: under the same flood the fair-share engine
-    must hold the protected tenant's p99 to a fraction of the FIFO
-    engine's — on FIFO, prod queues behind the whole best-effort wave;
-    with QoS, weighted admission interleaves it ahead."""
-    rows = [json.loads(l) for l in qos_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    p99 = [r for r in rows
-           if r["metric"] == "serving_qos_protected_p99_ms"][0]
-    assert p99["fifo_ratio"] >= 1.5, p99
-    lane = [l for l in qos_bench_run.stderr.splitlines()
-            if l.startswith("# serving qos:")]
-    assert lane, qos_bench_run.stderr[-2000:]
-
-
-def test_qos_sheds_best_effort_flood(qos_bench_run):
-    """The flood past the batch tenant's queue cap must shed
-    EOVERCROWDED at admission (the FIFO engine, with no per-tenant cap,
-    absorbs the whole wave into its queue)."""
-    rows = [json.loads(l) for l in qos_bench_run.stdout.splitlines()
-            if l.startswith("{")]
-    shed = [r for r in rows if r["metric"] == "serving_qos_shed_rate"][0]
-    assert shed["unit"] == "ratio", shed
-    assert shed["shed"] > 0 and shed["sent"] > 0, shed
-    assert shed["value"] >= 0.3, shed
-    assert shed["fifo_shed"] == 0, shed
-
-
-def test_qos_phase_skips_others(qos_bench_run):
-    err = qos_bench_run.stderr
-    assert "# serving lane:" not in err
-    assert "# serving spec:" not in err
-    assert "# tpu:// sweep" not in err
-    assert "# batch lane (" not in err
-
-
 def test_zero_copy_counters_emitted(bench_run):
     err = bench_run.stderr
     zc = [l for l in err.splitlines()

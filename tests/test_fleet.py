@@ -19,7 +19,6 @@ from brpc_tpu.fleet import (
     global_slo,
     set_global_observer,
 )
-from brpc_tpu.metrics import clear_registry
 from brpc_tpu.metrics.reducer import Adder
 from brpc_tpu.metrics.series import global_series
 from brpc_tpu.metrics.status import PassiveStatus
@@ -28,14 +27,12 @@ from brpc_tpu.metrics.watch import STATE_FIRING, STATE_OK, global_watch
 
 
 @pytest.fixture(autouse=True)
-def _clean_state():
-    clear_registry()
+def _clean_state(empty_registry):
     global_series().clear()
     yield
     global_slo().clear()
     set_global_observer(None)
     fault.disarm_all()
-    clear_registry()
     global_series().clear()
 
 

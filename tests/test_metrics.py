@@ -18,18 +18,13 @@ from brpc_tpu.metrics import (
     PassiveStatus,
     MultiDimension,
     Window,
-    clear_registry,
     dump_exposed,
     get_exposed,
     prometheus_text,
 )
 
 
-@pytest.fixture(autouse=True)
-def _clean_registry():
-    clear_registry()
-    yield
-    clear_registry()
+pytestmark = pytest.mark.usefixtures("empty_registry")
 
 
 class TestReducers:

@@ -238,6 +238,45 @@ class TestNativeTpuTunnel:
                                            payload=b"p" * 300000))
         assert r.message == "pn" and len(r.payload) == 300000
 
+    def test_python_client_heals_against_native_server(
+            self, tpu_native_server):
+        """A v1 peer's DATA and ACK bodies carry no epoch word, so the
+        stale-generation guard is off for it. It has nothing to guard: a
+        Python dialer heals on a NEW connection with a new endpoint (the
+        epoch is fixed for an endpoint's life), so a frame of the dead
+        generation arrives at the dead endpoint or nowhere."""
+        from brpc_tpu import fault
+        from brpc_tpu import flags as _flags
+        from brpc_tpu.tpu import transport as tr
+
+        stub = _stub(tpu_native_server, native=False, timeout_ms=30000)
+        assert stub.Echo(echo_pb2.EchoRequest(message="warm")).message \
+            == "warm"
+        ep = tpu_native_server.listen_endpoint()
+        key = (ep.host, ep.port, ep.device_ordinal)
+        vs0 = tr._remote_sockets.get(key)
+        assert vs0 is not None and vs0.endpoint.peer_version == 1
+        stale0 = tr.g_tunnel_stale_epoch_frames.get_value()
+        payload = b"h" * (4 * 1024 * 1024)
+        _flags.set_flag("fault_injection_enabled", True)
+        try:
+            # the 3rd DATA frame of the streamed send kills the vsock; the
+            # retried attempt lands on a healed tunnel
+            fault.arm("tpu.tunnel.kill", after=2)
+            r = stub.Echo(echo_pb2.EchoRequest(message="big",
+                                               payload=payload))
+        finally:
+            fault.disarm_all()
+            _flags.set_flag("fault_injection_enabled", False)
+        assert r.message == "big" and r.payload == payload
+        assert vs0.failed                              # the kill was real
+        vs1 = tr._remote_sockets.get(key)
+        assert vs1 is not None and vs1 is not vs0 and not vs1.failed
+        assert vs1.endpoint is not vs0.endpoint
+        assert vs1.endpoint.epoch > vs0.endpoint.epoch
+        assert vs1.endpoint.peer_version == 1
+        assert tr.g_tunnel_stale_epoch_frames.get_value() == stale0
+
     def test_native_client_python_server(self):
         server = Server(ServerOptions())  # Python tpu transport end
         server.add_service(EchoImpl())

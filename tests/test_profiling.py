@@ -390,16 +390,27 @@ class TestProfiling:
         assert results and results[0].status == 200
 
     def test_heap_snapshot_and_growth(self, server):
+        import tracemalloc
+
+        was_tracing = tracemalloc.is_tracing()
         ep = str(server.listen_endpoint())
-        http_fetch(ep, path="/hotspots/heap")  # may just start tracing
-        r = http_fetch(ep, path="/hotspots/heap")
-        assert r.status == 200 and b"allocation sites" in r.body
-        http_fetch(ep, path="/hotspots/growth")
-        # allocate between the two growth snapshots
-        blob = [bytearray(1024) for _ in range(100)]
-        r = http_fetch(ep, path="/hotspots/growth")
-        assert r.status == 200 and b"growth since" in r.body
-        del blob
+        try:
+            http_fetch(ep, path="/hotspots/heap")  # may just start tracing
+            r = http_fetch(ep, path="/hotspots/heap")
+            assert r.status == 200 and b"allocation sites" in r.body
+            http_fetch(ep, path="/hotspots/growth")
+            # allocate between the two growth snapshots
+            blob = [bytearray(1024) for _ in range(100)]
+            r = http_fetch(ep, path="/hotspots/growth")
+            assert r.status == 200 and b"growth since" in r.body
+            del blob
+        finally:
+            # /hotspots/heap starts tracemalloc (16 frames an allocation)
+            # for the process and nothing stops it: every file that runs
+            # later in this worker would run traced, several times slower,
+            # and those that time traffic (test_tail_dump.py) fail on it
+            if not was_tracing:
+                tracemalloc.stop()
 
     def test_contention_endpoint(self, server):
         ep = str(server.listen_endpoint())
@@ -443,15 +454,6 @@ class TestProfiling:
         for needle in (b"rss_kb:", b"threads:", b"tracemalloc:",
                        b"continuous_profiler:", b"/hotspots/cpu"):
             assert needle in r.body, needle
-        from brpc_tpu.metrics.variable import get_exposed
-        from brpc_tpu.profiling import sampler as _sampler
-
-        # earlier tests may clear_registry(); re-expose the import-time
-        # Adders so the /vars contract stays checkable
-        for name in ("g_prof_samples", "g_prof_dropped",
-                     "g_prof_overruns"):
-            if get_exposed(name) is None:
-                getattr(_sampler, name).expose_as(name)
         r = http_fetch(ep, path="/vars")
         assert b"g_prof_samples" in r.body
         assert b"g_prof_dropped" in r.body

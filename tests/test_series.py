@@ -9,7 +9,7 @@ import time
 import pytest
 
 from brpc_tpu import flags
-from brpc_tpu.metrics import clear_registry, prometheus_text
+from brpc_tpu.metrics import prometheus_text
 from brpc_tpu.metrics.reducer import Adder, Maxer
 from brpc_tpu.metrics.series import (
     HOUR_SAMPLES,
@@ -31,11 +31,9 @@ from tests.test_shard import shard_flags  # noqa: F401 (fixture reuse)
 
 
 @pytest.fixture(autouse=True)
-def _clean_state():
-    clear_registry()
+def _clean_state(empty_registry):
     global_series().clear()
     yield
-    clear_registry()
     global_series().clear()
 
 

@@ -225,28 +225,8 @@ class TestScheduling:
         finally:
             eng.running = False
 
-    def test_static_gang_drains_before_refill(self):
-        eng = _stub_engine(start=False, scheduling="static", max_batch=4)
-        eng.running = True
-        for _ in range(3):
-            assert eng.submit(eng.model.synth_prompt(4), 2)[0] == 0
-        with eng._cv:
-            gang = eng._admit_locked()
-        assert len(gang) == 3
-        assert eng.submit(eng.model.synth_prompt(4), 2)[0] == 0
-        with eng._cv:
-            assert eng._admit_locked() == []  # gang still running: no refill
-        for seq in list(eng._running):
-            eng._finish(seq, 0, "")
-        eng._running = []
-        with eng._cv:
-            assert len(eng._admit_locked()) == 1  # drained: next gang
-        eng.running = False
-        eng._abort_all_locked_out(errors.ELOGOFF, "test teardown")
-        eng.kv.assert_idle("static teardown")
-
     def test_continuous_refills_between_steps(self):
-        eng = _stub_engine(start=False, scheduling="continuous", max_batch=4)
+        eng = _stub_engine(start=False, max_batch=4)
         eng.running = True
         assert eng.submit(eng.model.synth_prompt(4), 2)[0] == 0
         with eng._cv:
@@ -431,7 +411,6 @@ class TestEngineRealModel:
     def test_snapshot_reports_the_step_loop(self, serving):
         _submit_wait(serving, 16, 4)
         snap = serving.snapshot()
-        assert snap["scheduling"] == "continuous"
         assert snap["steps"] > 0 and snap["tokens_generated"] > 0
         # nothing in flight: only radix-tree-held prefix chains remain
         assert snap["kv"]["blocks_used"] == snap["kv"]["blocks_cached"]

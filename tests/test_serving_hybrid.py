@@ -342,3 +342,40 @@ def test_generate_through_the_engine_serves_the_same_tokens(world):
     assert "kv window:" in page and "slots:" in page and "recycled=" in page
     eng.stop()
     kv.assert_idle("engine stopped")
+
+
+def test_a_decode_step_is_one_launch_and_one_host_sync(world):
+    """``SambaYModel.FUSED_STEP``, counted from outside: over an engine run
+    on the manager with its ledger armed (so the engine audits each step
+    too), ``step_dispatch`` moves by one launch and one host sync a decode
+    step whatever the batch, and by one of each a prefill."""
+    from brpc_tpu.tpu.device_lane import DispatchCounter, step_dispatch
+
+    model, kv = _stand(weights=world["host"])
+    kv._check = True
+    orig, deltas = model.decode_step, []
+
+    def counted(tokens, positions, tables):
+        before = step_dispatch.snapshot()
+        out = orig(tokens, positions, tables)
+        deltas.append(DispatchCounter.delta(before, step_dispatch.snapshot()))
+        return out
+
+    model.decode_step = counted
+    eng = ServingEngine(model, kv, EngineConfig(
+        max_batch=4, token_budget=256, idle_wait_s=0.005)).start()
+    before = step_dispatch.snapshot()
+    evs = []
+    for p in world["prompts"]:
+        ev = threading.Event()
+        evs.append(ev)
+        code, _ = eng.submit(p, 8, done=lambda _r, ev=ev: ev.set())
+        assert code == 0
+    assert all(ev.wait(120) for ev in evs)
+    launches, _ops, syncs = DispatchCounter.delta(before,
+                                                  step_dispatch.snapshot())
+    eng.stop()
+    kv.assert_idle("engine stopped")
+    assert deltas and all((l, s) == (1, 1) for l, _o, s in deltas), deltas
+    assert launches == syncs == len(world["prompts"]) + len(deltas)
+    assert len(deltas) == eng.steps    # no step without a decode batch

@@ -8,6 +8,7 @@ degenerate 1x1x1. Greedy decode is deterministic, so "sharded output ==
 single-device output" is an exact list equality, not a tolerance check.
 """
 
+import collections
 import threading
 import time
 import types
@@ -36,12 +37,11 @@ from tools.record_serving_corpus import SCHEDULE
 CFG = dict(vocab=256, d_model=32, n_heads=2, n_layers=2)
 
 
-def _run_schedule(model, kv, schedule, scheduling="continuous"):
+def _run_schedule(model, kv, schedule):
     """Drive one engine through the corpus schedule; returns each
     sequence's greedy token list in submit order."""
     engine = ServingEngine(model, kv, EngineConfig(
-        max_batch=8, token_budget=512, scheduling=scheduling,
-        idle_wait_s=0.002)).start()
+        max_batch=8, token_budget=512, idle_wait_s=0.002)).start()
     try:
         evs, seqs = [], []
         for plen, max_new in schedule:
@@ -69,6 +69,70 @@ def mesh_stack():
     model = MeshTransformer(cfg, kv)
     yield cfg, model, kv
     model.close()
+
+
+def _refill_every_step(lengths, max_batch):
+    """(steps, decode rows) a FIFO queue of answer lengths costs when every
+    step first refills the free rows: an admitted sequence's prefill yields
+    its first token, then every live row decodes one."""
+    waiting, running = collections.deque(lengths), []
+    steps = rows = 0
+    while waiting or running:
+        while waiting and len(running) < max_batch:
+            running.append(waiting.popleft() - 1)        # its prefill
+        running = [n for n in running if n > 0]
+        rows += len(running)
+        running = [n - 1 for n in running if n > 1]      # the decode step
+        steps += 1
+    return steps, rows
+
+
+@pytest.mark.parametrize("stack", ["single", "mesh"])
+def test_mixed_corpus_drains_in_the_steps_its_lengths_predict(stack,
+                                                              request):
+    """Three short answers to one long: the engine refills freed rows
+    between decode steps, so the corpus costs the steps the lengths
+    predict and no more. A batch that drained before it refilled would
+    take ``sum(max(gang) - 1)`` steps, 1.5 times as many and more."""
+    lengths = [64 if i % 4 == 3 else 4 for i in range(16)]
+    max_batch = 4
+    if stack == "mesh":
+        cfg, model, kv = request.getfixturevalue("mesh_stack")
+    else:
+        cfg = ModelConfig(**CFG)
+        kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
+                          cfg.n_layers, cfg.kv_dim)
+        kv._check = True
+        model = TinyTransformer(cfg, kv)
+    engine = ServingEngine(model, kv, EngineConfig(
+        max_batch=max_batch, token_budget=256, idle_wait_s=0.002),
+        prefix_cache=False).start()
+    try:
+        evs = []
+        # the loop thread admits under this lock: held here, the whole
+        # corpus is queued before the first step, whatever the threads do
+        with engine._cv:
+            for n in lengths:
+                ev = threading.Event()
+                code, _ = engine.submit(model.synth_prompt(16), n,
+                                        done=lambda _r, ev=ev: ev.set())
+                assert code == 0
+                evs.append(ev)
+        for ev in evs:
+            assert ev.wait(300), "corpus run stalled"
+        snap = engine.snapshot()
+    finally:
+        engine.stop()
+        kv.assert_idle()
+        if stack == "single":
+            model.close()
+    steps, rows = _refill_every_step(lengths, max_batch)
+    assert snap["tokens_generated"] == sum(lengths)
+    assert snap["steps"] == steps
+    assert snap["batch_occupancy_avg"] == round(rows / steps, 3)
+    gangs = [lengths[i:i + max_batch]
+             for i in range(0, len(lengths), max_batch)]
+    assert sum(max(g) - 1 for g in gangs) >= 1.5 * steps
 
 
 class TestMeshEquivalence:

@@ -324,6 +324,56 @@ class TestDisaggregatedServing:
         assert pre.migrator.seqs == len(SCHEDULE)
         assert pre.migrator.failed == 0
 
+    def test_decode_engine_commits_every_decode_token_and_no_prefill(
+            self, disagg_pair):
+        """What disaggregation moves, in counts: the decode-role engine
+        commits every token past each sequence's first and prefills none;
+        the migrator moved one chain a sequence, as many blocks as the
+        prompts fill."""
+        pre, dec, _srv = disagg_pair
+        sched = [(16, 4), (40, 8), (16, 4), (70, 12)]
+        for plen, max_new in sched:
+            code, _s, ev, box = _submit(
+                pre, pre.model.synth_prompt(plen), max_new)
+            assert code == 0 and ev.wait(300)
+            h = box["r"]
+            assert h.finish_reason == "handoff" and len(h.tokens) == 1
+            code, _s2, ev2, box2 = _submit(
+                dec, np.zeros(0, dtype=np.int32), 0, resume=h.seq_id)
+            assert code == 0 and ev2.wait(300)
+            assert len(box2["r"].tokens) == max_new - 1
+        assert dec.prefill_tokens == 0
+        assert dec.tokens_generated == sum(n - 1 for _, n in sched)
+        out = pre.snapshot()["migration"]["out"]
+        assert (out["seqs"], out["failed"]) == (len(sched), 0)
+        assert out["blocks"] == sum(pre.kv.blocks_for(p) for p, _ in sched)
+        assert out["bytes"] == out["blocks"] * chain_block_bytes(pre.kv)
+        assert dec.snapshot()["migration"]["in"]["seqs_in"] == len(sched)
+
+    def test_prefill_engine_never_decodes_a_handed_off_sequence(
+            self, disagg_pair):
+        """The other half: the prefill-role engine launches one prefill a
+        sequence and no decode step, whatever the answers' lengths."""
+        pre, dec, _srv = disagg_pair
+        sched = [(16, 4), (32, 64), (16, 4)]
+        handoffs = []
+        for plen, max_new in sched:
+            code, _s, ev, box = _submit(
+                pre, pre.model.synth_prompt(plen), max_new)
+            assert code == 0 and ev.wait(300)
+            handoffs.append(box["r"].seq_id)
+        snap = pre.snapshot()
+        assert snap["decode"]["decode_launches_paged"] \
+            + snap["decode"]["decode_launches_gather"] == 0
+        assert snap["tokens_generated"] == len(sched)
+        assert snap["steps"] == len(sched)
+        assert snap["batch_occupancy_avg"] == 0
+        assert pre.prefill_tokens == sum(p for p, _ in sched)
+        for sid in handoffs:
+            code, _s2, ev2, _b2 = _submit(
+                dec, np.zeros(0, dtype=np.int32), 0, resume=sid)
+            assert code == 0 and ev2.wait(300)
+
     def test_resume_attach_is_single_use(self, disagg_pair):
         pre, dec, _srv = disagg_pair
         code, _s, ev, box = _submit(pre, pre.model.synth_prompt(16), 4)
@@ -594,15 +644,8 @@ class TestMigrationObservability:
 
     def test_migration_vars_exposed(self):
         from brpc_tpu.metrics.variable import get_exposed
-        from brpc_tpu.serving import migration as _mig
 
-        # earlier test files may clear_registry(); re-expose the
-        # import-time vars so the /vars contract stays checkable
         for name in ("g_serving_migrate_seqs", "g_serving_migrate_blocks",
                      "g_serving_migrate_bytes", "g_serving_migrate_failed",
                      "g_serving_migrate_inflight"):
-            if get_exposed(name) is None:
-                var = getattr(_mig, name)
-                (var.expose_as if hasattr(var, "expose_as")
-                 else var.expose)(name)
             assert get_exposed(name) is not None, name

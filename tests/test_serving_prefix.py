@@ -23,6 +23,7 @@ Five layers, cheapest first:
   proving zero leaked blocks under an armed ledger.
 """
 
+import collections
 import itertools
 import random
 import threading
@@ -752,6 +753,65 @@ class TestForkOracle:
         finally:
             engine.stop()
         kv.assert_idle("builtin teardown")
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["single", "mesh"])
+def test_a_warm_request_costs_its_suffix_and_one_launch(mesh, stack,
+                                                        monkeypatch):
+    """What a hit saves, in counts: over a corpus of one shared prompt with
+    a tail token of each request's own, every request after the first
+    prefills its suffix alone, in ONE ``prefill_suffix`` launch and no
+    ``prefill``; the hit ratio is (n - 1) / n."""
+    from brpc_tpu.tpu.device_lane import DispatchCounter, step_dispatch
+
+    if mesh:
+        from brpc_tpu.serving import MeshTransformer
+
+        cfg = ModelConfig(**MODEL_CFG)
+        kv = ShardedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
+                            cfg.n_layers, cfg.kv_dim)
+        kv._check = True
+        model = MeshTransformer(cfg, kv)
+    else:
+        model, kv = stack
+    n, shared = 5, 4 * 16           # four whole blocks, then one own token
+    calls = collections.Counter()
+    for name in ("prefill", "prefill_suffix"):
+        def counted(*args, name=name, orig=getattr(model, name)):
+            calls[name] += 1
+            return orig(*args)
+        monkeypatch.setattr(model, name, counted)
+    engine = ServingEngine(model, kv, EngineConfig(
+        max_batch=4, token_budget=512, idle_wait_s=0.002)).start()
+    try:
+        base = model.synth_prompt(shared + 1)
+        for i in range(n):
+            prompt = base.copy()
+            prompt[-1] = 1 + (7 * i + 3) % (MODEL_CFG["vocab"] - 1)
+            calls.clear()
+            tokens0 = engine.prefill_tokens
+            before = step_dispatch.snapshot()
+            ev = threading.Event()
+            code, _ = engine.submit(prompt, 1, done=lambda _r: ev.set())
+            assert code == 0 and ev.wait(300)
+            launches, _ops, syncs = DispatchCounter.delta(
+                before, step_dispatch.snapshot())
+            if i == 0:
+                assert dict(calls) == {"prefill": 1}
+                assert engine.prefill_tokens - tokens0 == shared + 1
+            else:
+                assert dict(calls) == {"prefill_suffix": 1}
+                assert engine.prefill_tokens - tokens0 == 1
+                assert (launches, syncs) == (1, 1)
+        pfx = engine.snapshot()["prefix"]
+        assert (pfx["hit_seqs"], pfx["miss_seqs"]) == (n - 1, 1)
+        assert pfx["hit_ratio"] == (n - 1) / n
+        assert pfx["hit_blocks"] == (n - 1) * shared // 16
+    finally:
+        engine.stop()
+        kv.assert_idle("warm-request teardown")
+        if mesh:
+            model.close()
 
 
 # ------------------------------------------------------------------ chaos

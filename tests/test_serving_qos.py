@@ -32,10 +32,10 @@ import pytest
 from brpc_tpu import fault
 from brpc_tpu import flags as _flags
 from brpc_tpu.rpc import errors
-from brpc_tpu.serving import EngineConfig, LlmServingService, ServingEngine
+from brpc_tpu.serving import LlmServingService
 from brpc_tpu.serving.qos import (DEFAULT_TENANT, QosConfig, QosLimiter,
                                   TenantScheduler)
-from test_serving import _Cntl, _small_kv, _stub_engine, _StubModel
+from test_serving import _Cntl, _stub_engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CORPUS_OVERLOAD = os.path.join(REPO, "tests", "data",
@@ -538,92 +538,185 @@ def test_overload_corpus_replays_clean_through_qos_at_recorded_rate(
 
 
 # --------------------------------------- closed-loop overload (acceptance)
-class _QosStubModel(_StubModel):
-    """Decode-dominated stub: prefill compute is negligible next to the
-    decode steps, so latency ratios measure admission scheduling (the
-    thing QoS controls), not model speed."""
-
-    def prefill(self, prompt, table):
-        self.prefills += 1
-        time.sleep(0.0002)
-        return 1
+# what one decode step of the recorded engine took: it turns the corpus's
+# arrival offsets into steps, and steps spent queued into the governor's
+# sample
+STEP_S = 0.005
 
 
-def _overload_engine(qos):
-    kv = _small_kv(num_blocks=256)
-    # max_batch one above the ceiling+protected worst case: the pinned
-    # ceiling holds best-effort inflight at 3, so a protected arrival
-    # always finds a slot instead of waiting out a batch residual
-    eng = ServingEngine(
-        _QosStubModel(0.005), kv,
-        EngineConfig(max_batch=5, token_budget=64, max_queue=256,
-                     idle_wait_s=0.002, qos=qos))
-    eng.start()
-    return eng
+class _ByHand:
+    """A stub-model engine whose loop the test turns: arrivals, admission,
+    the model's step and the governor's tick all fall on step boundaries,
+    so a latency is a count of steps and no run differs from the last."""
+
+    def __init__(self, qos, **cfg):
+        self.eng = _stub_engine(start=False, qos=qos, num_blocks=256,
+                                max_queue=256, **cfg)
+        self.eng.running = True
+        self.step = 0
+        self.refused = collections.Counter()    # tenant -> submits refused
+        self.finished = []                      # (tenant, code, steps)
+        self._at = {}                           # seq_id -> step submitted
+        self._waits = []                        # steps queued, since a tick
+
+    def submit(self, tenant, priority, plen, max_new):
+        at, cntl = self.step, _Cntl()
+        code, seq = self.eng.submit(
+            self.eng.model.synth_prompt(plen), max_new, cntl=cntl,
+            tenant_id=tenant, priority=priority,
+            done=lambda _r: self.finished.append(
+                (tenant, cntl.code, self.step - at)))
+        if code != 0:
+            assert code == errors.EOVERCROWDED  # retriable, never an error
+            self.refused[tenant] += 1
+        else:
+            self._at[seq.seq_id] = at
+
+    def turn(self):
+        eng = self.eng
+        with eng._cv:
+            admitted = eng._admit_locked()
+        self._waits += [self.step - self._at[s.seq_id] for s in admitted]
+        if admitted or eng._running:
+            with eng.pool_gate:
+                eng._step(admitted)
+        self.step += 1
+
+    def tick(self):
+        """The governor's tick, fed the mean wait of what was admitted
+        since the last one (a step in the queue reads as STEP_S)."""
+        waits, self._waits = self._waits, []
+        self.eng._qos_governor.tick(
+            sample_us=(1 + sum(waits) / len(waits)) * STEP_S * 1e6
+            if waits else 0.0)
+
+    def busy(self):
+        return bool(self.eng.queue_depth or self.eng._running)
+
+    def worst(self, tenant):
+        got = [n for t, code, n in self.finished if t == tenant]
+        assert got and all(code == 0 for t, code, _ in self.finished
+                           if t == tenant)
+        return max(got)
+
+    def close(self, what):
+        self.eng.running = False
+        self.eng._abort_all_locked_out(errors.ELOGOFF, "test teardown")
+        self.eng.kv.assert_idle(what)
 
 
-def _replay_corpus(server, tmp_path, name, rate_mult):
-    from tools import rpc_replay
+def _replay_by_steps(qos, rate_mult, tick_every=20):
+    """The overload corpus's arrivals at ``rate_mult`` times the recorded
+    rate through a saturable engine (5 rows, 64 tokens a step)."""
+    from tools import record_serving_corpus_overload as recorder
 
-    out = tmp_path / f"{name}.json"
-    rpc_replay.main([
-        "--dump", CORPUS_OVERLOAD,
-        "--server", str(server.listen_endpoint()),
-        "--rate-mult", str(rate_mult), "--timeout-ms", "30000",
-        "--report-interval", "0", "--json-out", str(out)])
-    return json.loads(out.read_text())
+    run = _ByHand(qos, max_batch=5, token_budget=64)
+    arrivals = collections.deque(
+        (int(off / (rate_mult * STEP_S)), tenant, prio, plen, max_new)
+        for off, tenant, prio, plen, max_new in recorder.SCHEDULE)
+    while arrivals or run.busy():
+        while arrivals and arrivals[0][0] <= run.step:
+            run.submit(*arrivals.popleft()[1:])
+        run.turn()
+        if qos is not None and run.step % tick_every == 0:
+            run.tick()
+        assert run.step < 10_000, "the replay never drained"
+    return run
 
 
-def test_closed_loop_overload_protects_prod_and_sheds_batch(tmp_path):
-    """The acceptance gate: the diurnal-overload corpus replayed at 2x
+def test_closed_loop_overload_protects_prod_and_sheds_batch():
+    """The acceptance gate, in steps: the diurnal-overload corpus at 2x
     the recorded rate against a saturable engine. With QoS armed the
-    protected tenant's p99 stays within 1.5x its unloaded baseline while
-    best-effort sheds EOVERCROWDED; the identical wave against the same
-    engine with QoS off violates the bound."""
+    protected tenant's worst request stays within 1.5x its unloaded worst
+    while best-effort sheds EOVERCROWDED; the identical wave against the
+    same engine with QoS off violates the bound."""
     # ceiling pinned one below max_batch: best-effort can never occupy
     # every slot, so the protected lane always has admission headroom —
     # the closed-loop's dynamic version of this is exercised above
     qos_cfg = QosConfig(tenants={"prod": 8.0, "batch": 1.0}, queue_cap=8,
                         protected_priority=1, ceiling_start=3.0,
                         ceiling_min=2.0, ceiling_max=3.0)
+    # unloaded baseline: a quarter of the recorded rate leaves every
+    # request effectively alone on the engine
+    base = _replay_by_steps(qos_cfg, 0.25)
+    assert not base.refused
+    unloaded = base.worst("prod")
+    base.close("unloaded teardown")
 
-    eng = _overload_engine(qos_cfg)
-    server = _serving_server(eng)
-    try:
-        # warmup pass (discarded): sockets, threads, and the step loop
-        # pay their cold-start costs outside the measured baseline
-        _replay_corpus(server, tmp_path, "warmup", 2)
-        # unloaded baseline: a quarter of the recorded rate leaves every
-        # request effectively alone on the engine
-        base = _replay_corpus(server, tmp_path, "unloaded", 0.25)
-        assert base["tenants"]["prod"]["fail"] == 0, base
-        p99_unloaded = base["tenants"]["prod"]["p99_us"]
-        assert p99_unloaded > 0
-
-        # 2x the recorded rate: the batch burst pushes past saturation
-        over = _replay_corpus(server, tmp_path, "overload", 2)
-        prod, batch = over["tenants"]["prod"], over["tenants"]["batch"]
-        assert prod["fail"] == 0  # the protected lane never shed
-        assert batch["shed"] > 0  # best-effort shed EOVERCROWDED
-        assert batch["shed"] == batch["fail"]  # sheds, not errors
-        assert prod["p99_us"] <= 1.5 * p99_unloaded, (prod, p99_unloaded)
-        snap = eng.qos.snapshot()["tenants"]
-        assert snap["batch"]["shed"] >= batch["shed"]
-    finally:
-        server.stop()
-        server.join(timeout=2)
-        eng.stop()
-    eng.kv.assert_idle("overload qos teardown")
+    # 2x the recorded rate: the batch burst pushes past saturation
+    over = _replay_by_steps(qos_cfg, 2)
+    snap = over.eng.qos.snapshot()["tenants"]
+    assert over.refused["prod"] == 0 and snap["prod"]["shed"] == 0
+    assert snap["batch"]["shed"] > 0     # best-effort shed EOVERCROWDED
+    # sheds, not errors: every batch request was refused at the door,
+    # shed from the queue by the governor, or served
+    codes = collections.Counter(code for t, code, _ in over.finished
+                                if t == "batch")
+    assert set(codes) <= {0, errors.EOVERCROWDED}
+    assert snap["batch"]["shed"] == (over.refused["batch"]
+                                     + codes[errors.EOVERCROWDED])
+    assert over.worst("prod") <= 1.5 * unloaded, (over.worst("prod"),
+                                                  unloaded)
+    over.close("overload qos teardown")
 
     # the control arm: same engine shape, same wave, QoS off — the
     # burst queues ahead of the protected traffic and the bound breaks
-    eng = _overload_engine(None)
-    server = _serving_server(eng)
-    try:
-        fifo = _replay_corpus(server, tmp_path, "fifo", 2)
-        assert fifo["tenants"]["prod"]["p99_us"] > 1.5 * p99_unloaded, fifo
-    finally:
-        server.stop()
-        server.join(timeout=2)
-        eng.stop()
-    eng.kv.assert_idle("overload fifo teardown")
+    fifo = _replay_by_steps(None, 2)
+    assert not fifo.refused
+    assert fifo.worst("prod") > 1.5 * unloaded, (fifo.worst("prod"),
+                                                 unloaded)
+    fifo.close("overload fifo teardown")
+
+
+def test_a_best_effort_flood_sheds_on_its_own_lane_only():
+    """A flood three times the lane's cap, then steady protected work,
+    with the governor ticked every four steps on the waits it caused:
+    every shed, at the door or from the queue, is the flood's; the
+    protected lane sheds none and every one of its requests is served."""
+    qos_cfg = QosConfig(tenants={"prod": 4.0, "batch": 1.0}, queue_cap=12,
+                        protected_priority=1, ceiling_start=16.0)
+    run = _ByHand(qos_cfg, max_batch=2, token_budget=64)
+    flood, prod_reqs = 36, 8
+    for _ in range(flood):
+        run.submit("batch", 0, 16, 8)
+    assert run.refused["batch"] == flood - 12    # the lane's cap, no more
+    sent = 0
+    while sent < prod_reqs or run.busy():
+        if sent < prod_reqs:
+            run.submit("prod", 1, 16, 8)
+            sent += 1
+        run.turn()
+        if run.step % 4 == 0:
+            run.tick()
+        assert run.step < 10_000, "the flood never drained"
+    gov = run.eng._qos_governor
+    snap = run.eng.qos.snapshot()["tenants"]
+    assert gov.sheds > 0                         # the loop did close
+    assert run.refused["prod"] == 0 and snap["prod"]["shed"] == 0
+    assert snap["batch"]["shed"] == run.refused["batch"] + gov.sheds
+    assert snap["prod"]["admitted"] == prod_reqs
+    assert snap["batch"]["admitted"] + snap["batch"]["shed"] == flood
+    assert [c for t, c, _ in run.finished if t == "prod"] == [0] * prod_reqs
+    run.close("flood teardown")
+
+
+@pytest.mark.parametrize("weights", [(3.0, 1.0), (1.0, 1.0)],
+                         ids=["3to1", "1to1"])
+def test_prod_share_of_admitted_tokens_follows_its_weight(weights):
+    """Through the engine's own admission, both lanes kept backlogged and
+    the step's token budget the only limit: over 100 steps prod's share
+    of the admitted tokens is its weight's share, to 5 points."""
+    qos_cfg = QosConfig(tenants={"prod": weights[0], "batch": weights[1]})
+    run = _ByHand(qos_cfg, max_batch=8, token_budget=32)
+    for _ in range(100):
+        for tenant in ("prod", "batch"):
+            while run.eng.qos.tenant_depth(tenant) < 4:
+                run.submit(tenant, 0, 16, 1)
+        run.turn()
+    snap = run.eng.qos.snapshot()["tenants"]
+    assert snap["prod"]["admitted_tokens"] \
+        + snap["batch"]["admitted_tokens"] == 100 * 32
+    want = weights[0] / sum(weights)
+    assert abs(snap["prod"]["token_share"] - want) <= 0.05, snap
+    assert not run.refused
+    run.close("share teardown")

@@ -15,7 +15,6 @@ import pytest
 
 from brpc_tpu import fault
 from brpc_tpu import flags as _flags
-from brpc_tpu.metrics.variable import clear_registry
 from brpc_tpu.metrics.watch import (STATE_FIRING, WatchRule, global_watch)
 from brpc_tpu.proto import echo_pb2
 from brpc_tpu.rpc import Channel, Server, ServerOptions, Stub
@@ -34,15 +33,24 @@ _TAIL_FLAGS = ("rpc_dump_tail", "rpc_dump_tail_slow_x",
 
 
 @pytest.fixture(autouse=True)
-def _clean_state():
+def _clean_state(empty_registry):
     saved = {name: _flags.get(name) for name in _TAIL_FLAGS}
     _span.reset_for_test()
+    # the retainer reads the process's watch rules, and one that another
+    # file of this worker left firing (``tunnel_healer_trips`` after the
+    # healer's tests) retains every trace at once: the rules are the
+    # test's own for its length
+    watch = global_watch()
+    rules = watch.rules()
+    watch.clear()
     yield
+    watch.clear()
+    for rule in rules:
+        watch.add(rule)
     fault.disarm_all()
     for name, value in saved.items():
         _flags.set_flag(name, value)
     _span.reset_for_test()
-    clear_registry()
 
 
 @pytest.fixture()

@@ -424,6 +424,36 @@ class TestWindowAccounting:
             assert len(win._free) == win.block_count
 
 
+class TestV1Peer:
+    """The native lane speaks handshake version 1: no ``gen`` in HELLO and
+    no epoch word in DATA / ACK bodies, so ``on_data`` / ``on_ack`` take
+    this endpoint's own epoch for the frame's. That is sound only while
+    an endpoint with a v1 peer never changes generation."""
+
+    def test_a_repeat_hello_never_restarts_the_epoch(self):
+        import json
+
+        from brpc_tpu.tpu import transport as tr
+
+        fake = _FakeCtrl()
+        ep = tr.TpuEndpoint(fake, role="server")
+        hello = json.dumps({"pool": "no-such-pool", "bs": 65536, "bc": 8,
+                            "ordinal": 0}).encode()    # v1: no v, no gen
+        try:
+            ep.on_hello(hello)
+            assert ep.ready.is_set() and not ep._failed
+            assert (ep.peer_version, ep.epoch) == (1, 0)
+            restarts0 = tr.g_tunnel_epoch_restarts.get_value()
+            stale0 = tr.g_tunnel_stale_epoch_frames.get_value()
+            pool = ep.recv_pool
+            ep.on_hello(hello)
+            assert tr.g_tunnel_epoch_restarts.get_value() == restarts0
+            assert tr.g_tunnel_stale_epoch_frames.get_value() == stale0 + 1
+            assert ep.epoch == 0 and ep.recv_pool is pool
+        finally:
+            ep.fail(0, "test done")
+
+
 _CHILD_SERVER = r"""
 import sys
 from brpc_tpu.proto import echo_pb2

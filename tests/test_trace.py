@@ -179,10 +179,6 @@ class TestGenericPathPhases:
     def test_phase_aggregates_exposed(self, tcp_server, traced):
         from brpc_tpu.metrics import dump_exposed
 
-        # the per-phase Adders are created lazily and cached; another
-        # test file's clear_registry() may have dropped their exposure —
-        # drop the cache so this trace re-creates (and re-exposes) them
-        _span._phase_adders.clear()
         ch = Channel().init(addr(tcp_server))
         Stub(ch, ECHO).Echo(echo_pb2.EchoRequest(message="agg"))
         _wait_spans(lambda ss: _find(ss, "server") is not None)
@@ -384,13 +380,10 @@ class TestRpczHttp:
         assert "rpc_method_echoservice_echo_count" in snap
 
     def test_prometheus_counter_type_lines(self):
-        from brpc_tpu.fault import core as _fault_core
         from brpc_tpu.metrics import prometheus_text
 
-        # re-expose (overwrites in the registry — robust against another
-        # test file's clear_registry()): the TYPE line must say counter,
-        # carried by the prometheus_type attribute through expose_as
-        _fault_core.g_fault_hits.expose_as("g_fault_hits")
+        # the TYPE line must say counter, carried by the prometheus_type
+        # attribute through expose_as
         txt = prometheus_text()
         assert "# TYPE g_fault_hits counter" in txt
 

@@ -5,7 +5,7 @@ reference benchmarks likewise run client and server as separate binaries
 (/root/reference/example/multi_threaded_echo_c++/server.cpp). Prints
 ``LISTEN <endpoint>`` once the listener is up, then serves until stdin
 closes (the parent holds the pipe). A mode that computes with JAX
-(--batch/--device/--serving) owns the chip for its lifetime and prints
+(--batch/--device) owns the chip for its lifetime and prints
 ``DEVICE platform=... device_kind=... count=...`` before LISTEN.
 
     python tools/bench_server.py --listen 127.0.0.1:0
@@ -106,48 +106,6 @@ class BatchBenchService(Service):
                 for i in range(batch.size)]
 
 
-def _build_serving_engine():
-    """--serving: small continuous-batching engine (brpc_tpu/serving/),
-    pre-warmed so no timed request ever pays a jit compile. Warmup sweeps
-    the bench traffic's shape buckets — prefill S in {16, 32}, decode
-    batch B in {2, 4, 8}, context L in {32, 64} — and runs each round
-    twice because donated pool outputs give every program a second jit
-    cache signature (fresh-zeros vs decode-output arrays)."""
-    import threading
-
-    from brpc_tpu.serving import (EngineConfig, KVCacheConfig, ModelConfig,
-                                  PagedKVCache, ServingEngine,
-                                  TinyTransformer)
-
-    cfg = ModelConfig(vocab=256, d_model=32, n_heads=2, n_layers=2)
-    kv = PagedKVCache(KVCacheConfig(block_size=16, num_blocks=256),
-                      cfg.n_layers, cfg.kv_dim)
-    model = TinyTransformer(cfg, kv)
-    engine = ServingEngine(model, kv, EngineConfig(
-        max_batch=8, token_budget=512)).start()
-
-    def round_(prompt_len):
-        # staggered max_new: the batch shrinks through every B bucket
-        # while the longest sequence keeps the batch's L bucket pinned
-        evs = []
-        for i in range(8):
-            ev = threading.Event()
-            code, _ = engine.submit(model.synth_prompt(prompt_len),
-                                    2 * (i + 1),
-                                    done=lambda _r, ev=ev: ev.set())
-            if code != 0:
-                raise RuntimeError(f"serving warmup rejected: {code}")
-            evs.append(ev)
-        for ev in evs:
-            if not ev.wait(180):
-                raise RuntimeError("serving warmup timed out")
-
-    for _ in range(2):
-        round_(32)   # contexts 33..48 -> 3-4 blocks -> L bucket 64
-        round_(16)   # contexts 17..32 -> 2 blocks   -> L bucket 32
-    return engine
-
-
 class EchoServiceImpl(Service):
     DESCRIPTOR = echo_pb2.DESCRIPTOR.services_by_name["EchoService"]
 
@@ -188,11 +146,6 @@ def main(argv=None):
                     help="answer Echo as the null-service CONTROL: raw "
                          "body echo from the poll loop, no policy "
                          "(bench ceiling isolation, VERDICT r4 #2a)")
-    ap.add_argument("--serving", action="store_true",
-                    help="serve LlmService (continuous-batching engine, "
-                         "brpc_tpu/serving/); jit caches are pre-warmed "
-                         "before LISTEN so the bench measures serving, "
-                         "not compilation")
     ap.add_argument("--shard-workers", type=int, default=0,
                     help="spread dispatch over N worker processes "
                          "(brpc_tpu/shard sharded dispatch plane; the "
@@ -206,7 +159,7 @@ def main(argv=None):
         from brpc_tpu import flags
 
         flags.set_flag("tpu_shard_workers", args.shard_workers)
-    owns_device = args.batch or args.device or args.serving
+    owns_device = args.batch or args.device
     if owns_device:
         from brpc_tpu.tpu.compile_cache import enable_compile_cache
 
@@ -228,12 +181,6 @@ def main(argv=None):
                                               free_after=False)
     if args.batch:
         server.add_service(BatchBenchService())
-    serving_engine = None
-    if args.serving:
-        from brpc_tpu.serving import LlmServingService
-
-        serving_engine = _build_serving_engine()
-        server.add_service(LlmServingService(serving_engine))
     server.add_service(EchoServiceImpl(device_stream_impl=stream_impl))
     server.start(args.listen)
     if args.native_echo:
@@ -255,8 +202,6 @@ def main(argv=None):
         pass
     server.stop()
     server.join()
-    if serving_engine is not None:
-        serving_engine.stop()
     # run-to-completion activation report: which methods ran inline on
     # the cut loop this run (bench.py surfaces this on its stderr; the
     # test_bench_quick smoke asserts the lane engaged on the shm sweep)
