@@ -13,8 +13,10 @@ model trains under a dp×sp×tp mesh:
        blocks streaming between neighbors exactly like the reference's
        credit-windowed streams (SURVEY §5.7 mapping)
 
-Everything compiles under one jit; XLA overlaps the collectives with
-compute on ICI. Pallas RMSNorm (pallas_ops.py) is used on TPU.
+Everything compiles under one jit. The ring's transfers fly under its
+kernels by the order ring.py gives its hops; the gradients' all-reduce is
+not overlapped with anything yet (PERF.md section 5). Pallas RMSNorm
+(pallas_ops.py) is used on TPU.
 """
 
 from __future__ import annotations

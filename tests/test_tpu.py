@@ -2,6 +2,8 @@
 cluster substrate — N virtual chips stand in for a pod the way N loopback
 channels stand in for N servers in the reference)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -88,26 +90,47 @@ class TestCollectives:
         assert out.shape == (64, 1)
 
 
+RING_SIZES = [1, 2, 4, 8]
+
+
+def _ring_mesh(n):
+    """sp = n, with what is left of the 8 devices on dp and tp."""
+    dp = 2 if n <= 4 else 1
+    tp = 2 if n <= 2 else 1
+    return meshlib.make_mesh({"dp": dp, "sp": n, "tp": tp},
+                             jax.devices()[:dp * n * tp])
+
+
+def _qkv(seed, shape, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=shape), dtype=dtype)
+                 for _ in range(3))
+
+
+def _ring_transfers(fn, *args):
+    """(scope, element type) of every collective_permute in fn's lowering,
+    in program order."""
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, flags=re.M))
+    return [(locs[loc].rsplit("/", 1)[0], dtype) for dtype, loc in re.findall(
+        r'"stablehlo\.collective_permute".*-> tensor<[\dx]*x(\w+)> '
+        r'loc\((#loc\d+)\)', text)]
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
-    def test_matches_full_attention(self, mesh8, causal):
-        rng = np.random.default_rng(1)
-        B, S, H, D = 2, 32, 4, 16
-        q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        out_ring = ring_attention(q, k, v, mesh8, "x", causal=causal)
+    @pytest.mark.parametrize("n", RING_SIZES)
+    def test_matches_full_attention(self, n, causal):
+        m = meshlib.make_mesh({"x": n}, jax.devices()[:n])
+        q, k, v = _qkv(1, (2, 32, 4, 16))
+        out_ring = ring_attention(q, k, v, m, "x", causal=causal)
         out_full = full_attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_full),
                                    rtol=2e-4, atol=2e-5)
 
     def test_composes_with_dp_tp(self):
         m = meshlib.make_mesh({"dp": 2, "sp": 2, "tp": 2})
-        rng = np.random.default_rng(2)
-        B, S, H, D = 2, 16, 4, 8
-        q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
+        q, k, v = _qkv(2, (2, 16, 4, 8))
         out = ring_attention(q, k, v, m, "sp", causal=True,
                              batch_axis="dp", head_axis="tp")
         ref = full_attention_reference(q, k, v, causal=True)
@@ -115,17 +138,15 @@ class TestRingAttention:
                                    rtol=2e-4, atol=2e-5)
 
     @pytest.mark.parametrize("causal", [False, True])
-    def test_flash_kernel_inside_ring(self, mesh8, causal):
+    @pytest.mark.parametrize("n", RING_SIZES)
+    def test_flash_kernel_inside_ring(self, n, causal):
         # VERDICT r2 #5: the carry-form Pallas kernel accumulates ACROSS
         # hops; the lax path is the oracle
-        rng = np.random.default_rng(3)
-        B, S, H, D = 2, 32, 4, 16
-        q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        out_flash = ring_attention(q, k, v, mesh8, "x", causal=causal,
+        m = meshlib.make_mesh({"x": n}, jax.devices()[:n])
+        q, k, v = _qkv(3, (2, 32, 4, 16))
+        out_flash = ring_attention(q, k, v, m, "x", causal=causal,
                                    use_flash=True)
-        out_lax = ring_attention(q, k, v, mesh8, "x", causal=causal)
+        out_lax = ring_attention(q, k, v, m, "x", causal=causal)
         out_full = full_attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out_flash),
                                    np.asarray(out_lax),
@@ -136,11 +157,7 @@ class TestRingAttention:
 
     def test_flash_ring_composes_with_dp_tp(self):
         m = meshlib.make_mesh({"dp": 2, "sp": 2, "tp": 2})
-        rng = np.random.default_rng(4)
-        B, S, H, D = 2, 16, 4, 8
-        q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
+        q, k, v = _qkv(4, (2, 16, 4, 8))
         out = ring_attention(q, k, v, m, "sp", causal=True,
                              batch_axis="dp", head_axis="tp",
                              use_flash=True)
@@ -149,16 +166,13 @@ class TestRingAttention:
                                    rtol=2e-4, atol=2e-5)
 
     @pytest.mark.parametrize("causal", [False, True])
-    def test_flash_ring_gradients_match_reference(self, causal):
+    @pytest.mark.parametrize("n", RING_SIZES)
+    def test_flash_ring_gradients_match_reference(self, n, causal):
         # VERDICT r3 #3: the ring-flash path must be trainable — its
         # custom VJP runs the Pallas flash-backward kernels per hop and
         # rotates dk/dv home around the ring
-        m = meshlib.make_mesh({"dp": 2, "sp": 2, "tp": 2})
-        rng = np.random.default_rng(7)
-        B, S, H, D = 2, 32, 4, 16
-        q = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype=jnp.float32)
+        m = _ring_mesh(n)
+        q, k, v = _qkv(7, (2, 32, 4, 16))
 
         def loss_flash(q, k, v):
             o = ring_attention(q, k, v, m, "sp", causal=causal,
@@ -175,6 +189,36 @@ class TestRingAttention:
         for a, b in zip(g, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
+
+    @pytest.mark.parametrize("dtype,narrow", [(jnp.bfloat16, "bf16"),
+                                              (jnp.float32, "f32")])
+    def test_ring_transfer_counts_and_dtypes(self, dtype, narrow):
+        # the hop schedule is fixed when the program is traced, so it is
+        # read from the program: n - 1 rotations of K and V a pass, n of
+        # dK and dV, of which the first and the last carry the kernel's
+        # dtype (float32 inputs: the cast is the identity)
+        n = 4
+        m = meshlib.make_mesh({"sp": n}, jax.devices()[:n])
+        x = jax.ShapeDtypeStruct((1, 64, 2, 16), dtype)
+
+        def f(q, k, v):
+            return ring_attention(q, k, v, m, "sp", causal=True,
+                                  use_flash=True, block_q=16, block_k=16)
+
+        def loss(q, k, v):
+            return jnp.sum(f(q, k, v).astype(jnp.float32))
+
+        fwd = _ring_transfers(f, x, x, x)
+        assert fwd == [("ring_fwd_hop/ring_kv_ppermute", narrow)] * (
+            2 * (n - 1))
+        both = _ring_transfers(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+        assert both[:len(fwd)] == fwd
+        bwd = both[len(fwd):]
+        assert len(bwd) == 2 * (n - 1) + 2 * n
+        assert [t for t in bwd if t[0].endswith("ring_kv_ppermute")] == [
+            ("ring_bwd_hop/ring_kv_ppermute", narrow)] * (2 * (n - 1))
+        assert [d for s, d in bwd if s == "ring_bwd_hop/ring_dkv_ppermute"
+                ] == [narrow] * 2 + ["f32"] * (2 * (n - 2)) + [narrow] * 2
 
 
 class TestPallasOps:

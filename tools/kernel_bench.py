@@ -4,6 +4,7 @@ Run standalone (owns the chip):
 
     python tools/kernel_bench.py            # prints one line per metric
     python tools/kernel_bench.py paged_decode   # only the named benches
+    python tools/kernel_bench.py ring_hops      # needs four chips
 
 Timing methodology: marginal cost between two round counts inside ONE
 compiled loop. Every measurement ends in a dependent fetch, and the slope
@@ -296,6 +297,97 @@ def bench_ring_path(peak: dict):
     return tf
 
 
+def bench_ring_hops(peak: dict):
+    """The ring itself on four chips: ONE layer of train-16k-sp4's
+    attention (B=1, S=16384 over sp=4, 16 heads of 128, bfloat16, causal,
+    flash) under ring_attention's own shard_map, forward alone and forward
+    + backward. For each chip: a pass's time (host clock over the
+    repeats), the sum of its flash kernels and the time a collective holds
+    its op line (both from a profiler trace, read with the benchmark's
+    trace_reduce), beside the wire time the pass's bytes need at the link
+    rate measured here by a ring of bare ppermutes. Chip 0 runs one block
+    a pass under the causal mask and chip 3 four: what the kernels' sums
+    differ by is what sequence shards in another order would level."""
+    import functools
+    import shutil
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax, shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmark.harness import trace_reduce
+    from brpc_tpu.tpu import mesh as meshlib
+    from brpc_tpu.tpu.ring import ring_attention
+
+    SP, B, S, H, D, REPS = 4, 1, 16384, 16, 128, 8
+    if len(jax.devices()) < SP:
+        print(f"# ring_hops needs {SP} chips, found {len(jax.devices())}: "
+              "skipped", flush=True)
+        return None
+    mesh = meshlib.make_mesh({"sp": SP}, jax.devices()[:SP])
+    shard = NamedSharding(mesh, P(None, "sp", None, None))
+    make = jax.jit(lambda key: jax.random.normal(key, (B, S, H, D),
+                                                 jnp.bfloat16),
+                   out_shardings=shard)
+    q, k, v = (make(jax.random.PRNGKey(i)) for i in range(3))
+
+    def attend(q, k, v):
+        return ring_attention(q, k, v, mesh, "sp", causal=True,
+                              use_flash=True)
+
+    fwd = jax.jit(attend)
+    both = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
+
+    # the link: K and V's rotation and nothing else, chained
+    perm = [(i, (i + 1) % SP) for i in range(SP)]
+
+    @functools.partial(jax.jit, static_argnames=("n",))
+    def rotate(k, v, n: int):
+        @functools.partial(shard_map, mesh=mesh, in_specs=(shard.spec,) * 2,
+                           out_specs=(shard.spec,) * 2)
+        def f(k, v):
+            return lax.fori_loop(0, n, lambda i, kv: tuple(
+                lax.ppermute(x, "sp", perm) for x in kv), (k, v))
+        return f(k, v)
+
+    block = B * (S // SP) * H * D                 # elements a chip holds
+    sec = _marginal(lambda n: jax.block_until_ready(rotate(k, v, n)), 8, 64)
+    link = 2 * block * 2 / sec                    # bytes/s a chip sends
+    narrow, wide = 2 * block * 2, 2 * block * 4   # a K+V or dK+dV pair
+    sent = {"fwd": (SP - 1) * narrow,
+            "fwd+bwd": 2 * (SP - 1) * narrow + 2 * narrow
+            + (SP - 2) * wide}
+    print(f"# ring_hops sp={SP} B={B} S={S} H={H} D={D} bf16 causal: link "
+          f"{link / 1e9:.1f} GB/s a chip one way (bare ppermute of K+V, "
+          f"{narrow / 1e6:.1f} MB in {sec * 1e3:.3f} ms)", flush=True)
+    for name, fn in (("fwd", fwd), ("fwd+bwd", both)):
+        jax.block_until_ready(fn(q, k, v))        # compile
+        tdir = tempfile.mkdtemp(prefix="ring_hops_")
+        jax.profiler.start_trace(tdir)
+        t0 = time.perf_counter()
+        jax.block_until_ready([fn(q, k, v) for _ in range(REPS)])
+        wall = (time.perf_counter() - t0) / REPS
+        jax.profiler.stop_trace()
+        red = trace_reduce.Reduced(trace_reduce.extract(
+            trace_reduce.find_xplane(tdir)))
+        shutil.rmtree(tdir, ignore_errors=True)
+        for dev in red.devices:
+            print(f"# ring_hops {name:7s} chip {dev}: pass "
+                  f"{wall * 1e3:7.3f} ms, busy "
+                  f"{red.busy_s(dev) / REPS * 1e3:7.3f}, flash kernels "
+                  f"{red.op_ns('flash', dev)[0] / REPS / 1e6:7.3f}, "
+                  f"collectives on the op line "
+                  f"{red.collective_ns(dev) / REPS / 1e6:7.3f}; its "
+                  f"{sent[name] / 1e6:.1f} MB need "
+                  f"{sent[name] / link * 1e3:.3f} ms of wire",
+                  flush=True)
+    return link
+
+
 def bench_rmsnorm(peak: dict):
     """Chained-carry bandwidth, reported against the measured Mosaic DMA
     ceiling (a pure-copy Pallas kernel) AND the XLA wire (fused add)."""
@@ -537,6 +629,7 @@ def main():
           f"{peak['hbm_bytes_per_s'] / 1e9:.0f} GB/s HBM)", flush=True)
     benches = {"flash_attention": bench_flash_attention,
                "ring_path": bench_ring_path,
+               "ring_hops": bench_ring_hops,
                "rmsnorm": bench_rmsnorm,
                "paged_decode": bench_paged_decode,
                "train_step_mfu": bench_train_step_mfu}
