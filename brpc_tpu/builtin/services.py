@@ -737,6 +737,23 @@ def serving_service(server, http: HttpMessage):
                 f"pages live={dec['decode_pages_live']} "
                 f"bucket={dec['decode_pages_bucket']} "
                 f"live_share={dec['live_share']:.2f}")
+        # the expert layer: how loaded this chip's experts are, and the
+        # share of them (so of their weights) a decode step reaches
+        moe = s.get("moe")
+        if moe:
+            parts = []
+            for phase in ("decode", "prefill"):
+                c = moe[phase]
+                n = max(1, c["layer_launches"])
+                parts.append(
+                    f"{phase} pairs={c['pairs']} "
+                    f"experts_hit={c['experts_hit']} "
+                    f"layer_launches={c['layer_launches']} "
+                    f"pairs_max_expert={c['pairs_max_expert']} "
+                    f"(pairs/launch={c['pairs'] / n:.1f} hit_share="
+                    f"{c['experts_hit'] / n / moe['experts_held']:.2f})")
+            out.append(f"  moe: held={moe['experts_held']} "
+                       + " | ".join(parts))
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

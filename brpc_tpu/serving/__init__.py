@@ -46,6 +46,13 @@ mechanism:
   — a SambaY decoder-hybrid-decoder (Mamba-1, window and full differential
   attention, gated memory units over one shared K/V) and its cache manager:
   full-layer pages, window rings and recurrent slots under one ledger.
+- :mod:`brpc_tpu.serving.moe_model` — a ``cohere2_moe`` decoder over the
+  same manager (window rings and full layers' pages, bfloat16, no
+  recurrence) as ONE chip's share of an expert-parallel layer: a sigmoid
+  router over all experts, a grouped product over the experts held here
+  inside the one fused decode launch, shared experts averaged, a parallel
+  attention + FFN block, rotary window layers beside position-free full
+  ones.
 - :mod:`brpc_tpu.serving.speculative` — the speculative-decoding draft
   lane: host-side prompt-lookup drafting (zero weights, zero device
   work, lint-pinned) feeding the model's one fused ``verify_step``
@@ -89,9 +96,12 @@ def __getattr__(name):
     if name in ("HybridCacheConfig", "HybridStateCache", "HybridTable"):
         from brpc_tpu.serving import hybrid_cache
         return getattr(hybrid_cache, name)
-    if name in ("SambaYConfig", "SambaYModel"):
+    if name in ("SambaYConfig", "SambaYModel", "HybridServingModel"):
         from brpc_tpu.serving import hybrid_model
         return getattr(hybrid_model, name)
+    if name in ("Cohere2MoeConfig", "Cohere2MoeModel"):
+        from brpc_tpu.serving import moe_model
+        return getattr(moe_model, name)
     raise AttributeError(name)
 
 
@@ -104,7 +114,8 @@ __all__ = [
     "LlmServingService", "ShardedLlmChannel",
     "KVMigrator", "MigrationReceiver",
     "HybridCacheConfig", "HybridStateCache", "HybridTable",
-    "SambaYConfig", "SambaYModel",
+    "SambaYConfig", "SambaYModel", "HybridServingModel",
+    "Cohere2MoeConfig", "Cohere2MoeModel",
     "AdaptiveK", "accept_longest_prefix", "draft_tokens",
     "QosConfig", "QosGovernor", "QosLimiter", "TenantScheduler",
 ]

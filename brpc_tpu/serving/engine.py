@@ -206,17 +206,20 @@ class ServingEngine:
         self.model = model
         self.kv = kv if kv is not None else model.kv
         self.config = config or EngineConfig()
-        if getattr(self.kv, "recurrent_state", False):
-            # a state overwritten every token cannot be rolled back past a
-            # rejected draft, and nothing moves it between engines yet
+        if getattr(self.kv, "state_overwritten", False):
+            # rows or a state overwritten in place (window rings, recurrent
+            # state) cannot be rolled back past a rejected draft, and
+            # nothing moves them between engines yet
             if self.config.spec_k > 0:
                 raise ValueError(
                     "speculative decoding (spec_k > 0) over a cache manager "
-                    "with recurrent state is not supported")
+                    "that overwrites state in place (window rings, "
+                    "recurrent state) is not supported")
             if self.config.role != ROLE_BOTH:
                 raise ValueError(
                     f"role {self.config.role!r} migrates sequences, which a "
-                    f"cache manager with recurrent state does not support")
+                    f"cache manager that overwrites state in place (window "
+                    f"rings, recurrent state) does not support")
         # radix prefix cache: None auto-builds over the pool (gated per
         # admission by the serving_prefix_cache_enabled flag), False
         # disables outright (cold A/B lanes, oracle reference engines)
@@ -301,9 +304,10 @@ class ServingEngine:
         prefill-role engine hands every prefilled chain to it from the
         step loop; ANY engine with one drains live sequences to the
         destination on stop() instead of aborting them from scratch."""
-        if getattr(self.kv, "recurrent_state", False):
-            raise ValueError("migration over a cache manager with recurrent "
-                             "state is not supported")
+        if getattr(self.kv, "state_overwritten", False):
+            raise ValueError("migration over a cache manager that overwrites "
+                             "state in place (window rings, recurrent state) "
+                             "is not supported")
         self.migrator = migrator
         return self
 
@@ -1184,6 +1188,18 @@ class ServingEngine:
             4)
         return out
 
+    def _moe_snapshot(self) -> Optional[Dict[str, object]]:
+        """The model's expert-layer counters (serving/moe_model.py), for a
+        model that keeps them: ``experts_held`` and, for decode and prefill
+        apart, the token-expert pairs this chip computed, the distinct held
+        experts hit, the most pairs of one expert (summed over
+        layer-launches) and the layer-launches."""
+        counters = getattr(self.model, "moe_counters", None)
+        if counters is None:
+            return None
+        return {k: dict(v) if isinstance(v, dict) else v
+                for k, v in counters.items()}
+
     def snapshot(self) -> Dict[str, object]:
         kv = self.kv.snapshot()
         occ = (self._occupancy_sum / self.steps) if self.steps else 0.0
@@ -1235,6 +1251,7 @@ class ServingEngine:
             "prefix": (self.prefix.snapshot()
                        if self.prefix is not None else None),
             "decode": self._decode_snapshot(),
+            "moe": self._moe_snapshot(),
             "spec": (dict(self.spec_stats.snapshot(),
                           k_max=self.config.spec_k)
                      if self.spec_stats is not None else None),

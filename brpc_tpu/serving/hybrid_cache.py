@@ -32,11 +32,19 @@ the slack of the pools has gone round; :meth:`HybridStateCache.retired`
 returns a finished sequence's table until one of its blocks or its slot has
 been handed out again.
 
-``recurrent_state = True`` is the declaration the rest of the serving plane
-reads: nothing records the recurrent state at a block boundary yet, so
-``build_prefix_cache`` builds no radix tree over this manager, and
-``ServingEngine`` refuses speculative decoding (a rejected draft cannot be
-rolled back out of an overwritten state) and migration.
+A model may have any number of full layers (``full_layers``), no
+state-space layer at all (``recurrent_layers = 0``: the slot arrays are
+empty and a slot is only a row's index) and pools narrower than float32
+(``dtype``); the byte counts follow the pools' dtype.
+
+``state_overwritten = True`` is the declaration the rest of the serving
+plane reads: a ring row and a recurrent state are written over in place, and
+nothing records either at a block boundary yet, so ``build_prefix_cache``
+builds no radix tree over this manager (a hit needs the rows behind the
+window and the state at the matched length), and ``ServingEngine`` refuses
+speculative decoding (a rejected draft cannot be rolled back out of an
+overwritten row or state) and migration. ``recurrent_state`` says only what
+its name does: whether the model keeps a state-space state.
 
 ``snapshot()`` keeps the keys its readers have (``blocks_used``,
 ``blocks_total``, ``used_ratio``, ...: ``serving/service.py``'s ``Stats`` and
@@ -89,27 +97,33 @@ class HybridTable(list):
 
 
 class HybridStateCache:
-    """See the module docstring. ``window_layers`` / ``recurrent_layers``
-    say how many layers of each kind the model has; ONE layer attends over
-    the whole context (the architecture shares its K/V)."""
+    """See the module docstring. ``window_layers`` / ``recurrent_layers`` /
+    ``full_layers`` say how many layers of each kind the model has (SambaY:
+    ONE full layer, whose K/V the architecture shares); ``dtype`` is the
+    K/V pools' (float32 where it is left out; the recurrent state is always
+    float32)."""
 
-    recurrent_state = True
+    # rings and recurrent states are written over in place: no prefix
+    # reuse, speculation or migration over this manager
+    state_overwritten = True
 
     def __init__(self, config: HybridCacheConfig, kv_dim: int,
-                 window_layers: int, recurrent_layers: int, d_inner: int,
-                 d_state: int, d_conv: int, store=None):
+                 window_layers: int, recurrent_layers: int = 0,
+                 d_inner: int = 0, d_state: int = 0, d_conv: int = 1,
+                 store=None, full_layers: int = 1, dtype=None):
         import jax.numpy as jnp
 
         self.config = config
         self.kv_dim = kv_dim
+        self.recurrent_state = recurrent_layers > 0
         self.full = PagedKVCache(
             KVCacheConfig(config.block_size, config.num_blocks,
                           config.watermark),
-            1, kv_dim, store=store)
+            full_layers, kv_dim, store=store, dtype=dtype)
         self.window = PagedKVCache(
             KVCacheConfig(config.block_size,
                           config.max_sequences * config.ring_blocks, 1.0),
-            window_layers, kv_dim, store=self.full.store)
+            window_layers, kv_dim, store=self.full.store, dtype=dtype)
         self.store = self.full.store
         self._lock = threading.Lock()
         n = config.max_sequences
@@ -129,9 +143,10 @@ class HybridStateCache:
         self._retired: "collections.OrderedDict[int, HybridTable]" = \
             collections.OrderedDict()
         bs, f32 = config.block_size, 4
+        row = kv_dim * self.full.k_pool.dtype.itemsize
         self._block_bytes = {
-            "full": 2 * bs * kv_dim * f32,
-            "window": 2 * window_layers * bs * kv_dim * f32}
+            "full": 2 * full_layers * bs * row,
+            "window": 2 * window_layers * bs * row}
         # the running state: what a live sequence needs
         self._slot_bytes = (recurrent_layers * d_inner * f32
                             * (d_state + d_conv - 1))

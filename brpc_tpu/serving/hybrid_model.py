@@ -380,39 +380,40 @@ def _ring_live(pos, ring_rows: int, window: int):
     return (held >= 0) & (held > pos[:, None] - window)
 
 
-class SambaYModel:
-    """Weights + the prefill and decode programs over a HybridStateCache;
-    the interface :class:`~brpc_tpu.serving.engine.ServingEngine` drives."""
+class HybridServingModel:
+    """What every model over a :class:`HybridStateCache` shares: the launch
+    over the manager's six device arrays, the padded host arguments, the
+    ``prep`` / ``launch`` / ``sync`` spans and the interface
+    :class:`~brpc_tpu.serving.engine.ServingEngine` drives. A subclass
+    stages ``self._params`` / ``self._handles`` and gives the two programs:
+
+    - ``_prefill_fn(s_bucket, use_flash)`` -> jitted ``impl(w, fk, fv, wk,
+      wv, ssm, conv, tokens, table, ring_table, slot, length)``
+    - ``_decode_fn(b_bucket, l_bucket)`` -> jitted ``impl(w, fk, fv, wk, wv,
+      ssm, conv, tokens, pos, tables, ring_tables, slots)``
+
+    each returning the six arrays and ONE int32 array whose first entries
+    are the next tokens (one for prefill, ``b_bucket`` for decode); what
+    follows them, if anything, reaches ``_note_counters`` from the same
+    host sync."""
 
     FUSED_STEP = True     # decode_step: one launch, one host sync
 
-    def __init__(self, config: SambaYConfig, kv: HybridStateCache,
-                 weights=None):
-        """``weights``: a dict of host arrays by ``config.weight_specs()``'s
-        names (tests); drawn from ``config.seed`` where it is left out."""
-        import jax
-
+    def _init_programs(self, config, kv: HybridStateCache) -> None:
         self.config = config
         self.kv = kv
         self.store = kv.store
         self._lock = threading.Lock()
         self._prefill_cache = {}
         self._decode_cache = {}
-        # ---- weights: drawn, staged and registered ONE matrix at a time
-        rng = np.random.RandomState(config.seed)
         self._params, self._handles, self.param_nbytes = {}, [], 0
-        for name, shape, how in config.weight_specs():
-            # one matrix at a time ON PURPOSE (set-up, not a step loop): a
-            # stacked transfer would hold the 8.8 GB twice
-            host = (_draw(rng, shape, how) if weights is None else
-                    np.asarray(weights[name], np.float32))  # tpulint: disable=no-per-token-host-sync
-            if host.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {host.shape} != {shape}")
-            arr = jax.device_put(host, self.store.device)  # tpulint: disable=no-per-op-step-dispatch
-            handle, nbytes = self.store.adopt(arr)
-            self._params[name] = arr
-            self._handles.append(handle)
-            self.param_nbytes += nbytes
+
+    def _stage(self, name: str, arr) -> None:
+        """Hold one device array as a weight, registered with the store."""
+        handle, nbytes = self.store.adopt(arr)
+        self._params[name] = arr
+        self._handles.append(handle)
+        self.param_nbytes += nbytes
 
     def _use_flash(self) -> bool:
         if self.config.attn in ("flash", "reference"):
@@ -420,6 +421,150 @@ class SambaYModel:
         from brpc_tpu.tpu.pallas_ops import _on_tpu
 
         return _on_tpu()
+
+    def _decode_buckets(self, n_rows: int, tables):
+        return decode_buckets(n_rows, tables, self.kv.block_size,
+                              self.config.window)
+
+    def _note_counters(self, phase: str, tail) -> None:
+        """``tail``: what a launch returned after its tokens (host array)."""
+
+    def _launch(self, fn, writes, *args):
+        """One launch over the manager's six device arrays (donated in,
+        installed again as they come back). ``writes``: (table, first row,
+        last row + 1) of the full-layer rows the launch writes: under an
+        armed ledger every block they lie in has to be exclusively owned.
+        Returns the launch's further output."""
+        kv = self.kv
+        for table, start, stop in writes:
+            kv.full.assert_writable(table, start, stop)
+        out = fn(self._params, kv.full.k_pool, kv.full.v_pool,
+                 kv.window.k_pool, kv.window.v_pool, kv.ssm, kv.conv, *args)
+        kv.full.update_pools(out[0], out[1])
+        kv.window.update_pools(out[2], out[3])
+        kv.update_state(out[4], out[5])
+        return out[6]
+
+    def prefill(self, tokens: np.ndarray, table) -> int:
+        """Prompt prefill for ONE sequence: write its recurrent state, its
+        ring and its full-layer rows, return the first token (greedy)."""
+        from brpc_tpu.tpu.device_lane import step_dispatch
+
+        s = len(tokens)
+        bucket = prefill_bucket(s, self.config.window)
+        with _span("model.prefill", n=s, bucket=bucket):
+            with _span("model.prep"):
+                key = (bucket, self._use_flash())
+                with self._lock:
+                    fn = self._prefill_cache.get(key)
+                    if fn is None:
+                        fn = self._prefill_cache[key] = self._prefill_fn(*key)
+                toks = np.zeros(bucket, dtype=np.int32)
+                toks[:s] = tokens
+                tab = np.zeros(-(-bucket // self.kv.block_size), np.int32)
+                n = min(len(tab), len(table))
+                tab[:n] = table[:n]
+                ring = np.asarray(table.window, np.int32)
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                nxt = self._launch(fn, [(table, 0, s)], toks, tab, ring,
+                                   np.int32(table.slot), np.int32(s))
+            with _span("model.sync"):
+                host = np.asarray(nxt).reshape(-1)
+                step_dispatch.note_host_sync()
+            self._note_counters("prefill", host[1:])
+            return int(host[0])
+
+    def prefill_suffix(self, tokens: np.ndarray, table, start: int) -> int:
+        """Only ``start == 0`` (the whole prompt, as ``prefill``): a suffix
+        needs the recurrent state at ``start`` and the ring rows behind it,
+        which nothing records."""
+        if start:
+            raise NotImplementedError(
+                f"{type(self).__name__}: no prefill from a cached prefix: "
+                f"the recurrent state and the ring rows at row {start} are "
+                "not recorded")
+        return self.prefill(tokens, table)
+
+    def decode_step(self, tokens: np.ndarray, positions: np.ndarray,
+                    tables: List[Sequence[int]]) -> np.ndarray:
+        """ONE fused dispatch for the whole decode batch (one row a
+        sequence): every layer's state stepped or appended, the next token
+        of each sequence returned, host-materialized once."""
+        from brpc_tpu.tpu.device_lane import step_dispatch
+
+        B = len(tokens)
+        kv = self.kv
+        b_bucket, l_bucket = self._decode_buckets(B, tables)
+        with _span("model.decode", B=B, b_bucket=b_bucket,
+                   l_bucket=l_bucket):
+            with _span("model.prep"):
+                if len({t.slot for t in tables}) != B:
+                    raise ValueError(
+                        f"{type(self).__name__}.decode_step: one row a "
+                        "sequence (a ring row and a recurrent state step "
+                        "once a launch)")
+                key = (b_bucket, l_bucket)
+                with self._lock:
+                    fn = self._decode_cache.get(key)
+                    if fn is None:
+                        fn = self._decode_cache[key] = self._decode_fn(*key)
+                toks = np.zeros(b_bucket, dtype=np.int32)
+                toks[:B] = tokens
+                pos = np.zeros(b_bucket, dtype=np.int32)
+                pos[:B] = positions
+                tabs = np.zeros((b_bucket, l_bucket // kv.block_size),
+                                np.int32)
+                rings = np.zeros((b_bucket, kv.config.ring_blocks), np.int32)
+                slots = np.zeros(b_bucket, np.int32)
+                for i, t in enumerate(tables):
+                    tabs[i, :len(t)] = t
+                    rings[i] = t.window
+                    slots[i] = t.slot
+            with _span("model.launch"):
+                step_dispatch.note_launch(1)
+                nxt = self._launch(
+                    fn, [(t, int(p), int(p) + 1)
+                         for t, p in zip(tables, positions)],
+                    toks, pos, tabs, rings, slots)
+            with _span("model.sync"):
+                host = np.asarray(nxt)
+                step_dispatch.note_host_sync()
+            self._note_counters("decode", host[b_bucket:])
+            return host[:B]
+
+    def close(self) -> None:
+        for h in self._handles:
+            self.store.free(h)
+        self._handles = []
+
+    def synth_prompt(self, length: int) -> np.ndarray:
+        """As ``TinyTransformer.synth_prompt``: keyed only by length."""
+        v = self.config.vocab
+        return ((np.arange(length, dtype=np.int64) * 31 + 7)
+                % (v - 1)).astype(np.int32) + 1
+
+
+class SambaYModel(HybridServingModel):
+    """Weights + the prefill and decode programs over a HybridStateCache."""
+
+    def __init__(self, config: SambaYConfig, kv: HybridStateCache,
+                 weights=None):
+        """``weights``: a dict of host arrays by ``config.weight_specs()``'s
+        names (tests); drawn from ``config.seed`` where it is left out."""
+        import jax
+
+        self._init_programs(config, kv)
+        # ---- weights: drawn, staged and registered ONE matrix at a time
+        rng = np.random.RandomState(config.seed)
+        for name, shape, how in config.weight_specs():
+            # one matrix at a time ON PURPOSE (set-up, not a step loop): a
+            # stacked transfer would hold the 8.8 GB twice
+            host = (_draw(rng, shape, how) if weights is None else
+                    np.asarray(weights[name], np.float32))  # tpulint: disable=no-per-token-host-sync
+            if host.shape != tuple(shape):
+                raise ValueError(f"weight {name}: {host.shape} != {shape}")
+            self._stage(name, jax.device_put(host, self.store.device))  # tpulint: disable=no-per-op-step-dispatch
 
     # ------------------------------------------------------------- prefill
     def _prefill_fn(self, s_bucket: int, use_flash: bool):
@@ -523,60 +668,6 @@ class SambaYModel:
 
         return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
 
-    def _launch(self, fn, writes, *args):
-        """One launch over the manager's six device arrays (donated in,
-        installed again as they come back). ``writes``: (table, first row,
-        last row + 1) of the full-layer rows the launch writes: under an
-        armed ledger every block they lie in has to be exclusively owned.
-        Returns the launch's further output."""
-        kv = self.kv
-        for table, start, stop in writes:
-            kv.full.assert_writable(table, start, stop)
-        out = fn(self._params, kv.full.k_pool, kv.full.v_pool,
-                 kv.window.k_pool, kv.window.v_pool, kv.ssm, kv.conv, *args)
-        kv.full.update_pools(out[0], out[1])
-        kv.window.update_pools(out[2], out[3])
-        kv.update_state(out[4], out[5])
-        return out[6]
-
-    def prefill(self, tokens: np.ndarray, table) -> int:
-        """Prompt prefill for ONE sequence: write its recurrent state, its
-        ring and its full-layer rows, return the first token (greedy)."""
-        from brpc_tpu.tpu.device_lane import step_dispatch
-
-        s = len(tokens)
-        bucket = prefill_bucket(s, self.config.window)
-        with _span("model.prefill", n=s, bucket=bucket):
-            with _span("model.prep"):
-                key = (bucket, self._use_flash())
-                with self._lock:
-                    fn = self._prefill_cache.get(key)
-                    if fn is None:
-                        fn = self._prefill_cache[key] = self._prefill_fn(*key)
-                toks = np.zeros(bucket, dtype=np.int32)
-                toks[:s] = tokens
-                tab = np.zeros(-(-bucket // self.kv.block_size), np.int32)
-                n = min(len(tab), len(table))
-                tab[:n] = table[:n]
-                ring = np.asarray(table.window, np.int32)
-            with _span("model.launch"):
-                step_dispatch.note_launch(1)
-                nxt = self._launch(fn, [(table, 0, s)], toks, tab, ring,
-                                   np.int32(table.slot), np.int32(s))
-            with _span("model.sync"):
-                first = int(nxt)
-                step_dispatch.note_host_sync()
-            return first
-
-    def prefill_suffix(self, tokens: np.ndarray, table, start: int) -> int:
-        """Only ``start == 0`` (the whole prompt, as ``prefill``): a suffix
-        needs the recurrent state at ``start``, which nothing records."""
-        if start:
-            raise NotImplementedError(
-                "SambaYModel: no prefill from a cached prefix: the recurrent "
-                f"state at row {start} is not recorded")
-        return self.prefill(tokens, table)
-
     # -------------------------------------------------------------- decode
     def _decode_fn(self, b_bucket: int, l_bucket: int):
         import jax
@@ -673,61 +764,3 @@ class SambaYModel:
             return fk, fv, wk, wv, ssm, conv, nxt.astype(jnp.int32)
 
         return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
-
-    def decode_step(self, tokens: np.ndarray, positions: np.ndarray,
-                    tables: List[Sequence[int]]) -> np.ndarray:
-        """ONE fused dispatch for the whole decode batch (one row a
-        sequence): every layer's state stepped or appended, the next token
-        of each sequence returned, host-materialized once."""
-        from brpc_tpu.tpu.device_lane import step_dispatch
-
-        B = len(tokens)
-        kv = self.kv
-        b_bucket, l_bucket = decode_buckets(B, tables, kv.block_size,
-                                            self.config.window)
-        with _span("model.decode", B=B, b_bucket=b_bucket,
-                   l_bucket=l_bucket):
-            with _span("model.prep"):
-                if len({t.slot for t in tables}) != B:
-                    raise ValueError(
-                        "SambaYModel.decode_step: one row a sequence (a "
-                        "recurrent state steps once a launch)")
-                key = (b_bucket, l_bucket)
-                with self._lock:
-                    fn = self._decode_cache.get(key)
-                    if fn is None:
-                        fn = self._decode_cache[key] = self._decode_fn(*key)
-                toks = np.zeros(b_bucket, dtype=np.int32)
-                toks[:B] = tokens
-                pos = np.zeros(b_bucket, dtype=np.int32)
-                pos[:B] = positions
-                tabs = np.zeros((b_bucket, l_bucket // kv.block_size),
-                                np.int32)
-                rings = np.zeros((b_bucket, kv.config.ring_blocks), np.int32)
-                slots = np.zeros(b_bucket, np.int32)
-                for i, t in enumerate(tables):
-                    tabs[i, :len(t)] = t
-                    rings[i] = t.window
-                    slots[i] = t.slot
-            with _span("model.launch"):
-                step_dispatch.note_launch(1)
-                nxt = self._launch(
-                    fn, [(t, int(p), int(p) + 1)
-                         for t, p in zip(tables, positions)],
-                    toks, pos, tabs, rings, slots)
-            with _span("model.sync"):
-                out = np.asarray(nxt)[:B]
-                step_dispatch.note_host_sync()
-            return out
-
-    # ------------------------------------------------------------- helpers
-    def close(self) -> None:
-        for h in self._handles:
-            self.store.free(h)
-        self._handles = []
-
-    def synth_prompt(self, length: int) -> np.ndarray:
-        """As ``TinyTransformer.synth_prompt``: keyed only by length."""
-        v = self.config.vocab
-        return ((np.arange(length, dtype=np.int64) * 31 + 7)
-                % (v - 1)).astype(np.int32) + 1

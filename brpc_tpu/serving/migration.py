@@ -385,9 +385,10 @@ class MigrationReceiver:
             sid = meta.stream_settings.stream_id
         if not sid:
             return reject("migration needs a record stream")
-        if getattr(kv, "recurrent_state", False):
-            return reject("this shard's cache manager holds recurrent "
-                          "state: no migration into it")
+        if getattr(kv, "state_overwritten", False):
+            return reject("this shard's cache manager overwrites state in "
+                          "place (window rings, recurrent state): no "
+                          "migration into it")
         if (request.block_size != kv.block_size
                 or request.layers != kv.layers
                 or request.kv_dim != kv.kv_dim):

@@ -444,10 +444,11 @@ class ShardedPrefixCache:
 
 def build_prefix_cache(kv):
     """The engine's factory: per-shard trees over a ShardedKVCache, one
-    tree over a plain pool, none over a manager that declares recurrent
-    state (a hit needs that state at the matched length, which nothing
-    records yet): the engine then runs without a prefix cache."""
-    if getattr(kv, "recurrent_state", False):
+    tree over a plain pool, none over a manager that declares
+    ``state_overwritten`` (a hit needs the recurrent state at the matched
+    length and the ring rows behind it, which nothing records yet): the
+    engine then runs without a prefix cache."""
+    if getattr(kv, "state_overwritten", False):
         return None
     if isinstance(kv, ShardedKVCache):
         return ShardedPrefixCache(kv)

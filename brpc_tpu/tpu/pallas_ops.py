@@ -1090,6 +1090,94 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
 
 
 # ---------------------------------------------------------------------------
+# Grouped matmul over the experts a chip holds (sparse-expert layers).
+#
+# Rows arrive SORTED by expert and padded so that every tile of ``block_rows``
+# rows belongs to one expert; ``tile_expert`` (scalar-prefetched) names it and
+# picks the expert's weight block in the index map, so a tile streams exactly
+# one expert's (K, block_cols) column blocks, K whole (no accumulator). Tiles
+# from ``tiles_used`` on hold no row: their index maps repeat the last used
+# tile's blocks, so nothing is fetched for them, their body is skipped and
+# their output rows are zero. An expert no row was routed to has no tile: its
+# weights are never read. Operands are rounded as the backend's default
+# precision rounds a matmul's (bfloat16 on the TPU, not at all in the
+# interpreted kernel on the CPU); sums are float32.
+# ---------------------------------------------------------------------------
+def _moe_gmm_kernel(te_ref, used_ref, x_ref, w_ref, o_ref, *, op_dtype):
+    import jax.experimental.pallas as pl
+
+    del te_ref   # read by the index maps
+    i = pl.program_id(0)
+
+    @pl.when(i < used_ref[0])
+    def _tile():
+        o_ref[:] = jax.lax.dot_general(
+            x_ref[:].astype(op_dtype), w_ref[:].astype(op_dtype),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when(i >= used_ref[0])
+    def _empty():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_rows", "block_cols", "interpret",
+                                    "operand_dtype"))
+def moe_grouped_matmul(x, w, tile_expert, tiles_used, block_rows: int,
+                       block_cols: int = 256, interpret: bool = None,
+                       operand_dtype=None):
+    """``out[tile t] = x[tile t] @ w[tile_expert[t]]`` for the first
+    ``tiles_used`` tiles of ``block_rows`` rows, zeros after them.
+
+    x: (P, K), P a multiple of ``block_rows``; w: (E, K, N), N a multiple
+    of ``block_cols``; tile_expert: (P // block_rows,) int32 in [0, E);
+    tiles_used: int32 scalar. Returns (P, N) float32."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    if operand_dtype is None:
+        operand_dtype = jnp.float32 if interpret else jnp.bfloat16
+    P, K = x.shape
+    _, _, N = w.shape
+    bn = min(block_cols, N)
+    if P % block_rows or N % bn:
+        raise ValueError(f"rows {P} / columns {N} must divide into tiles "
+                         f"of {block_rows} x {bn}")
+    nt, nj = P // block_rows, N // bn
+
+    def last(i, used_ref):
+        return jnp.maximum(jnp.minimum(i, used_ref[0] - 1), 0)
+
+    def x_index(i, j, te_ref, used_ref):
+        return (last(i, used_ref), 0)
+
+    def w_index(i, j, te_ref, used_ref):
+        return (te_ref[last(i, used_ref)], 0,
+                jnp.where(i < used_ref[0], j, nj - 1))
+
+    kernel = functools.partial(_moe_gmm_kernel, op_dtype=operand_dtype)
+    params = (None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary")))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nt, nj),
+            in_specs=[pl.BlockSpec((block_rows, K), x_index),
+                      pl.BlockSpec((None, K, bn), w_index)],
+            out_specs=pl.BlockSpec((block_rows, bn),
+                                   lambda i, j, *_: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((P, N), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="moe_grouped_matmul",
+    )(tile_expert.astype(jnp.int32),
+      jnp.asarray(tiles_used, jnp.int32).reshape(1), x, w)
+
+
+# ---------------------------------------------------------------------------
 # Fused softmax cross-entropy — the other canonical memory-bound fusion:
 # per row, one VMEM pass computes max / logsumexp / target logit without
 # materializing the [rows, V] log-softmax in HBM.

@@ -457,8 +457,31 @@ for rows, pages in json.loads(sys.argv[1]):
             "temp": c.memory_analysis().temp_size_in_bytes}
     except Exception as e:
         out[f"{rows}x{pages}"] = {"error": str(e)[:500]}
+
+
+# the grouped matmul of the expert layer (serving/moe_model.py) at the
+# sparse-expert cell's widths: (rows, tile, K, N) over 16 held experts
+def g(x, w, tile_expert, used, tile):
+    return pallas_ops.moe_grouped_matmul(x, w, tile_expert, used,
+                                         block_rows=tile, interpret=False)
+
+
+for rows, tile, k, n in json.loads(sys.argv[2]):
+    try:
+        c = jax.jit(g, static_argnums=4).lower(
+            S((rows, k), jnp.bfloat16), S((16, k, n), jnp.bfloat16),
+            S((rows // tile,), jnp.int32), S((), jnp.int32), tile).compile()
+        out[f"gmm{rows}x{tile}x{k}x{n}"] = {
+            "custom_call": "tpu_custom_call" in c.as_text(),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+    except Exception as e:
+        out[f"gmm{rows}x{tile}x{k}x{n}"] = {"error": str(e)[:500]}
 print(json.dumps(out))
 """
+# decode gate-and-up and down at 32 rows (8 x 32 pairs + 16 x 15 pads),
+# prefill's gate-and-up over a chunk of 1024 rows
+_GMM_SHAPES = [(496, 16, 4096, 8192), (496, 16, 4096, 4096),
+               (10240, 128, 4096, 8192)]
 
 
 @pytest.fixture(scope="module")
@@ -477,7 +500,7 @@ def compiled_for_v5e():
     env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_SCRIPT,
-         json.dumps(_COMPILE_SHAPES)],
+         json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES)],
         env=env, capture_output=True, text=True, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert proc.returncode == 0 and lines, proc.stderr[-2000:]
@@ -495,6 +518,19 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(compiled_for_v5e,
     blocks x 12 layers (shapes only: nothing runs), and XLA hands it the
     pools without a copy: no temporary near a pool's size."""
     got = compiled_for_v5e[f"{rows}x{pages}"]
+    assert "error" not in got, got
+    assert got["custom_call"]
+    assert got["temp"] < 64 << 20
+
+
+@pytest.mark.parametrize("rows,tile,k,n", _GMM_SHAPES)
+def test_grouped_matmul_compiles_for_the_v5e_at_the_cells_shapes(
+        compiled_for_v5e, rows, tile, k, n):
+    """Mosaic accepts ``moe_grouped_matmul`` at the sparse-expert cell's
+    widths (bfloat16 operands, 16 held experts of 4096 x 8192 and 4096 x
+    4096, tiles of 16 rows at decode and 128 in prefill), and XLA hands it
+    the experts' weights without a copy."""
+    got = compiled_for_v5e[f"gmm{rows}x{tile}x{k}x{n}"]
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < 64 << 20
