@@ -751,7 +751,10 @@ def serving_service(server, http: HttpMessage):
                     f"layer_launches={c['layer_launches']} "
                     f"pairs_max_expert={c['pairs_max_expert']} "
                     f"(pairs/launch={c['pairs'] / n:.1f} hit_share="
-                    f"{c['experts_hit'] / n / moe['experts_held']:.2f})")
+                    f"{c['experts_hit'] / n / moe['experts_held']:.2f})"
+                    + (f" kernel_layers={c['kernel_layers']} "
+                       f"blocked_layers={c['blocked_layers']}"
+                       if "kernel_layers" in c else ""))
             out.append(f"  moe: held={moe['experts_held']} "
                        + " | ".join(parts))
         # speculative decoding: draft/verify economics — how many tokens

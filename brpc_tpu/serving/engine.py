@@ -1193,7 +1193,9 @@ class ServingEngine:
         model that keeps them: ``experts_held`` and, for decode and prefill
         apart, the token-expert pairs this chip computed, the distinct held
         experts hit, the most pairs of one expert (summed over
-        layer-launches) and the layer-launches."""
+        layer-launches) and the layer-launches; for prefill also the
+        attention layers that took the flash kernel and the blocked scan
+        (``kernel_layers``, ``blocked_layers``)."""
         counters = getattr(self.model, "moe_counters", None)
         if counters is None:
             return None

@@ -20,11 +20,13 @@ recurrent state), launched by what
 SambaY lane (buckets, ``prep`` / ``launch`` / ``sync``, one launch and one
 sync a decode step):
 
-- ``prefill``: all rows of one prompt. Attention within the window bucket
-  goes through the flash kernel; a window layer of a longer prompt scans
-  query blocks of ``QUERY_BLOCK`` rows over ``window + QUERY_BLOCK`` keys, so
-  nothing holds heads x rows x rows. The expert layer runs over chunks of
-  ``MOE_CHUNK`` rows.
+- ``prefill``: all rows of one prompt. A layer the window cuts nothing of
+  is ONE call of the batched flash forward over q, k and v as the
+  projections made them (rounded as every matmul's operands, a query head
+  reading its key/value head in place); a window layer of a longer prompt
+  scans query blocks of ``QUERY_BLOCK`` rows over ``window + QUERY_BLOCK``
+  keys, so nothing holds heads x rows x rows. The expert layer runs over
+  chunks of ``MOE_CHUNK`` rows.
 - ``decode_step``: one fused launch for the batch over ring rows and pages.
 
 The routed product is ONE routine for both, :func:`expert_layer`: pairs
@@ -33,7 +35,9 @@ static shapes by bucket, through ``pallas_ops.moe_grouped_matmul`` (a tile
 streams one expert's weights; an expert no row hit is never read). Each
 launch returns, beside its tokens and in the same sync, per layer the pairs
 computed here, the distinct held experts hit and the most pairs of one
-expert: ``moe_counters``, which ``ServingEngine.snapshot()["moe"]`` reads.
+expert: ``moe_counters``, which ``ServingEngine.snapshot()["moe"]`` reads
+(prefill's also how many layers of the launched programs took the kernel and
+how many the blocked scan, summed on the host).
 
 Storage: weights and K/V pools bfloat16 (K stored rotated); the router's
 weights, the residual stream, softmax, router and every sum float32; matmuls
@@ -58,6 +62,8 @@ QUERY_BLOCK = 128    # query rows a step of the blocked window attention
 MOE_CHUNK = 1024     # rows a pass of the expert layer in prefill
 DECODE_CONTEXT_FLOOR = 1024   # rows of the smallest decode context bucket
 COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
+# how a launched prefill program's attention layers were built (host sums)
+LAYER_PATHS = ("kernel_layers", "blocked_layers")
 
 
 class Cohere2MoeConfig:
@@ -194,17 +200,24 @@ def _mm(a, b, spec: Optional[str] = None):
 
 def rope(x, pos, theta: float):
     """``rope_gptj``: pairs ``(2j, 2j + 1)`` of the last axis turned by
-    ``pos * theta^(-2j / hd)``. x (rows, heads, hd) float32, pos (rows,)."""
+    ``pos * theta^(-2j / hd)``, the pairs left interleaved. x (rows, heads,
+    hd) float32, pos (rows,). As ``x cos + swap(x) sin`` at the full head
+    width, ``swap(x)[2j] = -x[2j + 1]``, ``swap(x)[2j + 1] = x[2j]`` a
+    product with the fixed signed pair-swap matrix (exact at ``highest``:
+    every column has one entry, 1 or -1): no slice strides the lanes."""
+    import jax
     import jax.numpy as jnp
 
     hd = x.shape[-1]
-    inv = jnp.asarray(theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd),
-                      jnp.float32)
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x0, x1 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
-                     axis=-1).reshape(x.shape)
+    inv = np.repeat(theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd), 2)
+    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv, jnp.float32)
+    swap = np.zeros((hd, hd), np.float32)
+    even = np.arange(0, hd, 2)
+    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+    turned = jnp.matmul(x, jnp.asarray(swap),
+                        precision=jax.lax.Precision.HIGHEST)
+    return (x * jnp.cos(ang)[:, None, :]
+            + turned * jnp.sin(ang)[:, None, :])
 
 
 def _softmax_out(sc, live, v, spec):
@@ -215,22 +228,18 @@ def _softmax_out(sc, live, v, spec):
     return _mm(prob, v, spec)
 
 
-def attend_flash(cfg, q, k, v):
-    """Causal attention of one sequence through the flash kernel, a head at
-    a time: q (S, H, hd), k, v (S, G, hd); a key/value head serves H / G
-    query heads."""
-    import jax
-    import jax.numpy as jnp
-
+def attend_flash(q, k, v):
+    """Causal attention of one sequence in ONE call of the batched flash
+    forward: q (S, H, hd), k, v (S, G, hd); query head ``h`` reads key/value
+    head ``h // (H / G)`` in place. Operands rounded as :func:`_mm` rounds
+    them (K and V are as stored), sums float32. Returns (S, H, hd) in the
+    operands' dtype: what the output projection rounds to."""
     from brpc_tpu.tpu import pallas_ops
 
-    per = cfg.n_heads // cfg.n_kv_heads
-    kh = jnp.repeat(k.transpose(1, 0, 2), per, axis=0).astype(jnp.float32)
-    vh = jnp.repeat(v.transpose(1, 0, 2), per, axis=0).astype(jnp.float32)
-    out = jax.vmap(functools.partial(pallas_ops.flash_attention,
-                                     causal=True))(
-        q.transpose(1, 0, 2), kh, vh)
-    return out.transpose(1, 0, 2)
+    dt = _operand_dtype()
+    qh, kh, vh = (x.astype(dt).transpose(1, 0, 2)[None] for x in (q, k, v))
+    out = pallas_ops.flash_attention_mha(qh, kh, vh, causal=True)
+    return out[0].transpose(1, 0, 2)
 
 
 def attend_blocked(cfg, q, k, v, window: int):
@@ -432,9 +441,9 @@ class Cohere2MoeModel(HybridServingModel):
                                self.config.decode_context_floor)[1])
 
     def reset_moe_counters(self) -> None:
-        for phase in ("decode", "prefill"):
+        for phase, more in (("decode", ()), ("prefill", LAYER_PATHS)):
             self.moe_counters[phase] = dict.fromkeys(
-                COUNTERS + ("layer_launches",), 0)
+                COUNTERS + ("layer_launches",) + more, 0)
 
     def _note_counters(self, phase: str, tail) -> None:
         c = self.moe_counters[phase]
@@ -487,12 +496,15 @@ class Cohere2MoeModel(HybridServingModel):
         chunk = min(MOE_CHUNK, s_bucket)
         pool_dt = self.kv.full.k_pool.dtype
 
-        def attend(q, k, v, window: bool):
-            if window and s_bucket > cfg.window:
-                return attend_blocked(cfg, q, k, v, cfg.window)
-            if use_flash:       # s_bucket <= window: the window cuts nothing
-                return attend_flash(cfg, q, k, v)
-            return attend_blocked(cfg, q, k, v, s_bucket)
+        # the kernel serves a layer where the window cuts nothing
+        kernel = [use_flash and (kind == "full" or s_bucket <= cfg.window)
+                  for kind in cfg.kinds]
+
+        def attend(q, k, v, l: int):
+            if kernel[l]:
+                return attend_flash(q, k, v)
+            window = cfg.window if cfg.kinds[l] == "window" else s_bucket
+            return attend_blocked(cfg, q, k, v, min(window, s_bucket))
 
         def impl(w, fk, fv, wk, wv, ssm, conv, tokens, table, ring_table,
                  slot, length):
@@ -523,8 +535,7 @@ class Cohere2MoeModel(HybridServingModel):
                         fv = fv.at[i_f, full_slots].set(v)
                         i_f += 1
                     a = attend(q, k.reshape(s_bucket, cfg.n_kv_heads, -1),
-                               v.reshape(s_bucket, cfg.n_kv_heads, -1),
-                               kind == "window")
+                               v.reshape(s_bucket, cfg.n_kv_heads, -1), l)
                     att = _mm(a.reshape(s_bucket, cfg.q_dim), w[p + "wo"])
 
                 def ffn(args):
@@ -543,7 +554,17 @@ class Cohere2MoeModel(HybridServingModel):
                                   + [c.astype(jnp.int32) for c in counts])
             return fk, fv, wk, wv, ssm, conv, out
 
-        return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
+        program = jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
+        built = dict(zip(LAYER_PATHS, (sum(kernel),
+                                       cfg.n_layers - sum(kernel))))
+
+        @functools.wraps(program)
+        def launch(*args):
+            for name, n in built.items():
+                self.moe_counters["prefill"][name] += n
+            return program(*args)
+
+        return launch
 
     # -------------------------------------------------------------- decode
     def _decode_fn(self, b_bucket: int, l_bucket: int):
@@ -577,12 +598,14 @@ class Cohere2MoeModel(HybridServingModel):
             # length at least) routes nowhere
             live = pos > 0
 
-            def context(pool, blocks):
-                """A batch's rows of one layer's pool, whole BLOCKS at a
+            def context(pool, layer: int, blocks):
+                """A batch's rows of one layer of a pool, whole BLOCKS at a
                 time (a block is 16 contiguous rows: gathered three times
-                as fast as its rows one by one, measured)."""
-                return pool.reshape(-1, bs, cfg.kv_dim)[blocks].reshape(
-                    b_bucket, -1, cfg.kv_dim)
+                as fast as its rows one by one, measured), the layer inside
+                the gather's index: ``pool[layer]`` first is a copy of the
+                whole layer, 404 MB of the rings a window layer."""
+                return pool.reshape(len(pool), -1, bs, cfg.kv_dim)[
+                    layer, blocks].reshape(b_bucket, -1, cfg.kv_dim)
 
             def attend(q, kc, vc, mask):
                 qh = q.reshape(b_bucket, g, per, hd)
@@ -604,14 +627,14 @@ class Cohere2MoeModel(HybridServingModel):
                     if kind == "window":
                         wk = wk.at[i_w, ring_write].set(k)
                         wv = wv.at[i_w, ring_write].set(v)
-                        a = attend(q, context(wk[i_w], ring_blocks),
-                                   context(wv[i_w], ring_blocks), ring_live)
+                        a = attend(q, context(wk, i_w, ring_blocks),
+                                   context(wv, i_w, ring_blocks), ring_live)
                         i_w += 1
                     else:
                         fk = fk.at[i_f, full_write].set(k)
                         fv = fv.at[i_f, full_write].set(v)
-                        a = attend(q, context(fk[i_f], tables),
-                                   context(fv[i_f], tables), full_live)
+                        a = attend(q, context(fk, i_f, tables),
+                                   context(fv, i_f, tables), full_live)
                         i_f += 1
                     att = _mm(a.reshape(b_bucket, cfg.q_dim), w[p + "wo"])
                 y, cnt = self._ffn(p, w, h, live, tile=16)

@@ -288,10 +288,11 @@ def _flash_fwd_bhsd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # bn heads ride one grid step (static unroll): the per-step
         # pipeline overhead (~µs on this substrate, docs/round5-notes.md)
         # is amortized over bn tiles' worth of MXU work
+        share = bn // k_ref.shape[0]   # query heads a K/V head of the block
         for j in range(bn):
             q = q_ref[j]
-            k = k_ref[j]
-            v = v_ref[j]
+            k = k_ref[j // share]
+            v = v_ref[j // share]
             scale = 1.0 / float(q.shape[-1]) ** 0.5
             s = _dot_f32(q, k, trans_b=True) * scale
             if masked:
@@ -456,13 +457,31 @@ def _pick_blocks(sq, sk, block_q, block_k, interpret, causal=False):
     return bq, bk
 
 
+def _kv_block(n: int, n_kv: int, bn: int):
+    """Grouped heads: query head ``i`` of ``n`` reads K/V head ``i // per``
+    of ``n_kv`` (``per = n // n_kv`` query heads a K/V head; 1 is a K/V head
+    of its own a query head). For blocks of ``bn`` query heads: how many K/V
+    heads a block holds, and its index from the query block's (the query
+    block's own where each holds whole groups, so ``per == 1`` is the
+    ungrouped program)."""
+    per = n // n_kv
+    if n % n_kv or (bn % per and per % bn):
+        raise ValueError(f"{n} query heads over {n_kv} K/V heads in blocks "
+                         f"of {bn}: a block holds whole groups or lies in "
+                         "one")
+    if bn % per == 0:
+        return bn // per, lambda bi: bi
+    return 1, lambda bi: bi * bn // per
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                              "interpret", "bn"))
 def _flash_fwd_bhsd(q, k, v, causal: bool, bq: int, bk: int,
                     interpret: bool, bn: int = 1):
     """Forward over [N, S, D] (N = B*H): returns (o [N,S,D], lse [N,S]).
     ``bn`` = heads per grid step (must divide N); >1 amortizes per-step
-    pipeline overhead at the cost of bn x the VMEM working set."""
+    pipeline overhead at the cost of bn x the VMEM working set. k, v may
+    hold fewer heads (:func:`_kv_block`)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -471,6 +490,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, bq: int, bk: int,
     nq, nk = sq // bq, sk // bk
     if n % bn:
         raise ValueError(f"bn ({bn}) must divide batch*heads ({n})")
+    kn, kb = _kv_block(n, k.shape[0], bn)
     kernel = functools.partial(_flash_fwd_bhsd_kernel, causal=causal,
                                bq=bq, bk=bk, nk=nk, bn=bn)
     params = (None if interpret else pltpu.CompilerParams(
@@ -480,8 +500,8 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, bq: int, bk: int,
         grid=(n // bn, nq, nk),
         in_specs=[
             pl.BlockSpec((bn, bq, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((bn, bk, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((bn, bk, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((kn, bk, d), lambda b, qi, ki: (kb(b), ki, 0)),
+            pl.BlockSpec((kn, bk, d), lambda b, qi, ki: (kb(b), ki, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bn, bq, d), lambda b, qi, ki: (b, qi, 0)),
@@ -552,10 +572,11 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[j, rows] = m_new
 
     def _accumulate(masked: bool):
+        share = bn // k_ref.shape[0]   # query heads a K/V head of the block
         for j in range(bn):
             q = q_ref[j]
-            k = k_ref[j]
-            v = v_ref[j]
+            k = k_ref[j // share]
+            v = v_ref[j // share]
             scale = 1.0 / float(q.shape[-1]) ** 0.5
             if not masked:
                 _update(j, slice(None),
@@ -611,7 +632,8 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
                       diag_split: bool = False):
     """Causal forward over [N, S, D] via the triangular grid; bq = bk = b.
     Returns (o, lse). Causal masking uses absolute positions aligned at 0
-    (the non-ring case); ring hops keep the (qi, ki) kernels."""
+    (the non-ring case); ring hops keep the (qi, ki) kernels. k, v may hold
+    fewer heads (:func:`_kv_block`)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -621,6 +643,7 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
         raise ValueError("folded causal kernel requires sq == sk")
     if n % bn or sq % b:
         raise ValueError(f"shape ({n},{sq}) vs blocks (bn={bn},b={b})")
+    kn, kb = _kv_block(n, k.shape[0], bn)
     nq = sq // b
     steps = nq * (nq + 1) // 2
     kernel = functools.partial(_flash_fwd_folded_kernel, b=b, bn=bn,
@@ -633,15 +656,15 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
 
     def kmap(bi, t):
         qi = _tri_row(t)
-        return (bi, t - qi * (qi + 1) // 2, 0)
+        return (kb(bi), t - qi * (qi + 1) // 2, 0)
 
     return pl.pallas_call(
         kernel,
         grid=(n // bn, steps),
         in_specs=[
             pl.BlockSpec((bn, b, d), qmap),
-            pl.BlockSpec((bn, b, d), kmap),
-            pl.BlockSpec((bn, b, d), kmap),
+            pl.BlockSpec((kn, b, d), kmap),
+            pl.BlockSpec((kn, b, d), kmap),
         ],
         out_specs=[
             pl.BlockSpec((bn, b, d), qmap),
@@ -751,8 +774,10 @@ def _flash_fwd_best(q, k, v, causal, bq, bk, interpret):
     n = q.shape[0]
     if causal and bq == bk and q.shape[1] == k.shape[1]:
         return _flash_fwd_folded(q, k, v, bq, interpret)
-    # bn=2 at bq=1024 exceeds the 16MB VMEM scoped limit (sweep FAILs)
-    bn = 2 if n % 2 == 0 and bq <= 512 else 1
+    # bn=2 at bq=1024 exceeds the 16MB VMEM scoped limit (sweep FAILs);
+    # two heads a step read one K/V head or two whole groups' (_kv_block)
+    per = n // k.shape[0]
+    bn = 2 if n % 2 == 0 and bq <= 512 and (per == 1 or per % 2 == 0) else 1
     return _flash_fwd_bhsd(q, k, v, causal, bq, bk, interpret, bn)
 
 
@@ -781,14 +806,18 @@ def flash_attention_mha(q, k, v, causal: bool = False, block_q: int = None,
                         block_k: int = None, interpret: bool = None):
     """(B, H, S, D) multi-head flash attention — one pallas_call with a
     (B*H, q-tiles, k-tiles) grid, differentiable via the Pallas backward
-    kernels above."""
+    kernels above. k, v (B, G, S, D) with G dividing H: query head ``h``
+    reads K/V head ``h // (H / G)`` in place, nothing repeated; the forward
+    only (the backward kernels take a K/V head a query head)."""
     if interpret is None:
         interpret = not _on_tpu()
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    g, sk = k.shape[1], k.shape[2]
     bq, bk = _pick_blocks(sq, sk, block_q, block_k, interpret, causal)
-    o = _flash_mha_diff(q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-                        v.reshape(b * h, sk, d), causal, bq, bk, interpret)
+    fwd = _flash_mha_diff if g == h else (
+        lambda *args: _flash_fwd_best(*args)[0])
+    o = fwd(q.reshape(b * h, sq, d), k.reshape(b * g, sk, d),
+            v.reshape(b * g, sk, d), causal, bq, bk, interpret)
     return o.reshape(b, h, sq, d)
 
 

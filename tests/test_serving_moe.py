@@ -296,6 +296,137 @@ def test_rope_turns_pairs_by_the_position_and_keeps_their_norm():
                        np.linalg.norm(xs, axis=-1), rtol=1e-5)
 
 
+def _rope_stride2(x, pos, theta):
+    """The oracle: the rotation as it was written until PR 34, pairs taken
+    by stride-2 slices and put back by a stack."""
+    import jax.numpy as jnp
+
+    hd = x.shape[-1]
+    inv = jnp.asarray(theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd),
+                      jnp.float32)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x0, x1 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos],
+                     axis=-1).reshape(x.shape)
+
+
+@pytest.mark.parametrize("pos", [0, 1, 4095, 8191])
+@pytest.mark.parametrize("hd", [16, 128])
+def test_rope_is_the_stride_two_form_to_an_ulp_pairs_interleaved(hd, pos):
+    """Same products, one sum: the pair-swap product moves values and
+    changes none, so the rotation agrees with the sliced form to an ulp of
+    the larger term, element for element in the INTERLEAVED layout (K is
+    stored so)."""
+    import jax.numpy as jnp
+
+    x = jnp.asarray(np.random.RandomState(hd + pos).standard_normal(
+        (3, 4, hd)), jnp.float32)
+    at = jnp.asarray([pos, pos, 7])
+    got = np.asarray(moe_model.rope(x, at, 50000.0))
+    want = np.asarray(_rope_stride2(x, at, 50000.0))
+    assert got.dtype == np.float32 and got.shape == want.shape
+    ulp = np.spacing(np.maximum(np.abs(want), np.abs(np.asarray(x)).max()))
+    assert (np.abs(got - want) <= ulp).all()
+    if pos == 0:
+        assert np.array_equal(got[:2], np.asarray(x)[:2])
+
+
+def _prefill_jaxpr(model, s_bucket):
+    """The prefill program of ``s_bucket`` rows, traced over the manager's
+    own arrays (nothing runs)."""
+    import jax
+
+    kv = model.kv
+    blocks = -(-s_bucket // kv.block_size)
+    args = (model._params, kv.full.k_pool, kv.full.v_pool, kv.window.k_pool,
+            kv.window.v_pool, kv.ssm, kv.conv,
+            np.zeros(s_bucket, np.int32), np.zeros(blocks, np.int32),
+            np.zeros(kv.config.ring_blocks, np.int32), np.int32(0),
+            np.int32(s_bucket))
+    fn = model._prefill_fn(s_bucket, True)
+    return jax.make_jaxpr(fn.__wrapped__)(*args)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("s_bucket,kernel_layers", [(16, 4), (32, 1)])
+def test_the_prefill_program_hands_the_kernel_what_the_projections_made(
+        world, s_bucket, kernel_layers):
+    """Structure, read off the traced program: no slice strides the minor
+    axis (the rotation's pairs), ONE ``pallas_call`` an attention layer that
+    takes the kernel (not one a head), and none of its operands is a K/V
+    head repeated for its query heads."""
+    model, _kv = _stand(attn="flash", weights=world["host"])
+    eqns = list(_eqns(_prefill_jaxpr(model, s_bucket).jaxpr))
+    for e in eqns:
+        if e.primitive.name == "slice" and e.params["strides"]:
+            assert e.params["strides"][-1] == 1, e
+    flash = [e for e in eqns if e.primitive.name == "pallas_call"
+             and "flash" in e.params["name"]]
+    assert len(flash) == kernel_layers
+    k_size = s_bucket * M["num_key_value_heads"] * M["head_dim"]
+    for e in flash:
+        sizes = sorted(int(np.prod(v.aval.shape)) for v in e.invars)
+        assert sizes == [k_size, k_size,
+                         s_bucket * M["num_attention_heads"] * M["head_dim"]]
+
+
+@pytest.mark.parametrize("n,kernel,blocked", [(9, 4, 0), (37, 1, 3)],
+                         ids=["inside_the_window", "past_the_window"])
+def test_prefill_counts_the_layers_the_kernel_and_the_blocked_scan_took(
+        world, n, kernel, blocked):
+    """``kernel_layers`` / ``blocked_layers``: what the launched program's
+    layers were built as. Inside the window every layer takes the kernel;
+    past it the window layers scan blocks and the full layer alone keeps
+    the kernel. Without the kernel every layer is blocked."""
+    for attn, want in (("flash", (kernel, blocked)), ("reference", (0, 4))):
+        model, kv = _stand(attn=attn, weights=world["host"])
+        prompt = np.arange(1, n + 1, dtype=np.int32)
+        for seq in (1, 2):
+            model.prefill(prompt, kv.alloc_sequence(seq, n))
+        c = model.moe_counters["prefill"]
+        assert (c["kernel_layers"], c["blocked_layers"]) == tuple(
+            2 * w for w in want)
+        assert c["layer_launches"] == 8
+        model.reset_moe_counters()
+        assert model.moe_counters["prefill"]["kernel_layers"] == 0
+        assert "kernel_layers" not in model.moe_counters["decode"]
+
+
+def test_a_decode_step_gathers_its_blocks_and_copies_no_layer_of_a_pool(
+        world):
+    """``pool[layer]`` ahead of the gather is a copy of the whole layer (on
+    the chip 404 MB of the rings, six times a step): the layer rides in the
+    gather's index, and no slice takes a pool for its operand."""
+    import jax
+
+    model, kv = _stand(weights=world["host"])
+    b, ctx = 8, 64
+    args = (model._params, kv.full.k_pool, kv.full.v_pool, kv.window.k_pool,
+            kv.window.v_pool, kv.ssm, kv.conv, np.zeros(b, np.int32),
+            np.ones(b, np.int32), np.zeros((b, ctx // BS), np.int32),
+            np.zeros((b, kv.config.ring_blocks), np.int32),
+            np.zeros(b, np.int32))
+    jaxpr = jax.make_jaxpr(model._decode_fn(b, ctx))(*args)
+    pools = {kv.full.k_pool.shape, kv.window.k_pool.shape}
+    eqns = list(_eqns(jaxpr.jaxpr))
+    for e in eqns:
+        if e.primitive.name in ("slice", "dynamic_slice", "squeeze"):
+            assert e.invars[0].aval.shape not in pools, e
+    reads = [e for e in eqns if e.primitive.name == "gather"
+             and e.invars[0].aval.shape[-2:] == (BS, M["head_dim"] * 2)]
+    assert len(reads) == 2 * len(model.config.kinds)
+
+
 def test_grouped_matmul_reads_one_expert_a_tile_and_zeroes_unused_tiles():
     import jax.numpy as jnp
 
@@ -429,10 +560,17 @@ def test_generate_through_the_engine_serves_the_same_tokens(world):
     assert moe["decode"]["pairs"] > 0 and moe["prefill"]["pairs"] > 0
     assert set(moe["decode"]) == {"pairs", "experts_hit", "layer_launches",
                                   "pairs_max_expert"}
+    # prompts of 37, 9 and 70 rows over a window of 16: the 9-row one alone
+    # lies inside it; this stand runs no kernel, so every layer is blocked
+    assert set(moe["prefill"]) == set(moe["decode"]) | {"kernel_layers",
+                                                        "blocked_layers"}
+    assert (moe["prefill"]["kernel_layers"],
+            moe["prefill"]["blocked_layers"]) == (0, 12)
     from brpc_tpu.builtin.services import serving_service
     from brpc_tpu.policy.http_protocol import HttpMessage
     page = serving_service(None, HttpMessage())[2]
     assert "moe: held=4 decode pairs=" in page and "hit_share=" in page
+    assert "kernel_layers=0 blocked_layers=12" in page
     eng.stop()
     kv.assert_idle("engine stopped")
 

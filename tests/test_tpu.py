@@ -405,6 +405,71 @@ class TestFlashAttention:
                                           causal=True)
                 assert jnp.allclose(out[b, h], ref, atol=2e-3)
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("s", [128, 384, 1024])
+    @pytest.mark.parametrize("per", [1, 4, 16])
+    def test_grouped_heads_read_their_kv_head_in_place(self, per, s, dtype):
+        """K/V with fewer heads than q: query head ``n`` against K/V head
+        ``n // per``, each against the O(S^2) reference on the same
+        operands; nothing is repeated on the way in."""
+        import jax
+
+        from brpc_tpu.tpu.pallas_ops import (attention_reference,
+                                             flash_attention_mha)
+
+        g, d = (2 if s < 1024 else 1), 32
+        dt = jnp.dtype(dtype)
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(per * s), 3)
+        q = jax.random.normal(kq, (1, g * per, s, d), jnp.float32).astype(dt)
+        k = jax.random.normal(kk, (1, g, s, d), jnp.float32).astype(dt)
+        v = jax.random.normal(kv, (1, g, s, d), jnp.float32).astype(dt)
+        out = flash_attention_mha(q, k, v, causal=True, interpret=True)
+        assert out.shape == q.shape and out.dtype == dt
+        tol = 2e-3 if dtype == "float32" else 3e-2
+        for n in range(g * per):
+            ref = attention_reference(q[0, n], k[0, n // per], v[0, n // per],
+                                      causal=True)
+            np.testing.assert_allclose(
+                np.asarray(out[0, n], np.float32),
+                np.asarray(ref, np.float32), atol=tol)
+
+    @pytest.mark.parametrize("causal,bq,bk", [(True, 64, 64), (True, 64, 32),
+                                              (False, 64, 64)],
+                             ids=["folded", "causal_grid", "full_grid"])
+    def test_grouped_forward_is_the_ungrouped_one_bit_for_bit(self, causal,
+                                                              bq, bk):
+        """The index map is all that differs: over K/V repeated a query
+        head the ungrouped program gives the same bits, in every forward of
+        the family, two heads a step (one K/V head under both, or two whole
+        groups) included; and a K/V head a query head IS the ungrouped
+        program, equal to the single-head kernel a head."""
+        import jax
+
+        from brpc_tpu.tpu.pallas_ops import (flash_attention,
+                                             flash_attention_mha)
+
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+        q = jax.random.normal(kq, (2, 4, 128, 32), jnp.float32)
+        for g in (4, 2, 1):
+            k = jax.random.normal(kk, (2, g, 128, 32), jnp.float32)
+            v = jax.random.normal(kv, (2, g, 128, 32), jnp.float32)
+            kw = dict(causal=causal, block_q=bq, block_k=bk, interpret=True)
+            got = flash_attention_mha(q, k, v, **kw)
+            rep = flash_attention_mha(q, jnp.repeat(k, 4 // g, axis=1),
+                                      jnp.repeat(v, 4 // g, axis=1), **kw)
+            assert np.array_equal(np.asarray(got), np.asarray(rep))
+        one = jax.vmap(jax.vmap(lambda a, b, c: flash_attention(
+            a, b, c, causal=causal, block_q=bq, block_k=bk,
+            interpret=True)))(q, k.repeat(4, axis=1), v.repeat(4, axis=1))
+        assert np.array_equal(np.asarray(got), np.asarray(one))
+
+    def test_grouped_heads_must_divide(self):
+        from brpc_tpu.tpu.pallas_ops import flash_attention_mha
+
+        q, k = jnp.zeros((1, 4, 64, 32)), jnp.zeros((1, 3, 64, 32))
+        with pytest.raises(ValueError):
+            flash_attention_mha(q, k, k, causal=True, interpret=True)
+
     def test_block_misalignment_rejected(self):
         import jax
 

@@ -31,6 +31,28 @@ class TestKernelsOnChip:
                 np.asarray(out, dtype=np.float32),
                 np.asarray(ref, dtype=np.float32), rtol=0.1, atol=0.06)
 
+    def test_flash_grouped_heads_on_chip(self, tpu_device):
+        # the serving prefill's call at the rag-steady cell's shapes: 128
+        # query heads over 8 K/V heads of 128, 2048 rows, bfloat16, causal,
+        # ONE call; every query head against the reference on ITS K/V head
+        from brpc_tpu.tpu.pallas_ops import (attention_reference,
+                                             flash_attention_mha)
+
+        rng = np.random.default_rng(3)
+        H, G, S, D = 128, 8, 2048, 128
+        q = jnp.asarray(rng.normal(size=(1, H, S, D)), dtype=jnp.bfloat16)
+        k = jnp.asarray(rng.normal(size=(1, G, S, D)), dtype=jnp.bfloat16)
+        v = jnp.asarray(rng.normal(size=(1, G, S, D)), dtype=jnp.bfloat16)
+        out = flash_attention_mha(q, k, v, causal=True, interpret=False)
+        assert out.shape == q.shape and out.dtype == jnp.bfloat16
+        ref = jax.jit(jax.vmap(
+            lambda q1, k1, v1: attention_reference(q1, k1, v1, causal=True)))(
+                q[0], jnp.repeat(k[0], H // G, axis=0),
+                jnp.repeat(v[0], H // G, axis=0))
+        np.testing.assert_allclose(
+            np.asarray(out[0], dtype=np.float32),
+            np.asarray(ref, dtype=np.float32), rtol=0.1, atol=0.06)
+
     def test_flash_mha_bwd_on_chip(self, tpu_device):
         # the Pallas backward kernels under the NATIVE Mosaic lowering;
         # oracle = AD through the O(S^2) reference in f32

@@ -136,7 +136,43 @@ def bench_flash_attention(peak: dict):
               f"(custom-vjp Pallas backward): {tf:7.2f} TFLOP/s "
               f"({_pct_of_peak(tf, peak)})", flush=True)
     _bench_splash_control(q, k, v, causal_fwd_flops, peak)
+    _bench_grouped_forward(peak)
     return tf
+
+
+def _bench_grouped_forward(peak: dict):
+    """The serving prefill's shape (Command A+ in ``rag-steady``): one
+    sequence of 2048 rows, 128 query heads over 8 K/V heads of 128, causal,
+    bfloat16, the forward only; query head ``h`` reads K/V head ``h // 16``
+    in place. The output feeds the next round's q (same shape, bounded)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.tpu.pallas_ops import flash_attention_mha
+
+    H, G, S, D = 128, 8, 2048, 128
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, H, S, D)), dtype=jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(1, G, S, D)), dtype=jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(1, G, S, D)), dtype=jnp.bfloat16)
+
+    @functools.partial(jax.jit, static_argnames=("n",))
+    def loop(q, k, v, n: int):
+        return jax.lax.fori_loop(
+            0, n, lambda i, x: flash_attention_mha(x, k, v, causal=True,
+                                                   interpret=False), q)
+
+    def run(n):
+        float(jax.device_get(loop(q, k, v, n)[0, 0, 0, 0]))
+
+    sec = _marginal(run, 16, 128)
+    tf = 2.0 * H * S * (S + 1) * D / sec / 1e12
+    print(f"# kernel flash_attention fwd CAUSAL GROUPED H={H} over G={G} "
+          f"S={S} D={D}: {sec * 1e3:7.3f} ms {tf:7.2f} TFLOP/s "
+          f"({_pct_of_peak(tf, peak)})", flush=True)
 
 
 def _bench_splash_control(q, k, v, causal_fwd_flops, peak: dict):
