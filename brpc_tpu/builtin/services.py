@@ -715,6 +715,12 @@ def serving_service(server, http: HttpMessage):
                 f"cache_bytes={kv['cache_bytes']} "
                 f"(peak {kv['cache_bytes_peak']} at "
                 f"{kv['tokens_at_peak']} tokens)")
+        if s.get("prefill_chunks") or s.get("prefilling"):
+            # chunked prefill: launches that were part of a longer prompt
+            out.append(
+                f"  prefill chunks: {s['prefill_chunks']} launches, "
+                f"{s['prefill_chunk_rows']} rows, "
+                f"{s['prefilling']} mid-prompt now")
         pfx = s.get("prefix")
         if pfx:
             out.append(

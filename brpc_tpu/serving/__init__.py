@@ -53,6 +53,12 @@ mechanism:
   inside the one fused decode launch, shared experts averaged, a parallel
   attention + FFN block, rotary window layers beside position-free full
   ones.
+- :mod:`brpc_tpu.serving.jamba_model` — a ``jamba`` decoder over the same
+  manager (recurrent slots beside several full layers' pages, no ring,
+  bfloat16 with a float32 recurrent state): Mamba-1 layers with normed
+  dt / B / C beside position-free attention of many query heads over one
+  key/value head, whose prefill CONTINUES from the slot's state and the
+  pages, so the engine prefills a long prompt a chunk a step.
 - :mod:`brpc_tpu.serving.speculative` — the speculative-decoding draft
   lane: host-side prompt-lookup drafting (zero weights, zero device
   work, lint-pinned) feeding the model's one fused ``verify_step``
@@ -102,6 +108,9 @@ def __getattr__(name):
     if name in ("Cohere2MoeConfig", "Cohere2MoeModel"):
         from brpc_tpu.serving import moe_model
         return getattr(moe_model, name)
+    if name in ("JambaConfig", "JambaModel"):
+        from brpc_tpu.serving import jamba_model
+        return getattr(jamba_model, name)
     raise AttributeError(name)
 
 
@@ -115,7 +124,7 @@ __all__ = [
     "KVMigrator", "MigrationReceiver",
     "HybridCacheConfig", "HybridStateCache", "HybridTable",
     "SambaYConfig", "SambaYModel", "HybridServingModel",
-    "Cohere2MoeConfig", "Cohere2MoeModel",
+    "Cohere2MoeConfig", "Cohere2MoeModel", "JambaConfig", "JambaModel",
     "AdaptiveK", "accept_longest_prefix", "draft_tokens",
     "QosConfig", "QosGovernor", "QosLimiter", "TenantScheduler",
 ]

@@ -20,8 +20,13 @@ front door:
 
 Each step issues ONE fused device program for the whole decode batch and
 one per prefill (see serving/model.py) — dispatch coalescing at the step
-level. Tokens are host-materialized exactly once per step; per-token
-streaming writes fan out of that single sync (tpulint's
+level. A model that can go on from what an earlier launch left of a prompt
+(``CONTINUES_PREFILL``: serving/hybrid_model.py) has a prompt longer than
+the step's budget prefilled a CHUNK a step: the sequence holds its slot and
+pages, stays out of the decode batch until its last chunk yields the first
+token, and every step still runs the decode launch — a live sequence waits
+one chunk, not one prompt. Tokens are host-materialized exactly once per
+step; per-token streaming writes fan out of that single sync (tpulint's
 ``no-per-token-host-sync`` rule keeps it that way).
 
 Streaming: a request that arrived with stream settings gets TokenDelta
@@ -39,6 +44,7 @@ CreditLedger audits window teardown.
 from __future__ import annotations
 
 import collections
+import math
 import threading
 import time
 from typing import Deque, Dict, List, Optional
@@ -114,7 +120,9 @@ class EngineConfig:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self.max_batch = max_batch
         # per-step budget over prefill tokens + one decode token per
-        # running sequence — the Orca iteration-level knob
+        # running sequence — the Orca iteration-level knob. It gates
+        # admission; for a model that can continue a prefill it also cuts
+        # a longer prompt into chunks of what the budget leaves a step
         self.token_budget = token_budget
         self.max_queue = max_queue
         self.max_new_tokens_cap = max_new_tokens_cap
@@ -172,6 +180,9 @@ class Sequence:
         # tokens covered by a forked prefix-cache chain (block-aligned);
         # prefill runs only the suffix past this point
         self.prefix_len = 0
+        # rows of the prompt prefilled so far (chunked prefill: under
+        # len(prompt) while the sequence is mid-prompt)
+        self.prefilled = 0
         self.t_submit = time.monotonic()
         self.t_admit = 0.0   # when admission moved it to running
         self.t_first_token = 0.0
@@ -230,6 +241,17 @@ class ServingEngine:
         self._cv = threading.Condition()
         self._waiting: Deque[Sequence] = collections.deque()
         self._running: List[Sequence] = []
+        # chunked prefill: the ONE admitted sequence whose prompt is still
+        # being prefilled (slot and pages held, not in the decode batch);
+        # rows a chunk is a multiple of (the model's scan chunk and the
+        # cache's block), 0 for a model that cannot continue a prefill
+        self._prefilling: Optional[Sequence] = None
+        self._chunk_unit = 0
+        if getattr(model, "CONTINUES_PREFILL", False):
+            self._chunk_unit = math.lcm(int(model.PREFILL_GRANULE),
+                                        int(self.kv.block_size))
+        self.prefill_chunks = 0       # launches that were part of a prompt
+        self.prefill_chunk_rows = 0   # rows those launches prefilled
         self._thread: Optional[threading.Thread] = None
         self.running = False
         self.steps = 0
@@ -498,6 +520,11 @@ class ServingEngine:
     def running_count(self) -> int:
         return len(self._running)
 
+    @property
+    def prefilling_count(self) -> int:
+        """Sequences admitted and still mid-prompt (chunked prefill)."""
+        return 0 if self._prefilling is None else 1
+
     # -------------------------------------------------- migration adoption
     def make_adopted_sequence(self, prompt: np.ndarray,
                               out_tokens: List[int], max_new_tokens: int,
@@ -615,7 +642,8 @@ class ServingEngine:
                             return
                         admitted = self._admit_locked()
                     sp.note(admitted=len(admitted))
-                if not admitted and not self._running:
+                if (not admitted and not self._running
+                        and self._prefilling is None):
                     # waiting work exists but the pool is full — let
                     # in-flight frees land instead of spinning the step
                     with _span("engine.pool_wait"):
@@ -627,10 +655,10 @@ class ServingEngine:
                         with self.pool_gate:
                             self._step(admitted)
                     except Exception as e:  # engine must survive a bad step
-                        for seq in list(self._running):
+                        for seq in self._live():
                             self._finish(seq, errors.EINTERNAL,
                                          f"step failed: {e}")
-                        self._running = []
+                        self._running, self._prefilling = [], None
                 self.last_step_us = sp.elapsed_ns / 1000.0
                 g_serving_step.record(self.last_step_us)
         finally:
@@ -639,6 +667,7 @@ class ServingEngine:
     def _has_work(self) -> bool:
         """Something to admit or to step (lock held)."""
         return bool(self._waiting or self._running or self._adopted_pending
+                    or self._prefilling is not None
                     or (self.qos is not None and self.qos.total_depth()))
 
     def _admit_locked(self) -> List[Sequence]:
@@ -657,12 +686,14 @@ class ServingEngine:
         # accepted-length is variable spend: a speculating sequence can
         # commit up to 1 + k tokens per step, so it reserves that many
         # budget slots, not one (a collapsed sequence is back to 1)
-        budget = cfg.token_budget - sum(self._decode_cost(s)
-                                        for s in self._running)
+        budget = self._budget_left()
         if self.qos is not None:
             return self._admit_qos_locked(admitted, budget)
+        # a sequence mid-prompt takes the step's budget and the next seat
+        # of the batch: nothing is admitted behind it (FIFO order is kept)
         while (self._waiting and len(self._running) < cfg.max_batch
-               and budget >= self._prefill_cost(self._waiting[0])):
+               and self._prefilling is None
+               and budget >= self._admit_cost(self._waiting[0], budget)):
             seq = self._waiting[0]
             deadline = (getattr(seq.cntl, "deadline_mono", 0.0)
                         if seq.cntl else 0.0)
@@ -686,7 +717,7 @@ class ServingEngine:
                 except KVCacheFull:
                     break
             self._waiting.popleft()
-            budget -= self._prefill_cost(seq)
+            budget -= self._admit_cost(seq, budget)
             self._mark_admitted(seq, admitted)
         return admitted
 
@@ -701,7 +732,13 @@ class ServingEngine:
         if rspan is not None:
             rspan.add_phase("serving_queue_us", wait_us)
         seq.state = STATE_RUNNING
-        self._running.append(seq)
+        if self._chunk_unit and self._prefill_cost(seq) \
+                > self._chunk_rows(self._budget_left()):
+            # longer than what a step may prefill: a chunk a step, and into
+            # the decode batch with its first token
+            self._prefilling = seq
+        else:
+            self._running.append(seq)
         admitted.append(seq)
         g_serving_admitted.put(1)
 
@@ -713,8 +750,9 @@ class ServingEngine:
         sequence exactly as the FIFO path does, and a pool-full head
         keeps its turn for the next step's full budget."""
         cfg = self.config
-        while len(self._running) < cfg.max_batch:
-            seq = self.qos.peek(budget, self._prefill_cost)
+        while len(self._running) < cfg.max_batch and self._prefilling is None:
+            seq = self.qos.peek(budget,
+                                lambda s: self._admit_cost(s, budget))
             if seq is None:
                 break
             deadline = (getattr(seq.cntl, "deadline_mono", 0.0)
@@ -736,7 +774,7 @@ class ServingEngine:
                     self._alloc_for(seq)
                 except KVCacheFull:
                     break
-            cost = self._prefill_cost(seq)
+            cost = self._admit_cost(seq, budget)
             self.qos.commit(seq, cost)
             budget -= cost
             self._mark_admitted(seq, admitted)
@@ -759,6 +797,32 @@ class ServingEngine:
         if seq.prefix_len:  # already forked (allocated, not yet stepped)
             return max(1, len(seq.prompt) - seq.prefix_len)
         return max(1, len(seq.prompt) - self.prefix.match_len(seq.prompt))
+
+    def _chunk_rows(self, budget: int) -> int:
+        """Rows of ONE prompt a step may prefill out of ``budget``, for a
+        model that can continue a prefill: what the budget leaves, rounded
+        down to whole scan chunks and blocks."""
+        return max(0, budget) // self._chunk_unit * self._chunk_unit
+
+    def _budget_left(self) -> int:
+        """The step's budget less one decode token a running sequence."""
+        return self.config.token_budget - sum(self._decode_cost(s)
+                                              for s in self._running)
+
+    def _admit_cost(self, seq: Sequence, budget: int) -> int:
+        """What admitting ``seq`` takes of this step's ``budget``: its
+        prefill; for a prompt longer than a step may prefill of a model that
+        can continue, its first chunk (at least one unit: less admits
+        nothing)."""
+        cost = self._prefill_cost(seq)
+        if not self._chunk_unit:
+            return cost
+        # the step's chunk is cut from the budget at the step's start, so
+        # that every step launches the ONE chunk size
+        rows = self._chunk_rows(self._budget_left())
+        if cost <= rows:
+            return cost
+        return rows if rows else budget + 1
 
     def _alloc_for(self, seq: Sequence) -> None:
         """Allocate ``seq``'s block table — forking the longest cached
@@ -792,8 +856,12 @@ class ServingEngine:
         prefill per new sequence, then ONE fused program for the whole
         decode batch."""
         for seq in admitted:
-            if not seq.adopted:   # an adopted chain arrived prefilled
+            # an adopted chain arrived prefilled; a long prompt of a model
+            # that can continue goes a chunk a step, below
+            if not seq.adopted and seq is not self._prefilling:
                 self._prefill_one(seq)
+        if self._prefilling is not None:
+            self._prefill_chunk()
         self._reap_finished()
         # ---- disaggregated handoff: a prefill-role engine ships every
         # live chain to the decode shard right after its first token; a
@@ -818,17 +886,57 @@ class ServingEngine:
                                                       s.context_len())
                         except KeyError:
                             pass
-                self._finish(batch[-1], errors.EOVERCROWDED,
+                # the youngest holder of pages: a sequence mid-prompt
+                # first (it has yielded no token yet)
+                shed, self._prefilling = (self._prefilling or batch[-1]), None
+                self._finish(shed, errors.EOVERCROWDED,
                              "kv pool exhausted mid-decode")
         self._reap_finished()
         self.steps += 1
         self._occupancy_sum += len(batch)
         g_serving_steps.put(1)
 
-    def _prefill_one(self, seq: Sequence) -> None:
-        with _span("engine.prefill", seq=seq.seq_id,
-                   n=len(seq.prompt)) as sp:
-            if seq.prefix_len:
+    def _prefill_chunk(self) -> None:
+        """This step's chunk of the sequence mid-prompt: what the budget
+        leaves beside the decode rows (one unit at least: a prompt always
+        advances); its last chunk yields the first token and the sequence
+        joins the decode batch. A dead connection or a spent deadline frees
+        its slot and pages here, as ``_reap_finished`` does for the batch."""
+        seq = self._prefilling
+        sock = getattr(seq.cntl, "_srv_socket", None)
+        deadline = getattr(seq.cntl, "deadline_mono", 0.0) if seq.cntl else 0.0
+        code, reason = 0, ""
+        if sock is not None and getattr(sock, "failed", False):
+            code, reason = (errors.EFAILEDSOCKET,
+                            "connection failed mid-prompt")
+        elif deadline and time.monotonic() >= deadline:
+            g_serving_deadline_rejects.put(1)
+            code, reason = errors.ERPCTIMEDOUT, "deadline expired mid-prompt"
+        if code:
+            self._prefilling = None
+            self._finish(seq, code, reason)
+            return
+        rows = max(self._chunk_unit, self._chunk_rows(self._budget_left()))
+        start = seq.prefilled
+        self._prefill_one(seq, min(len(seq.prompt), start + rows))
+        self.prefill_chunks += 1
+        self.prefill_chunk_rows += seq.prefilled - start
+        if seq.prefilled == len(seq.prompt):
+            self._prefilling = None
+            self._running.append(seq)
+
+    def _prefill_one(self, seq: Sequence, end: Optional[int] = None) -> None:
+        """Prefill ``seq``'s prompt, or (chunked prefill) its rows from
+        ``seq.prefilled`` up to ``end``; the first token comes with the
+        prompt's last row."""
+        start, whole = seq.prefilled, len(seq.prompt)
+        end = whole if end is None else end
+        with _span("engine.prefill", seq=seq.seq_id, n=end - start,
+                   start=start, of=whole) as sp:
+            if start or end < whole:
+                first = self.model.prefill_suffix(
+                    seq.prompt[:end], self.kv.block_table(seq.seq_id), start)
+            elif seq.prefix_len:
                 # forked chain: cow-split the divergence block if shared,
                 # then run only the suffix — hit TTFT is one decode-shaped
                 # launch, not O(prompt) prefill
@@ -839,11 +947,13 @@ class ServingEngine:
             else:
                 first = self.model.prefill(
                     seq.prompt, self.kv.block_table(seq.seq_id))
-            n_new = len(seq.prompt) - seq.prefix_len
+            n_new = end - max(start, seq.prefix_len)
             g_serving_prefill_tokens.put(n_new)
             self.prefill_tokens += n_new
-            with _span("engine.commit", batch=1):
-                self._append_token(seq, first)
+            seq.prefilled = end
+            if end == whole:
+                with _span("engine.commit", batch=1):
+                    self._append_token(seq, first)
         rspan = getattr(seq.cntl, "span", None)
         if rspan is not None:
             rspan.add_phase("prefill_us", sp.elapsed_ns / 1000.0)
@@ -1160,12 +1270,18 @@ class ServingEngine:
             prompt_len=len(seq.prompt), steps=len(toks),
             ttft_us=ttft_us, finish_reason=seq.finish_reason or "length")
 
+    def _live(self) -> List[Sequence]:
+        """Every admitted sequence: the decode batch and the one
+        mid-prompt."""
+        return list(self._running) + ([self._prefilling]
+                                      if self._prefilling is not None else [])
+
     def _abort_all_locked_out(self, code: int, reason: str) -> None:
         with self._cv:
-            pending = (list(self._waiting) + list(self._running)
+            pending = (list(self._waiting) + self._live()
                        + list(self._adopted_pending))
             self._waiting.clear()
-            self._running = []
+            self._running, self._prefilling = [], None
             self._adopted_pending.clear()
             if self.qos is not None:
                 for seq in list(self.qos.iter_waiting()):
@@ -1221,6 +1337,11 @@ class ServingEngine:
             "token_budget": self.config.token_budget,
             "queue_depth": self.queue_depth,
             "running": self.running_count,
+            # chunked prefill: sequences mid-prompt now, the launches that
+            # were one chunk of a longer prompt and the rows they prefilled
+            "prefilling": self.prefilling_count,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_rows": self.prefill_chunk_rows,
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
             "batch_occupancy_avg": round(occ, 3),

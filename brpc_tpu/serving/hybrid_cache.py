@@ -34,8 +34,10 @@ been handed out again.
 
 A model may have any number of full layers (``full_layers``), no
 state-space layer at all (``recurrent_layers = 0``: the slot arrays are
-empty and a slot is only a row's index) and pools narrower than float32
-(``dtype``); the byte counts follow the pools' dtype.
+empty and a slot is only a row's index), no window layer at all
+(``window_layers = 0``: ``ring_blocks`` is 0, no ring is allocated, counted
+or asked for at admission, and a table's ``.window`` is empty) and pools
+narrower than float32 (``dtype``); the byte counts follow the pools' dtype.
 
 ``state_overwritten = True`` is the declaration the rest of the serving
 plane reads: a ring row and a recurrent state are written over in place, and
@@ -116,21 +118,26 @@ class HybridStateCache:
         self.config = config
         self.kv_dim = kv_dim
         self.recurrent_state = recurrent_layers > 0
+        # blocks in a sequence's ring: none for a model with no window layer
+        # (its ``window`` pool has no layer and hands nothing out)
+        self.ring_blocks = config.ring_blocks if window_layers else 0
         self.full = PagedKVCache(
             KVCacheConfig(config.block_size, config.num_blocks,
                           config.watermark),
             full_layers, kv_dim, store=store, dtype=dtype)
         self.window = PagedKVCache(
             KVCacheConfig(config.block_size,
-                          config.max_sequences * config.ring_blocks, 1.0),
+                          max(1, config.max_sequences * self.ring_blocks),
+                          1.0),
             window_layers, kv_dim, store=self.full.store, dtype=dtype)
         self.store = self.full.store
         self._lock = threading.Lock()
         n = config.max_sequences
         self.ssm = jnp.zeros((2, recurrent_layers, n + 1, d_state, d_inner),
-                             jnp.float32)
+                             jnp.float32, device=self.store.device)
         self.conv = jnp.zeros((2, recurrent_layers, n + 1, d_conv - 1,
-                               d_inner), jnp.float32)
+                               d_inner), jnp.float32,
+                              device=self.store.device)
         self.ssm_handle, _ = self.store.adopt(self.ssm)
         self.conv_handle, _ = self.store.adopt(self.conv)
         self._free_slots = collections.deque(range(1, n + 1))
@@ -181,7 +188,7 @@ class HybridStateCache:
         applies it, AND a ring and a recurrent slot free for the sequence."""
         with self._lock:
             return (bool(self._free_slots)
-                    and self.window.free_blocks >= self.config.ring_blocks
+                    and self.window.free_blocks >= self.ring_blocks
                     and self.full.can_admit(ntokens))
 
     def note_rejected(self) -> None:
@@ -195,19 +202,20 @@ class HybridStateCache:
             if seq_id in self._slot_of:
                 raise ValueError(f"sequence {seq_id} already has a table")
             if (not self._free_slots
-                    or self.window.free_blocks < cfg.ring_blocks):
+                    or self.window.free_blocks < self.ring_blocks):
                 self.full.note_rejected()
                 raise KVCacheFull(
                     f"no ring or recurrent slot free "
                     f"({cfg.max_sequences} sequences)")
             blocks = self.full.alloc_sequence(seq_id, ntokens)  # may raise
-            ring = self.window.alloc_sequence(
-                seq_id, cfg.ring_blocks * cfg.block_size)
+            ring = (self.window.alloc_sequence(
+                seq_id, self.ring_blocks * cfg.block_size)
+                if self.ring_blocks else ())
             slot = self._free_slots.popleft()
             self._slot_of[seq_id] = slot
             self._len[seq_id] = ntokens
             self._full_owner[blocks] = seq_id
-            self._ring_owner[ring] = seq_id
+            self._ring_owner[list(ring)] = seq_id
             self._slot_owner[slot] = seq_id
             self._note_peak_locked()
             return HybridTable(blocks, ring, slot)
@@ -225,15 +233,16 @@ class HybridStateCache:
             grown = blocks[self.full.blocks_for(old):]
             if grown:
                 self._full_owner[grown] = seq_id
-            ring_n = self.config.ring_blocks
+            ring_n = self.ring_blocks
 
             def wrapped(n):
                 return max(0, self.full.blocks_for(n) - ring_n)
 
-            self.window_blocks_recycled += wrapped(new_len) - wrapped(old)
+            if ring_n:
+                self.window_blocks_recycled += wrapped(new_len) - wrapped(old)
             self._len[seq_id] = max(old, new_len)
             self._note_peak_locked()
-            return HybridTable(blocks, self.window.block_table(seq_id), slot)
+            return HybridTable(blocks, self._ring_of(seq_id), slot)
 
     def free_sequence(self, seq_id: int) -> int:
         """Return pages, ring and slot. Returns full-layer blocks freed."""
@@ -242,10 +251,11 @@ class HybridStateCache:
             if slot is None:
                 return 0
             table = HybridTable(self.full.block_table(seq_id),
-                                self.window.block_table(seq_id), slot,
+                                self._ring_of(seq_id), slot,
                                 self._len.pop(seq_id))
             freed = self.full.free_sequence(seq_id)
-            self.window.free_sequence(seq_id)
+            if self.ring_blocks:
+                self.window.free_sequence(seq_id)
             self._free_slots.append(slot)
             self._retired[seq_id] = table
             while len(self._retired) > 4 * self.config.max_sequences:
@@ -258,7 +268,12 @@ class HybridStateCache:
             if slot is None:
                 return None
             return HybridTable(self.full.block_table(seq_id),
-                               self.window.block_table(seq_id), slot)
+                               self._ring_of(seq_id), slot)
+
+    def _ring_of(self, seq_id: int):
+        """The sequence's ring, in ring order; () for a model that has no
+        window layer: it holds no ring."""
+        return self.window.block_table(seq_id) if self.ring_blocks else ()
 
     def seq_len(self, seq_id: int) -> int:
         with self._lock:
@@ -344,9 +359,10 @@ class HybridStateCache:
             snap["sequences"] = len(self._slot_of)
             snap["full"] = {"used": snap["blocks_used"],
                             "total": snap["blocks_total"]}
-            snap["window"] = {"used": self.window.used_blocks,
-                              "total": self.window.num_blocks,
-                              "ring_blocks": self.config.ring_blocks}
+            ringed = self.window if self.ring_blocks else None
+            snap["window"] = {"used": ringed.used_blocks if ringed else 0,
+                              "total": ringed.num_blocks if ringed else 0,
+                              "ring_blocks": self.ring_blocks}
             snap["slots"] = {"used": len(self._slot_of),
                              "total": self.config.max_sequences}
             snap["window_blocks_recycled"] = self.window_blocks_recycled

@@ -31,7 +31,9 @@ Weights are drawn and staged matrix by matrix and held once. Greedy argmax.
 
 Not served (refused loudly): a prompt continued from a cached prefix
 (``prefill_suffix`` with ``start > 0``: nothing records the recurrent state
-at the matched length) and so speculative verification.
+at the matched length, and the window rings keep no row behind a chunk) and
+so speculative verification. ``HybridServingModel`` carries the chunked form
+for a model without rings (``CONTINUES_PREFILL``: ``serving/jamba_model.py``).
 """
 
 from __future__ import annotations
@@ -219,6 +221,15 @@ def _ln(x, w, b, eps):
     return (x - mu) * jax.lax.rsqrt(var + eps) * w + b
 
 
+def _rms(x, w, eps):
+    """RMSNorm over the last axis, statistics in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * w
+
+
 def _mlp(cfg, p, w, x):
     import jax
 
@@ -228,13 +239,15 @@ def _mlp(cfg, p, w, x):
             @ w[p + "wd"]
 
 
-def ssm_scan(dt, u, bm, cm, a):
-    """``s_t = exp(dt_t a) * s_{t-1} + (dt_t u_t) b_t'`` from ``s = 0`` over
-    all rows, ``y_t = s_t c_t``: a ``lax.scan`` over chunks of rows, inside
-    a chunk an associative scan (log depth, not ``SCAN_CHUNK`` sequential
-    steps; a bucket that ``SCAN_CHUNK`` does not divide takes their gcd).
-    dt, u (S, di); bm, cm (S, n); a (n, di). State is (n, di): the wide axis
-    last. Float32 throughout, no matmul. Returns the last state and y."""
+def ssm_scan(dt, u, bm, cm, a, s0=None):
+    """``s_t = exp(dt_t a) * s_{t-1} + (dt_t u_t) b_t'`` over all rows from
+    ``s0`` (zero where it is left out: a prompt's first row; a later chunk
+    of the prompt gives the state its slot holds), ``y_t = s_t c_t``: a
+    ``lax.scan`` over chunks of rows, inside a chunk an associative scan
+    (log depth, not ``SCAN_CHUNK`` sequential steps; a bucket that
+    ``SCAN_CHUNK`` does not divide takes their gcd). dt, u (S, di); bm, cm
+    (S, n); a, s0 (n, di). State is (n, di): the wide axis last. Float32
+    throughout, no matmul. Returns the last state and y."""
     import jax
     import jax.numpy as jnp
 
@@ -254,25 +267,49 @@ def ssm_scan(dt, u, bm, cm, a):
         return s[-1], jnp.sum(s * c_c[:, :, None], axis=1)
 
     s_end, ys = jax.lax.scan(
-        body, jnp.zeros((n, di), jnp.float32),
+        body, jnp.zeros((n, di), jnp.float32) if s0 is None else s0,
         (dt.reshape(-1, t, di), u.reshape(-1, t, di),
          bm.reshape(-1, t, n), cm.reshape(-1, t, n)))
     return s_end, ys.reshape(s_len, di)
 
 
-def _mamba_inputs(cfg, p, w, window_u):
+def conv_windows(u_in, tail):
+    """The causal conv's input windows of a prompt's rows: ``u_in`` (S, di)
+    behind ``tail`` (d_conv - 1, di), the rows before them (zeros before a
+    prompt's first row; a later chunk gives the tail its slot holds).
+    Returns the padded rows (d_conv - 1 + S, di) and the windows (S, d_conv,
+    di), window t ending with row t."""
+    import jax.numpy as jnp
+
+    s_len, kc = u_in.shape[0], tail.shape[0] + 1
+    upad = jnp.concatenate([tail, u_in])
+    return upad, jnp.stack([upad[j:j + s_len] for j in range(kc)], axis=1)
+
+
+def _mamba_inputs(cfg, p, w, window_u, mm=None):
     """What both programs share of a Mamba layer: from the conv's input
-    windows ``window_u`` (..., d_conv, di) to (u, dt, B, C)."""
+    windows ``window_u`` (..., d_conv, di) to (u, dt, B, C). A layer that
+    has them (``dt_norm``, ``b_norm``, ``c_norm`` beside its weights) takes
+    the RMS norm of each of ``dt_r``, B and C over its own width first, in
+    float32. ``mm``: the caller's matmul where it rounds operands itself
+    (``@`` at the backend's default precision where it is left out)."""
     import jax
     import jax.numpy as jnp
 
+    mm = mm or jnp.matmul
     with jax.named_scope("conv"):
         u = jax.nn.silu(jnp.sum(window_u * w[p + "conv_w"], axis=-2)
                         + w[p + "conv_b"])
-    dbc = u @ w[p + "wx"]
+    dbc = mm(u, w[p + "wx"])
     r, n = cfg.dt_rank, cfg.d_state
-    dt = jax.nn.softplus(dbc[..., :r] @ w[p + "wdt"] + w[p + "b_dt"])
-    return u, dt, dbc[..., r:r + n], dbc[..., r + n:]
+    dt_r, bm, cm = dbc[..., :r], dbc[..., r:r + n], dbc[..., r + n:]
+    if p + "dt_norm" in w:
+        with jax.named_scope("inner_norms"):
+            dt_r, bm, cm = (_rms(x, w[p + k], cfg.eps) for x, k in
+                            ((dt_r, "dt_norm"), (bm, "b_norm"),
+                             (cm, "c_norm")))
+    dt = jax.nn.softplus(mm(dt_r, w[p + "wdt"]) + w[p + "b_dt"])
+    return u, dt, bm, cm
 
 
 def _q_heads(cfg, q):
@@ -395,9 +432,27 @@ class HybridServingModel:
     each returning the six arrays and ONE int32 array whose first entries
     are the next tokens (one for prefill, ``b_bucket`` for decode); what
     follows them, if anything, reaches ``_note_counters`` from the same
-    host sync."""
+    host sync.
+
+    A model whose prefill can go on from what its slot and pages hold
+    declares ``CONTINUES_PREFILL`` and gives, in place of ``_prefill_fn``,
+
+    - ``_chunk_fn(c_bucket, l_bucket, use_flash)`` -> jitted ``impl(w, fk,
+      fv, wk, wv, ssm, conv, tokens, table, slot, length, start)``: rows
+      ``[start, start + length)`` of a prompt, ``c_bucket`` padded rows over
+      ``l_bucket`` context rows read through ``table``
+
+    and :class:`~brpc_tpu.serving.engine.ServingEngine` then prefills a long
+    prompt a chunk a step (``prefill_suffix`` with ``start > 0``)."""
 
     FUSED_STEP = True     # decode_step: one launch, one host sync
+    # prefill_suffix(start > 0) goes on from the slot's scan state and conv
+    # tail and the rows in the pages: nothing else of the model's state
+    # looks back (no window ring, whose rows behind a chunk are overwritten)
+    CONTINUES_PREFILL = False
+    # rows an engine's chunk of a prompt is a multiple of: whole steps of
+    # the prefill scan
+    PREFILL_GRANULE = SCAN_CHUNK
 
     def _init_programs(self, config, kv: HybridStateCache) -> None:
         self.config = config
@@ -445,46 +500,100 @@ class HybridServingModel:
         kv.update_state(out[4], out[5])
         return out[6]
 
+    def _program(self, cache: dict, key, make):
+        """The jitted program of ``key``, built once."""
+        with self._lock:
+            fn = cache.get(key)
+            if fn is None:
+                fn = cache[key] = make(*key)
+        return fn
+
+    def _first_token(self, fn, writes, *args) -> int:
+        """A prefill launch and its one host sync: the token it returns
+        first, what follows it to ``_note_counters``."""
+        from brpc_tpu.tpu.device_lane import step_dispatch
+
+        with _span("model.launch"):
+            step_dispatch.note_launch(1)
+            nxt = self._launch(fn, writes, *args)
+        with _span("model.sync"):
+            host = np.asarray(nxt).reshape(-1)
+            step_dispatch.note_host_sync()
+        self._note_counters("prefill", host[1:])
+        return int(host[0])
+
     def prefill(self, tokens: np.ndarray, table) -> int:
         """Prompt prefill for ONE sequence: write its recurrent state, its
         ring and its full-layer rows, return the first token (greedy)."""
-        from brpc_tpu.tpu.device_lane import step_dispatch
-
+        if self.CONTINUES_PREFILL:
+            return self._prefill_rows(tokens, table, 0)
         s = len(tokens)
         bucket = prefill_bucket(s, self.config.window)
         with _span("model.prefill", n=s, bucket=bucket):
             with _span("model.prep"):
-                key = (bucket, self._use_flash())
-                with self._lock:
-                    fn = self._prefill_cache.get(key)
-                    if fn is None:
-                        fn = self._prefill_cache[key] = self._prefill_fn(*key)
+                fn = self._program(self._prefill_cache,
+                                   (bucket, self._use_flash()),
+                                   self._prefill_fn)
                 toks = np.zeros(bucket, dtype=np.int32)
                 toks[:s] = tokens
                 tab = np.zeros(-(-bucket // self.kv.block_size), np.int32)
                 n = min(len(tab), len(table))
                 tab[:n] = table[:n]
                 ring = np.asarray(table.window, np.int32)
-            with _span("model.launch"):
-                step_dispatch.note_launch(1)
-                nxt = self._launch(fn, [(table, 0, s)], toks, tab, ring,
-                                   np.int32(table.slot), np.int32(s))
-            with _span("model.sync"):
-                host = np.asarray(nxt).reshape(-1)
-                step_dispatch.note_host_sync()
-            self._note_counters("prefill", host[1:])
-            return int(host[0])
+            return self._first_token(fn, [(table, 0, s)], toks, tab, ring,
+                                     np.int32(table.slot), np.int32(s))
 
     def prefill_suffix(self, tokens: np.ndarray, table, start: int) -> int:
-        """Only ``start == 0`` (the whole prompt, as ``prefill``): a suffix
-        needs the recurrent state at ``start`` and the ring rows behind it,
-        which nothing records."""
+        """Rows ``[start, len(tokens))`` of the prompt ``tokens``. A model
+        that ``CONTINUES_PREFILL`` goes on from what the SAME sequence's
+        earlier chunks left in its slot and pages (the scan from the slot's
+        state, the conv from its tail, attention over rows ``[0,
+        len(tokens))`` through the table); the token it returns is the
+        prompt's first where ``tokens`` ends the prompt, and means nothing
+        before. Any other model takes only ``start == 0`` (the whole prompt,
+        as ``prefill``): a suffix needs the recurrent state at ``start`` and
+        the ring rows behind it, which nothing records."""
+        if self.CONTINUES_PREFILL:
+            return self._prefill_rows(tokens, table, start)
         if start:
             raise NotImplementedError(
                 f"{type(self).__name__}: no prefill from a cached prefix: "
                 f"the recurrent state and the ring rows at row {start} are "
                 "not recorded")
         return self.prefill(tokens, table)
+
+    def _chunk_buckets(self, n: int, end: int, start: int):
+        """The chunk program's padded (rows, context): rows to a power of
+        two from ``SCAN_CHUNK``; a prompt's first chunk is its own context,
+        a later one reads a power of two of rows from the configuration's
+        ``prefill_context_floor`` up. Few programs: one chunk size serves
+        every step of a long prompt but its last."""
+        c = max(SCAN_CHUNK, _next_pow2(n))
+        if not start:
+            return c, c
+        return c, max(self.config.prefill_context_floor, _next_pow2(end))
+
+    def _prefill_rows(self, tokens: np.ndarray, table, start: int) -> int:
+        """One launch over rows ``[start, len(tokens))`` of a prompt."""
+        end = len(tokens)
+        n = end - start
+        if n < 1 or start < 0:
+            raise ValueError(f"prefill of rows [{start}, {end})")
+        c_bucket, l_bucket = self._chunk_buckets(n, end, start)
+        with _span("model.prefill", n=n, start=start, bucket=c_bucket,
+                   context=l_bucket):
+            with _span("model.prep"):
+                fn = self._program(
+                    self._prefill_cache,
+                    (c_bucket, l_bucket, self._use_flash()), self._chunk_fn)
+                toks = np.zeros(c_bucket, dtype=np.int32)
+                toks[:n] = tokens[start:]
+                tab = np.zeros(l_bucket // self.kv.block_size, np.int32)
+                k = min(len(tab), len(table))
+                tab[:k] = table[:k]
+            return self._first_token(fn, [(table, start, end)], toks, tab,
+                                     np.int32(table.slot), np.int32(n),
+                                     np.int32(start))
 
     def decode_step(self, tokens: np.ndarray, positions: np.ndarray,
                     tables: List[Sequence[int]]) -> np.ndarray:
@@ -504,18 +613,15 @@ class HybridServingModel:
                         f"{type(self).__name__}.decode_step: one row a "
                         "sequence (a ring row and a recurrent state step "
                         "once a launch)")
-                key = (b_bucket, l_bucket)
-                with self._lock:
-                    fn = self._decode_cache.get(key)
-                    if fn is None:
-                        fn = self._decode_cache[key] = self._decode_fn(*key)
+                fn = self._program(self._decode_cache, (b_bucket, l_bucket),
+                                   self._decode_fn)
                 toks = np.zeros(b_bucket, dtype=np.int32)
                 toks[:B] = tokens
                 pos = np.zeros(b_bucket, dtype=np.int32)
                 pos[:B] = positions
                 tabs = np.zeros((b_bucket, l_bucket // kv.block_size),
                                 np.int32)
-                rings = np.zeros((b_bucket, kv.config.ring_blocks), np.int32)
+                rings = np.zeros((b_bucket, kv.ring_blocks), np.int32)
                 slots = np.zeros(b_bucket, np.int32)
                 for i, t in enumerate(tables):
                     tabs[i, :len(t)] = t
@@ -605,10 +711,8 @@ class SambaYModel(HybridServingModel):
                     uz = h @ w[p + "win"]
                     u_in, gate = uz[:, :cfg.d_inner], uz[:, cfg.d_inner:]
                     kc = cfg.d_conv
-                    upad = jnp.concatenate(
-                        [jnp.zeros((kc - 1, cfg.d_inner), x.dtype), u_in])
-                    windows = jnp.stack([upad[j:j + s_bucket]
-                                         for j in range(kc)], axis=1)
+                    upad, windows = conv_windows(
+                        u_in, jnp.zeros((kc - 1, cfg.d_inner), x.dtype))
                     u, dt, bm, cm = _mamba_inputs(cfg, p, w, windows)
                     with scope("ssm_scan"):
                         dt = jnp.where(live[:, None], dt, 0.0)  # pads: stay

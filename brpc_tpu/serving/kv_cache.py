@@ -117,8 +117,13 @@ class PagedKVCache:
             # physical block 0 is scratch (pad scatter target): +1 below
             slots = (config.num_blocks + 1) * config.block_size
             dtype = dtype or jnp.float32
-            self.k_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype)
-            self.v_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype)
+            # ON the store's device from the start (committed, as every
+            # launch returns them): a program whose first launch saw them
+            # uncommitted is lowered a second time at its next one
+            self.k_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype,
+                                    device=self.store.device)
+            self.v_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype,
+                                    device=self.store.device)
             self.k_handle, _ = self.store.adopt(self.k_pool)
             self.v_handle, _ = self.store.adopt(self.v_pool)
         # the block that has lain free longest goes out first, so a freed

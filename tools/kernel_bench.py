@@ -4,6 +4,7 @@ Run standalone (owns the chip):
 
     python tools/kernel_bench.py            # prints one line per metric
     python tools/kernel_bench.py paged_decode   # only the named benches
+    python tools/kernel_bench.py ssm_scan       # the prefill's selective scan
     python tools/kernel_bench.py ring_hops      # needs four chips
 
 Timing methodology: marginal cost between two round counts inside ONE
@@ -568,6 +569,53 @@ def bench_paged_decode(peak: dict):
     case("two short rows as served", 2, 512, [80, 300], table_pages=128)
 
 
+def bench_ssm_scan(peak: dict):
+    """The prefill's selective scan alone (XLA's: ``hybrid_model.ssm_scan``)
+    at the shape of one chunk of the `longdoc-steady` cell: 2048 rows,
+    d_inner 5120, d_state 16, float32, from a NON-ZERO state (a later chunk
+    of a prompt), chained through the state it returns. Beside it the least
+    time the chip could take: dt, u and y once (B and C are 16 wide) over
+    the HBM peak; the (rows, 16, 5120) decay and drive need never leave
+    the chip. The number a scan kernel has to beat; read by no metric."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.serving.hybrid_model import SCAN_CHUNK, ssm_scan
+
+    rows, di, n = 2048, 5120, 16
+    key = jax.random.key(0)
+
+    def draw(i, shape, scale=1.0):
+        return scale * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                         jnp.float32)
+
+    dt = jax.nn.softplus(draw(1, (rows, di)) - 4.0)      # ~2e-2
+    u, bm, cm = draw(2, (rows, di)), draw(3, (rows, n)), draw(4, (rows, n))
+    a = -jnp.broadcast_to(jnp.arange(1.0, n + 1)[:, None], (n, di))
+    s0 = draw(5, (n, di), 0.1)
+
+    @functools.partial(jax.jit, static_argnames=("k",))
+    def loop(s, k: int):
+        def body(_i, carry):
+            s, acc = carry
+            s, y = ssm_scan(dt, u, bm, cm, a, s)
+            return s, acc + y[-1]
+        return jax.lax.fori_loop(0, k, body, (s, jnp.zeros(di)))
+
+    def run(k):
+        jax.device_get(loop(s0, k)[1][:1])
+
+    secs = _marginal(run, 4, 24)
+    least = 3 * rows * di * 4 / peak["hbm_bytes_per_s"]
+    print(f"# kernel ssm_scan (XLA) {rows} rows x d_inner {di} x d_state {n}"
+          f" float32 from a non-zero state, {SCAN_CHUNK}-row chunks: "
+          f"{secs * 1e3:7.3f} ms a layer ({rows / secs / 1e6:.2f} M rows/s); "
+          f"dt, u and y once over the HBM peak {least * 1e3:.3f} ms "
+          f"({least / secs * 100:.1f}% of that roofline)", flush=True)
+
+
 def bench_train_step_mfu(peak: dict):
     """Single-chip train step of the flagship LM, reported BOTH ways:
     kernels ON (Pallas flash fwd+bwd, Pallas norm, fused xent — the
@@ -668,6 +716,7 @@ def main():
                "ring_hops": bench_ring_hops,
                "rmsnorm": bench_rmsnorm,
                "paged_decode": bench_paged_decode,
+               "ssm_scan": bench_ssm_scan,
                "train_step_mfu": bench_train_step_mfu}
     for name in sys.argv[1:] or list(benches):   # all, or the named ones
         benches[name](peak)
