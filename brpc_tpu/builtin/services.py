@@ -763,6 +763,12 @@ def serving_service(server, http: HttpMessage):
                        if "kernel_layers" in c else ""))
             out.append(f"  moe: held={moe['experts_held']} "
                        + " | ".join(parts))
+        # the selective scan: prefill launches that ran the kernel, and the
+        # rows (padded rows x Mamba layers) they handed it
+        scan = s.get("scan")
+        if scan:
+            out.append(f"  scan: launches={scan['launches']} "
+                       f"rows={scan['rows']}")
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

@@ -1318,6 +1318,14 @@ class ServingEngine:
         return {k: dict(v) if isinstance(v, dict) else v
                 for k, v in counters.items()}
 
+    def _scan_snapshot(self) -> Optional[Dict[str, int]]:
+        """The model's selective-scan counters (serving/hybrid_model.py),
+        for a model with Mamba layers: the prefill ``launches`` that ran
+        the ``ssm_scan`` kernel and the ``rows`` handed to it (a launch's
+        padded rows times its Mamba layers)."""
+        counters = getattr(self.model, "scan_counters", None)
+        return dict(counters) if counters is not None else None
+
     def snapshot(self) -> Dict[str, object]:
         kv = self.kv.snapshot()
         occ = (self._occupancy_sum / self.steps) if self.steps else 0.0
@@ -1375,6 +1383,7 @@ class ServingEngine:
                        if self.prefix is not None else None),
             "decode": self._decode_snapshot(),
             "moe": self._moe_snapshot(),
+            "scan": self._scan_snapshot(),
             "spec": (dict(self.spec_stats.snapshot(),
                           k_max=self.config.spec_k)
                      if self.spec_stats is not None else None),

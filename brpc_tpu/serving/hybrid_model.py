@@ -13,8 +13,8 @@ gated SiLU MLP; LayerNorm with bias; tied head; no positional encoding.
 Two programs over different layers, both against a
 :class:`~brpc_tpu.serving.hybrid_cache.HybridStateCache`:
 
-- ``prefill``: the self-decoder over all rows of the prompt -- a chunked
-  associative scan per Mamba layer (``ssm_scan``), window attention (the flash
+- ``prefill``: the self-decoder over all rows of the prompt -- one scan
+  kernel per Mamba layer (``ssm_scan``), window attention (the flash
   kernel while the prompt fits the window, a banded einsum past it), the full
   layer through the flash kernel -- writing the recurrent state at the prompt's
   end, the last ring of window rows and the full layer's rows; then the
@@ -50,8 +50,8 @@ from brpc_tpu.serving.hybrid_cache import HybridStateCache
 from brpc_tpu.serving.model import _next_pow2
 
 NEG = -1e30
-# rows a step of the prefill scan: (rows, d_state, d_inner) float32 a tensor,
-# 42 MB at the published widths, where the whole prompt's would be 1 GB
+# rows a chunk of a prompt is a multiple of (``PREFILL_GRANULE``), and the
+# smallest padded chunk: whole row blocks of the scan kernel
 SCAN_CHUNK = 128
 
 
@@ -242,35 +242,15 @@ def _mlp(cfg, p, w, x):
 def ssm_scan(dt, u, bm, cm, a, s0=None):
     """``s_t = exp(dt_t a) * s_{t-1} + (dt_t u_t) b_t'`` over all rows from
     ``s0`` (zero where it is left out: a prompt's first row; a later chunk
-    of the prompt gives the state its slot holds), ``y_t = s_t c_t``: a
-    ``lax.scan`` over chunks of rows, inside a chunk an associative scan
-    (log depth, not ``SCAN_CHUNK`` sequential steps; a bucket that
-    ``SCAN_CHUNK`` does not divide takes their gcd). dt, u (S, di); bm, cm
-    (S, n); a, s0 (n, di). State is (n, di): the wide axis last. Float32
-    throughout, no matmul. Returns the last state and y."""
-    import jax
-    import jax.numpy as jnp
+    of the prompt gives the state its slot holds), ``y_t = s_t c_t``: ONE
+    Pallas kernel a layer that keeps the state in VMEM and walks the rows
+    (``pallas_ops.ssm_scan``). dt, u (S, di); bm, cm (S, n); a, s0 (n, di).
+    State is (n, di): the wide axis last. Float32 throughout, no matmul; a
+    row with ``dt == 0`` leaves the state as it was, which is how callers
+    mask pads. Returns the last state and y."""
+    from brpc_tpu.tpu import pallas_ops
 
-    s_len, di = dt.shape
-    n = a.shape[0]
-    t = math.gcd(SCAN_CHUNK, s_len)
-
-    def combine(x, y):
-        return x[0] * y[0], y[0] * x[1] + y[1]
-
-    def body(s0, inp):
-        dt_c, u_c, b_c, c_c = inp
-        decay = jnp.exp(dt_c[:, None, :] * a[None])           # (t, n, di)
-        drive = (dt_c * u_c)[:, None, :] * b_c[:, :, None]
-        dec, drv = jax.lax.associative_scan(combine, (decay, drive), axis=0)
-        s = dec * s0[None] + drv
-        return s[-1], jnp.sum(s * c_c[:, :, None], axis=1)
-
-    s_end, ys = jax.lax.scan(
-        body, jnp.zeros((n, di), jnp.float32) if s0 is None else s0,
-        (dt.reshape(-1, t, di), u.reshape(-1, t, di),
-         bm.reshape(-1, t, n), cm.reshape(-1, t, n)))
-    return s_end, ys.reshape(s_len, di)
+    return pallas_ops.ssm_scan(dt, u, bm, cm, a, s0)
 
 
 def conv_windows(u_in, tail):
@@ -450,8 +430,7 @@ class HybridServingModel:
     # tail and the rows in the pages: nothing else of the model's state
     # looks back (no window ring, whose rows behind a chunk are overwritten)
     CONTINUES_PREFILL = False
-    # rows an engine's chunk of a prompt is a multiple of: whole steps of
-    # the prefill scan
+    # rows an engine's chunk of a prompt is a multiple of
     PREFILL_GRANULE = SCAN_CHUNK
 
     def _init_programs(self, config, kv: HybridStateCache) -> None:
@@ -462,6 +441,11 @@ class HybridServingModel:
         self._prefill_cache = {}
         self._decode_cache = {}
         self._params, self._handles, self.param_nbytes = {}, [], 0
+        # the prefill launches that ran the scan kernel and the rows handed
+        # to it (a launch's padded rows x its Mamba layers); read by
+        # ``ServingEngine.snapshot()["scan"]``; None without Mamba layers
+        self.scan_counters = ({"launches": 0, "rows": 0}
+                              if config.count("mamba") else None)
 
     def _stage(self, name: str, arr) -> None:
         """Hold one device array as a weight, registered with the store."""
@@ -508,14 +492,19 @@ class HybridServingModel:
                 fn = cache[key] = make(*key)
         return fn
 
-    def _first_token(self, fn, writes, *args) -> int:
-        """A prefill launch and its one host sync: the token it returns
-        first, what follows it to ``_note_counters``."""
+    def _first_token(self, fn, writes, toks, *args) -> int:
+        """A prefill launch over the padded token rows ``toks`` and its one
+        host sync: the token it returns first, what follows it to
+        ``_note_counters``."""
         from brpc_tpu.tpu.device_lane import step_dispatch
 
+        if self.scan_counters is not None:
+            self.scan_counters["launches"] += 1
+            self.scan_counters["rows"] += \
+                len(toks) * self.config.count("mamba")
         with _span("model.launch"):
             step_dispatch.note_launch(1)
-            nxt = self._launch(fn, writes, *args)
+            nxt = self._launch(fn, writes, toks, *args)
         with _span("model.sync"):
             host = np.asarray(nxt).reshape(-1)
             step_dispatch.note_host_sync()

@@ -9,6 +9,7 @@ paths share one numerics test against the jnp reference.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -1204,6 +1205,133 @@ def moe_grouped_matmul(x, w, tile_expert, tiles_used, block_rows: int,
         name="moe_grouped_matmul",
     )(tile_expert.astype(jnp.int32),
       jnp.asarray(tiles_used, jnp.int32).reshape(1), x, w)
+
+
+# ---------------------------------------------------------------------------
+# Selective scan (Mamba-1) -- the recurrence of a prefill launch's rows,
+# ``s_t = exp(dt_t a) * s_{t-1} + (dt_t u_t) b_t'``, ``y_t = s_t c_t``, as
+# one kernel a layer: the (d_state, channels) state stays in VMEM and a loop
+# walks the rows, so HBM sees dt and u once on the way in and y once on the
+# way out; the (rows, d_state, d_inner) decay and drive of the XLA form
+# (``ssm_scan_reference``) never exist. Layout: the CHANNELS fill whole
+# (8, 128) tiles -- dt, u and y are handed over as (rows, d_inner / 128, 128),
+# so a row's channels are whole tiles and so is each of the ``d_state`` state
+# rows -- and ``b_t[k]``, ``c_t[k]`` are scalars read from SMEM: a row is
+# ``d_state`` multiply-adds over full tiles with no broadcast and no
+# reduction across a tile. The grid is (channel blocks, row blocks): the
+# channel axis is independent, the row axis carries the state in scratch from
+# one row block to the next. Float32 throughout; a row with ``dt == 0``
+# leaves the state as it was (decay 1, drive 0).
+# ---------------------------------------------------------------------------
+_SCAN_BLOCK_BYTES = 2 << 20    # one of dt, u, y a grid step (each held twice)
+
+
+def ssm_scan_reference(dt, u, bm, cm, a, s0=None):
+    """The same recurrence in plain XLA: a ``lax.scan`` over chunks of 128
+    rows, inside a chunk an associative scan over (rows, d_state, d_inner)
+    decay and drive tensors. What the kernel is compared with; no served
+    program calls it. Shapes and results as :func:`ssm_scan`."""
+    s_len, di = dt.shape
+    n = a.shape[0]
+    t = math.gcd(128, s_len)
+
+    def combine(x, y):
+        return x[0] * y[0], y[0] * x[1] + y[1]
+
+    def body(s0, inp):
+        dt_c, u_c, b_c, c_c = inp
+        decay = jnp.exp(dt_c[:, None, :] * a[None])           # (t, n, di)
+        drive = (dt_c * u_c)[:, None, :] * b_c[:, :, None]
+        dec, drv = jax.lax.associative_scan(combine, (decay, drive), axis=0)
+        s = dec * s0[None] + drv
+        return s[-1], jnp.sum(s * c_c[:, :, None], axis=1)
+
+    s_end, ys = jax.lax.scan(
+        body, jnp.zeros((n, di), jnp.float32) if s0 is None else s0,
+        (dt.reshape(-1, t, di), u.reshape(-1, t, di),
+         bm.reshape(-1, t, n), cm.reshape(-1, t, n)))
+    return s_end, ys.reshape(s_len, di)
+
+
+def _ssm_scan_kernel(bc_ref, dt_ref, u_ref, a_ref, s0_ref, y_ref, send_ref,
+                     s_scr, *, rows: int, n: int):
+    import jax.experimental.pallas as pl
+
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        s_scr[:] = s0_ref[:]
+
+    def row(t, carry):
+        dt = dt_ref[t]                       # (channel tiles, 128)
+        du = dt * u_ref[t]
+        y = jnp.zeros_like(dt)
+        for k in range(n):                   # b_t[k], c_t[k]: SMEM scalars
+            s = (jnp.exp(dt * a_ref[k]) * s_scr[k]
+                 + bc_ref[t * 2 * n + k] * du)
+            s_scr[k] = s
+            y = y + bc_ref[t * 2 * n + n + k] * s
+        y_ref[t] = y
+        return carry
+
+    jax.lax.fori_loop(0, rows, row, 0)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _finish():
+        send_ref[:] = s_scr[:]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssm_scan(dt, u, bm, cm, a, s0=None, interpret: bool = None):
+    """``s_t = exp(dt_t a) * s_{t-1} + (dt_t u_t) b_t'`` over all rows from
+    ``s0`` (zeros where it is left out), ``y_t = s_t c_t``. dt, u (S, di);
+    bm, cm (S, n); a, s0 (n, di); float32. Returns the last state (n, di)
+    and y (S, di). The grid walks blocks of rows, each holding the WHOLE
+    channel axis (the fastest form on the chip at d_inner 5120, PERF.md
+    section 6, PR 36), as 128 channels a lane row where ``di`` divides so
+    and as one lane row otherwise; a block has as many rows as keep one
+    operand's block under ``_SCAN_BLOCK_BYTES``, in units of the rows whose
+    B and C fill whole SMEM tiles (32 at ``n`` 16), S padded to such a unit
+    with ``dt == 0`` rows."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    S, di = dt.shape
+    n = a.shape[0]
+    lanes = 128 if di % 128 == 0 else di
+    tiles = di // lanes
+    unit = 1024 // math.gcd(1024, 2 * n)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    dt, u, bm, cm = (jnp.pad(f32(x), ((0, -S % unit), (0, 0)))
+                     for x in (dt, u, bm, cm))
+    sp = dt.shape[0]
+    rows = unit * _fit_block(
+        sp // unit, max(1, _SCAN_BLOCK_BYTES // (di * 4 * unit)))
+    if s0 is None:
+        s0 = jnp.zeros((n, di), jnp.float32)
+    by_row = pl.BlockSpec((rows, tiles, lanes), lambda i: (i, 0, 0))
+    by_state = pl.BlockSpec((n, tiles, lanes), lambda i: (0, 0, 0))
+    y, s_end = pl.pallas_call(
+        functools.partial(_ssm_scan_kernel, rows=rows, n=n),
+        grid=(sp // rows,),
+        in_specs=[pl.BlockSpec((rows * 2 * n,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  by_row, by_row, by_state, by_state],
+        out_specs=[by_row, by_state],
+        out_shape=[jax.ShapeDtypeStruct((sp, tiles, lanes), jnp.float32),
+                   jax.ShapeDtypeStruct((n, tiles, lanes), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, tiles, lanes), jnp.float32)],
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="ssm_scan",
+    )(jnp.concatenate([bm, cm], axis=1).reshape(-1),
+      dt.reshape(sp, tiles, lanes), u.reshape(sp, tiles, lanes),
+      f32(a).reshape(n, tiles, lanes), f32(s0).reshape(n, tiles, lanes))
+    return s_end.reshape(n, di), y.reshape(sp, di)[:S]
 
 
 # ---------------------------------------------------------------------------

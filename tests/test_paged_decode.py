@@ -419,7 +419,7 @@ def test_a_model_without_the_counters_has_no_decode_line():
 _COMPILE_SHAPES = [(8, 64), (16, 64), (16, 128), (2, 2)]
 
 _COMPILE_SCRIPT = r"""
-import json, sys
+import functools, json, sys
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
 from jax.sharding import SingleDeviceSharding
@@ -476,12 +476,32 @@ for rows, tile, k, n in json.loads(sys.argv[2]):
             "temp": c.memory_analysis().temp_size_in_bytes}
     except Exception as e:
         out[f"gmm{rows}x{tile}x{k}x{n}"] = {"error": str(e)[:500]}
+
+
+# the selective scan of a prefill launch (serving/hybrid_model.py) at the
+# hybrid cells' widths: (rows, d_inner, d_state)
+for rows, di, n in json.loads(sys.argv[3]):
+    try:
+        c = jax.jit(functools.partial(
+            pallas_ops.ssm_scan, interpret=False)).lower(
+            S((rows, di)), S((rows, di)), S((rows, n)), S((rows, n)),
+            S((n, di)), S((n, di))).compile()
+        out[f"scan{rows}x{di}x{n}"] = {
+            "custom_call": "tpu_custom_call" in c.as_text(),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+    except Exception as e:
+        out[f"scan{rows}x{di}x{n}"] = {"error": str(e)[:500]}
 print(json.dumps(out))
 """
 # decode gate-and-up and down at 32 rows (8 x 32 pairs + 16 x 15 pads),
 # prefill's gate-and-up over a chunk of 1024 rows
 _GMM_SHAPES = [(496, 16, 4096, 8192), (496, 16, 4096, 4096),
                (10240, 128, 4096, 8192)]
+# a chunk of `longdoc-steady` and its smallest last chunk; of `reason-steady`'s
+# prefill buckets the smallest, the window-sized, one whose rows do not
+# divide by a power of two past 512 and the longest
+_SCAN_SHAPES = [(2048, 5120, 16), (128, 5120, 16), (16, 5120, 16),
+                (512, 5120, 16), (2560, 5120, 16), (3072, 5120, 16)]
 
 
 @pytest.fixture(scope="module")
@@ -500,7 +520,8 @@ def compiled_for_v5e():
     env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_SCRIPT,
-         json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES)],
+         json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES),
+         json.dumps(_SCAN_SHAPES)],
         env=env, capture_output=True, text=True, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert proc.returncode == 0 and lines, proc.stderr[-2000:]
@@ -534,3 +555,16 @@ def test_grouped_matmul_compiles_for_the_v5e_at_the_cells_shapes(
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < 64 << 20
+
+
+@pytest.mark.parametrize("rows,di,n", _SCAN_SHAPES)
+def test_ssm_scan_compiles_for_the_v5e_at_the_cells_shapes(
+        compiled_for_v5e, rows, di, n):
+    """Mosaic accepts ``ssm_scan`` at the hybrid cells' widths (d_inner
+    5120, d_state 16, float32) for a whole chunk, the smallest last chunk
+    and SambaY's prefill buckets; what XLA adds around it is the layout
+    change of dt, u and y, never a (rows, d_state, d_inner) tensor."""
+    got = compiled_for_v5e[f"scan{rows}x{di}x{n}"]
+    assert "error" not in got, got
+    assert got["custom_call"]
+    assert got["temp"] < 4 * rows * di * 4

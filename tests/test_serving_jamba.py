@@ -179,8 +179,14 @@ def test_flash_carry_path_agrees_with_the_blocked_one(world):
     model.prefill_suffix(p[:128], t, 0)
     first = model.prefill_suffix(p, t, 128)
     assert first == world["outs"][2][0]
+    # the FIRST Mamba layer has no stored K/V row before it: the scan
+    # kernel alone, held to STATE_TOL
+    assert _rel(kv.ssm[1, 0, t.slot],
+                world["refs"][2][1]["ssm0"][0]) <= STATE_TOL
+    # the LAST lies behind both attention layers: DEEP_TOL, as in
+    # test_recurrent_state_equals_the_references (readings: PERF.md §6)
     assert _rel(kv.ssm[1, -1, t.slot],
-                world["refs"][2][1]["ssmL"][0]) <= STATE_TOL
+                world["refs"][2][1]["ssmL"][0]) <= DEEP_TOL
 
 
 # -------------------------------------------- chunked against whole prefill
@@ -496,6 +502,41 @@ def test_a_long_prompt_goes_a_chunk_a_step_beside_the_decode_rows(
     assert snap["prefill_chunks"] == 6 and snap["prefill_chunk_rows"] == 700
     assert snap["prefilling"] == 0
     assert page_line and "6" in page_line and "700" in page_line
+
+
+def test_snapshot_counts_the_rows_and_launches_handed_to_the_scan(
+        long_prompt, engine):
+    """``snapshot()["scan"]``: every prefill launch of a model with Mamba
+    layers runs the scan kernel once a layer over its padded rows. The
+    700-row prompt at 128 rows a step is 6 launches of 128 padded rows
+    (the last holds 60) over the 6 Mamba layers; `/serving` prints it."""
+    model, kv, eng = engine(132)
+    before = dict(eng.snapshot()["scan"])
+    got = {}
+    ev, _ = _submit(eng, long_prompt, 2, got, "long")
+    assert ev.wait(180)
+    snap = eng.snapshot()
+    from brpc_tpu.builtin.services import serving_service
+    from brpc_tpu.policy.http_protocol import HttpMessage
+    lines = [l for l in serving_service(None, HttpMessage())[2].splitlines()
+             if l.strip().startswith("scan:")]
+    eng.stop()
+    assert snap["prefill_chunks"] == 6
+    assert snap["scan"]["launches"] - before["launches"] == 6
+    assert snap["scan"]["rows"] - before["rows"] == 6 * 128 * 6
+    assert model.config.count("mamba") == 6
+    assert lines and f"launches={snap['scan']['launches']}" in lines[0]
+
+
+def test_a_model_without_mamba_layers_has_no_scan_part():
+    from brpc_tpu.serving.engine import ServingEngine as E
+
+    class NoScan:
+        pass
+
+    eng = E.__new__(E)
+    eng.model = NoScan()
+    assert eng._scan_snapshot() is None
 
 
 def test_cancel_mid_prompt_frees_slot_and_pages(world, long_prompt, engine):

@@ -150,6 +150,39 @@ class TestKernelsOnChip:
                                    np.asarray(want, dtype=np.float32),
                                    rtol=0.05, atol=0.05)
 
+    @pytest.mark.parametrize("rows,from_state", [(2048, True), (128, True),
+                                                 (512, False), (3072, False)])
+    def test_ssm_scan_on_chip(self, tpu_device, rows, from_state):
+        # the hybrid cells' shapes: a chunk of `longdoc-steady` and its
+        # smallest last chunk from a slot's state, `reason-steady`'s
+        # window-sized and longest prefill buckets from zero; the last 100
+        # rows pads (dt == 0), as the callers mask them
+        from brpc_tpu.tpu.pallas_ops import ssm_scan, ssm_scan_reference
+
+        rng = np.random.default_rng(rows)
+        di, n = 5120, 16
+        f = np.float32
+        dt = np.log1p(np.exp(rng.normal(size=(rows, di)) - 4.0)).astype(f)
+        dt[rows - 100:] = 0.0
+        u = rng.normal(size=(rows, di)).astype(f)
+        bm = rng.normal(size=(rows, n)).astype(f)
+        cm = rng.normal(size=(rows, n)).astype(f)
+        a = -np.broadcast_to(np.arange(1.0, n + 1)[:, None], (n, di)).astype(f)
+        s0 = (0.1 * rng.normal(size=(n, di))).astype(f) if from_state \
+            else None
+        s_end, y = ssm_scan(dt, u, bm, cm, a, s0, interpret=False)
+        want_s, want_y = ssm_scan_reference(
+            *(jnp.asarray(x) for x in (dt, u, bm, cm, a)),
+            None if s0 is None else jnp.asarray(s0))
+        scale = float(jnp.max(jnp.abs(want_y)))
+        assert float(jnp.max(jnp.abs(y - want_y))) <= 1e-4 * scale
+        assert float(jnp.max(jnp.abs(s_end - want_s))) <= 1e-4 * max(
+            1.0, float(jnp.max(jnp.abs(want_s))))
+        live, _ = ssm_scan(dt[:rows - 100], u[:rows - 100],
+                           bm[:rows - 100], cm[:rows - 100], a, s0,
+                           interpret=False)
+        np.testing.assert_array_equal(np.asarray(s_end), np.asarray(live))
+
 
 class TestServingAndRingOnChip:
     """The two paths no hardware test had, and the chip refused (PR 21)."""

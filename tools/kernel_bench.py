@@ -570,50 +570,72 @@ def bench_paged_decode(peak: dict):
 
 
 def bench_ssm_scan(peak: dict):
-    """The prefill's selective scan alone (XLA's: ``hybrid_model.ssm_scan``)
-    at the shape of one chunk of the `longdoc-steady` cell: 2048 rows,
-    d_inner 5120, d_state 16, float32, from a NON-ZERO state (a later chunk
-    of a prompt), chained through the state it returns. Beside it the least
-    time the chip could take: dt, u and y once (B and C are 16 wide) over
-    the HBM peak; the (rows, 16, 5120) decay and drive need never leave
-    the chip. The number a scan kernel has to beat; read by no metric."""
+    """The prefill's selective scan alone, the kernel
+    (``pallas_ops.ssm_scan``, what ``hybrid_model.ssm_scan`` hands off to)
+    and the XLA form beside it (``ssm_scan_reference``), d_inner 5120,
+    d_state 16, float32, chained through the state each returns: at one
+    chunk of the `longdoc-steady` cell (2048 rows from a NON-ZERO state, a
+    later chunk of a prompt) and at `reason-steady`'s window-sized and
+    longest prefill buckets (512 and 3072 rows). Each against the least
+    time the chip could take for dt, u and y once over the HBM peak (B and
+    C are 16 wide; the (rows, 16, 5120) decay and drive need never leave
+    the chip), and as the rate of the block's own count of a row's
+    elementwise operations (``blocks/jamba/work.py:scan_row_flops``: 7 a
+    (channel, state) element and the conv's 8 a channel). Read by no
+    metric."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from brpc_tpu.serving.hybrid_model import SCAN_CHUNK, ssm_scan
+    from brpc_tpu.tpu.pallas_ops import ssm_scan, ssm_scan_reference
 
-    rows, di, n = 2048, 5120, 16
+    # the block's own count; appended, so it shadows no module of another
+    # bench in this process
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from blocks.jamba.work import scan_row_flops
+
+    di, n = 5120, 16
     key = jax.random.key(0)
 
     def draw(i, shape, scale=1.0):
         return scale * jax.random.normal(jax.random.fold_in(key, i), shape,
                                          jnp.float32)
 
-    dt = jax.nn.softplus(draw(1, (rows, di)) - 4.0)      # ~2e-2
-    u, bm, cm = draw(2, (rows, di)), draw(3, (rows, n)), draw(4, (rows, n))
     a = -jnp.broadcast_to(jnp.arange(1.0, n + 1)[:, None], (n, di))
     s0 = draw(5, (n, di), 0.1)
+    for rows in (2048, 512, 3072):
+        dt = jax.nn.softplus(draw(1, (rows, di)) - 4.0)      # ~2e-2
+        u = draw(2, (rows, di))
+        bm, cm = draw(3, (rows, n)), draw(4, (rows, n))
+        least = 3 * rows * di * 4 / peak["hbm_bytes_per_s"]
+        flops = rows * scan_row_flops({"di": di, "kc": 4, "n": n})
+        for name, scan, lo, hi in (("kernel", ssm_scan, 8, 48),
+                                   ("XLA reference", ssm_scan_reference, 4,
+                                    16)):
+            @functools.partial(jax.jit, static_argnames=("k",))
+            def loop(s, k: int, scan=scan):
+                def body(_i, carry):
+                    s, acc = carry
+                    s, y = scan(dt, u, bm, cm, a, s)
+                    return s, acc + y[-1]
+                return jax.lax.fori_loop(0, k, body, (s, jnp.zeros(di)))
 
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def loop(s, k: int):
-        def body(_i, carry):
-            s, acc = carry
-            s, y = ssm_scan(dt, u, bm, cm, a, s)
-            return s, acc + y[-1]
-        return jax.lax.fori_loop(0, k, body, (s, jnp.zeros(di)))
+            def run(k):
+                jax.device_get(loop(s0, k)[1][:1])
 
-    def run(k):
-        jax.device_get(loop(s0, k)[1][:1])
-
-    secs = _marginal(run, 4, 24)
-    least = 3 * rows * di * 4 / peak["hbm_bytes_per_s"]
-    print(f"# kernel ssm_scan (XLA) {rows} rows x d_inner {di} x d_state {n}"
-          f" float32 from a non-zero state, {SCAN_CHUNK}-row chunks: "
-          f"{secs * 1e3:7.3f} ms a layer ({rows / secs / 1e6:.2f} M rows/s); "
-          f"dt, u and y once over the HBM peak {least * 1e3:.3f} ms "
-          f"({least / secs * 100:.1f}% of that roofline)", flush=True)
+            secs = _marginal(run, lo, hi)
+            print(f"# kernel ssm_scan ({name}) {rows} rows x d_inner {di} x "
+                  f"d_state {n} float32 from a non-zero state: "
+                  f"{secs * 1e3:7.3f} ms a layer "
+                  f"({rows / secs / 1e6:.2f} M rows/s); dt, u and y once "
+                  f"over the HBM peak {least * 1e3:.3f} ms "
+                  f"({least / secs * 100:.1f}% of that roofline); "
+                  f"scan_row_flops {flops / 1e9:.2f} GFLOP = "
+                  f"{flops / secs / 1e9:.0f} GFLOP/s", flush=True)
 
 
 def bench_train_step_mfu(peak: dict):
