@@ -698,6 +698,20 @@ def serving_service(server, http: HttpMessage):
             out.append("  loop: " + " ".join(
                 f"{name}={share:.1%}" for name, share in sorted(
                     s["loop_share"].items(), key=lambda kv: -kv[1])))
+        # the host side on the CPU clock since start: CPU seconds by
+        # thread role (lane.* the native lane's threads, runtime what the
+        # process holds beyond the listed ones), the mean wait of the
+        # lane's events for the poller, the collector's pauses
+        host = s["host"]
+        out.append(
+            "  host: cpu_s " + " ".join(
+                f"{role}={cpu / 1e6:.2f}" for role, (_n, cpu) in sorted(
+                    host["threads"].items(), key=lambda kv: -kv[1][1]))
+            + "; lane_wait_us " + " ".join(
+                f"{kind}={wait / max(1, n):.0f} (n={n}, max={worst:.0f})"
+                for kind, (n, wait, worst) in host["lane_wait"].items())
+            + "; gc={} pause_ms={:.1f} max_ms={:.1f}".format(
+                host["gc"][0], host["gc"][1] / 1e3, host["gc"][2] / 1e3))
         out.append(f"  kv: {kv['blocks_used']}/{kv['blocks_total']} blocks "
                    f"used ({kv['used_ratio']:.0%}), "
                    f"watermark={kv['watermark']:.0%}, "

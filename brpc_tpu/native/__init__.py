@@ -108,6 +108,7 @@ class DpEventStruct(ctypes.Structure):
         ("meta_len", ctypes.c_uint64),
         ("body", ctypes.c_void_p),
         ("body_len", ctypes.c_uint64),
+        ("t_ns", ctypes.c_int64),
     ]
 
 
@@ -186,6 +187,10 @@ def load_dataplane() -> Optional[ctypes.CDLL]:
         lib.dp_conn_stats.restype = ctypes.c_int
         lib.dp_conn_stats.argtypes = [ctypes.c_void_p, ctypes.c_uint64] + \
             [ctypes.POINTER(ctypes.c_uint64)] * 4
+        lib.dp_thread_stats.restype = ctypes.c_int
+        lib.dp_thread_stats.argtypes = [ctypes.c_void_p,
+                                        ctypes.POINTER(ctypes.c_int64),
+                                        ctypes.c_int]
         lib.dp_bench_echo.restype = ctypes.c_int
         lib.dp_bench_echo.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,

@@ -34,6 +34,7 @@
 #include <netinet/in.h>
 #include <netinet/tcp.h>
 #include <poll.h>
+#include <pthread.h>
 #include <stdio.h>
 #include <time.h>
 #include <stdint.h>
@@ -87,7 +88,8 @@ constexpr int kTpuMaxSegs = 32;
 
 // event kinds (Python mirror in rpc/native_transport.py)
 enum {
-  EV_FRAME = 1,     // tag: 0 TRPC / 1 TSTR; meta+body buffers
+  EV_FRAME = 1,     // tag: 0 TRPC / 1 TSTR; meta+body buffers;
+                    // aux: 1 request / 2 response / 0 not told (TSTR)
   EV_FAILED = 2,    // tag: error class; meta: reason text
   EV_ACCEPTED = 3,  // aux: listener id; meta: "host:port" of peer
   EV_DETACHED = 4,  // aux: fd (now owned by consumer); meta: buffered bytes
@@ -148,6 +150,7 @@ struct DpEvent {
   uint64_t meta_len;
   void* body;
   uint64_t body_len;
+  int64_t t_ns;  // CLOCK_MONOTONIC when the event was queued (push_event*)
 };
 
 // ------------------------------------------------------------ pb wire codec
@@ -966,6 +969,9 @@ struct Runtime {
   };
   std::mutex smu_senders;
   std::vector<SenderSlot> senders;
+  // CPU ns of the sender workers that have ended (each adds its own clock
+  // on its way out: dp_thread_stats keeps counting them)
+  std::atomic<int64_t> senders_ended_cpu_ns{0};
 
   // listeners muted after EMFILE/ENFILE (fd exhaustion): disarmed from
   // epoll so level-triggered readiness cannot busy-spin loop 0, re-armed
@@ -990,6 +996,28 @@ int64_t mono_ns() {
   return int64_t(ts.tv_sec) * 1000000000 + ts.tv_nsec;
 }
 
+// A thread's CPU clock, user + system; -1 once the thread has ended.
+int64_t thread_cpu_ns(pthread_t th) {
+  clockid_t clk;
+  timespec ts;
+  if (pthread_getcpuclockid(th, &clk) != 0 || clock_gettime(clk, &ts) != 0) {
+    return -1;
+  }
+  return int64_t(ts.tv_sec) * 1000000000 + ts.tv_nsec;
+}
+
+// What a sender worker does on every way out: leave its CPU time with the
+// runtime, THEN say it is done (a reader never sees it done and uncounted).
+struct SenderExit {
+  Runtime* rt;
+  std::shared_ptr<std::atomic<bool>> done;
+  ~SenderExit() {
+    int64_t cpu = thread_cpu_ns(pthread_self());
+    if (cpu > 0) rt->senders_ended_cpu_ns.fetch_add(cpu);
+    done->store(true);
+  }
+};
+
 void register_sender(Runtime* rt, std::thread thr,
                      std::shared_ptr<std::atomic<bool>> done) {
   std::lock_guard<std::mutex> lk(rt->smu_senders);
@@ -1006,6 +1034,7 @@ void register_sender(Runtime* rt, std::thread thr,
 
 // ------------------------------------------------------------------ helpers
 void push_event(Runtime* rt, DpEvent ev) {
+  ev.t_ns = mono_ns();  // the consumer reads how long the event stood here
   std::unique_lock<std::mutex> lk(rt->emu);
   rt->event_bytes += ev.meta_len + ev.body_len + sizeof(DpEvent);
   // soft cap: beyond it the loop threads stall here — natural backpressure
@@ -1031,7 +1060,11 @@ void push_event(Runtime* rt, DpEvent ev) {
 void push_event_batch(Runtime* rt, std::vector<DpEvent>& evs) {
   if (evs.empty()) return;
   uint64_t add = 0;
-  for (auto& ev : evs) add += ev.meta_len + ev.body_len + sizeof(DpEvent);
+  int64_t now = mono_ns();  // one stamp a parse pass
+  for (auto& ev : evs) {
+    ev.t_ns = now;
+    add += ev.meta_len + ev.body_len + sizeof(DpEvent);
+  }
   std::unique_lock<std::mutex> lk(rt->emu);
   rt->event_bytes += add;
   while (rt->running.load() && rt->event_bytes > kEventQueueMaxBytes &&
@@ -1543,6 +1576,7 @@ void tpu_enqueue_resp(Runtime* rt, const std::shared_ptr<Conn>& c,
       ts->sender_running = true;
       auto done = std::make_shared<std::atomic<bool>>(false);
       std::thread thr([rt, c, ts, done] {
+        SenderExit on_exit{rt, done};
         for (;;) {
           TpuState::Resp item;
           {
@@ -1552,7 +1586,6 @@ void tpu_enqueue_resp(Runtime* rt, const std::shared_ptr<Conn>& c,
                      c->failed.load();
             });
             if (ts->respq.empty()) {  // closed/failed: drain done
-              done->store(true);
               return;
             }
             item = std::move(ts->respq.front());
@@ -1597,7 +1630,6 @@ void tpu_enqueue_resp(Runtime* rt, const std::shared_ptr<Conn>& c,
                           "native service response undeliverable");
               });
             }
-            done->store(true);
             return;
           }
         }
@@ -1957,6 +1989,13 @@ void batch_fast_response(ParseBatch* b, Conn* c, const MetaLite& m,
   b->events.push_back(ev);
 }
 
+// EV_FRAME.aux: which side of a call a TRPC frame is, for the consumer's
+// wait counters (a TSTR frame's meta is not parsed here: 0).
+int64_t frame_side(bool meta_ok, const MetaLite& m) {
+  if (!meta_ok) return 0;
+  return m.has_request ? 1 : (m.has_response ? 2 : 0);
+}
+
 // Cut complete TRPC/TSTR frames out of (buf, pos) — the wire buffer for
 // plain conns, the reassembled tunnel stream for TPUC conns.
 void cut_trpc(Runtime* rt, const std::shared_ptr<Conn>& c, RBuf& buf,
@@ -2076,6 +2115,7 @@ void cut_trpc(Runtime* rt, const std::shared_ptr<Conn>& c, RBuf& buf,
         DpEvent ev{};
         ev.kind = EV_FRAME;
         ev.tag = is_tstr ? 1 : 0;
+        ev.aux = frame_side(meta_ok, m);
         ev.conn_id = c->id;
         ev.base = buf.data;
         ev.meta = buf.data + kHeaderSize;
@@ -2110,6 +2150,7 @@ void cut_trpc(Runtime* rt, const std::shared_ptr<Conn>& c, RBuf& buf,
       DpEvent ev{};
       ev.kind = EV_FRAME;
       ev.tag = is_tstr ? 1 : 0;
+      ev.aux = frame_side(meta_ok, m);
       ev.conn_id = c->id;
       ev.base = blk;
       ev.meta = blk;
@@ -4320,12 +4361,12 @@ int dp_poll(void* h, DpEvent* out, int maxn, int timeout_ms) {
 // tunnel descriptors) stay zero-copy as pointer records the consumer
 // frees as before. Record layout (host endian, packed):
 //   i32 kind (bit 30 set = pointer record)  i32 tag
-//   u64 conn_id  i64 aux  u64 meta_len  u64 body_len
+//   u64 conn_id  i64 aux  u64 meta_len  u64 body_len  i64 t_ns
 //   inline:  meta bytes, body bytes
 //   pointer: u64 base, u64 meta_ptr, u64 body_ptr
 constexpr int32_t kPackedPtrFlag = 1 << 30;
 constexpr uint64_t kPackInlineMax = 8 << 10;  // per-event inline budget
-constexpr uint64_t kPackedHdr = 40;
+constexpr uint64_t kPackedHdr = 48;
 
 int dp_poll_packed(void* h, uint8_t* buf, uint64_t cap, int timeout_ms,
                    int maxn) {
@@ -4371,6 +4412,7 @@ int dp_poll_packed(void* h, uint8_t* buf, uint64_t cap, int timeout_ms,
     memcpy(p + 16, &ev.aux, 8);
     memcpy(p + 24, &ev.meta_len, 8);
     memcpy(p + 32, &blen, 8);
+    memcpy(p + 40, &ev.t_ns, 8);
     p += kPackedHdr;
     if (inlined) {
       if (ev.meta_len) memcpy(p, ev.meta, ev.meta_len);
@@ -4391,6 +4433,37 @@ int dp_poll_packed(void* h, uint8_t* buf, uint64_t cap, int timeout_ms,
 }
 
 void dp_free(void* base) { free(base); }
+
+// The threads this runtime owns, for the process's CPU table: out[2i] is a
+// thread's kind (0 event loop, 1 sender worker, 2 the sender workers that
+// have ended, as one entry) and out[2i + 1] its CPU ns, user + system.
+// Returns the entries written (at most cap).
+int dp_thread_stats(void* h, int64_t* out, int cap) {
+  auto* rt = static_cast<Runtime*>(h);
+  int n = 0;
+  auto put = [&](int64_t kind, int64_t cpu) {
+    if (cpu < 0 || n >= cap) return;
+    out[2 * n] = kind;
+    out[2 * n + 1] = cpu;
+    n++;
+  };
+  for (auto& l : rt->loops) {
+    if (l->thr.joinable()) put(0, thread_cpu_ns(l->thr.native_handle()));
+  }
+  {
+    // under the lock no sender is joined, so every handle read is live or
+    // ended-and-unjoined (the clock then answers ESRCH / EINVAL: skipped,
+    // its CPU is in senders_ended_cpu_ns)
+    std::lock_guard<std::mutex> lk(rt->smu_senders);
+    for (auto& sl : rt->senders) {
+      if (!sl.done->load() && sl.thr.joinable()) {
+        put(1, thread_cpu_ns(sl.thr.native_handle()));
+      }
+    }
+  }
+  put(2, rt->senders_ended_cpu_ns.load());
+  return n;
+}
 
 void dp_conn_close(void* h, uint64_t conn_id) {
   auto* rt = static_cast<Runtime*>(h);

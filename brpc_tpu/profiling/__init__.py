@@ -10,9 +10,10 @@
 
 from brpc_tpu.profiling.registry import (  # noqa: F401
     ROLE_BATCH, ROLE_HEALER, ROLE_POLLER, ROLE_SAMPLER, ROLE_TIMER,
-    ROLE_USER, ROLE_WORKER, phase, phase_of, register_current_thread,
-    role_of, set_phase, span, thread_spans, threads_by_role,
-    unregister_current_thread)
+    ROLE_USER, ROLE_WORKER, cpu_by_role, gc_pauses, phase_of,
+    register_current_thread, role_of, set_phase, span, spans_by_role,
+    thread_spans, thread_waits, threads_by_role, unregister_current_thread,
+    wait_span)
 from brpc_tpu.profiling.sampler import (  # noqa: F401
     ContinuousProfiler, FoldedProfile, ProfileSession, collapse,
     continuous, ensure_continuous_started, run_profile)

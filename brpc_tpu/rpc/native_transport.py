@@ -53,15 +53,24 @@ EV_DETACHED = 4
 EV_REQUEST = 5      # engine-parsed unary request (ReqLite struct + body)
 EV_RESPONSE = 6     # engine-parsed unary response (RespLite struct + body)
 EV_RESPONSE_ZC = 7  # zero-copy response: pool-block views + ack blob
+# which lane_wait counter an event's wait goes to: 1 request, 2 response
+# (an EV_FRAME says it in aux, or is a stream frame: tag 1)
+_EVENT_SIDE = {EV_REQUEST: 1, EV_RESPONSE: 2, EV_RESPONSE_ZC: 2}
 
 # ReqLite / RespLite (dataplane.cpp mirrors, host endianness)
 _REQ_STRUCT = struct.Struct("<QQQqqqiHH")  # cid,att_v,att,log,trace,span,to,sl,ml
 _RESP_ATT = struct.Struct("<Q")           # att_size at offset 8
 _RESP_HDR = 16
 # dp_poll_packed record framing (dataplane.cpp kPackedHdr/kPackedPtrFlag)
-_PACKED_HDR = struct.Struct("<iiQqQQ")    # kind,tag,conn,aux,mlen,blen
+_PACKED_HDR = struct.Struct("<iiQqQQq")   # kind,tag,conn,aux,mlen,blen,t_ns
 _PACKED_PTRS = struct.Struct("<QQQ")      # base,meta,body for big events
 _PACKED_PTR_FLAG = 1 << 30
+
+# The lane's events whose wait for the poller is counted (lane_wait), and
+# the roles its threads take in the process's CPU table (dp_thread_stats'
+# kinds: event loop, sender worker, sender workers that have ended)
+LANE_WAIT_KINDS = ("request", "response", "stream")
+_LANE_ROLES = ("lane.loop", "lane.sender", "lane.sender")
 
 # Poll-batch boundary hook (brpc_tpu.batch installs flush_poll_batch here):
 # the packed poll loop calls it after each event batch, mirroring
@@ -313,6 +322,10 @@ class NativeDataplane:
         self._conn_pools: Dict[tuple, list] = {}  # pooled free lists
         self._conn_map_lock = threading.Lock()
         self._running = True
+        # kind -> [events, wait_ns, max_ns]: queued by a lane thread
+        # (DpEvent.t_ns) to picked up here; only the poller writes it
+        self.lane_wait = {kind: [0, 0, 0] for kind in LANE_WAIT_KINDS}
+        self._lane_cpu: Dict[str, list] = {}    # thread_stats' last reading
         self._proto_trpc = None
         self._proto_tstr = None
         self._poller = threading.Thread(target=self._poll_loop, daemon=True,
@@ -323,6 +336,26 @@ class NativeDataplane:
         self._poller.start()
 
     # --------------------------------------------------------------- engine
+    def thread_stats(self) -> Dict[str, list]:
+        """``{"lane.loop" | "lane.sender": [threads, cpu_ns]}``: the CPU
+        clocks of the threads the engine owns, which no ``threading.Thread``
+        stands for (profiling/registry.py ``cpu_by_role``). After
+        ``shutdown`` the last reading stands, with no thread."""
+        with self._lock:     # shutdown joins the threads under it
+            if not self._running:
+                return {role: [0, cpu]
+                        for role, (_n, cpu) in self._lane_cpu.items()}
+            out = {"lane.loop": [0, 0], "lane.sender": [0, 0]}
+            cap = 256
+            arr = (ctypes.c_int64 * (2 * cap))()
+            for i in range(self._lib.dp_thread_stats(self._rt, arr, cap)):
+                kind, cpu = arr[2 * i], arr[2 * i + 1]
+                mine = out[_LANE_ROLES[kind]]
+                mine[0] += kind != 2   # the ended ones are a sum, no thread
+                mine[1] += cpu
+            self._lane_cpu = out
+        return out
+
     def send(self, conn_id: int, payload: bytes) -> int:
         return self._lib.dp_send(self._rt, conn_id, payload, len(payload))
 
@@ -707,14 +740,21 @@ class NativeDataplane:
         hdr = _PACKED_HDR.unpack_from
         ptrs = _PACKED_PTRS.unpack_from
         string_at = ctypes.string_at
+        clock = _time.perf_counter_ns
+        # by an event's side: none, request, response, stream frame
+        waits = (None,) + tuple(self.lane_wait[kind]
+                                for kind in LANE_WAIT_KINDS)
         last_sweep = _time.monotonic()
         while self._running:
             nbytes = lib.dp_poll_packed(rt, buf, self.POLL_BUF, 200,
                                         self.POLL_BATCH)
+            # once a batch, right after the call has the interpreter back:
+            # perf_counter_ns reads the clock the lane stamped with
+            now_ns = clock()
             off = 0
             while off < nbytes:
-                kind, tag, conn_id, aux, mlen, blen = hdr(mv, off)
-                off += 40
+                kind, tag, conn_id, aux, mlen, blen, t_ns = hdr(mv, off)
+                off += 48
                 base = 0
                 if kind & _PACKED_PTR_FLAG:
                     kind &= ~_PACKED_PTR_FLAG
@@ -727,10 +767,25 @@ class NativeDataplane:
                     meta_b = bytes(mv[off:end])
                     body_b = bytes(mv[end:end + blen]) if blen else b""
                     off = end + blen
+                # the event's wait for this thread, by what it carries (an
+                # EV_FRAME says in aux which side of a call it is)
+                if kind == EV_FRAME:
+                    side = 3 if tag == 1 else aux
+                else:
+                    side = _EVENT_SIDE.get(kind, 0)
+                rec = waits[side]
+                if rec is not None:
+                    wait = now_ns - t_ns
+                    if wait < 0:
+                        wait = 0
+                    rec[0] += 1
+                    rec[1] += wait
+                    if wait > rec[2]:
+                        rec[2] = wait
                 try:
                     if kind == EV_REQUEST:
                         item = self._crack_fast_request(conn_id, meta_b,
-                                                        body_b)
+                                                        body_b, t_ns)
                         if item is not None:
                             nulls = item[0]._null_methods
                             if nulls and (item[2], item[3]) in nulls:
@@ -755,7 +810,7 @@ class NativeDataplane:
                                                   meta_b)
                     else:
                         self._dispatch(kind, tag, conn_id, aux, meta_b,
-                                       body_b)
+                                       body_b, t_ns)
                 except Exception:
                     log.exception("native event dispatch failed (kind=%d)",
                                   kind)
@@ -773,8 +828,10 @@ class NativeDataplane:
                 self._sweep_fast_timeouts(now)
 
     # ------------------------------------------------------- fast-path events
-    def _crack_fast_request(self, conn_id, meta_b, body):
-        """EV_REQUEST -> dispatch tuple (engine already parsed the meta)."""
+    def _crack_fast_request(self, conn_id, meta_b, body, t_ns=0):
+        """EV_REQUEST -> dispatch tuple (engine already parsed the meta);
+        its last item is the request's arrival, the lane's stamp in
+        ``time.monotonic()``'s seconds."""
         sock = self._socks.get(conn_id)  # GIL-atomic read, hot path
         if sock is None:
             return None  # conn already failed/removed; nobody to answer
@@ -800,7 +857,7 @@ class NativeDataplane:
         sock.in_bytes += len(meta_b) + len(body)
         sock.last_active = _time.monotonic()
         return (server, sock, svc, meth, cid, attempt, att_size, log_id,
-                trace_id, span_id, timeout_ms, body)
+                trace_id, span_id, timeout_ms, body, t_ns / 1e9)
 
     def _on_fast_response(self, conn_id, cid, tag, meta_b, body_b) -> None:
         sock = self._socks.get(conn_id)
@@ -890,7 +947,8 @@ class NativeDataplane:
                     rec.text = "fast-call deadline exceeded"
                     rec.finish()
 
-    def _dispatch(self, kind, tag, conn_id, aux, meta_b, body_b) -> None:
+    def _dispatch(self, kind, tag, conn_id, aux, meta_b, body_b,
+                  t_ns=0) -> None:
         if kind == EV_FRAME:
             sock = self.lookup(conn_id)
             if sock is None:
@@ -901,7 +959,7 @@ class NativeDataplane:
                         self._gc_orphans()
                         return
                     sock = self._socks[conn_id]
-            self._process_frame(sock, tag, meta_b, body_b)
+            self._process_frame(sock, tag, meta_b, body_b, t_ns=t_ns)
         elif kind == EV_ACCEPTED:
             peer = meta_b.decode("utf-8", "replace") if meta_b else "?:0"
             self._on_accepted(conn_id, int(aux), peer)
@@ -937,7 +995,7 @@ class NativeDataplane:
             self._orphans.clear()
 
     def _process_frame(self, sock: NativeSocket, tag: int, meta_b,
-                       body_b: bytes, prebuilt_meta=None) -> None:
+                       body_b: bytes, prebuilt_meta=None, t_ns=0) -> None:
         from brpc_tpu.rpc.input_messenger import _process_one
         from brpc_tpu.rpc.protocol import ParsedMessage
 
@@ -957,6 +1015,10 @@ class NativeDataplane:
             return
         msg = ParsedMessage(proto, meta, IOBuf(body_b))
         msg.socket = sock
+        if t_ns:
+            # arrival is where the frame left the wire, not this pick-up:
+            # the lane's stamp is on time.monotonic()'s clock
+            msg.arrival = t_ns / 1e9
         sock.in_messages += 1
         sock.in_bytes += (len(meta_b) if meta_b else 0) + len(body_b)
         sock.last_active = _time.monotonic()
@@ -1060,11 +1122,14 @@ class NativeDataplane:
 
     # -------------------------------------------------------------- teardown
     def shutdown(self) -> None:
-        if not self._running:
-            return
-        self._running = False
+        self.thread_stats()     # the last reading of the lane's CPU clocks
+        with self._lock:
+            if not self._running:
+                return
+            self._running = False
         self._poller.join(timeout=2)
-        self._lib.dp_rt_shutdown(self._rt)
+        with self._lock:
+            self._lib.dp_rt_shutdown(self._rt)
 
 
 # lazy hook into the server-side fast dispatch (import cycle: server
@@ -1107,6 +1172,25 @@ def get_dataplane() -> Optional[NativeDataplane]:
             log.warning("native dataplane disabled: %s", e)
             return None
         return _dataplane
+
+
+def lane_wait() -> Dict[str, list]:
+    """``{"request" | "response" | "stream": [events, wait_ns, max_ns]}``:
+    how long the lane's events stood queued between the lane thread that
+    stamped them and the poller that picked them up, cumulative; zeros
+    while no engine runs (asking starts none)."""
+    dp = _dataplane
+    if dp is None:
+        return {kind: [0, 0, 0] for kind in LANE_WAIT_KINDS}
+    return {kind: list(rec) for kind, rec in dp.lane_wait.items()}
+
+
+def lane_cpu() -> Dict[str, list]:
+    """``{"lane.loop" | "lane.sender": [threads, cpu_ns]}`` of the engine's
+    own threads (``NativeDataplane.thread_stats``); nothing while no engine
+    runs (asking starts none)."""
+    dp = _dataplane
+    return dp.thread_stats() if dp is not None else {}
 
 
 def dataplane_available() -> bool:

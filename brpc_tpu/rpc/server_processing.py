@@ -633,7 +633,7 @@ def _replay_full(item) -> None:
     whose options demand per-request hooks (auth/interceptor) when a fast
     event arrives anyway (options changed after start)."""
     (server, sock, svc, meth, cid, attempt, att_size, log_id, trace_id,
-     span_id, timeout_ms, body) = item
+     span_id, timeout_ms, body, arrival) = item
     from brpc_tpu.butil.iobuf import IOBuf
     from brpc_tpu.rpc.protocol import ParsedMessage, find_protocol
 
@@ -642,6 +642,8 @@ def _replay_full(item) -> None:
                          trace_id, span_id, timeout_ms)
     msg = ParsedMessage(proto, meta, IOBuf(body))
     msg.socket = sock
+    if arrival:
+        msg.arrival = arrival
     process_rpc_request(proto, msg, server)
 
 
@@ -664,7 +666,7 @@ def fast_process_request(item) -> None:
         _span_mod = span
         _collector = global_collector()
     (server, sock, svc, meth, cid, attempt, att_size, log_id, trace_id,
-     span_id, timeout_ms, body) = item
+     span_id, timeout_ms, body, arrival) = item
     _span = _span_mod
 
     dp = sock._dp
@@ -688,6 +690,13 @@ def fast_process_request(item) -> None:
     else:
         span = _span.start_server_span_ids(trace_id, span_id, svc, meth,
                                            peer=sock.peer_str)
+        if span is not None and arrival:
+            # queue_us: the lane's stamp (the frame parsed on a lane
+            # thread) -> this dispatch, as the Python lanes give it
+            q_us = max(0.0, (time.monotonic() - arrival) * 1e6)
+            span.start_mono_us -= q_us
+            span.start_us -= q_us
+            span.add_phase("queue_us", q_us)
 
     def send_error(code: int, text: str = "") -> None:
         if span is not None:
@@ -730,9 +739,11 @@ def fast_process_request(item) -> None:
     cntl = FastServerController(server, sock, svc, meth, log_id, timeout_ms)
     cntl.span = span
     if timeout_ms > 0:
-        # the engine dispatches EV_REQUEST promptly, so the budget starts
-        # (approximately) now; batch enqueue re-checks this deadline
-        cntl.deadline_mono = time.monotonic() + timeout_ms / 1000.0
+        # the budget starts where the lane stamped the request (now, for
+        # a caller that has no stamp); batch enqueue re-checks this
+        # deadline
+        cntl.deadline_mono = ((arrival or time.monotonic())
+                              + timeout_ms / 1000.0)
 
     # dump sampling rides the fast path natively (no full-pipeline replay):
     # the meta pb is rebuilt only for the sampled few, before the

@@ -57,7 +57,9 @@ from brpc_tpu.metrics.reducer import Adder
 from brpc_tpu.metrics.status import PassiveStatus
 from brpc_tpu.profiling import registry as _prof
 from brpc_tpu.profiling.registry import span as _span
+from brpc_tpu.profiling.registry import wait_span as _wait_span
 from brpc_tpu.rpc import errors
+from brpc_tpu.rpc import native_transport as _native_transport
 from brpc_tpu.serving import qos as _qos
 from brpc_tpu.serving import speculative as _spec
 from brpc_tpu.serving.kv_cache import KVCacheFull, PagedKVCache
@@ -82,6 +84,10 @@ g_serving_itl = LatencyRecorder().expose("g_serving_itl")
 
 _engines: List["ServingEngine"] = []
 _engines_lock = threading.Lock()
+
+
+def _ns_to_us(ns: int) -> float:
+    return round(ns / 1000.0, 1)
 
 
 def active_engines() -> List["ServingEngine"]:
@@ -264,6 +270,7 @@ class ServingEngine:
         # the loop thread's span counters (profiling/registry.py), kept
         # here so snapshot() reads them from any thread
         self._spans: Dict[str, List[int]] = {}
+        self._waits: Dict[str, int] = {}     # its waiting spans' CPU time
         # disaggregation plumbing: the migrator ships chains OUT (set via
         # set_migrator), the receiver (installed by LlmServingService)
         # adopts chains IN; _adopted parks migrated-in sequences until a
@@ -627,6 +634,7 @@ class ServingEngine:
         profile attributes the device's idle gaps to the host."""
         _prof.register_current_thread("serving")
         self._spans = _prof.thread_spans()
+        self._waits = _prof.thread_waits()
         try:
             while True:
                 # admit's own time: the wait for the lock (an RPC thread
@@ -636,7 +644,7 @@ class ServingEngine:
                         # a span a wait: a profiler session that starts
                         # mid-span never sees it, so none is long
                         while self.running and not self._has_work():
-                            with _span("engine.idle"):
+                            with _wait_span("engine.idle"):
                                 self._cv.wait(self.config.idle_wait_s)
                         if not self.running:
                             return
@@ -646,7 +654,7 @@ class ServingEngine:
                         and self._prefilling is None):
                     # waiting work exists but the pool is full — let
                     # in-flight frees land instead of spinning the step
-                    with _span("engine.pool_wait"):
+                    with _wait_span("engine.pool_wait"):
                         time.sleep(0.002)
                     continue
                 with _span("engine.step", step=self.steps,
@@ -1326,11 +1334,48 @@ class ServingEngine:
         counters = getattr(self.model, "scan_counters", None)
         return dict(counters) if counters is not None else None
 
+    def _host_snapshot(self, loop_spans) -> Dict[str, object]:
+        """The host side of the process, every number cumulative since
+        start so that two snapshots difference (docs/serving.md "Reading a
+        profile"): the loop thread's spans with their long closes, the CPU
+        time inside those of them that wait by design, every thread's spans
+        and CPU time by role (the native lane's threads too), the wait of
+        the lane's events for the poller, and the collector's pauses."""
+        us = _ns_to_us
+        return {
+            "wall_us": us(time.perf_counter_ns()),
+            # the loop thread's: [self_us, long_n, long_self_us]
+            "loop": {name: [us(rec[2]), rec[3], us(rec[4])]
+                     for name, rec in loop_spans},
+            # the loop's spans that wait by design (nothing to run, a full
+            # pool, the device), with the CPU time used inside them: the
+            # loop's CPU time (threads["serving"]) less these is what its
+            # working spans cost
+            "waits": {name: us(cpu)
+                      for name, cpu in sorted(list(self._waits.items()))},
+            # every thread's, live and ended: [count, self_us]
+            "spans": {role: {name: [rec[0], us(rec[2])]
+                             for name, rec in sorted(by_name.items())}
+                      for role, by_name
+                      in sorted(_prof.spans_by_role().items())},
+            # [threads, cpu_us]: Python roles, lane.*, process, runtime
+            "threads": {role: [n, us(cpu)] for role, (n, cpu) in sorted(
+                _prof.cpu_by_role(_native_transport.lane_cpu()).items())},
+            # [events, wait_us, max_us]: queued on a lane thread to picked
+            # up by the poller
+            "lane_wait": {kind: [n, us(wait), us(worst)]
+                          for kind, (n, wait, worst)
+                          in _native_transport.lane_wait().items()},
+            # [collections, pause_us, max_us]
+            "gc": [g if i == 0 else us(g)
+                   for i, g in enumerate(_prof.gc_pauses())],
+        }
+
     def snapshot(self) -> Dict[str, object]:
         kv = self.kv.snapshot()
         occ = (self._occupancy_sum / self.steps) if self.steps else 0.0
         spans = sorted(list(self._spans.items()))
-        loop_ns = sum(own for _n, (_c, _t, own) in spans) or 1
+        loop_ns = sum(rec[2] for _n, rec in spans) or 1
         migration = None
         if self.migrator is not None or self._migration_rx is not None:
             migration = {"parked": len(self._adopted)}
@@ -1361,11 +1406,12 @@ class ServingEngine:
             # the loop thread's spans since start: [count, total_us,
             # self_us]; self times partition the loop's time, so their
             # shares say where the loop spends it
-            "span_us": {name: [n, round(tot / 1000.0, 1),
-                               round(own / 1000.0, 1)]
-                        for name, (n, tot, own) in spans},
-            "loop_share": {name: round(own / loop_ns, 4)
-                           for name, (_n, _tot, own) in spans},
+            "span_us": {name: [rec[0], round(rec[1] / 1000.0, 1),
+                               round(rec[2] / 1000.0, 1)]
+                        for name, rec in spans},
+            "loop_share": {name: round(rec[2] / loop_ns, 4)
+                           for name, rec in spans},
+            "host": self._host_snapshot(spans),
             "step_us_p50": g_serving_step.latency_percentile(0.5),
             "step_us_p99": g_serving_step.latency_percentile(0.99),
             "ttft_us_p50": g_serving_ttft.latency_percentile(0.5),

@@ -46,6 +46,7 @@ from typing import List, Sequence
 import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
+from brpc_tpu.profiling.registry import wait_span as _wait_span
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
 from brpc_tpu.serving.model import _next_pow2
 
@@ -505,7 +506,7 @@ class HybridServingModel:
         with _span("model.launch"):
             step_dispatch.note_launch(1)
             nxt = self._launch(fn, writes, toks, *args)
-        with _span("model.sync"):
+        with _wait_span("model.sync"):
             host = np.asarray(nxt).reshape(-1)
             step_dispatch.note_host_sync()
         self._note_counters("prefill", host[1:])
@@ -622,7 +623,7 @@ class HybridServingModel:
                     fn, [(t, int(p), int(p) + 1)
                          for t, p in zip(tables, positions)],
                     toks, pos, tabs, rings, slots)
-            with _span("model.sync"):
+            with _wait_span("model.sync"):
                 host = np.asarray(nxt)
                 step_dispatch.note_host_sync()
             self._note_counters("decode", host[b_bucket:])

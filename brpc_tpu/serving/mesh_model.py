@@ -33,6 +33,7 @@ from typing import List, Sequence
 import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
+from brpc_tpu.profiling.registry import wait_span as _wait_span
 from brpc_tpu.serving.kv_cache import ShardedKVCache
 from brpc_tpu.serving.model import (ModelConfig, TinyTransformer,
                                     _block_tables, _decode_body,
@@ -140,7 +141,7 @@ class MeshTransformer(TinyTransformer):
                                          self.kv.v_pools, toks, slots,
                                          np.int32(s), np.int32(shard))
                 self.kv.update_pools(kpools, vpools)
-            with _span("model.sync"):
+            with _wait_span("model.sync"):
                 first = int(nxt)
                 step_dispatch.note_host_sync()
             return first
@@ -187,7 +188,7 @@ class MeshTransformer(TinyTransformer):
                 x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
             self.kv.update_pools(kpools, vpools)
             logits = _rms(x[s - 1]) @ p["embed"].T
-        with _span("model.sync"):
+        with _wait_span("model.sync"):
             first = int(jnp.argmax(logits))
             step_dispatch.note_host_sync()
         return first
@@ -262,7 +263,7 @@ class MeshTransformer(TinyTransformer):
                                          self.kv.v_pools, toks, pos,
                                          block_tables)
                 self.kv.update_pools(kpools, vpools)
-            with _span("model.sync"):
+            with _wait_span("model.sync"):
                 flat = np.asarray(nxt)
                 step_dispatch.note_host_sync()
                 out = np.zeros(B, dtype=np.int32)

@@ -36,6 +36,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
+from brpc_tpu.profiling.registry import wait_span as _wait_span
 from brpc_tpu.serving.kv_cache import PagedKVCache
 
 
@@ -391,7 +392,7 @@ class TinyTransformer:
                 kpool, vpool, nxt = fn(self._params, self.kv.k_pool,
                                        self.kv.v_pool, toks, slots, s)
                 self.kv.update_pools(kpool, vpool)
-            with _span("model.sync"):
+            with _wait_span("model.sync"):
                 first = int(nxt)
                 step_dispatch.note_host_sync()
             return first
@@ -467,7 +468,7 @@ class TinyTransformer:
                 x = x + jax.nn.relu(h2 @ p[f"w1{l}"]) @ p[f"w2{l}"]
             self.kv.update_pools(kpool, vpool)
             logits = rms(x[s - 1]) @ p["embed"].T
-        with _span("model.sync"):
+        with _wait_span("model.sync"):
             first = int(jnp.argmax(logits))
             step_dispatch.note_host_sync()
         return first
@@ -551,7 +552,7 @@ class TinyTransformer:
                                        self.kv.v_pool, toks, pos,
                                        block_tables)
                 self.kv.update_pools(kpool, vpool)
-            with _span("model.sync"):
+            with _wait_span("model.sync"):
                 out = np.asarray(nxt[:B])
                 step_dispatch.note_host_sync()
             return out

@@ -306,7 +306,7 @@ class TestDispatchPathsSample:
             dp = _FakeDp()
             body = echo_pb2.EchoRequest(message="fast").SerializeToString()
             item = (server, _FakeSock(dp), "EchoService", "Echo",
-                    99, 1, 0, 5, 0xfeed, 0xbeef, 0, body)
+                    99, 1, 0, 5, 0xfeed, 0xbeef, 0, body, 0.0)
             sp_mod.fast_process_request(item)
             assert dp.responses and dp.responses[0][2] == 0
             server.rpc_dumper.close()

@@ -165,6 +165,118 @@ class TestPhaseAttribution:
             ["a.py:f;b.py:g 3"]
 
 
+class TestCpuByRole:
+    """registry.cpu_by_role / spans_by_role beside the sampler: who in the
+    process was on the CPU, by the roles the sampler already attributes
+    stacks to. Counts and inequalities only."""
+
+    def test_a_busy_unregistered_thread_shows_under_user(self, busy_thread):
+        from brpc_tpu.profiling import registry
+
+        a = registry.cpu_by_role()
+        time.sleep(0.1)
+        b = registry.cpu_by_role()
+        # the spinner (and this thread) are unregistered: user grew by the
+        # better part of what the whole process used meanwhile
+        grown = b["user"][1] - a["user"][1]
+        assert grown > 0
+        assert grown <= b["process"][1] - a["process"][1]
+        assert b["user"][0] >= 2
+
+    def test_the_samplers_thread_is_counted_while_it_runs_and_after(self):
+        from brpc_tpu.profiling import registry
+        from brpc_tpu.profiling.sampler import ProfileSession
+
+        before = registry.cpu_by_role().get("sampler", [0, 0])
+        sess = ProfileSession(hz=400.0, budget=False).start()
+        try:
+            end = time.monotonic() + 5
+            while time.monotonic() < end:
+                live = registry.cpu_by_role().get("sampler", [0, 0])
+                if live[0] > before[0] and live[1] > before[1]:
+                    break
+                time.sleep(0.02)
+        finally:
+            sess.stop()
+        assert live[0] == before[0] + 1 and live[1] > before[1]
+        end = time.monotonic() + 5
+        while time.monotonic() < end:
+            after = registry.cpu_by_role()["sampler"]
+            if after[0] == before[0]:
+                break
+            time.sleep(0.02)
+        # it ended without a word: its last reading is what is kept
+        assert after[0] == before[0] and after[1] >= live[1]
+
+    @pytest.mark.parametrize("role", ["worker", "timer"])
+    def test_a_servers_threads_are_in_the_table(self, server, role):
+        from brpc_tpu.profiling import registry
+
+        ch = Channel(ChannelOptions(timeout_ms=5000))
+        ch.init(str(server.listen_endpoint()))
+        Stub(ch, Echo.DESCRIPTOR).Echo(echo_pb2.EchoRequest(message="x"))
+        table = registry.cpu_by_role()
+        assert table[role][0] >= 1 and table[role][1] >= 0
+        assert table[role][0] <= registry.threads_by_role()[role] + 1
+
+    def test_served_calls_leave_spans_under_their_threads_roles(self,
+                                                                server):
+        from brpc_tpu.profiling import registry
+
+        def counts():
+            out = {}
+            for by_name in registry.spans_by_role().values():
+                for name in ("rpc.parse", "rpc.execute", "rpc.call"):
+                    out[name] = out.get(name, 0) + by_name.get(name, [0])[0]
+            return out
+
+        a = counts()
+        ch = Channel(ChannelOptions(timeout_ms=5000))
+        ch.init(str(server.listen_endpoint()))
+        stub = Stub(ch, Echo.DESCRIPTOR)
+        for _ in range(3):
+            stub.Echo(echo_pb2.EchoRequest(message="x"))
+        end = time.monotonic() + 5
+        while time.monotonic() < end:
+            b = counts()
+            if all(b.get(n, 0) - a.get(n, 0) >= 3 for n in
+                   ("rpc.parse", "rpc.execute", "rpc.call")):
+                break
+            time.sleep(0.01)
+        assert all(b.get(n, 0) - a.get(n, 0) >= 3
+                   for n in ("rpc.parse", "rpc.execute", "rpc.call")), (a, b)
+        # the caller is this (unregistered) thread
+        assert registry.spans_by_role()["user"]["rpc.call"][0] >= 3
+
+    def test_the_samplers_prune_keeps_a_dead_threads_spans(self):
+        from brpc_tpu.profiling import registry
+        from brpc_tpu.profiling.sampler import ProfileSession
+
+        def run():
+            registry.register_current_thread("test.shortlived")
+            with registry.span("rpc.execute"):
+                pass
+
+        before = registry.spans_by_role().get("test.shortlived", {}).get(
+            "rpc.execute", [0])[0]
+        # the session first, so the short-lived thread's ident is not
+        # the sampler's; the sampler prunes every 64th tick
+        sess = ProfileSession(hz=800.0, budget=False).start()
+        try:
+            th = threading.Thread(target=run)
+            th.start()
+            ident = th.ident
+            th.join()
+            end = time.monotonic() + 10
+            while ident in registry._threads and time.monotonic() < end:
+                time.sleep(0.02)
+        finally:
+            sess.stop()
+        assert ident not in registry._threads
+        assert registry.spans_by_role()["test.shortlived"]["rpc.execute"][0] \
+            == before + 1
+
+
 class TestContinuousRing:
     def test_ring_retention_and_eviction(self):
         """A dedicated ContinuousProfiler honors the (reloadable) window
