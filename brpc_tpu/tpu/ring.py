@@ -8,6 +8,36 @@ while every device accumulates its queries' attention over each visiting
 block with an online (flash-style) softmax — memory stays O(S/n) and the
 result is bit-for-bit a full attention.
 
+Which rows a device owns is the caller's statement about its data
+(``layout``; ``shard_blocks``, ``shard_rows``):
+
+  contiguous  shard d is block d of n: rows [d S/n, (d + 1) S/n). What a
+              cache written in sequence order holds (the serving prefill).
+  zigzag      the sequence is cut into 2n blocks and shard d holds blocks d
+              and 2n - 1 - d, an early and a late one, in that order. What
+              the causal train step feeds (train.py permutes its batch
+              once): nothing else there depends on a row's position.
+
+Causal masking is by block pair (``pair_schedule``, a host function of
+(n, layout, causal) that the flash bodies run and the tests read). A q
+block and a k block are two of the sequence's blocks: the pair is dead
+where the k block is later (it launches nothing), full where it is
+earlier (the mask-free kernel) and on the diagonal where they are the same
+block. A shard's blocks lie in increasing order, so
+
+  - at home (hop 0, K/V of the device's own rows) q and k are the same
+    blocks in the same order and the mask is the triangle over LOCAL row
+    numbers: one masked kernel call over the whole shard, whatever the
+    layout;
+  - the live pairs of a visiting shard are all full and fill one box of
+    (q blocks, k blocks): one mask-free call on those rows. Contiguous:
+    the whole shard where it came from an earlier device, nothing where
+    from a later one, so device d runs d + 1 of the n hops and everyone
+    waits for device n - 1. Zigzag: from an earlier device, all of q
+    against the low k block; from a later one, the high q block against
+    all of k: half a shard-by-shard tile on every device at every hop, and
+    nobody waits for anybody's kernels.
+
 One hop schedule (``_ring_hops``) serves the three bodies (lax forward,
 flash forward, flash backward). n is static, so the hops are unrolled and
 each hop's transfers are issued BEFORE its kernels and read after them:
@@ -22,18 +52,22 @@ each hop's transfers are issued BEFORE its kernels and read after them:
             ((b0 + b1) + b2) + ... of the hops. n rotations (the last one
             brings every block's gradient home, after the last kernels);
             the first and the last carry the narrow dtype, the n - 2
-            between them float32. That is exact: after hop 0 the sum is
-            0 + b0, a value of the kernel's output dtype, and what comes
-            home is cast to k's dtype on arrival anyway. No partial sum of
-            two or more contributions is ever rounded below float32.
+            between them float32. That is exact: a hop's contribution to
+            dK and dV is ONE kernel call's output in every layout (the
+            home hop's k rows meet both q blocks inside that call, which
+            sums them in float32 before it rounds once; a visiting hop's
+            box is one call, and rows of k outside it get zeros), so after
+            hop 0 the sum is 0 + b0, a value of the kernel's output dtype,
+            and what comes home is cast to k's dtype on arrival anyway. No
+            partial sum of two or more contributions is ever rounded below
+            float32.
 
 What still shows as collective time on a chip is each transfer's start and
-done, and the wait for a neighbor that has more kernels to run (under the
-causal mask chip 0 runs one block a pass and chip n - 1 runs n).
+done, and under the contiguous layout the wait for a neighbor that has more
+kernels to run.
 
-Causal masking is handled at block granularity: a KV block strictly in the
-future contributes nothing (its exp-weights are -inf masked); the diagonal
-block applies the in-block triangular mask.
+The lax body masks by each row's position in the sequence and skips
+nothing: it is the oracle for the schedule, not a user of it.
 """
 
 from __future__ import annotations
@@ -42,12 +76,99 @@ from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from brpc_tpu.tpu.pallas_ops import _on_tpu
 
 NEG_INF = -1e30
+
+LAYOUTS = ("contiguous", "zigzag")
+
+
+def shard_blocks(n: int, layout: str = "contiguous"):
+    """The blocks of the sequence (equal, numbered in sequence order) that
+    each of the n shards holds, in the order of the shard's rows."""
+    if layout == "contiguous":
+        return tuple((d,) for d in range(n))
+    if layout == "zigzag":
+        return tuple((d, 2 * n - 1 - d) for d in range(n))
+    raise ValueError(f"layout {layout!r} is not one of {LAYOUTS}")
+
+
+def shard_rows(seq: int, n: int, layout: str = "contiguous") -> np.ndarray:
+    """The sequence position of every row of an array sharded n ways in
+    ``layout``, shard after shard: ``x[:, shard_rows(...)]`` puts a
+    sequence-order array into the layout, ``np.argsort`` of it back."""
+    blocks = shard_blocks(n, layout)
+    rows, rest = divmod(seq, n * len(blocks[0]))
+    if rest:
+        raise ValueError(f"{seq} rows do not cut into {n} {layout} shards")
+    return np.concatenate([np.arange(b * rows, (b + 1) * rows)
+                           for shard in blocks for b in shard])
+
+
+def pair_schedule(n: int, layout: str = "contiguous", causal: bool = True):
+    """``schedule[i][d]``: the live (q block, k block) pairs of device d at
+    hop i, when the K/V of device (d - i) % n visit, as ``(a, b, kind)``
+    with a and b numbered within their shards and kind ``"diag"`` (the same
+    block of the sequence: the in-block triangle) or ``"full"`` (the k
+    block is earlier, or the attention is not causal: no mask). A pair
+    whose k block is later is dead and not listed."""
+    blocks = shard_blocks(n, layout)
+    return tuple(tuple(tuple(
+        (a, b, "diag" if causal and qb == kb else "full")
+        for a, qb in enumerate(blocks[d])
+        for b, kb in enumerate(blocks[(d - i) % n])
+        if qb >= kb or not causal) for d in range(n)) for i in range(n))
+
+
+def _hop_call(pairs, nb: int):
+    """The ONE kernel call that runs a hop's live pairs: ``((q0, q1),
+    (k0, k1), masked)`` over blocks [q0, q1) of the device's rows and
+    [k0, k1) of the visiting ones, or None where nothing is live. One call
+    a hop is what keeps a hop's dK/dV one kernel's output (module
+    docstring), so a layout whose live pairs do not make one is refused."""
+    if not pairs:
+        return None
+    masked = any(kind == "diag" for _, _, kind in pairs)
+    q0, q1 = min(a for a, _, _ in pairs), max(a for a, _, _ in pairs) + 1
+    k0, k1 = min(b for _, b, _ in pairs), max(b for _, b, _ in pairs) + 1
+    # a diagonal pair is at home, where both sides are the same blocks in
+    # the same increasing order: the mask is the triangle over local row
+    # numbers, the whole shard less the pairs above the diagonal
+    want = nb * (nb + 1) // 2 if masked else (q1 - q0) * (k1 - k0)
+    if len(pairs) != want or masked and (q1 - q0, k1 - k0) != (nb, nb):
+        raise ValueError(f"live pairs that make no one call: {pairs}")
+    return (q0, q1), (k0, k1), masked
+
+
+def _on_device(my, calls, run, operands):
+    """Run this device's entry of ``calls`` (one per device, fixed when the
+    program is traced; ``my`` is the traced device index): ``run(call)``
+    gives the branch ``operands -> results`` of one distinct call. Where
+    every device runs the same call there is no branch at all."""
+    distinct = list(dict.fromkeys(calls))
+    if len(distinct) == 1:
+        return run(distinct[0])(operands)
+    which = jnp.asarray([distinct.index(c) for c in calls], jnp.int32)
+    return lax.switch(which[my], [run(c) for c in distinct], operands)
+
+
+def _rows(span, rows: int, nb: int):
+    """A span of a shard's nb blocks as a slice of its rows; None for the
+    whole shard, which is then never sliced."""
+    lo, hi = span
+    return None if hi - lo == nb else slice(lo * rows // nb,
+                                            hi * rows // nb)
+
+
+def _part(x, span, nb: int, axis: int):
+    """The rows of x (along ``axis``) that a span of its nb blocks holds."""
+    r = _rows(span, x.shape[axis], nb)
+    return x if r is None else lax.slice_in_dim(x, r.start, r.stop,
+                                                axis=axis)
 
 
 def _pvary(x, axes):
@@ -65,8 +186,8 @@ def _rotate(xs, dtypes, axis, perm, scope):
 def _ring_hops(n, axis, perm, scope, kv, state, hop):
     """The ring's hop schedule, one for every body.
 
-    ``hop(src, kv, state) -> (state, grads)`` runs the kernels of one hop
-    on the visiting block ``kv``, which left device ``src``; ``grads`` is
+    ``hop(i, kv, state) -> (state, grads)`` runs the kernels of hop i on
+    the visiting block ``kv``, which left device (my - i) % n; ``grads`` is
     None (a forward body) or this hop's contribution to the gradient of
     each array of ``kv``, shaped like it. Returns the last state and the
     gradient of the device's OWN block in its dtype (None forward).
@@ -76,7 +197,6 @@ def _ring_hops(n, axis, perm, scope, kv, state, hop):
     has the counts, the dtypes and why they are exact). The sums are
     float32 between hops whatever crosses the wire.
     """
-    my = lax.axis_index(axis)
     f32 = jnp.float32
     home = tuple(x.dtype for x in kv)
     acc = narrow = None
@@ -93,7 +213,7 @@ def _ring_hops(n, axis, perm, scope, kv, state, hop):
             if acc is not None:
                 wire = narrow if i == 1 else (f32,) * len(acc)
                 acc = _rotate(acc, wire, axis, perm, "ring_dkv_ppermute")
-            state, grads = hop((my - i) % n, kv, state)
+            state, grads = hop(i, kv, state)
             if grads is not None:
                 if acc is None:
                     # 0 + b0: a value of b0's dtype, which is why the
@@ -136,59 +256,71 @@ def _block_attend(q, k, v, o, m, l, mask):
     return o_new, m_new, l_new
 
 
-def _make_ring_flash(axis, n, perm, causal, block_q, block_k, vaxes,
-                     interp):
+def _make_ring_flash(axis, n, perm, causal, layout, block_q, block_k,
+                     vaxes, interp):
     """Differentiable ring-flash attention, shard-local (call inside the
     shard_map). Forward threads (m, l, acc) through the carry-form flash
     kernel across KV ring hops; backward is its OWN ring: each hop runs
     the Pallas flash-backward kernels (pallas_ops._flash_bwd_bhsd) on the
     visiting KV block, and the dk/dv sums travel WITH the block, one hop
     behind it (_ring_hops), so after n rotations every gradient block is
-    back on its home device. The custom_vjp means AD never differentiates
-    through a pallas_call or the forward's hops."""
+    back on its home device. A hop is one kernel call on the rows its live
+    block pairs cover, or none (module docstring). The custom_vjp means AD
+    never differentiates through a pallas_call or the forward's hops."""
     from brpc_tpu.tpu.pallas_ops import (flash_attention_carry,
                                          _fit_block, _flash_bwd_bhsd,
                                          _flash_delta)
     vma = vaxes or None
+    nb = len(shard_blocks(n, layout)[0])
+    calls = [[_hop_call(pairs, nb) for pairs in hop]
+             for hop in pair_schedule(n, layout, causal)]
 
     def _fwd_impl(q, k, v):
         B, sq, H, D = q.shape
         my = lax.axis_index(axis)
-        q_start = my * sq
         qt = q.transpose(0, 2, 1, 3)           # [B,H,sq,D], kernel layout
         m0 = _pvary(jnp.full((B, H, sq, 1), NEG_INF, jnp.float32),
                        vaxes)
         l0 = _pvary(jnp.zeros((B, H, sq, 1), jnp.float32), vaxes)
         a0 = _pvary(jnp.zeros((B, H, sq, D), jnp.float32), vaxes)
 
-        def hop(src, kv, state):
-            k_cur, v_cur = kv
-            at, mt, lt = state
-            sk = k_cur.shape[1]
-            k_start = src * sk
+        def run(call):
+            if call is None:
+                # nothing live: no launch, the carry as it was (the
+                # kernel would skip every tile, but the launch and the
+                # streaming of dead blocks are real wall clock)
+                return lambda ops: ops[2:]
+
+            def part(x, span):
+                return _part(x, span, nb, 2)
 
             def one_head(q1, k1, v1, m1, l1, a1):
+                # a masked call is the home hop's: the triangle over
+                # local row numbers, so both starts are 0
                 return flash_attention_carry(
-                    q1, k1, v1, m1, l1, a1, q_start, k_start,
-                    causal=causal, block_q=_fit_block(sq, block_q),
-                    block_k=_fit_block(sk, block_k), vma=vma)
+                    q1, k1, v1, m1, l1, a1, 0, 0, causal=call[2],
+                    block_q=_fit_block(q1.shape[0], block_q),
+                    block_k=_fit_block(k1.shape[0], block_k), vma=vma)
 
-            kt = k_cur.transpose(0, 2, 1, 3)
-            vt = v_cur.transpose(0, 2, 1, 3)
-            if causal:
-                # a KV block entirely in this shard's future contributes
-                # nothing: skip the kernel launch, keep the carry (the
-                # kernels would skip every tile anyway, but the launch +
-                # VMEM streaming of dead blocks is real wall clock —
-                # lax.cond picks the identity at runtime per device)
-                mt, lt, at = lax.cond(
-                    k_start <= q_start + sq - 1,
-                    lambda ops: jax.vmap(jax.vmap(one_head))(*ops),
-                    lambda ops: (ops[3], ops[4], ops[5]),
-                    (qt, kt, vt, mt, lt, at))
-            else:
-                mt, lt, at = jax.vmap(jax.vmap(one_head))(qt, kt, vt, mt,
-                                                          lt, at)
+            def branch(ops):
+                kt, vt, *state = ops
+                qs, ks, _ = call
+                new = jax.vmap(jax.vmap(one_head))(
+                    part(qt, qs), part(kt, ks), part(vt, ks),
+                    *(part(x, qs) for x in state))
+                qr = _rows(qs, sq, nb)
+                if qr is None:
+                    return tuple(new)
+                return tuple(lax.dynamic_update_slice_in_dim(x, y, qr.start,
+                                                             axis=2)
+                             for x, y in zip(state, new))
+
+            return branch
+
+        def hop(i, kv, state):
+            kt, vt = (x.transpose(0, 2, 1, 3) for x in kv)
+            at, mt, lt = state
+            mt, lt, at = _on_device(my, calls[i], run, (kt, vt, mt, lt, at))
             return (at, mt, lt), None
 
         (at, mt, lt), _ = _ring_hops(n, axis, perm, "ring_fwd_hop", (k, v),
@@ -201,7 +333,6 @@ def _make_ring_flash(axis, n, perm, causal, block_q, block_k, vaxes,
     def _bwd_impl(q, k, v, out_bhsd, lse, do):
         B, sq, H, D = q.shape
         my = lax.axis_index(axis)
-        q_start = my * sq
         qb = q.transpose(0, 2, 1, 3).reshape(B * H, sq, D)
         dob = do.transpose(0, 2, 1, 3).reshape(B * H, sq, D)
         lseb = lse.reshape(B * H, sq, 1)
@@ -209,34 +340,46 @@ def _make_ring_flash(axis, n, perm, causal, block_q, block_k, vaxes,
         deltab = _flash_delta(out_bhsd.reshape(B * H, sq, D), dob)
         dq0 = _pvary(jnp.zeros((B * H, sq, D), jnp.float32), vaxes)
 
-        def hop(src, kv, dq_acc):
-            k_cur, v_cur = kv
-            sk = k_cur.shape[1]
-            k_start = src * sk
-            kb = k_cur.transpose(0, 2, 1, 3).reshape(B * H, sk, D)
-            vb = v_cur.transpose(0, 2, 1, 3).reshape(B * H, sk, D)
+        def run(call):
+            if call is None:
+                # nothing live: zeros, which carry the kernel outputs'
+                # varying-axes type (the branches must agree under
+                # check_vma)
+                return lambda ops: tuple(
+                    _pvary(jnp.zeros(x.shape, x.dtype), vaxes)
+                    for x in (qb,) + ops)
 
-            def run_bwd(ops):
-                qb2, kb2, vb2 = ops
-                return _flash_bwd_bhsd(
-                    qb2, kb2, vb2, lseb, dob, deltab, q_start, k_start,
-                    causal, _fit_block(sq, block_q),
-                    _fit_block(sk, block_k), interp, vma=vma)
+            def part(x, span):
+                return _part(x, span, nb, 1)
 
-            if causal:
-                # fully-future KV block: dq/dk/dv contributions are
-                # identically zero — skip both backward kernels. The
-                # zeros carry the kernel outputs' varying-axes type:
-                # both cond branches must agree under check_vma
-                zero_q = _pvary(jnp.zeros((B * H, sq, D), qb.dtype), vaxes)
-                zero_kv = _pvary(jnp.zeros((B * H, sk, D), kb.dtype),
-                                 vaxes)
-                dq_b, dk_b, dv_b = lax.cond(
-                    k_start <= q_start + sq - 1, run_bwd,
-                    lambda ops: (zero_q, zero_kv, zero_kv),
-                    (qb, kb, vb))
-            else:
-                dq_b, dk_b, dv_b = run_bwd((qb, kb, vb))
+            def whole(g, x, span):
+                # rows outside the call met nothing live: zeros
+                r = _rows(span, x.shape[1], nb)
+                return g if r is None else jnp.pad(
+                    g, ((0, 0), (r.start, x.shape[1] - r.stop), (0, 0)))
+
+            def branch(ops):
+                qs, ks, masked = call
+                q2, (k2, v2) = part(qb, qs), (part(x, ks) for x in ops)
+                dq_b, dk_b, dv_b = _flash_bwd_bhsd(
+                    q2, k2, v2, part(lseb, qs), part(dob, qs),
+                    part(deltab, qs), 0, 0, masked,
+                    _fit_block(q2.shape[1], block_q),
+                    _fit_block(k2.shape[1], block_k), interp, vma=vma)
+                return (whole(dq_b, qb, qs), whole(dk_b, ops[0], ks),
+                        whole(dv_b, ops[1], ks))
+
+            return branch
+
+        def hop(i, kv, dq_acc):
+            sk = kv[0].shape[1]
+            kb, vb = (x.transpose(0, 2, 1, 3).reshape(B * H, sk, D)
+                      for x in kv)
+            # the branches return the kernels' own dtype and the float32
+            # sum is kept outside them: XLA:TPU's bfloat16 propagation
+            # (libtpu 0.0.34) narrows a float32 result of the last hop's
+            # conditional in some of its branches only, and fails
+            dq_b, dk_b, dv_b = _on_device(my, calls[i], run, (kb, vb))
             dq_acc = dq_acc + dq_b.astype(jnp.float32)
             # the block's dk/dv sums travel with it (_ring_hops)
             return dq_acc, tuple(
@@ -266,14 +409,15 @@ def _make_ring_flash(axis, n, perm, causal, block_q, block_k, vaxes,
 
 
 @cache
-def _ring_program(mesh, axis, causal, batch_axis, head_axis, use_flash,
-                  block_q, block_k, interp):
+def _ring_program(mesh, axis, causal, layout, batch_axis, head_axis,
+                  use_flash, block_q, block_k, interp):
     """ring_attention's jitted shard_map for one set of its static
     arguments: built once, so a caller outside any jit (the serving
     prefill, one call a layer) runs one compiled program per shape and not
     the unrolled hops op by op."""
     n = mesh.shape[axis]
     perm = [(i, (i + 1) % n) for i in range(n)]
+    blocks = np.asarray(shard_blocks(n, layout))          # [n, nb]
     spec = P(batch_axis, axis, head_axis, None)
     vaxes = tuple(a for a in (batch_axis, axis, head_axis) if a)
     # the INTERPRETED pallas kernel (CPU test substrate) evaluates as jax
@@ -287,10 +431,13 @@ def _ring_program(mesh, axis, causal, batch_axis, head_axis, use_flash,
              out_specs=spec, check_vma=check_vma)
     def _f(q, k, v):
         B, sq, H, D = q.shape
+        if sq % blocks.shape[1]:
+            raise ValueError(f"a shard of {sq} rows does not cut into "
+                             f"{blocks.shape[1]} {layout} blocks")
 
         if use_flash:
-            rf = _make_ring_flash(axis, n, perm, causal, block_q, block_k,
-                                  vaxes, interp)
+            rf = _make_ring_flash(axis, n, perm, causal, layout, block_q,
+                                  block_k, vaxes, interp)
             return rf(q, k, v)
 
         my = lax.axis_index(axis)
@@ -303,12 +450,17 @@ def _ring_program(mesh, axis, causal, batch_axis, head_axis, use_flash,
         l = _pvary(jnp.zeros((B, H, sq), dtype=jnp.float32), vaxes)
         qf = q.astype(jnp.float32)
 
-        def hop(src, kv, state):
+        def positions(dev, rows):
+            """Where in the sequence each row of device dev's shard is."""
+            per = rows // blocks.shape[1]
+            return (jnp.asarray(blocks)[dev][:, None] * per
+                    + jnp.arange(per)).reshape(-1)
+
+        def hop(i, kv, state):
             k_cur, v_cur = kv
             if causal:
-                sk = k_cur.shape[1]
-                q_pos = my * sq + jnp.arange(sq)
-                k_pos = src * sk + jnp.arange(sk)
+                q_pos = positions(my, sq)
+                k_pos = positions((my - i) % n, k_cur.shape[1])
                 mask = q_pos[:, None] >= k_pos[None, :]
             else:
                 mask = None
@@ -328,8 +480,13 @@ def _ring_program(mesh, axis, causal, batch_axis, head_axis, use_flash,
 def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
                    batch_axis: str = None, head_axis: str = None,
                    use_flash: bool = False, block_q: int = 512,
-                   block_k: int = 1024):
+                   block_k: int = 1024, layout: str = "contiguous"):
     """Attention over sequence-sharded q/k/v: [B, S, H, D] sharded on S.
+
+    ``layout`` states which rows of the sequence each shard holds
+    (``shard_rows``): "contiguous", or "zigzag" for a caller that has put
+    its rows in that order, which levels the causal work over the devices
+    (module docstring). The result's rows are in the same order.
 
     Composes with data parallelism (batch_axis shards B) and tensor
     parallelism (head_axis shards H) — attention is independent per batch
@@ -348,7 +505,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str, causal: bool = False,
     last hop in the kernel's dtype (exact: module docstring). The lax path
     remains the numerics oracle.
     """
-    return _ring_program(mesh, axis, causal, batch_axis, head_axis,
+    return _ring_program(mesh, axis, causal, layout, batch_axis, head_axis,
                          use_flash, block_q, block_k, not _on_tpu())(q, k, v)
 
 

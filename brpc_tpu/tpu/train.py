@@ -11,7 +11,12 @@ model trains under a dp×sp×tp mesh:
        tp (PartitionChannel semantics)
   sp — sequence sharded; attention runs as ring attention (ring.py), KV
        blocks streaming between neighbors exactly like the reference's
-       credit-windowed streams (SURVEY §5.7 mapping)
+       credit-windowed streams (SURVEY §5.7 mapping). Under the causal mask
+       the rows go round in ring.py's zigzag order (every shard an early
+       and a late block, so every chip has the same kernels to run): the
+       batch is permuted once where it enters, and nothing after it
+       depends on a row's position (no positional encoding, per-row norms
+       and MLP, the loss a mean over rows)
 
 Everything compiles under one jit. The ring's transfers fly under its
 kernels by the order ring.py gives its hops; the gradients' all-reduce is
@@ -31,7 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from brpc_tpu.tpu.pallas_ops import rmsnorm, rmsnorm_reference
-from brpc_tpu.tpu.ring import ring_attention
+from brpc_tpu.tpu.ring import ring_attention, shard_rows
 
 
 @dataclass(frozen=True)
@@ -109,10 +114,37 @@ def _norm(x, w, cfg: ModelConfig):
     return rmsnorm_reference(x, w)
 
 
+def _ring_layout(seq: int, mesh: Mesh, causal: bool):
+    """How the rows of a sequence go over the mesh's sp axis: (ring.py's
+    layout, the sequence position of each row in it; None where that is
+    the sequence's own order). Zigzag wherever the causal mask would give
+    the last chip sp times the first one's kernels and the rows cut into
+    2 sp blocks."""
+    sp = mesh.shape["sp"] if mesh is not None else 1
+    if not causal or sp == 1 or seq % (2 * sp):
+        return "contiguous", None
+    return "zigzag", shard_rows(seq, sp, "zigzag")
+
+
 def forward(params, tokens, cfg: ModelConfig, mesh: Mesh = None,
             causal: bool = True):
-    """tokens [B, S] -> logits [B, S, V]. With a mesh, activations are
-    dp/sp-sharded and attention is ring attention over sp."""
+    """tokens [B, S] -> logits [B, S, V], both in sequence order. With a
+    mesh, activations are dp/sp-sharded and attention is ring attention
+    over sp; where the rows go round in another order (``_ring_layout``)
+    the logits are put back here, a [B, S, V] relayout that the train
+    step does not pay: ``loss_fn`` permutes its targets instead."""
+    layout, order = _ring_layout(tokens.shape[1], mesh, causal)
+    if order is None:
+        return _forward_rows(params, tokens, cfg, mesh, causal, layout)
+    logits = _forward_rows(params, tokens[:, order], cfg, mesh, causal,
+                           layout)
+    return logits[:, np.argsort(order)]
+
+
+def _forward_rows(params, tokens, cfg: ModelConfig, mesh: Mesh,
+                  causal: bool, layout: str):
+    """``forward`` on rows that are already in ``layout``'s order; the
+    logits come back in that order too."""
     B, S = tokens.shape
     H, Dh = cfg.n_heads, cfg.head_dim
 
@@ -136,7 +168,8 @@ def forward(params, tokens, cfg: ModelConfig, mesh: Mesh = None,
                 v = constrain(v, "dp", "sp", "tp", None)
                 att = ring_attention(q, k, v, mesh, axis="sp", causal=causal,
                                      batch_axis="dp", head_axis="tp",
-                                     use_flash=cfg.use_flash_attention)
+                                     use_flash=cfg.use_flash_attention,
+                                     layout=layout)
             elif cfg.use_flash_attention:
                 from brpc_tpu.tpu.pallas_ops import flash_attention_mha
 
@@ -163,7 +196,13 @@ def forward(params, tokens, cfg: ModelConfig, mesh: Mesh = None,
 
 def loss_fn(params, batch, cfg: ModelConfig, mesh: Mesh = None):
     tokens, targets = batch
-    logits = forward(params, tokens, cfg, mesh).astype(jnp.float32)
+    layout, order = _ring_layout(tokens.shape[1], mesh, True)
+    if order is not None:
+        # two int32 [B, S] arrays, once a step; no activation is ever
+        # permuted back
+        tokens, targets = tokens[:, order], targets[:, order]
+    logits = _forward_rows(params, tokens, cfg, mesh, True,
+                           layout).astype(jnp.float32)
     with jax.named_scope("loss"):
         if cfg.use_fused_xent and mesh is None:
             from brpc_tpu.tpu.pallas_ops import softmax_xent

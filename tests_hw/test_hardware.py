@@ -206,10 +206,13 @@ class TestServingAndRingOnChip:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
                                    rtol=2e-2, atol=2e-2)
 
-    def test_ring_flash_causal_under_shard_map(self, tpu_device):
+    @pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
+    def test_ring_flash_causal_under_shard_map(self, tpu_device, layout):
         # ring attention with the carry-form kernel and the Pallas ring
         # backward under a REAL sp=2 shard_map (varying-axes checker on),
-        # forward and gradients, against the lax path
+        # forward and gradients, against the lax path, which masks by
+        # position; q, k and v are random, so the layout is only what
+        # the call states about their rows
         from brpc_tpu.tpu.mesh import make_mesh
         from brpc_tpu.tpu.ring import ring_attention
 
@@ -225,7 +228,7 @@ class TestServingAndRingOnChip:
         def loss(use_flash):
             def f(q, k, v):
                 out = ring_attention(q, k, v, mesh, "sp", causal=True,
-                                     use_flash=use_flash)
+                                     use_flash=use_flash, layout=layout)
                 return jnp.sum(jnp.sin(out)), out
             return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
                                               has_aux=True))

@@ -342,9 +342,11 @@ def bench_ring_hops(peak: dict):
     repeats), the sum of its flash kernels and the time a collective holds
     its op line (both from a profiler trace, read with the benchmark's
     trace_reduce), beside the wire time the pass's bytes need at the link
-    rate measured here by a ring of bare ppermutes. Chip 0 runs one block
-    a pass under the causal mask and chip 3 four: what the kernels' sums
-    differ by is what sequence shards in another order would level."""
+    rate measured here by a ring of bare ppermutes. Both layouts of the
+    rows (ring.LAYOUTS; q, k and v are random, so the layout is only what
+    the call states): contiguous, where chip 0 runs one block a pass under
+    the causal mask and chip 3 four, and zigzag, which levels the
+    kernels' sums (the train step's)."""
     import functools
     import shutil
     import tempfile
@@ -356,7 +358,7 @@ def bench_ring_hops(peak: dict):
 
     from benchmark.harness import trace_reduce
     from brpc_tpu.tpu import mesh as meshlib
-    from brpc_tpu.tpu.ring import ring_attention
+    from brpc_tpu.tpu.ring import LAYOUTS, ring_attention
 
     SP, B, S, H, D, REPS = 4, 1, 16384, 16, 128, 8
     if len(jax.devices()) < SP:
@@ -370,14 +372,14 @@ def bench_ring_hops(peak: dict):
                    out_shardings=shard)
     q, k, v = (make(jax.random.PRNGKey(i)) for i in range(3))
 
-    def attend(q, k, v):
-        return ring_attention(q, k, v, mesh, "sp", causal=True,
-                              use_flash=True)
+    def passes(layout):
+        def attend(q, k, v):
+            return ring_attention(q, k, v, mesh, "sp", causal=True,
+                                  use_flash=True, layout=layout)
 
-    fwd = jax.jit(attend)
-    both = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
-        argnums=(0, 1, 2)))
+        return (("fwd", jax.jit(attend)), ("fwd+bwd", jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2)))))
 
     # the link: K and V's rotation and nothing else, chained
     perm = [(i, (i + 1) % SP) for i in range(SP)]
@@ -401,27 +403,28 @@ def bench_ring_hops(peak: dict):
     print(f"# ring_hops sp={SP} B={B} S={S} H={H} D={D} bf16 causal: link "
           f"{link / 1e9:.1f} GB/s a chip one way (bare ppermute of K+V, "
           f"{narrow / 1e6:.1f} MB in {sec * 1e3:.3f} ms)", flush=True)
-    for name, fn in (("fwd", fwd), ("fwd+bwd", both)):
-        jax.block_until_ready(fn(q, k, v))        # compile
-        tdir = tempfile.mkdtemp(prefix="ring_hops_")
-        jax.profiler.start_trace(tdir)
-        t0 = time.perf_counter()
-        jax.block_until_ready([fn(q, k, v) for _ in range(REPS)])
-        wall = (time.perf_counter() - t0) / REPS
-        jax.profiler.stop_trace()
-        red = trace_reduce.Reduced(trace_reduce.extract(
-            trace_reduce.find_xplane(tdir)))
-        shutil.rmtree(tdir, ignore_errors=True)
-        for dev in red.devices:
-            print(f"# ring_hops {name:7s} chip {dev}: pass "
-                  f"{wall * 1e3:7.3f} ms, busy "
-                  f"{red.busy_s(dev) / REPS * 1e3:7.3f}, flash kernels "
-                  f"{red.op_ns('flash', dev)[0] / REPS / 1e6:7.3f}, "
-                  f"collectives on the op line "
-                  f"{red.collective_ns(dev) / REPS / 1e6:7.3f}; its "
-                  f"{sent[name] / 1e6:.1f} MB need "
-                  f"{sent[name] / link * 1e3:.3f} ms of wire",
-                  flush=True)
+    for layout in LAYOUTS:
+        for name, fn in passes(layout):
+            jax.block_until_ready(fn(q, k, v))        # compile
+            tdir = tempfile.mkdtemp(prefix="ring_hops_")
+            jax.profiler.start_trace(tdir)
+            t0 = time.perf_counter()
+            jax.block_until_ready([fn(q, k, v) for _ in range(REPS)])
+            wall = (time.perf_counter() - t0) / REPS
+            jax.profiler.stop_trace()
+            red = trace_reduce.Reduced(trace_reduce.extract(
+                trace_reduce.find_xplane(tdir)))
+            shutil.rmtree(tdir, ignore_errors=True)
+            for dev in red.devices:
+                print(f"# ring_hops {layout:10s} {name:7s} chip {dev}: pass "
+                      f"{wall * 1e3:7.3f} ms, busy "
+                      f"{red.busy_s(dev) / REPS * 1e3:7.3f}, flash kernels "
+                      f"{red.op_ns('flash', dev)[0] / REPS / 1e6:7.3f}, "
+                      f"collectives on the op line "
+                      f"{red.collective_ns(dev) / REPS / 1e6:7.3f}; its "
+                      f"{sent[name] / 1e6:.1f} MB need "
+                      f"{sent[name] / link * 1e3:.3f} ms of wire",
+                      flush=True)
     return link
 
 
