@@ -774,7 +774,9 @@ def serving_service(server, http: HttpMessage):
                     f"{c['experts_hit'] / n / moe['experts_held']:.2f})"
                     + (f" kernel_layers={c['kernel_layers']} "
                        f"blocked_layers={c['blocked_layers']}"
-                       if "kernel_layers" in c else ""))
+                       if "kernel_layers" in c else "")
+                    # rows a router sent to an output that computes nothing
+                    + (f" skipped={c['skipped']}" if "skipped" in c else ""))
             out.append(f"  moe: held={moe['experts_held']} "
                        + " | ".join(parts))
         # the selective scan: prefill launches that ran the kernel, and the

@@ -59,6 +59,12 @@ mechanism:
   dt / B / C beside position-free attention of many query heads over one
   key/value head, whose prefill CONTINUES from the slot's state and the
   pages, so the engine prefills a long prompt a chunk a step.
+- :mod:`brpc_tpu.serving.zaya_model` — a ``zaya`` decoder over the same
+  manager (pages of every layer beside a conv tail a layer and no scan
+  state, bfloat16 with float32 tails, streams and router): compressed
+  convolutional attention inside a narrow latent, then one of 16 whole
+  experts, or none, chosen by an MLP router whose state goes down the
+  layers beside the residual stream; it continues a prefill too.
 - :mod:`brpc_tpu.serving.speculative` — the speculative-decoding draft
   lane: host-side prompt-lookup drafting (zero weights, zero device
   work, lint-pinned) feeding the model's one fused ``verify_step``
@@ -111,6 +117,9 @@ def __getattr__(name):
     if name in ("JambaConfig", "JambaModel"):
         from brpc_tpu.serving import jamba_model
         return getattr(jamba_model, name)
+    if name in ("ZayaConfig", "ZayaModel"):
+        from brpc_tpu.serving import zaya_model
+        return getattr(zaya_model, name)
     raise AttributeError(name)
 
 
@@ -125,6 +134,7 @@ __all__ = [
     "HybridCacheConfig", "HybridStateCache", "HybridTable",
     "SambaYConfig", "SambaYModel", "HybridServingModel",
     "Cohere2MoeConfig", "Cohere2MoeModel", "JambaConfig", "JambaModel",
+    "ZayaConfig", "ZayaModel",
     "AdaptiveK", "accept_longest_prefix", "draft_tokens",
     "QosConfig", "QosGovernor", "QosLimiter", "TenantScheduler",
 ]

@@ -1319,7 +1319,9 @@ class ServingEngine:
         experts hit, the most pairs of one expert (summed over
         layer-launches) and the layer-launches; for prefill also the
         attention layers that took the flash kernel and the blocked scan
-        (``kernel_layers``, ``blocked_layers``)."""
+        (``kernel_layers``, ``blocked_layers``); where the router has an
+        output that computes nothing (serving/zaya_model.py), the rows it
+        sent there (``skipped``)."""
         counters = getattr(self.model, "moe_counters", None)
         if counters is None:
             return None

@@ -289,14 +289,18 @@ def route(cfg, h, w_router, live):
             top / jnp.sum(top, axis=-1, keepdims=True))
 
 
-def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int):
+def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int, first=None):
     """This chip's part of ``sum_e w_e E_e(h)``: the pairs (row, expert)
     whose expert is held here, sorted by expert, each expert's run padded
     to whole tiles of ``tile`` rows, through the grouped matmul (gate and up
     as one product, then down), and gathered back to their rows. h (R, d)
     float32; idx, wts (R, k) from :func:`route`; wgu (held, d, 2 ff), wd
-    (held, ff, d). Returns (R, d) float32 and the pairs held by expert
-    (held,) int32."""
+    (held, ff, d). An index that is nobody's here (another chip's expert,
+    an output that computes nothing, ``-1``) is left out. ``first``: where
+    held expert 0 lies in ``wgu`` and ``wd`` when they stack the experts of
+    many layers (layers x held, ...), so that the kernel indexes the stack
+    and no layer is sliced out of it. Returns (R, d) float32 and the pairs
+    held by expert (held,) int32."""
     import jax
     import jax.numpy as jnp
 
@@ -332,6 +336,8 @@ def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int):
                          p_start[e_pair] + rank - u_start[e_pair], 0)
     with jax.named_scope("experts"):
         used = p_end[-1] // tile
+        if first is not None:
+            tile_expert = tile_expert + first
         gmm = functools.partial(pallas_ops.moe_grouped_matmul,
                                 tile_expert=tile_expert, tiles_used=used,
                                 block_rows=tile)

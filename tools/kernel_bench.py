@@ -5,6 +5,7 @@ Run standalone (owns the chip):
     python tools/kernel_bench.py            # prints one line per metric
     python tools/kernel_bench.py paged_decode   # only the named benches
     python tools/kernel_bench.py ssm_scan       # the prefill's selective scan
+    python tools/kernel_bench.py cca_mix        # the zaya lane's decode-side mix
     python tools/kernel_bench.py ring_hops      # needs four chips
 
 Timing methodology: marginal cost between two round counts inside ONE
@@ -641,6 +642,83 @@ def bench_ssm_scan(peak: dict):
                   f"{flops / secs / 1e9:.0f} GFLOP/s", flush=True)
 
 
+def bench_cca_mix(peak: dict):
+    """The decode-side mix of the ``zaya`` lane alone
+    (``zaya_model.cca_mix`` behind the tail shift: both convs, the q-k mean,
+    the L2 norms, the temperature, rotary and the value shift) at the
+    `solve-steady` cell's shape: 32 rows, 20 layers' worth in one
+    ``fori_loop`` over stacked weights, each layer from a NON-ZERO tail that
+    it shifts, the published widths (8-over-2 heads of 128, so 1280 mixed
+    channels and a tail of 2688 floats a row a layer). XLA fusions today, so
+    a later fused kernel has its number to beat; against the least time for
+    the tails read and written and the convs' weights once over the HBM
+    peak. Read by no metric."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.serving.zaya_model import ZayaConfig, cca_mix
+
+    cfg = ZayaConfig(hidden_size=2048, num_attention_heads=8,
+                     num_key_value_heads=2, head_dim=128,
+                     moe_intermediate_size=2048, num_experts=16,
+                     router_hidden_size=256, num_hidden_layers=20,
+                     vocab_size=262272)
+    rows, n, qk, hd = 32, cfg.n_layers, cfg.qk_dim, cfg.head_dim
+    cut = cfg.back * qk
+    key = jax.random.key(0)
+
+    def draw(i, shape, scale=1.0, at=0.0):
+        return at + scale * jax.random.normal(jax.random.fold_in(key, i),
+                                              shape, jnp.float32)
+
+    w = {"c0w": draw(1, (n, cfg.t0, qk), 0.1, 0.5),
+         "c0b": draw(2, (n, qk), 0.1),
+         "c1w": draw(3, (n, cfg.t1, qk // hd, hd, hd), 0.5 / 16),
+         "c1b": draw(4, (n, qk), 0.1), "temp": draw(5, (n, 2), 0.1, 1.0)}
+    w = {k: v.astype(jnp.bfloat16) for k, v in w.items()}   # as stored
+    proj0 = draw(6, (rows, qk + 2 * hd))
+    tails0 = draw(7, (n, rows, cfg.tail_width))
+    pos = jnp.arange(rows, dtype=jnp.int32) + 1000
+
+    @functools.partial(jax.jit, static_argnames=("k",))
+    def loop(proj, tails, k: int):
+        def layer(i, carry):
+            proj, tails = carry
+            wl = {name: v[i].astype(jnp.float32) for name, v in w.items()}
+            tail = tails[i]
+            windows = jnp.concatenate(
+                [tail[:, :cut].reshape(rows, cfg.back, qk),
+                 proj[:, None, :qk]], axis=1)
+            q, kk, v = cca_mix(cfg, wl, windows, proj[:, qk:qk + hd],
+                               tail[:, cut:], pos)
+            tails = tails.at[i].set(jnp.concatenate(
+                [windows[:, 1:].reshape(rows, cut), proj[:, qk + hd:]],
+                axis=-1))
+            # the next layer's rows depend on this one's mix
+            return proj + 1e-3 * jnp.concatenate(
+                [q.reshape(rows, -1), kk, v], axis=-1), tails
+
+        def many(_j, carry):
+            return jax.lax.fori_loop(0, n, layer, carry)
+
+        return jax.lax.fori_loop(0, k, many, (proj, tails))
+
+    def run(k):
+        jax.device_get(loop(proj0, tails0, k)[0][:1, :1])
+
+    secs = _marginal(run, 8, 40)
+    nbytes = 2 * tails0.size * 4 + sum(v.size * 2 for v in w.values())
+    least = nbytes / peak["hbm_bytes_per_s"]
+    print(f"# cca_mix (XLA fusions) {rows} rows x {n} layers from a non-zero "
+          f"tail, {qk} mixed channels, tail {cfg.tail_width} floats: "
+          f"{secs * 1e3:7.3f} ms a step's worth ({secs / n * 1e6:.1f} us a "
+          f"layer); tails in and out and the convs' weights once over the "
+          f"HBM peak {least * 1e3:.3f} ms ({least / secs * 100:.1f}% of "
+          f"that roofline)", flush=True)
+
+
 def bench_train_step_mfu(peak: dict):
     """Single-chip train step of the flagship LM, reported BOTH ways:
     kernels ON (Pallas flash fwd+bwd, Pallas norm, fused xent — the
@@ -742,6 +820,7 @@ def main():
                "rmsnorm": bench_rmsnorm,
                "paged_decode": bench_paged_decode,
                "ssm_scan": bench_ssm_scan,
+               "cca_mix": bench_cca_mix,
                "train_step_mfu": bench_train_step_mfu}
     for name in sys.argv[1:] or list(benches):   # all, or the named ones
         benches[name](peak)
