@@ -89,19 +89,18 @@ class MeshTransformer(TinyTransformer):
                 q, k, vv = jnp.split(qkv, 3, axis=-1)
                 kp = jnp.where(own, kp.at[l, slots].set(k), kp)
                 vp = jnp.where(own, vp.at[l, slots].set(vv), vp)
-                qh = q.reshape(s_bucket, H, hd)
-                kh = k.reshape(s_bucket, H, hd)
-                vh = vv.reshape(s_bucket, H, hd)
-                # tp head shard: attend only this device's head slice
-                qh = lax.dynamic_slice_in_dim(qh, tp_i * Hl, Hl, 1)
-                kh = lax.dynamic_slice_in_dim(kh, tp_i * Hl, Hl, 1)
-                vh = lax.dynamic_slice_in_dim(vh, tp_i * Hl, Hl, 1)
-                attn = _prefill_attention(qh, kh, vh, use_flash)
+                # tp head shard: attend only this device's head slice,
+                # packed again as the projection packs all heads
+                local_qkv = jnp.concatenate(
+                    [lax.dynamic_slice_in_dim(part, tp_i * Hl * hd, Hl * hd,
+                                              1) for part in (q, k, vv)],
+                    axis=1)
+                attn = _prefill_attention(local_qkv, Hl, use_flash)
                 # gather heads back before the projection: the matmul then
                 # contracts the same (S, H*hd) operand as single-device,
                 # keeping greedy decode bit-identical across mesh shapes
                 attn = lax.all_gather(attn, "tp", axis=1, tiled=True)
-                x = x + attn.reshape(s_bucket, -1) @ params[f"wo{l}"]
+                x = x + attn @ params[f"wo{l}"]
                 h2 = _rms(x)
                 x = x + jax.nn.relu(h2 @ params[f"w1{l}"]) @ params[f"w2{l}"]
             last = _rms(x[length - 1])

@@ -98,21 +98,29 @@ def _rms(x):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
 
-def _prefill_attention(qh, kh, vh, use_flash: bool):
-    """Causal self-attention over one prompt's (S, H, hd) rows — the one
-    spelling the single-device and mesh prefill programs share. Heads
-    lead into the kernel: Mosaic tiles the LAST TWO block dims, so the
-    single-head (S, hd) kernel vmaps over a leading head axis but not
-    over the middle axis of (S, H, hd)."""
+def _prefill_attention(qkv, n_heads: int, use_flash: bool):
+    """Causal self-attention over one prompt's packed projection, (S, 3 x
+    heads x hd) as ``h @ wqkv`` made it, to (S, heads x hd) as ``wo``
+    contracts it: the one spelling the single-device and mesh prefill
+    programs share. The kernel is ONE call of the folded flash forward for
+    all heads: it reads a head where the projection put it (a column block,
+    lane-aligned at hd = 128) and is handed the packed rows three times, so
+    nothing is split or transposed around it."""
     import jax
+    import jax.numpy as jnp
 
     from brpc_tpu.tpu import pallas_ops
 
-    kernel = (pallas_ops.flash_attention if use_flash
-              else pallas_ops.attention_reference)
-    out = jax.vmap(functools.partial(kernel, causal=True))(
-        qh.transpose(1, 0, 2), kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
-    return out.transpose(1, 0, 2)
+    s, width = qkv.shape
+    hd = width // (3 * n_heads)
+    if use_flash:
+        return pallas_ops.flash_attention_rows(
+            qkv, qkv, qkv, n_heads, hd, heads_at=(0, n_heads, 2 * n_heads))
+    q, k, v = (x.reshape(s, n_heads, hd).transpose(1, 0, 2)
+               for x in jnp.split(qkv, 3, axis=-1))
+    out = jax.vmap(functools.partial(pallas_ops.attention_reference,
+                                     causal=True))(q, k, v)
+    return out.transpose(1, 0, 2).reshape(s, n_heads * hd)
 
 
 def _block_tables(tables, rows: int, n_pages: int) -> np.ndarray:
@@ -318,7 +326,7 @@ class TinyTransformer:
         import jax.numpy as jnp
 
         cfg = self.config
-        H, hd = cfg.n_heads, cfg.head_dim
+        H = cfg.n_heads
 
         def rms(x):
             return x * jax.lax.rsqrt(
@@ -332,15 +340,12 @@ class TinyTransformer:
                 with scope("kv_write"):
                     h = rms(x)
                     qkv = h @ params[f"wqkv{l}"]
-                    q, k, vv = jnp.split(qkv, 3, axis=-1)
+                    _, k, vv = jnp.split(qkv, 3, axis=-1)
                     kpool = kpool.at[l, slots].set(k)
                     vpool = vpool.at[l, slots].set(vv)
                 with scope("attention"):
-                    qh = q.reshape(s_bucket, H, hd)
-                    kh = k.reshape(s_bucket, H, hd)
-                    vh = vv.reshape(s_bucket, H, hd)
-                    attn = _prefill_attention(qh, kh, vh, use_flash)
-                    x = x + attn.reshape(s_bucket, -1) @ params[f"wo{l}"]
+                    attn = _prefill_attention(qkv, H, use_flash)
+                    x = x + attn @ params[f"wo{l}"]
                 with scope("mlp"):
                     h2 = rms(x)
                     x = x + (jax.nn.relu(h2 @ params[f"w1{l}"])

@@ -438,12 +438,14 @@ def _fit_block(s: int, want: int) -> int:
 
 
 def _pick_blocks(sq, sk, block_q, block_k, interpret, causal=False):
-    """Swept on a v5e (docs/round4-notes.md §1; not re-measured on the
-    current machine): causal peaks at 1024x1024
+    """Swept on a v5e (docs/round4-notes.md §1): causal peaks at 1024x1024
     (smaller k-tiles keep the block-granular skip tight), non-causal at
-    512x2048 (deepest k-stream per q residency). Explicit block sizes are
-    honored exactly (and rejected if they don't divide); defaults fall
-    back to the largest dividing block."""
+    512x2048 (deepest k-stream per q residency). The causal half was
+    measured again in PR 40 through the folded grid's rows-first call
+    (:func:`_rows_tiles`, float32, 16 heads of 128): the largest tile won at
+    every length from 128 to 1536. Explicit block sizes are honored exactly
+    (and rejected if they don't divide); defaults fall back to the largest
+    dividing block."""
     if interpret:
         want_q, want_k = 128, 128
     elif causal:
@@ -547,12 +549,27 @@ def _tri_row(t):
 
 def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                              m_scr, l_scr, acc_scr, *,
-                             b: int, bn: int, diag_split: bool):
+                             b: int, bn: int, diag_split: bool,
+                             one_tile: bool = False):
     import jax.experimental.pallas as pl
 
-    t = pl.program_id(1)
-    qi = _tri_row(t)
-    ki = t - qi * (qi + 1) // 2
+    if one_tile:
+        # the whole sequence is ONE tile a head: the triangle is its
+        # diagonal step, so nothing is looked up and `pl.when` on a plain
+        # bool keeps or drops a body while tracing (half the program to
+        # trace and lower)
+        qi = ki = 0
+    else:
+        t = pl.program_id(1)
+        qi = _tri_row(t)
+        ki = t - qi * (qi + 1) // 2
+    # a block is (bn, b, d), heads first, or (b, bn * d), the rows as a
+    # projection made them: head j is then a lane-aligned column block
+    d = acc_scr.shape[-1]
+    rows_first = len(q_ref.shape) == 2
+
+    def tile(ref, j):
+        return ref[:, j * d:(j + 1) * d] if rows_first else ref[j]
 
     @pl.when(ki == 0)
     def _init():
@@ -573,11 +590,12 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[j, rows] = m_new
 
     def _accumulate(masked: bool):
-        share = bn // k_ref.shape[0]   # query heads a K/V head of the block
+        # query heads a K/V head of the block (rows first: one)
+        share = 1 if rows_first else bn // k_ref.shape[0]
         for j in range(bn):
-            q = q_ref[j]
-            k = k_ref[j // share]
-            v = v_ref[j // share]
+            q = tile(q_ref, j)
+            k = tile(k_ref, j // share)
+            v = tile(v_ref, j // share)
             scale = 1.0 / float(q.shape[-1]) ** 0.5
             if not masked:
                 _update(j, slice(None),
@@ -622,7 +640,11 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         for j in range(bn):
             l = l_scr[j]
             safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[j] = (acc_scr[j] / safe).astype(o_ref.dtype)
+            out = (acc_scr[j] / safe).astype(o_ref.dtype)
+            if rows_first:
+                o_ref[:, j * d:(j + 1) * d] = out
+            else:
+                o_ref[j] = out
             lse_ref[j] = jnp.where(l == 0.0, NEG_INF,
                                    m_scr[j] + jnp.log(safe))
 
@@ -684,6 +706,123 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
         interpret=interpret,
         name="flash_fwd_folded",
     )(q, k, v)
+
+
+# The rows-first call of the folded forward: one sequence whose q, k, v lie
+# as a projection made them, (S, heads * d), a head a column block of d
+# lanes. A BlockSpec of (b, bn * d) columns reads a head where it lies and
+# writes the output as the next projection contracts it, so no caller
+# splits or transposes (at d = 128 the heads-first call costs seven copies
+# a layer around the kernel). Swept on a v5e (PR 40: 16 heads of 128,
+# float32, every 128th length to 1536, tiles of 128 .. S, 1 .. 16 heads a
+# step): the LARGEST tile won at every length, a one-tile 1024 at 78 us
+# against 117 us for tiles of 512. Heads share a step only where a tile is
+# small: at 128 rows four heads a step take 9 us where one takes 15, from
+# 512 rows on a second head gained 0-8% of the kernel, and every head more
+# is a copy more of the body to trace and lower in every bucket's program
+# (~0.1 s each on the chip's host, which `setup_s` pays). Mosaic's default
+# product of float32 tiles is ONE bfloat16 pass summed in float32 (the same
+# sweep: to the last digit the output of explicitly rounded operands),
+# which is the precision an XLA matmul has on the TPU.
+_ROWS_VMEM_LIMIT = 64 << 20   # scoped, of a v5e's 128 MiB
+_ROWS_VMEM_TILES = 40 << 20   # what _rows_tiles lets its own estimate reach
+_ROWS_A_STEP = 512            # rows of q a grid step, over its heads ...
+_ROWS_HEADS = 4               # ... and the most heads
+
+
+def _rows_tiles(s: int, n_heads: int, d: int, itemsize: int,
+                heads_at=(0, 0, 0)):
+    """(b, bn) of a rows-first call from the shape and the operands' bytes:
+    ``b`` the largest tile that divides ``s`` (``s`` itself, or a multiple
+    of 128 lanes for the (b, b) scores) whose working set fits, ``bn`` the
+    most heads a step (a power of two that divides ``n_heads`` and every
+    offset of ``heads_at``) within _ROWS_A_STEP rows and _ROWS_HEADS."""
+    def fits(b):
+        # scores and probabilities in float32 once; a head's q, k, v, o
+        # tiles held twice (the pipeline), its accumulator, and its (b, 1)
+        # statistics and lse blocks padded to a lane tile each
+        return (8 * b * b + b * (8 * d * itemsize + 4 * d + 4 * 512)
+                <= _ROWS_VMEM_TILES)
+
+    tiles = [s] + [b for b in range(s - s % 128, 0, -128) if s % b == 0]
+    b = next((b for b in tiles if fits(b)), tiles[-1])
+    bn = 1
+    while (2 * bn <= _ROWS_HEADS and 2 * bn * b <= _ROWS_A_STEP
+           and not any(x % (2 * bn) for x in (n_heads, *heads_at))):
+        bn *= 2
+    return b, bn
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "head_dim",
+                                             "heads_at", "interpret"))
+def flash_attention_rows(q, k, v, n_heads: int, head_dim: int,
+                         heads_at=(0, 0, 0), interpret: bool = None):
+    """Causal self-attention of ONE sequence in one call of the folded
+    forward, rows first. q, k, v: (S, >= n_heads * head_dim); head ``h`` of
+    q lies in columns ``(heads_at[0] + h) * head_dim ...``, of k and v from
+    ``heads_at[1]`` and ``heads_at[2]``, so the packed (S, 3 H d) output of
+    one projection is handed in three times and never split. Returns
+    (S, n_heads * head_dim) in q's dtype. The forward only. Where a head is
+    no lane-aligned column block (``head_dim % 128`` on a TPU) the heads go
+    first through copies into :func:`flash_attention_mha`."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    s, d = q.shape[0], head_dim
+    if not interpret and d % 128:
+        qh, kh, vh = (
+            x[:, at * d:(at + n_heads) * d].reshape(s, n_heads, d)
+            .transpose(1, 0, 2)[None] for x, at in zip((q, k, v), heads_at))
+        out = flash_attention_mha(qh, kh, vh, causal=True, interpret=False)
+        return out[0].transpose(1, 0, 2).reshape(s, n_heads * d)
+    b, bn = _rows_tiles(s, n_heads, d, q.dtype.itemsize, heads_at)
+    nq = s // b
+    q_at, k_at, v_at = (at // bn for at in heads_at)
+
+    def q_tile(t):   # of triangle step t
+        return 0 if nq == 1 else _tri_row(t)
+
+    def rows(at):
+        return lambda bi, t: (q_tile(t), at + bi)
+
+    def keys(at):
+        def index(bi, t):
+            qi = q_tile(t)
+            return (t - qi * (qi + 1) // 2, at + bi)
+        return index
+
+    params = (None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_ROWS_VMEM_LIMIT))
+    out, _ = pl.pallas_call(
+        functools.partial(_flash_fwd_folded_kernel, b=b, bn=bn,
+                          diag_split=False, one_tile=nq == 1),
+        grid=(n_heads // bn, nq * (nq + 1) // 2),
+        in_specs=[
+            pl.BlockSpec((b, bn * d), rows(q_at)),
+            pl.BlockSpec((b, bn * d), keys(k_at)),
+            pl.BlockSpec((b, bn * d), keys(v_at)),
+        ],
+        out_specs=[
+            pl.BlockSpec((b, bn * d), rows(0)),
+            pl.BlockSpec((bn, b, 1), lambda bi, t: (bi, q_tile(t), 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((s, n_heads * d), q.dtype),
+            jax.ShapeDtypeStruct((n_heads, s, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bn, b, 1), jnp.float32),
+            pltpu.VMEM((bn, b, 1), jnp.float32),
+            pltpu.VMEM((bn, b, d), jnp.float32),
+        ],
+        compiler_params=params,
+        interpret=interpret,
+        name="flash_attention_rows",
+    )(q, k, v)
+    return out
 
 
 def _flash_delta(o, do):

@@ -158,6 +158,28 @@ class TestMeshEquivalence:
         assert got == ref
         kv.assert_idle()
 
+    @pytest.mark.parametrize("plen", [5, 20, 40, 150])
+    def test_flash_prefill_token_identical_to_single_device(self, plen):
+        """Through the kernel (``attn="flash"``, interpreted here): the
+        mesh prefill hands the rows-first call ITS heads (tp=2 of 4, packed
+        again) and the single-device one all four; the first token and
+        what follows must not differ by a bit of an argmax."""
+        cfg = ModelConfig(vocab=256, d_model=64, n_heads=4, n_layers=2,
+                          attn="flash")
+        kvc = KVCacheConfig(block_size=16, num_blocks=64)
+        kv = ShardedKVCache(kvc, cfg.n_layers, cfg.kv_dim)
+        model = MeshTransformer(cfg, kv)
+        ref_kv = PagedKVCache(kvc, cfg.n_layers, cfg.kv_dim)
+        ref_model = TinyTransformer(cfg, ref_kv)
+        try:
+            assert model.tp == 2
+            got = _run_schedule(model, kv, [(plen, 3)])
+            ref = _run_schedule(ref_model, ref_kv, [(plen, 3)])
+        finally:
+            model.close()
+            ref_model.close()
+        assert got == ref and len(got[0]) == 3
+
     def test_dispatch_invariant_one_launch_one_sync_per_step(
             self, mesh_stack):
         """Every decode step costs exactly ONE fused program launch and

@@ -187,24 +187,33 @@ class TestKernelsOnChip:
 class TestServingAndRingOnChip:
     """The two paths no hardware test had, and the chip refused (PR 21)."""
 
-    @pytest.mark.parametrize("S", [16, 128, 384])
-    def test_serving_prefill_attention_layout(self, tpu_device, S):
-        # serving's layout and dtype: (S, H, hd) float32, heads in the
-        # MIDDLE — through the one spelling both prefill programs share
+    @pytest.mark.parametrize("H,hd,S", [
+        *((16, 128, S) for S in [16, 128, *range(384, 1537, 128)]),
+        (4, 16, 64), (4, 16, 256)])
+    def test_serving_prefill_attention_layout(self, tpu_device, H, hd, S):
+        # the serving cell's shape and dtype: one prompt's packed
+        # projection (S, 3 x 16 x 128) float32, through the one spelling
+        # both prefill programs share, at 16 and 128 rows and at each of
+        # prefill-closed's ten buckets; every head against the reference at
+        # the configuration's stated precision (the TPU's default: both
+        # operands of a product rounded to bfloat16, sums float32), which
+        # is what the reference einsum runs at here. Heads of 16 are no
+        # lane-aligned column block: they go heads first, through copies
         from brpc_tpu.serving.model import _prefill_attention
 
         rng = np.random.default_rng(S)
-        H, hd = 16, 128
-        q, k, v = (jnp.asarray(rng.normal(size=(S, H, hd)) * 0.5,
-                               dtype=jnp.float32) for _ in range(3))
-        flash = jax.jit(lambda q, k, v: _prefill_attention(q, k, v, True))
-        ref = jax.jit(lambda q, k, v: _prefill_attention(q, k, v, False))
-        out = flash(q, k, v)
-        assert out.shape == (S, H, hd) and out.dtype == jnp.float32
-        # the reference einsum runs at the TPU's default matmul precision
-        # (bf16 passes); the kernel's float32 dots do not
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
-                                   rtol=2e-2, atol=2e-2)
+        qkv = jnp.asarray(rng.normal(size=(S, 3 * H * hd)) * 0.5,
+                          dtype=jnp.float32)
+        flash = jax.jit(lambda x: _prefill_attention(x, H, True))
+        ref = jax.jit(lambda x: _prefill_attention(x, H, False))
+        out = flash(qkv)
+        assert out.shape == (S, H * hd) and out.dtype == jnp.float32
+        want = np.asarray(ref(qkv))
+        for h in range(H):
+            np.testing.assert_allclose(
+                np.asarray(out[:, h * hd:(h + 1) * hd]),
+                want[:, h * hd:(h + 1) * hd], rtol=1e-2, atol=1e-2,
+                err_msg=f"head {h}")
 
     @pytest.mark.parametrize("layout", ["contiguous", "zigzag"])
     def test_ring_flash_causal_under_shard_map(self, tpu_device, layout):
