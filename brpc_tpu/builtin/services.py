@@ -785,6 +785,18 @@ def serving_service(server, http: HttpMessage):
         if scan:
             out.append(f"  scan: launches={scan['launches']} "
                        f"rows={scan['rows']}")
+        # latent attention: the live latent rows a launch read (x layers;
+        # a decode step fetches each once) and the context rows later
+        # chunks of a prompt built K and V of again
+        mla = s.get("mla")
+        if mla:
+            dec, pre = mla["decode"], mla["prefill"]
+            out.append(
+                f"  mla: decode launches={dec['launches']} "
+                f"latent_rows={dec['latent_rows']} | prefill "
+                f"launches={pre['launches']} "
+                f"latent_rows={pre['latent_rows']} "
+                f"expanded_rows={pre['expanded_rows']}")
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

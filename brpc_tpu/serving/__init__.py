@@ -65,6 +65,13 @@ mechanism:
   convolutional attention inside a narrow latent, then one of 16 whole
   experts, or none, chosen by an MLP router whose state goes down the
   layers beside the residual stream; it continues a prefill too.
+- :mod:`brpc_tpu.serving.glm_model` — a ``glm4_moe_lite`` decoder over the
+  same manager with ONE array a page row (``v_dim = 0``): multi-head latent
+  attention whose page keeps the compressed latent and the shared rotary
+  key, expanded through the flash kernels for prefill and absorbed for
+  decode (``pallas_ops.mla_paged_decode`` reads each live row once, in
+  place), beside a chip's share of sigmoid-routed top-k experts chosen with
+  a selection bias, one shared expert and a leading dense layer.
 - :mod:`brpc_tpu.serving.speculative` — the speculative-decoding draft
   lane: host-side prompt-lookup drafting (zero weights, zero device
   work, lint-pinned) feeding the model's one fused ``verify_step``
@@ -120,6 +127,9 @@ def __getattr__(name):
     if name in ("ZayaConfig", "ZayaModel"):
         from brpc_tpu.serving import zaya_model
         return getattr(zaya_model, name)
+    if name in ("GlmMoeLiteConfig", "GlmMoeLiteModel"):
+        from brpc_tpu.serving import glm_model
+        return getattr(glm_model, name)
     raise AttributeError(name)
 
 
@@ -134,7 +144,7 @@ __all__ = [
     "HybridCacheConfig", "HybridStateCache", "HybridTable",
     "SambaYConfig", "SambaYModel", "HybridServingModel",
     "Cohere2MoeConfig", "Cohere2MoeModel", "JambaConfig", "JambaModel",
-    "ZayaConfig", "ZayaModel",
+    "ZayaConfig", "ZayaModel", "GlmMoeLiteConfig", "GlmMoeLiteModel",
     "AdaptiveK", "accept_longest_prefix", "draft_tokens",
     "QosConfig", "QosGovernor", "QosLimiter", "TenantScheduler",
 ]

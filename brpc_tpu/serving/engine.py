@@ -1336,6 +1336,19 @@ class ServingEngine:
         counters = getattr(self.model, "scan_counters", None)
         return dict(counters) if counters is not None else None
 
+    def _mla_snapshot(self) -> Optional[Dict[str, object]]:
+        """The model's latent-attention counters (serving/glm_model.py),
+        for a model whose pages hold one latent row a token: for decode and
+        prefill apart the ``launches``, the live ``latent_rows`` they read
+        (a launch's live context rows times its layers: what the decode
+        kernel fetches, once each) and, for prefill, the ``expanded_rows``
+        (context rows whose K and V a later chunk built again, times the
+        layers)."""
+        counters = getattr(self.model, "mla_counters", None)
+        if counters is None:
+            return None
+        return {phase: dict(c) for phase, c in counters.items()}
+
     def _host_snapshot(self, loop_spans) -> Dict[str, object]:
         """The host side of the process, every number cumulative since
         start so that two snapshots difference (docs/serving.md "Reading a
@@ -1432,6 +1445,7 @@ class ServingEngine:
             "decode": self._decode_snapshot(),
             "moe": self._moe_snapshot(),
             "scan": self._scan_snapshot(),
+            "mla": self._mla_snapshot(),
             "spec": (dict(self.spec_stats.snapshot(),
                           k_max=self.config.spec_k)
                      if self.spec_stats is not None else None),

@@ -39,6 +39,21 @@ empty and a slot is only a row's index), no window layer at all
 or asked for at admission, and a table's ``.window`` is empty) and pools
 narrower than float32 (``dtype``); the byte counts follow the pools' dtype.
 
+A page row need not be a K row and a V row of equal width. ``v_dim`` is the
+width of a row of the value pools where it is not ``kv_dim``'s; at ``v_dim =
+0`` a page row is ONE array of ``kv_dim`` values, whatever the model keeps
+of a token that is key and value at once (multi-head latent attention: the
+compressed latent and the shared rotary key side by side,
+``serving/glm_model.py``), the value pools are empty ``(layers, slots, 0)``
+arrays that cost nothing and go through every launch beside the others, and
+a block's bytes count ``kv_dim + v_dim`` values a row where two pools of
+equal width count ``2 * kv_dim``. (The device pool of such a page is
+allocated at whole 128-lane tiles, as ``PagedKVCache`` says; the byte counts
+stay those of the values a token holds.) Nothing of such a page is
+overwritten, but the manager declares ``state_overwritten`` all the same for
+now: prefix reuse, truncation and migration over one-array pages are not
+built (ROADMAP M5).
+
 ``state_overwritten = True`` is the declaration the rest of the serving
 plane reads: a ring row and a recurrent state are written over in place, and
 nothing records either at a block boundary yet, so ``build_prefix_cache``
@@ -103,7 +118,8 @@ class HybridStateCache:
     ``full_layers`` say how many layers of each kind the model has (SambaY:
     ONE full layer, whose K/V the architecture shares); ``dtype`` is the
     K/V pools' (float32 where it is left out; the recurrent state is always
-    float32)."""
+    float32); ``v_dim`` the width of a value row where it is not ``kv_dim``
+    (0: a page row is one array, the module docstring)."""
 
     # rings and recurrent states are written over in place: no prefix
     # reuse, speculation or migration over this manager
@@ -112,11 +128,13 @@ class HybridStateCache:
     def __init__(self, config: HybridCacheConfig, kv_dim: int,
                  window_layers: int, recurrent_layers: int = 0,
                  d_inner: int = 0, d_state: int = 0, d_conv: int = 1,
-                 store=None, full_layers: int = 1, dtype=None):
+                 store=None, full_layers: int = 1, dtype=None,
+                 v_dim: Optional[int] = None):
         import jax.numpy as jnp
 
         self.config = config
         self.kv_dim = kv_dim
+        self.v_dim = kv_dim if v_dim is None else v_dim
         self.recurrent_state = recurrent_layers > 0
         # blocks in a sequence's ring: none for a model with no window layer
         # (its ``window`` pool has no layer and hands nothing out)
@@ -124,12 +142,13 @@ class HybridStateCache:
         self.full = PagedKVCache(
             KVCacheConfig(config.block_size, config.num_blocks,
                           config.watermark),
-            full_layers, kv_dim, store=store, dtype=dtype)
+            full_layers, kv_dim, store=store, dtype=dtype, v_dim=v_dim)
         self.window = PagedKVCache(
             KVCacheConfig(config.block_size,
                           max(1, config.max_sequences * self.ring_blocks),
                           1.0),
-            window_layers, kv_dim, store=self.full.store, dtype=dtype)
+            window_layers, kv_dim, store=self.full.store, dtype=dtype,
+            v_dim=v_dim)
         self.store = self.full.store
         self._lock = threading.Lock()
         n = config.max_sequences
@@ -150,10 +169,11 @@ class HybridStateCache:
         self._retired: "collections.OrderedDict[int, HybridTable]" = \
             collections.OrderedDict()
         bs, f32 = config.block_size, 4
-        row = kv_dim * self.full.k_pool.dtype.itemsize
+        # a page row: its key values and its value values (none at v_dim 0)
+        row = (kv_dim + self.v_dim) * self.full.k_pool.dtype.itemsize
         self._block_bytes = {
-            "full": 2 * full_layers * bs * row,
-            "window": 2 * window_layers * bs * row}
+            "full": full_layers * bs * row,
+            "window": window_layers * bs * row}
         # the running state: what a live sequence needs
         self._slot_bytes = (recurrent_layers * d_inner * f32
                             * (d_state + d_conv - 1))
@@ -351,9 +371,13 @@ class HybridStateCache:
         FULL layer's pool, the kind that grows with the context (what
         ``Stats``, ``/serving`` and a sampler of occupancy have always
         read). ``full`` / ``window`` / ``slots``: each kind's used and
-        total. ``cache_bytes``: bytes of pages and slots held by live
-        sequences; ``cache_bytes_peak`` with ``tokens_at_peak`` (the live
-        context tokens at that moment) since the last ``reset_peak()``."""
+        total. ``page_row``: the values a token holds a layer in the key
+        and in the value pools (``{"k": kv_dim, "v": v_dim}``; ``v`` 0: a
+        page row is one array, key and value at once). ``cache_bytes``:
+        bytes of pages and slots held by live sequences, a page row counted
+        at ``k + v`` values; ``cache_bytes_peak`` with ``tokens_at_peak``
+        (the live context tokens at that moment) since the last
+        ``reset_peak()``."""
         with self._lock:
             snap = self.full.snapshot()
             snap["sequences"] = len(self._slot_of)
@@ -365,6 +389,7 @@ class HybridStateCache:
                               "ring_blocks": self.ring_blocks}
             snap["slots"] = {"used": len(self._slot_of),
                              "total": self.config.max_sequences}
+            snap["page_row"] = {"k": self.kv_dim, "v": self.v_dim}
             snap["window_blocks_recycled"] = self.window_blocks_recycled
             snap["cache_bytes"] = self._cache_bytes_locked()
             snap["cache_bytes_peak"] = self.cache_bytes_peak

@@ -73,6 +73,9 @@ g_serving_kv_blocks_used = PassiveStatus(
 g_serving_kv_blocks_used.prometheus_type = "gauge"
 
 
+LANES = 128   # values a row of the device's tiling holds
+
+
 class KVCacheFull(Exception):
     """Raised when the pool cannot satisfy an allocation (maps to
     EOVERCROWDED at the RPC surface)."""
@@ -96,13 +99,27 @@ class PagedKVCache:
     ``device_pools=False`` runs ledger-only: the full block/refcount/
     watermark/audit machinery with no device arrays of its own — how
     :class:`ShardedKVCache` gives every shard its own ledger while the
-    device residency lives in the stacked per-mesh pools."""
+    device residency lives in the stacked per-mesh pools.
+
+    ``v_dim``: the width of a row of ``v_pool`` where it is not ``kv_dim``'s.
+    At 0 a page row is ONE array: ``k_pool`` holds whatever the model keeps
+    of a token (a compressed latent that is key and value at once), and
+    ``v_pool`` is an empty ``(layers, slots, 0)`` array that costs nothing
+    and goes through every launch beside it. Such a pool's rows are
+    ALLOCATED at whole 128-lane tiles (``LANES``; the columns past
+    ``kv_dim`` hold nothing and are counted nowhere): a one-array page is
+    read where it lies by a kernel that copies pages itself, a copy out of
+    HBM takes whole tiles, and the device pads an array's rows to them in
+    HBM whatever the shape says, so the padded width costs the memory it
+    cost anyway."""
 
     def __init__(self, config: KVCacheConfig, layers: int, kv_dim: int,
-                 store=None, dtype=None, device_pools: bool = True):
+                 store=None, dtype=None, device_pools: bool = True,
+                 v_dim: Optional[int] = None):
         self.config = config
         self.layers = layers
         self.kv_dim = kv_dim
+        self.v_dim = kv_dim if v_dim is None else v_dim
         self._lock = threading.Lock()
         self.store = store
         self.k_pool = self.v_pool = None
@@ -120,9 +137,11 @@ class PagedKVCache:
             # ON the store's device from the start (committed, as every
             # launch returns them): a program whose first launch saw them
             # uncommitted is lowered a second time at its next one
-            self.k_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype,
+            # a one-array page's rows: whole lane tiles (the docstring)
+            wide = kv_dim if self.v_dim else -(-kv_dim // LANES) * LANES
+            self.k_pool = jnp.zeros((layers, slots, wide), dtype=dtype,
                                     device=self.store.device)
-            self.v_pool = jnp.zeros((layers, slots, kv_dim), dtype=dtype,
+            self.v_pool = jnp.zeros((layers, slots, self.v_dim), dtype=dtype,
                                     device=self.store.device)
             self.k_handle, _ = self.store.adopt(self.k_pool)
             self.v_handle, _ = self.store.adopt(self.v_pool)

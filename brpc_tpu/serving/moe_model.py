@@ -275,18 +275,26 @@ def attend_blocked(cfg, q, k, v, window: int):
     return out.reshape(s, cfg.n_heads, hd)
 
 
-def route(cfg, h, w_router, live):
+def route(cfg, h, w_router, live, bias=None, scale=None):
     """``sigmoid(h Wr)`` in float32 at ``highest``, the ``top_k`` largest,
-    weights renormalised over them. Returns (rows, k) expert ids (published
-    indices; ``-1`` for a row that is not live) and weights."""
+    weights renormalised over them. ``bias`` (experts,): a stored selection
+    bias added to the scores for the CHOICE only (the weights are the
+    chosen experts' scores without it); ``scale``: a factor on the
+    renormalised weights. Returns (rows, k) expert ids (published indices;
+    ``-1`` for a row that is not live) and weights."""
     import jax
     import jax.numpy as jnp
 
     s = jax.nn.sigmoid(jnp.dot(h, w_router,
                                precision=jax.lax.Precision.HIGHEST))
-    top, idx = jax.lax.top_k(s, cfg.top_k)
+    if bias is None:
+        top, idx = jax.lax.top_k(s, cfg.top_k)
+    else:
+        _, idx = jax.lax.top_k(s + bias, cfg.top_k)
+        top = jnp.take_along_axis(s, idx, axis=-1)
+    wts = top / jnp.sum(top, axis=-1, keepdims=True)
     return (jnp.where(live[:, None], idx, -1),
-            top / jnp.sum(top, axis=-1, keepdims=True))
+            wts if scale is None else wts * scale)
 
 
 def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int, first=None):

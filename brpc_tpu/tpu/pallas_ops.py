@@ -1259,6 +1259,165 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
 
 
 # ---------------------------------------------------------------------------
+# Paged decode over LATENT pages (multi-head latent attention, absorbed form)
+# — one row of H query heads per sequence against pages of ONE pool whose row
+# is key and value at once: ``[c | k_r]``, the compressed latent and the
+# shared rotary key side by side (576 values at kv_lora_rank 512 + 64 rotary
+# dims, in a row allocated at D = 640: the kernel copies pages itself, and a
+# copy out of HBM takes whole 128-lane tiles; the device pads a 576-wide
+# array's rows to 640 in any case). Every head reads the same row, so the scores of all heads are one
+# product ``(H, D) x (D, T)`` with no block-diagonal query, and the output
+# ``P (H, T) x tile[:, :d_v]`` reads the FIRST ``d_v`` columns of the same
+# tile: a live row is fetched once a layer. The grid is the batch's rows
+# alone; the kernel walks ITS row's pages in a loop whose trip count is the
+# row's own (a latent page is 18 KB at block 16, a fifth of a microsecond of
+# the chip's bandwidth for 128 positions: a grid step a chunk, as
+# ``paged_decode_attention`` takes them, would spend more on the steps past
+# a short row's end in a batch with a long one than on the rows). Pages are
+# copied where they lie in the pool (HBM) into one of two VMEM tiles, the
+# next chunk's while this one is computed; a chunk's pages past the row's
+# last are its last page again, masked. Operands are rounded as the
+# backend's default precision rounds a matmul's (bfloat16 on the TPU, not at
+# all in the interpreted kernel); the online softmax and every sum are
+# float32.
+# ---------------------------------------------------------------------------
+MLA_CHUNK = 1024   # positions a step of the kernel's loop
+
+
+def _mla_decode_kernel(layer_ref, table_ref, len_ref, q_ref, pool_ref, o_ref,
+                       buf, sem, m_scr, l_scr, acc_scr, *, pages: int,
+                       bs: int, n_pages: int, d_v: int, scale: float,
+                       op_dtype):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    length = len_ref[b]
+    layer = layer_ref[0]
+    H = acc_scr.shape[0]
+    T = pages * bs
+    last_page = (length - 1) // bs
+    n_chunks = (length + T - 1) // T
+
+    def copies(c, slot):
+        """The copies of chunk ``c``'s pages into tile ``slot``: a page past
+        the row's last is its last page again, so the whole tile is always
+        written (a tile row nothing wrote could hold anything, and a masked
+        score times a not-a-number is one), by a loop the compiler unrolls
+        (copies started from a loop of the row's own page count ran a third
+        slower: measured, PERF.md section 6, PR 41)."""
+        out = []
+        for i in range(pages):
+            page = table_ref[b * n_pages
+                             + jnp.minimum(c * pages + i, last_page)]
+            out.append(pltpu.make_async_copy(
+                pool_ref.at[layer, pl.ds(page * bs, bs)],
+                buf.at[slot, pl.ds(i * bs, bs)], sem.at[slot]))
+        return out
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    q = q_ref[0].astype(op_dtype)
+    for dma in copies(0, 0):
+        dma.start()
+
+    def chunk(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _ahead():
+            for dma in copies(c + 1, 1 - slot):
+                dma.start()
+
+        for dma in copies(c, slot):
+            dma.wait()
+        rows = buf[slot].astype(op_dtype)
+        s = _dot_f32(q, rows, trans_b=True) * scale
+        pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
+        # the first chunk always holds position 0, so m is finite from
+        # there on and a masked score's exp underflows to the correct 0
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + _dot_f32(p.astype(op_dtype),
+                                                   rows[:, :d_v])
+        m_scr[:] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+    o_ref[0] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_size", "d_v", "scale", "chunk",
+                                    "interpret", "operand_dtype"))
+def mla_paged_decode(q, pool, layer, block_tables, lengths, block_size: int,
+                     d_v: int, scale: float, chunk: int = MLA_CHUNK,
+                     interpret: bool = None, operand_dtype=None):
+    """Absorbed-form latent attention of ONE row of query heads per
+    sequence over its own paged prefix.
+
+    q: (B, H, D) float32, each head ``[q_nope Wuk' | q_rope | 0]``; pool:
+    the WHOLE pool, (layers, slots, D), a row ``[c | k_r | 0]`` (D a
+    multiple of 128: the rows' allocated width) and a page ``block_size``
+    consecutive slots; layer: int32 scalar (an operand, so
+    every layer of a program runs the same kernel); block_tables: (B, pages)
+    int32 physical block ids, as wide as the caller likes (the width costs
+    SMEM, not time); lengths: (B,) int32, each >= 1, the positions ``0 ..
+    length - 1`` a row attends to (pages past it are neither fetched nor
+    computed). Scores are ``q pool_row' * scale``; the values are the first
+    ``d_v`` columns of the same rows. ``chunk``: positions a step of the
+    kernel's loop. Returns (B, H, d_v) float32: each head's ``P c``, to be
+    taken through its ``Wuv``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    if operand_dtype is None:
+        operand_dtype = jnp.float32 if interpret else jnp.bfloat16
+    B, H, D = q.shape
+    bs = block_size
+    n_pages = block_tables.shape[1]
+    pages = max(1, chunk // bs)
+
+    def row(b, *_):
+        return (b, 0, 0)
+
+    kernel = functools.partial(_mla_decode_kernel, pages=pages, bs=bs,
+                               n_pages=n_pages, d_v=d_v, scale=scale,
+                               op_dtype=operand_dtype)
+    params = (None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel",)))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, D), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, d_v), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, D), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, d_v), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, d_v), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="mla_paged_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      block_tables.astype(jnp.int32).reshape(-1),
+      lengths.astype(jnp.int32), q, pool)
+
+
+# ---------------------------------------------------------------------------
 # Grouped matmul over the experts a chip holds (sparse-expert layers).
 #
 # Rows arrive SORTED by expert and padded so that every tile of ``block_rows``

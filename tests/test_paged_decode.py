@@ -491,6 +491,28 @@ for rows, di, n in json.loads(sys.argv[3]):
             "temp": c.memory_analysis().temp_size_in_bytes}
     except Exception as e:
         out[f"scan{rows}x{di}x{n}"] = {"error": str(e)[:500]}
+
+
+# the absorbed decode over latent pages (serving/glm_model.py) at the
+# latent cell's widths: 20 heads over rows of 576 values allocated at 640,
+# 24 layers x 12288 blocks of 16 bfloat16 rows; (rows, table pages)
+def m(q, pool, layer, tables, lengths):
+    return pallas_ops.mla_paged_decode(
+        q, pool, layer, tables, lengths, block_size=bs, d_v=512,
+        scale=1.0 / 16, interpret=False)
+
+
+latent = S((24, (12288 + 1) * bs, 640), jnp.bfloat16)
+for rows, pages in json.loads(sys.argv[4]):
+    try:
+        c = jax.jit(m).lower(
+            S((rows, 20, 640)), latent, S((), jnp.int32),
+            S((rows, pages), jnp.int32), S((rows,), jnp.int32)).compile()
+        out[f"mla{rows}x{pages}"] = {
+            "custom_call": "tpu_custom_call" in c.as_text(),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+    except Exception as e:
+        out[f"mla{rows}x{pages}"] = {"error": str(e)[:500]}
 print(json.dumps(out))
 """
 # decode gate-and-up and down at 32 rows (8 x 32 pairs + 16 x 15 pads),
@@ -502,6 +524,10 @@ _GMM_SHAPES = [(496, 16, 4096, 8192), (496, 16, 4096, 4096),
 # divide by a power of two past 512 and the longest
 _SCAN_SHAPES = [(2048, 5120, 16), (128, 5120, 16), (16, 5120, 16),
                 (512, 5120, 16), (2560, 5120, 16), (3072, 5120, 16)]
+
+
+# a step of `agent-steady` and its widest: rows x pages of the block table
+_MLA_SHAPES = [(16, 512), (32, 2048)]
 
 
 @pytest.fixture(scope="module")
@@ -521,7 +547,7 @@ def compiled_for_v5e():
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_SCRIPT,
          json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES),
-         json.dumps(_SCAN_SHAPES)],
+         json.dumps(_SCAN_SHAPES), json.dumps(_MLA_SHAPES)],
         env=env, capture_output=True, text=True, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert proc.returncode == 0 and lines, proc.stderr[-2000:]
@@ -568,3 +594,16 @@ def test_ssm_scan_compiles_for_the_v5e_at_the_cells_shapes(
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < 4 * rows * di * 4
+
+
+@pytest.mark.parametrize("rows,pages", _MLA_SHAPES)
+def test_mla_paged_decode_compiles_for_the_v5e_at_the_cells_shapes(
+        compiled_for_v5e, rows, pages):
+    """Mosaic accepts ``mla_paged_decode`` at the latent cell's widths (20
+    heads, rows allocated at 640 values of which 576 are held, 24 layers x
+    12288 blocks of 16 bfloat16 rows: its own copies out of HBM take whole
+    lane tiles), and XLA hands it the 6 GB pool without a copy."""
+    got = compiled_for_v5e[f"mla{rows}x{pages}"]
+    assert "error" not in got, got
+    assert got["custom_call"]
+    assert got["temp"] < 64 << 20

@@ -6,6 +6,7 @@ Run standalone (owns the chip):
     python tools/kernel_bench.py paged_decode   # only the named benches
     python tools/kernel_bench.py ssm_scan       # the prefill's selective scan
     python tools/kernel_bench.py cca_mix        # the zaya lane's decode-side mix
+    python tools/kernel_bench.py mla_paged_decode   # latent pages' decode kernel
     python tools/kernel_bench.py ring_hops      # needs four chips
 
 Timing methodology: marginal cost between two round counts inside ONE
@@ -782,6 +783,77 @@ def bench_cca_mix(peak: dict):
           f"that roofline)", flush=True)
 
 
+def bench_mla_paged_decode(peak: dict):
+    """The absorbed decode attention over latent pages alone, at the widths
+    of the `agent-steady` cell (20 heads over rows of 576 values allocated
+    at 640, 24 layers x 12288 blocks of 16 bfloat16 rows): the kernel
+    chained through its own output, against the least time of the rows'
+    lengths (each live row once, 1152 B, over the HBM peak), at several
+    chunks of the kernel's loop."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.tpu.pallas_ops import MLA_CHUNK, mla_paged_decode
+
+    H, lat, wide, d_v, bs, layers, blocks = 20, 576, 640, 512, 16, 24, 12288
+    key = jax.random.key(0)
+    pool = jax.random.normal(jax.random.fold_in(key, 1),
+                             (layers, (blocks + 1) * bs, wide), jnp.bfloat16)
+    rng = np.random.default_rng(0)
+    hbm = peak["hbm_bytes_per_s"]
+
+    def case(label, rows, lengths, table_rows, chunk):
+        lengths = np.asarray(lengths, np.int32)
+        pad = np.ones(rows - len(lengths), np.int32)
+        lens = jnp.asarray(np.concatenate([lengths, pad]))
+        ids = rng.permutation(np.arange(1, blocks + 1))
+        tables = np.zeros((rows, table_rows // bs), np.int32)
+        used = 0
+        for b, n in enumerate(lengths):
+            live = -(-int(n) // bs)
+            tables[b, :live] = ids[used:used + live]
+            used += live
+        tables = jnp.asarray(tables)
+        q0 = jax.random.normal(jax.random.fold_in(key, 3), (rows, H, wide),
+                               jnp.float32)
+
+        @functools.partial(jax.jit, static_argnames=("n",))
+        def loop(q, pl_, n: int):
+            def body(i, qc):
+                out = mla_paged_decode(qc, pl_, i % layers, tables, lens,
+                                       block_size=bs, d_v=d_v,
+                                       scale=1.0 / 16, chunk=chunk,
+                                       interpret=False)
+                # chained: the next query is a function of this output
+                return qc.at[:, :, :d_v].set(out * 0.5)
+            return jax.lax.fori_loop(0, n, body, q)
+
+        def run(n):
+            jax.device_get(loop(q0, pool, n)[0, 0, :1])
+
+        took = _marginal(run, 24, 240)
+        live_bytes = 2.0 * lat * float(lengths.sum())
+        print(f"# kernel mla_paged_decode {label} ({rows} rows, table "
+              f"{table_rows} positions, {int(lengths.sum())} live latent "
+              f"rows, chunk {chunk}): {took * 1e6:8.1f} us a layer, least "
+              f"{live_bytes / hbm * 1e6:7.1f} us = "
+              f"{live_bytes / took / hbm * 100:.0f}% of the roofline "
+              f"({live_bytes / took / 1e9:6.1f} GB/s of latent rows)",
+              flush=True)
+
+    agent = rng.lognormal(np.log(6144), 0.7, size=16).clip(1024, 24576)
+    for chunk in sorted({256, 512, MLA_CHUNK, 2048}):
+        case("the cell's shape", 16, [8192] * 16, 8192, chunk)
+    case("the cell's shape, the widest table", 16, [8192] * 16, 32768,
+         MLA_CHUNK)
+    case("agent-steady rows", 16, agent.astype(np.int32), 32768, MLA_CHUNK)
+    case("few short rows in a wide batch", 32, [1500, 3000, 900, 5000],
+         32768, MLA_CHUNK)
+
+
 def bench_train_step_mfu(peak: dict):
     """Single-chip train step of the flagship LM, reported BOTH ways:
     kernels ON (Pallas flash fwd+bwd, Pallas norm, fused xent — the
@@ -884,6 +956,7 @@ def main():
                "paged_decode": bench_paged_decode,
                "ssm_scan": bench_ssm_scan,
                "cca_mix": bench_cca_mix,
+               "mla_paged_decode": bench_mla_paged_decode,
                "train_step_mfu": bench_train_step_mfu}
     for name in sys.argv[1:] or list(benches):   # all, or the named ones
         benches[name](peak)
