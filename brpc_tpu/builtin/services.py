@@ -786,8 +786,9 @@ def serving_service(server, http: HttpMessage):
             out.append(f"  scan: launches={scan['launches']} "
                        f"rows={scan['rows']}")
         # latent attention: the live latent rows a launch read (x layers;
-        # a decode step fetches each once) and the context rows later
-        # chunks of a prompt built K and V of again
+        # a decode step fetches each once), the context rows later chunks
+        # of a prompt built K and V of again, and the rows of their context
+        # buckets the kernel never read, of the buckets' rows
         mla = s.get("mla")
         if mla:
             dec, pre = mla["decode"], mla["prefill"]
@@ -796,7 +797,9 @@ def serving_service(server, http: HttpMessage):
                 f"latent_rows={dec['latent_rows']} | prefill "
                 f"launches={pre['launches']} "
                 f"latent_rows={pre['latent_rows']} "
-                f"expanded_rows={pre['expanded_rows']}")
+                f"expanded_rows={pre['expanded_rows']} "
+                f"skipped_rows={pre['skipped_rows']} "
+                f"bucket_rows={pre['bucket_rows']}")
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

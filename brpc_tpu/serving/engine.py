@@ -1342,8 +1342,11 @@ class ServingEngine:
         prefill apart the ``launches``, the live ``latent_rows`` they read
         (a launch's live context rows times its layers: what the decode
         kernel fetches, once each) and, for prefill, the ``expanded_rows``
-        (context rows whose K and V a later chunk built again, times the
-        layers)."""
+        (context rows whose K and V a later chunk built again), the
+        ``skipped_rows`` (rows of a later chunk's context bucket past its
+        last row's tile, which the kernel never read) and the
+        ``bucket_rows`` (the later chunks' context buckets), each times the
+        layers."""
         counters = getattr(self.model, "mla_counters", None)
         if counters is None:
             return None

@@ -23,9 +23,11 @@ case); the programs pad what they write and ask to the pool's own width.
   from the rows as stored and attended to by the flash kernels at head width
   ``qk_nope + qk_rope = v_head_dim``. A prompt's first launch builds them
   from its own rows (``flash_attention_mha``, all heads in one call); a later
-  chunk (``CONTINUES_PREFILL``) reads rows ``[0, start + n)`` back through
-  the block table and builds them again, a head at a time, for the carry
-  kernel (``flash_attention_carry``): nothing but the latent is ever kept.
+  chunk (``CONTINUES_PREFILL``) reads its context's rows back through the
+  block table, and ONE kernel (``pallas_ops.mla_chunk_attention``) builds
+  every head's K and V again a tile of rows at a time in VMEM, only up to
+  the chunk's last row, and attends to them there: nothing but the latent
+  is ever kept.
 - *absorbed* (decode; the same numbers, no K or V built): ``Wkvb`` is staged
   split by head into ``Wuk_h`` and ``Wuv_h``; a query head becomes ``q~_h =
   [q_nope_h Wuk_h' | q_rope_h]``, its scores are ``q~_h [c | k_r]' / sqrt(qk
@@ -73,7 +75,8 @@ CHUNK_FLOOR = 512             # rows of the smallest padded chunk of a prompt
 DECODE_CONTEXT_FLOOR = 8192   # rows of the narrowest decode block table
 PREFILL_CONTEXT_FLOOR = 4096  # rows of a later chunk's smallest context
 COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
-MLA_COUNTERS = ("latent_rows", "expanded_rows")
+MLA_COUNTERS = ("latent_rows", "expanded_rows", "skipped_rows",
+                "bucket_rows")
 
 
 class GlmMoeLiteConfig:
@@ -227,7 +230,8 @@ class GlmMoeLiteModel(HybridServingModel):
         self.reset_moe_counters()
         # per phase: launches, the live latent rows they read (summed over
         # the launch's rows, x layers) and, for prefill, the context rows a
-        # later chunk built K and V of again (x layers)
+        # later chunk built K and V of again, the rows of its context bucket
+        # the kernel never read and the bucket's rows (each x layers)
         self.mla_counters = {
             phase: dict.fromkeys(("launches",) + MLA_COUNTERS, 0)
             for phase in ("decode", "prefill")}
@@ -469,6 +473,7 @@ class GlmMoeLiteModel(HybridServingModel):
         cfg = self.config
         bs, h, r = self.kv.block_size, cfg.n_heads, cfg.kv_lora
         first = l_bucket == c_bucket      # a prompt's first launch
+        tile = min(pallas_ops.MLA_CONTEXT_TILE, l_bucket)
         dt = _operand_dtype()
 
         def expand(w, i, rows):
@@ -493,34 +498,16 @@ class GlmMoeLiteModel(HybridServingModel):
             return attend_chunk_blocked(cfg, q, k.transpose(1, 0, 2),
                                         v.transpose(1, 0, 2), 0)
 
-        def attend_later(w, i, q, ctx, start):
+        def attend_later(w, i, q, ctx, start, length):
             if not use_flash:
                 k, v = expand(w, i, ctx)
                 return attend_chunk_blocked(cfg, q, k.transpose(1, 0, 2),
                                             v.transpose(1, 0, 2), start)
-            # a head at a time: its K and V of the whole context are built,
-            # attended to by the carry kernel (the chunk's first row its
-            # run-time offset; tiles past a query block's rows are skipped)
-            # and dropped
-            c, k_r = ctx[:, :r], ctx[:, r:cfg.kv_dim].astype(dt)
-            m0 = jnp.full((c_bucket, 1), pallas_ops.NEG_INF, jnp.float32)
-            l0 = jnp.zeros((c_bucket, 1), jnp.float32)
-            acc0 = jnp.zeros((c_bucket, cfg.v_dim), jnp.float32)
-
-            def head(args):
-                qh, wuk, wuv = args
-                with jax.named_scope("mla_expand"):
-                    k = jnp.concatenate([_mm(c, wuk).astype(dt), k_r],
-                                        axis=-1)
-                    v = _mm(c, wuv).astype(dt)
-                _m, l, acc = pallas_ops.flash_attention_carry(
-                    qh, k, v, m0, l0, acc0, start, 0, causal=True,
-                    block_q=min(512, c_bucket), block_k=512)
-                return acc / l
-
-            out = jax.lax.map(head, (q.astype(dt).transpose(1, 0, 2),
-                                     w["wuk"][i], w["wuv"][i]))
-            return out.transpose(1, 0, 2)
+            # every head in one kernel: its K and V built a tile of rows at
+            # a time in VMEM, only up to the chunk's last row
+            return pallas_ops.mla_chunk_attention(
+                q.astype(dt).reshape(c_bucket, h * cfg.head_dim), ctx,
+                w["wuk"][i], w["wuv"][i], start, length, block_k=tile)
 
         def impl(w, fk, fv, wk, wv, ssm, conv, tokens, table, slot, length,
                  start):
@@ -542,7 +529,7 @@ class GlmMoeLiteModel(HybridServingModel):
                         # whole blocks at a time, this chunk's among them
                         ctx = fk.reshape(len(fk), -1, bs, fk.shape[-1])[
                             i, table].reshape(l_bucket, fk.shape[-1])
-                        a = attend_later(w, i, q, ctx, start)
+                        a = attend_later(w, i, q, ctx, start, length)
                     return _mm(a.reshape(c_bucket, h * cfg.v_dim),
                                w["wo"][i]), fk
 
@@ -551,8 +538,14 @@ class GlmMoeLiteModel(HybridServingModel):
             with jax.named_scope("head"):
                 last = _rms(x[length - 1], _f32(w["lnf"]), cfg.eps)
                 nxt = jnp.argmax(_mm(last[None], w["head"].T)[0])
+            if first:
+                skipped = bucket = 0
+            else:   # the context rows the kernel never reads
+                bucket = l_bucket
+                skipped = (l_bucket - ((start + length - 1) // tile + 1)
+                           * tile if use_flash else 0)
             return fk, fv, wk, wv, ssm, conv, self._out(
-                nxt, counts, start + length, start)
+                nxt, counts, start + length, start, skipped, bucket)
 
         return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
 
@@ -603,7 +596,7 @@ class GlmMoeLiteModel(HybridServingModel):
                 last = _rms(x, _f32(w["lnf"]), cfg.eps)
                 nxt = jnp.argmax(_mm(last, w["head"].T), axis=-1)
             return fk, fv, wk, wv, ssm, conv, self._out(
-                nxt, counts, jnp.sum(jnp.where(live, lengths, 0)), 0)
+                nxt, counts, jnp.sum(jnp.where(live, lengths, 0)), 0, 0, 0)
 
         return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
 

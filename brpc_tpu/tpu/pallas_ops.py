@@ -1418,6 +1418,166 @@ def mla_paged_decode(q, pool, layer, block_tables, lengths, block_size: int,
 
 
 # ---------------------------------------------------------------------------
+# A LATER prefill chunk of multi-head latent attention over the latent rows
+# its context's pages keep, in the expanded form: each head's keys ``[c
+# Wuk_h | k_r]`` and values ``c Wuv_h`` are built a tile of context rows at a
+# time in VMEM and attended to there, so they never reach HBM, are built
+# once a head (not once a query tile) and only for the rows the chunk
+# attends to. The grid is (head, context tile): a head's query rows, its
+# output and its online-softmax state stay in VMEM across its tiles; a tile
+# past the one that holds the chunk's last row keeps that tile's block index
+# (the pipeline fetches nothing) and `pl.when` skips it. A tile's keys are
+# ONE product of the stored rows with a (row width, head width) matrix that
+# holds ``Wuk_h`` and, in the rotary rows, an identity (exact: a sum with one
+# non-zero term is the term), so no row is cut at an unaligned lane. Query
+# rows go in blocks, a block wholly before a tile's first row skipped, the
+# diagonal masked by absolute positions (the chunk's first row is a run-time
+# offset, any row). Keys and values of rows past the chunk's end are zeroed
+# (another sequence's rows or the scratch block: a masked score times a
+# not-a-number is one). Operands in the caller's dtype (bfloat16 on the TPU:
+# keys, values and probabilities rounded to it where the XLA form rounds
+# them), every sum and the online softmax float32.
+# ---------------------------------------------------------------------------
+# swept on a v5e (PR 42, `kernel_bench mla_chunk`: a chunk of 2048 rows at
+# 2048 .. 22528, tiles of 512 / 1024 x 512 / 1024 and 2048 query rows):
+# 1024 x 1024 fastest at every shape, 512 x 512 3-11% slower
+MLA_CONTEXT_TILE = 1024   # context rows a grid step
+MLA_QUERY_BLOCK = 1024    # query rows a block of a step
+
+
+def _mla_chunk_kernel(pos_ref, q_ref, ctx_ref, wk_ref, wv_ref, o_ref, k_scr,
+                      v_scr, m_scr, l_scr, acc_scr, *, bq: int, bk: int,
+                      r: int, scale: float):
+    import jax.experimental.pallas as pl
+
+    t = pl.program_id(1)
+    start, end = pos_ref[0], pos_ref[1]
+    last = (end - 1) // bk
+    k0 = t * bk
+
+    @pl.when(t == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def build(clip: bool):
+        k = _dot_f32(ctx_ref[:], wk_ref[0])
+        v = _dot_f32(ctx_ref[:, :r], wv_ref[0])
+        if clip:
+            k = jnp.where(k0 + jax.lax.broadcasted_iota(
+                jnp.int32, k.shape, 0) < end, k, 0.0)
+            v = jnp.where(k0 + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) < end, v, 0.0)
+        k_scr[:] = k.astype(k_scr.dtype)
+        v_scr[:] = v.astype(v_scr.dtype)
+
+    pl.when(t < last)(lambda: build(False))
+    pl.when(t == last)(lambda: build(True))
+
+    for j in range(q_ref.shape[0] // bq):
+        rows = slice(j * bq, (j + 1) * bq)
+        q0 = start + j * bq        # the block's first row's position
+
+        def accumulate(masked: bool, rows=rows, q0=q0):
+            s = _dot_f32(q_ref[rows, :], k_scr[:], trans_b=True) * scale
+            if masked:
+                q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            # every row's first tile holds key 0, so m is finite from there
+            # on and a masked score's exp underflows to the correct 0
+            m_prev = m_scr[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[rows, :] = (l_scr[rows, :] * alpha
+                              + jnp.sum(p, axis=-1, keepdims=True))
+            acc_scr[rows, :] = (acc_scr[rows, :] * alpha
+                                + _dot_f32(p.astype(v_scr.dtype), v_scr[:]))
+            m_scr[rows, :] = m_new
+
+        _causal_three_way(
+            jnp.logical_and(t <= last, q0 + bq - 1 >= k0),
+            jnp.logical_and(t <= last, q0 >= k0 + bk - 1), accumulate)
+
+    @pl.when(t == last)
+    def _finish():
+        o_ref[:] = (acc_scr[:] / l_scr[:]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+                                             "interpret"))
+def mla_chunk_attention(q, ctx, wuk, wuv, start, length,
+                        block_q: int = MLA_QUERY_BLOCK,
+                        block_k: int = MLA_CONTEXT_TILE,
+                        interpret: bool = None):
+    """Causal attention of a chunk's query rows over the latent rows of its
+    context, all heads in one call, in the expanded form.
+
+    q: (C, H * hd), row ``i`` the query heads of position ``start + i``, head
+    ``h`` in columns ``h * hd ...``, each ``[q_nope | q_rope]``; its dtype is
+    the operands' (and the output's). ctx: (L, D) the latent rows of
+    positions ``0 .. L - 1`` as the pages keep them, ``[c | k_r | 0]`` with
+    ``c`` ``r`` wide and ``k_r`` ``hd - nope``; wuk: (H, r, nope), wuv: (H,
+    r, v); start, length: int32 scalars. Row ``i`` attends to positions ``0
+    .. start + i``; rows of ``ctx`` from ``start + length`` on weigh nothing,
+    and whole tiles of them are never read. Scores are scaled by ``1 /
+    sqrt(hd)``. Returns (C, H * v), the rows as ``Wo`` contracts them; rows
+    from ``length`` on are pads."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not _on_tpu()
+    c = q.shape[0]
+    n_ctx, d = ctx.shape
+    h, r, nope = wuk.shape
+    d_v, hd, dt = wuv.shape[2], q.shape[1] // h, q.dtype
+    bq, bk = min(block_q, c), min(block_k, n_ctx)
+    if c % bq or n_ctx % bk:
+        raise ValueError(f"rows ({c}, {n_ctx}) must divide blocks "
+                         f"({bq}, {bk})")
+    # a head's keys from the stored row in one product: Wuk_h over c, the
+    # identity over k_r, zeros over the pad
+    wk = jnp.zeros((h, d, hd), dt).at[:, :r, :nope].set(wuk.astype(dt))
+    wk = wk.at[:, r:r + hd - nope, nope:].set(jnp.eye(hd - nope, dtype=dt))
+    pos = jnp.stack([jnp.asarray(start, jnp.int32),
+                     jnp.asarray(start + length, jnp.int32)])
+
+    def tile(_h, t, pos):        # never past the chunk's last row's tile
+        return (jnp.minimum(t, (pos[1] - 1) // bk), 0)
+
+    params = (None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_ROWS_VMEM_LIMIT))
+    return pl.pallas_call(
+        functools.partial(_mla_chunk_kernel, bq=bq, bk=bk, r=r,
+                          scale=1.0 / math.sqrt(hd)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h, n_ctx // bk),
+            in_specs=[pl.BlockSpec((c, hd), lambda i, _t, _p: (0, i)),
+                      pl.BlockSpec((bk, d), tile),
+                      pl.BlockSpec((1, d, hd), lambda i, _t, _p: (i, 0, 0)),
+                      pl.BlockSpec((1, r, d_v),
+                                   lambda i, _t, _p: (i, 0, 0))],
+            out_specs=pl.BlockSpec((c, d_v), lambda i, _t, _p: (0, i)),
+            scratch_shapes=[
+                pltpu.VMEM((bk, hd), dt),
+                pltpu.VMEM((bk, d_v), dt),
+                pltpu.VMEM((c, 1), jnp.float32),
+                pltpu.VMEM((c, 1), jnp.float32),
+                pltpu.VMEM((c, d_v), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((c, h * d_v), dt),
+        compiler_params=params,
+        interpret=interpret,
+        name="mla_chunk_attention",
+    )(pos, q, ctx.astype(dt), wk, wuv.astype(dt))
+
+
+# ---------------------------------------------------------------------------
 # Grouped matmul over the experts a chip holds (sparse-expert layers).
 #
 # Rows arrive SORTED by expert and padded so that every tile of ``block_rows``

@@ -513,6 +513,28 @@ for rows, pages in json.loads(sys.argv[4]):
             "temp": c.memory_analysis().temp_size_in_bytes}
     except Exception as e:
         out[f"mla{rows}x{pages}"] = {"error": str(e)[:500]}
+
+
+# a later prefill chunk over latent rows (serving/glm_model.py) at the
+# latent cell's widths: 20 heads of 256 (192 + 64 rotary), latent 512, rows
+# allocated at 640, bfloat16; (chunk rows, context rows)
+def mc(q, ctx, wuk, wuv, start, length):
+    return pallas_ops.mla_chunk_attention(q, ctx, wuk, wuv, start, length,
+                                          interpret=False)
+
+
+bf = jnp.bfloat16
+for rows, ctx_rows in json.loads(sys.argv[5]):
+    try:
+        c = jax.jit(mc).lower(
+            S((rows, 20 * 256), bf), S((ctx_rows, 640), bf),
+            S((20, 512, 192), bf), S((20, 512, 256), bf),
+            S((), jnp.int32), S((), jnp.int32)).compile()
+        out[f"mlachunk{rows}x{ctx_rows}"] = {
+            "custom_call": "tpu_custom_call" in c.as_text(),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+    except Exception as e:
+        out[f"mlachunk{rows}x{ctx_rows}"] = {"error": str(e)[:500]}
 print(json.dumps(out))
 """
 # decode gate-and-up and down at 32 rows (8 x 32 pairs + 16 x 15 pads),
@@ -528,6 +550,9 @@ _SCAN_SHAPES = [(2048, 5120, 16), (128, 5120, 16), (16, 5120, 16),
 
 # a step of `agent-steady` and its widest: rows x pages of the block table
 _MLA_SHAPES = [(16, 512), (32, 2048)]
+# later chunks of `agent-steady`: the smallest bucket, and a full chunk at
+# the narrowest and the widest context
+_MLA_CHUNK_SHAPES = [(512, 4096), (2048, 4096), (2048, 32768)]
 
 
 @pytest.fixture(scope="module")
@@ -547,7 +572,8 @@ def compiled_for_v5e():
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_SCRIPT,
          json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES),
-         json.dumps(_SCAN_SHAPES), json.dumps(_MLA_SHAPES)],
+         json.dumps(_SCAN_SHAPES), json.dumps(_MLA_SHAPES),
+         json.dumps(_MLA_CHUNK_SHAPES)],
         env=env, capture_output=True, text=True, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert proc.returncode == 0 and lines, proc.stderr[-2000:]
@@ -607,3 +633,16 @@ def test_mla_paged_decode_compiles_for_the_v5e_at_the_cells_shapes(
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < 64 << 20
+
+
+@pytest.mark.parametrize("rows,ctx_rows", _MLA_CHUNK_SHAPES)
+def test_mla_chunk_attention_compiles_for_the_v5e_at_the_cells_shapes(
+        compiled_for_v5e, rows, ctx_rows):
+    """Mosaic accepts ``mla_chunk_attention`` at the latent cell's widths
+    (20 heads of 256, latent 512 in rows allocated at 640, bfloat16) within
+    its VMEM, and XLA builds no per-head K or V around it: no temporary
+    near one head's keys over the context."""
+    got = compiled_for_v5e[f"mlachunk{rows}x{ctx_rows}"]
+    assert "error" not in got, got
+    assert got["custom_call"]
+    assert got["temp"] < ctx_rows * 256 * 2

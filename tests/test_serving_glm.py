@@ -89,8 +89,9 @@ def _ref(host, mode="float32", m=M):
         for k, v in host.items()})
 
 
-def _stand(weights=None, attn="reference", m=M, **cache):
-    cfg = GlmMoeLiteConfig(**m, max_context=1024, seed=SEED, attn=attn)
+def _stand(weights=None, attn="reference", m=M, max_context=1024, **cache):
+    cfg = GlmMoeLiteConfig(**m, max_context=max_context, seed=SEED,
+                           attn=attn)
     cache = dict(dict(block_size=BS, num_blocks=96, max_sequences=4), **cache)
     kv = cfg.cache(HybridCacheConfig(**cache))
     return GlmMoeLiteModel(cfg, kv, weights=weights), kv
@@ -202,7 +203,8 @@ def test_the_counters_count_pairs_and_latent_rows(world):
     assert 0 < moe["prefill"]["pairs"] <= 4 * sum(LENS) * (layers - 1)
     assert mla["prefill"] == {"launches": len(LENS),
                               "latent_rows": sum(LENS) * layers,
-                              "expanded_rows": 0}
+                              "expanded_rows": 0, "skipped_rows": 0,
+                              "bucket_rows": 0}
     now = model.mla_counters["decode"]
     assert now["launches"] == NEW - 1
     assert now["latent_rows"] == layers * sum(
@@ -246,8 +248,16 @@ def test_chunked_prefill_agrees_with_whole_prefill(world, cuts, attn):
         kv.free_sequence(9)
 
 
-def test_later_chunks_count_the_rows_they_expand_again(world):
-    model, kv = world["model"], world["kv"]
+@pytest.mark.parametrize("attn,skipped", [("reference", 0),
+                                           ("flash", 2 * (4096 - 1024))])
+def test_later_chunks_count_the_rows_they_expand_again(world, attn, skipped):
+    """Two later chunks, each over a context bucket of 4096 rows (the floor
+    at a context of 4096): the blocked path builds K and V of all of them;
+    the kernel reads the first tile of ``MLA_CONTEXT_TILE`` rows alone (both
+    chunks end at or before row 150), and ``skipped_rows`` counts the
+    rest."""
+    assert pallas_ops.MLA_CONTEXT_TILE == 1024
+    model, kv = _stand(weights=world["host"], attn=attn, max_context=4096)
     p = world["prompts"][2]
     before = dict(model.mla_counters["prefill"])
     t = kv.alloc_sequence(9, len(p))
@@ -262,6 +272,8 @@ def test_later_chunks_count_the_rows_they_expand_again(world):
         == (64 + 128) * layers
     assert after["latent_rows"] - before["latent_rows"] \
         == (64 + 128 + len(p)) * layers
+    assert after["bucket_rows"] - before["bucket_rows"] == 2 * 4096 * layers
+    assert after["skipped_rows"] - before["skipped_rows"] == skipped * layers
 
 
 def test_a_chunk_that_reads_no_context_is_far_outside(world):
@@ -393,6 +405,59 @@ def test_mla_paged_decode_ignores_what_lies_past_a_rows_length():
         block_size=BS, d_v=96, scale=0.25, chunk=32)
     assert np.isfinite(np.asarray(got)).all()
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _plain_chunk(q, ctx, wuk, wuv, start, length):
+    """A chunk's rows over K and V built from the latent rows ``[0, start +
+    length)`` in float64, a softmax a head: (C, H x v)."""
+    h, r, nope = wuk.shape
+    hd = q.shape[1] // h
+    end, c = start + length, q.shape[0]
+    rows = np.asarray(ctx, np.float64)[:end]
+    live = start + np.arange(c)[:, None] >= np.arange(end)[None, :]
+    out = []
+    for j in range(h):
+        k = np.concatenate([rows[:, :r] @ wuk[j], rows[:, r:r + hd - nope]],
+                           axis=1)
+        s = np.asarray(q, np.float64)[:, j * hd:(j + 1) * hd] @ k.T
+        s = np.where(live, s / np.sqrt(hd), -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out.append((p / p.sum(-1, keepdims=True)) @ (rows[:, :r] @ wuv[j]))
+    return np.concatenate(out, axis=1)
+
+
+@pytest.mark.parametrize("heads,c,n_ctx,start,length", [
+    (5, 64, 256, 128, 64),     # several heads, the chunk on a tile's edge
+    (3, 64, 256, 37, 64),      # a chunk from a row inside a tile
+    (3, 64, 256, 100, 40),     # pad rows past the chunk's length
+    (3, 64, 256, 150, 1),      # a chunk of one row
+    (3, 64, 1024, 70, 50),     # a bucket far wider than the chunk's end
+], ids=["heads", "unaligned_start", "pad_rows", "one_row", "wide_bucket"])
+def test_mla_chunk_attention_equals_a_softmax_over_built_keys(
+        heads, c, n_ctx, start, length):
+    """The later chunk's kernel (interpreted) at tiles of 64 context rows
+    and blocks of 32 query rows, against K and V built from the latent rows
+    in float64. Every row of ``ctx`` past the chunk's end is not-a-number:
+    whole tiles of them are never read and the rest weigh nothing, so every
+    output row, pads too, is finite."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(heads * 1000 + start)
+    r, nope, rot, d_v, d = 32, 16, 8, 24, 128
+    ctx = rng.standard_normal((n_ctx, d)).astype(np.float32)
+    ctx[:, r + rot:] = 0.0
+    ctx[start + length:] = np.nan
+    q = rng.standard_normal((c, heads * (nope + rot))).astype(np.float32)
+    wuk = (rng.standard_normal((heads, r, nope)) / np.sqrt(r)).astype(
+        np.float32)
+    wuv = (rng.standard_normal((heads, r, d_v)) / np.sqrt(r)).astype(
+        np.float32)
+    got = np.asarray(pallas_ops.mla_chunk_attention(
+        jnp.asarray(q), jnp.asarray(ctx), jnp.asarray(wuk), jnp.asarray(wuv),
+        start, length, block_q=32, block_k=64))
+    assert got.shape == (c, heads * d_v) and np.isfinite(got).all()
+    want = _plain_chunk(q, ctx, wuk, wuv, start, length)
+    assert np.abs(got[:length] - want[:length]).max() <= KERNEL_TOL
 
 
 # ------------------------------------------------------------------ the router
@@ -833,7 +898,10 @@ def test_a_long_prompt_goes_a_chunk_a_step_beside_the_decode_rows(
     assert pre == {"launches": 7,
                    "latent_rows": layers * (LENS[0] + 128 + 256 + 384 + 512
                                             + 640 + 700),
-                   "expanded_rows": layers * (128 + 256 + 384 + 512 + 640)}
+                   "expanded_rows": layers * (128 + 256 + 384 + 512 + 640),
+                   # five later chunks over buckets of 1024 rows; the
+                   # blocked path (the CPU's default) reads all of them
+                   "skipped_rows": 0, "bucket_rows": layers * 5 * 1024}
     dec = {k: mla["decode"][k] - before["decode"][k] for k in mla["decode"]}
     assert dec["launches"] >= 39 and dec["expanded_rows"] == 0
     assert dec["latent_rows"] == layers * (
@@ -843,3 +911,5 @@ def test_a_long_prompt_goes_a_chunk_a_step_beside_the_decode_rows(
     assert len(lines) == 2
     assert f"latent_rows={mla['decode']['latent_rows']}" in lines[1]
     assert f"expanded_rows={mla['prefill']['expanded_rows']}" in lines[1]
+    assert f"skipped_rows={mla['prefill']['skipped_rows']}" in lines[1]
+    assert f"bucket_rows={mla['prefill']['bucket_rows']}" in lines[1]

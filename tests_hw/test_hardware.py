@@ -183,6 +183,52 @@ class TestKernelsOnChip:
                            interpret=False)
         np.testing.assert_array_equal(np.asarray(s_end), np.asarray(live))
 
+    @pytest.mark.parametrize("start,bucket", [(6016, 8192), (22528, 32768)])
+    def test_mla_chunk_attention_on_chip(self, tpu_device, start, bucket):
+        # `agent-steady`'s later chunk at the published widths (20 heads of
+        # 192 + 64 rotary, latent 512 in rows of 640, values 256,
+        # bfloat16): a chunk of 2048 rows, its last 37 pads, from a row
+        # inside a tile and from a tile's edge in the widest bucket; every
+        # row past the chunk's end is not-a-number. Against the XLA
+        # expanded form a head at a time: K and V built with bfloat16
+        # operands and float32 sums, rounded to bfloat16, softmax float32
+        from brpc_tpu.tpu.pallas_ops import mla_chunk_attention
+
+        H, r, nope, hd, d_v, c, n = 20, 512, 192, 256, 256, 2048, 2011
+        bf, f32 = jnp.bfloat16, jnp.float32
+        key = jax.random.key(start)
+        draw = lambda i, shape: jax.random.normal(  # noqa: E731
+            jax.random.fold_in(key, i), shape, f32)
+        ctx = draw(0, (bucket, 640)).at[:, r + hd - nope:].set(0.0)
+        ctx = ctx.at[start + n:].set(jnp.nan).astype(bf)
+        wuk = (draw(1, (H, r, nope)) / r ** 0.5).astype(bf)
+        wuv = (draw(2, (H, r, d_v)) / r ** 0.5).astype(bf)
+        q = draw(3, (c, H * hd)).astype(bf)
+        got = mla_chunk_attention(q, ctx, wuk, wuv, start, n,
+                                  interpret=False)
+        rows = ctx[:start + n]
+        live = (start + jnp.arange(c)[:, None]
+                >= jnp.arange(start + n)[None, :])
+
+        @jax.jit
+        def want(qh, wk, wv):
+            k = jnp.concatenate([jnp.matmul(
+                rows[:, :r], wk, preferred_element_type=f32).astype(bf),
+                rows[:, r:r + hd - nope]], axis=-1)
+            v = jnp.matmul(rows[:, :r], wv,
+                           preferred_element_type=f32).astype(bf)
+            s = jnp.matmul(qh, k.T, preferred_element_type=f32) / hd ** 0.5
+            p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
+            return jnp.matmul(p, v.astype(f32))
+
+        got = np.asarray(got.astype(f32))
+        assert np.isfinite(got).all()
+        for h in range(H):
+            ref = np.asarray(want(q[:, h * hd:(h + 1) * hd], wuk[h], wuv[h]))
+            out = got[:n, h * d_v:(h + 1) * d_v]
+            gap = np.linalg.norm(out - ref[:n]) / np.linalg.norm(ref[:n])
+            assert gap < 1e-2, (h, gap)
+
 
 class TestServingAndRingOnChip:
     """The two paths no hardware test had, and the chip refused (PR 21)."""

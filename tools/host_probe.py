@@ -15,7 +15,11 @@ prints, after the run's own line, ONE line ``HOSTPROBE {...}`` with:
 - ``loop_self_over_wall``: the loop's self times over ``wall_us`` (every
   instant of the loop lies in a span: 0.995 or more);
 - ``threads_cpu_s``: CPU seconds by thread role in the window;
-- ``lane_wait``, ``gc``: the window's differences.
+- ``lane_wait``, ``gc``: the window's differences;
+- ``mla_prefill``: where the program counts latent attention
+  (``snapshot()["mla"]``), the window's difference of its prefill counters
+  and ``skipped_share``, the rows of the later chunks' context buckets that
+  the kernel never read over the buckets' rows.
 
 With ``--out DIR`` the two ``host`` groups are written to ``DIR/<tag>.json``.
 Run it from the root of the checkout to measure, which may be another than
@@ -138,6 +142,13 @@ def main(argv=None) -> int:
         probe.update(_difference(a["host"], b["host"], probe["steps"]))
         hosts = {"host0": a["host"], "host1": b["host"],
                  "steps": [a["steps"], b["steps"]]}
+    pre0 = (a.get("mla") or {}).get("prefill")
+    pre1 = (b.get("mla") or {}).get("prefill")
+    if pre0 and pre1:                    # a latent-attention model
+        mla = {k: v - pre0.get(k, 0) for k, v in pre1.items()}
+        if mla.get("bucket_rows"):
+            mla["skipped_share"] = mla["skipped_rows"] / mla["bucket_rows"]
+        probe["mla_prefill"] = mla
     if args.out:
         os.makedirs(args.out, exist_ok=True)
         with open(os.path.join(args.out, args.tag + ".json"), "w") as f:

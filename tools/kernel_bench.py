@@ -7,6 +7,7 @@ Run standalone (owns the chip):
     python tools/kernel_bench.py ssm_scan       # the prefill's selective scan
     python tools/kernel_bench.py cca_mix        # the zaya lane's decode-side mix
     python tools/kernel_bench.py mla_paged_decode   # latent pages' decode kernel
+    python tools/kernel_bench.py mla_chunk      # a later chunk's latent attention
     python tools/kernel_bench.py ring_hops      # needs four chips
 
 Timing methodology: marginal cost between two round counts inside ONE
@@ -854,6 +855,101 @@ def bench_mla_paged_decode(peak: dict):
          32768, MLA_CHUNK)
 
 
+def bench_mla_chunk(peak: dict):
+    """A later prefill chunk's latent attention alone, at the widths of the
+    `agent-steady` cell (20 heads of 192 + 64 rotary, latent 512 in rows
+    allocated at 640, values 256, bfloat16): the kernel
+    (``pallas_ops.mla_chunk_attention``) at several tiles beside the path it
+    replaced (PR 41's, copied here: a ``lax.map`` over the heads of the XLA
+    expansion of the whole context bucket, then ``flash_attention_carry`` at
+    512 x 512), each chained through its own output. A chunk of 2048 rows
+    at 2048 / 6144 / 14336 / 22528 in buckets of 4096 / 8192 / 16384 /
+    32768. Against the least operations: (query, key) pairs x 1024 x H and
+    the rows up to the chunk's end x 2 x 512 x 448 x H (their K and V built
+    once)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.tpu.pallas_ops import (NEG_INF, flash_attention_carry,
+                                         mla_chunk_attention)
+
+    H, r, nope, hd, d_v, wide, c = 20, 512, 192, 256, 256, 640, 2048
+    bf = jnp.bfloat16
+    key = jax.random.key(0)
+    wuk = (jax.random.normal(jax.random.fold_in(key, 1), (H, r, nope))
+           / r ** 0.5).astype(bf)
+    wuv = (jax.random.normal(jax.random.fold_in(key, 2), (H, r, d_v))
+           / r ** 0.5).astype(bf)
+    q0 = jax.random.normal(jax.random.fold_in(key, 3), (c, H * hd), bf)
+
+    def parent(q, ctx, start):
+        lat, k_r = ctx[:, :r], ctx[:, r:r + hd - nope]
+        m0 = jnp.full((c, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((c, 1), jnp.float32)
+        acc0 = jnp.zeros((c, d_v), jnp.float32)
+
+        def head(args):
+            qh, wk, wv = args
+            k = jnp.concatenate([jnp.matmul(
+                lat, wk, preferred_element_type=jnp.float32).astype(bf),
+                k_r], axis=-1)
+            v = jnp.matmul(lat, wv,
+                           preferred_element_type=jnp.float32).astype(bf)
+            _m, l, acc = flash_attention_carry(
+                qh, k, v, m0, l0, acc0, start, 0, causal=True,
+                block_q=512, block_k=512, interpret=False)
+            return acc / l
+
+        out = jax.lax.map(head, (q.reshape(c, H, hd).transpose(1, 0, 2),
+                                 wuk, wuv))
+        return out.transpose(1, 0, 2).reshape(c, H * d_v).astype(bf)
+
+    def kernel(bq, bk):
+        return lambda q, ctx, start: mla_chunk_attention(
+            q, ctx, wuk, wuv, start, c, block_q=bq, block_k=bk,
+            interpret=False)
+
+    paths = [("parent's path", parent)] + [
+        (f"kernel {bq} x {bk}", kernel(bq, bk))
+        for bq, bk in ((512, 512), (1024, 512), (2048, 512), (512, 1024),
+                       (1024, 1024))]
+    total = {name: 0.0 for name, _ in paths}
+    least_total = 0.0
+    for start, bucket in ((2048, 4096), (6144, 8192), (14336, 16384),
+                          (22528, 32768)):
+        ctx = jax.random.normal(jax.random.fold_in(key, bucket),
+                                (bucket, wide), jnp.float32)
+        ctx = ctx.at[:, r + hd - nope:].set(0.0).astype(bf)
+        pairs = c * start + c * (c + 1) // 2
+        flops = H * (pairs * 4 * hd + (start + c) * 2 * r * (nope + d_v))
+        least = flops / peak["bf16_flops"]
+        least_total += least
+        for name, fn in paths:
+            @functools.partial(jax.jit, static_argnames=("n",))
+            def loop(q, n: int, fn=fn):
+                return jax.lax.fori_loop(
+                    0, n, lambda _i, qc: fn(qc, ctx, jnp.int32(start)), q)
+
+            def run(n):
+                jax.device_get(loop(q0, n)[0, :1])
+
+            took = _marginal(run, 2, 10, reps=3)
+            total[name] += took
+            print(f"# kernel mla_chunk {name}: chunk {c} at {start} in a "
+                  f"bucket of {bucket}: {took * 1e3:8.3f} ms a layer, "
+                  f"least {least * 1e3:.3f} ms = "
+                  f"{least / took * 100:.1f}% of the MXU peak "
+                  f"({flops / 1e12:.2f} TFLOP)", flush=True)
+    base = total["parent's path"]
+    for name, took in total.items():
+        print(f"# kernel mla_chunk {name}, summed over the four shapes: "
+              f"{took * 1e3:8.3f} ms a layer, {took / base * 100:.1f}% of "
+              f"the parent's path, {least_total / took * 100:.1f}% of the "
+              f"MXU peak", flush=True)
+
+
 def bench_train_step_mfu(peak: dict):
     """Single-chip train step of the flagship LM, reported BOTH ways:
     kernels ON (Pallas flash fwd+bwd, Pallas norm, fused xent — the
@@ -957,6 +1053,7 @@ def main():
                "ssm_scan": bench_ssm_scan,
                "cca_mix": bench_cca_mix,
                "mla_paged_decode": bench_mla_paged_decode,
+               "mla_chunk": bench_mla_chunk,
                "train_step_mfu": bench_train_step_mfu}
     for name in sys.argv[1:] or list(benches):   # all, or the named ones
         benches[name](peak)
