@@ -387,6 +387,45 @@ def _attend_banded(cfg, qh, kh, vh):
     return a.reshape((s,) + a.shape[2:])
 
 
+def _block_context(pool, layer: int, blocks, block_size: int):
+    """A decode batch's rows of ONE layer of a pool, whole blocks at a time
+    (a block is ``block_size`` contiguous rows, gathered three times as fast
+    as its rows one by one, measured), the layer inside the gather's index:
+    ``pool[layer]`` first is a slice of the whole layer. ``blocks`` (B, n)
+    -> (B, n * block_size, width), position p of the context at row p."""
+    layers, _, width = pool.shape
+    return pool.reshape(layers, -1, block_size, width)[layer, blocks] \
+        .reshape(blocks.shape[0], -1, width)
+
+
+def _attend_decode(cfg, qh, kc, vc, live):
+    """One query row a sequence over its gathered context: qh (B, g, per, 2,
+    hd); kc, vc (B, K, kv_dim) as :func:`_block_context` leaves them, K
+    before the heads; ``live`` (B, K). Returns (B, g, per, 2, 2 hd), as
+    :func:`_diff_out` takes it.
+
+    The einsums read the context whole rows at a time, as it lies: each
+    query head is spread over the row's kv_dim values, zero outside its own
+    key head's hd, and each takes back the 2 hd values of its own pair's v.
+    Splitting the row into heads instead makes the device relay out the
+    whole context (its minor axis would be hd wide); the products of zeros
+    cost MXU time a decode step has to spare, and every sum is the one over
+    the head's own values."""
+    import jax
+    import jax.numpy as jnp
+
+    b, g, per, _, hd = qh.shape
+    own_g = jnp.eye(g, dtype=qh.dtype)
+    own = (own_g[:, None, None, :, None] * jnp.eye(2, dtype=qh.dtype)
+           [None, None, :, None, :])                    # (g, 1, 2, g, 2)
+    qx = (qh[:, :, :, :, None, None, :] * own[None, ..., None]).reshape(
+        b, g * per * 2, -1)                             # (B, heads, kv_dim)
+    sc = jnp.einsum("bhe,bke->bhk", qx, kc) / math.sqrt(hd)
+    prob = jax.nn.softmax(jnp.where(live[:, None, :], sc, NEG), axis=-1)
+    out = jnp.einsum("bhk,bke->bhe", prob, vc).reshape(b, g, per, 2, g, -1)
+    return jnp.sum(out * own_g[None, :, None, None, :, None], axis=4)
+
+
 def _ring_live(pos, ring_rows: int, window: int):
     """(B, ring rows): which rows of its ring a decode row at ``pos`` reads.
     Ring row r holds the newest position p <= pos with p = r (mod ring
@@ -771,28 +810,23 @@ class SambaYModel(HybridServingModel):
         kinds = cfg.kinds
         bs, ring = self.kv.block_size, self.kv.config.ring_blocks
         ring_rows = ring * bs
+        # every row's position lies under l_bucket: a ring that has not
+        # wrapped holds position p at ring row p
+        ring_read = min(ring_rows, l_bucket)
         scope = jax.named_scope
         rows = jnp.arange(b_bucket)
 
         def impl(w, fk, fv, wk, wv, ssm, conv, tokens, pos, tables,
                  ring_tables, slots):
-            # full layer: every context position's row, and this token's
+            # full layer: context position t at row t of the gathered
+            # blocks; this token's row
             t = jnp.arange(l_bucket)
-            ctx = jnp.take_along_axis(tables, (t // bs)[None, :], axis=1) \
-                * bs + (t % bs)[None, :]
-            ctx = jnp.where(t[None, :] <= pos[:, None], ctx, 0)
-            full_live = (t[None, :] <= pos[:, None])[:, None, :]
-            full_write = ctx[rows, pos]
-            r = jnp.arange(ring_rows)
-            ring_ctx = jnp.take_along_axis(
-                ring_tables, (r // bs)[None, :], axis=1) * bs \
-                + (r % bs)[None, :]
-            ring_live = _ring_live(pos, ring_rows, cfg.window)[:, None, :]
-            ring_write = ring_ctx[rows, pos % ring_rows]
-
-            def attend(qh, kh, vh, live):
-                return jax.vmap(functools.partial(_attend_rows, cfg))(
-                    qh[:, None], kh, vh, live)[:, 0]
+            full_live = t[None, :] <= pos[:, None]
+            full_write = tables[rows, pos // bs] * bs + pos % bs
+            ring_live = _ring_live(pos, ring_rows, cfg.window)[:, :ring_read]
+            at = pos % ring_rows
+            ring_write = ring_tables[rows, at // bs] * bs + at % bs
+            ring_blocks = ring_tables[:, :ring_read // bs]
 
             x = w["embed"][tokens]
             i_m = i_w = 0
@@ -827,7 +861,8 @@ class SambaYModel(HybridServingModel):
                         q = h @ w[p + "wq"] + w[p + "bq"]
                         x = x + _diff_out(
                             cfg, p, w, l,
-                            attend(_q_heads(cfg, q), kf, vf, full_live))
+                            _attend_decode(cfg, _q_heads(cfg, q), kf, vf,
+                                           full_live))
                 else:
                     name = ("window_attention" if kind == "window"
                             else "full_attention")
@@ -839,18 +874,20 @@ class SambaYModel(HybridServingModel):
                         if kind == "window":
                             wk = wk.at[i_w, ring_write].set(k)
                             wv = wv.at[i_w, ring_write].set(v)
-                            kh, vh = _kv_heads(cfg, wk[i_w][ring_ctx],
-                                               wv[i_w][ring_ctx])
+                            kc = _block_context(wk, i_w, ring_blocks, bs)
+                            vc = _block_context(wv, i_w, ring_blocks, bs)
                             live = ring_live
                             i_w += 1
                         else:       # gathered ONCE, read by the cross layers
                             fk = fk.at[0, full_write].set(k)
                             fv = fv.at[0, full_write].set(v)
-                            kf, vf = _kv_heads(cfg, fk[0][ctx], fv[0][ctx])
-                            kh, vh, live = kf, vf, full_live
+                            kf = _block_context(fk, 0, tables, bs)
+                            vf = _block_context(fv, 0, tables, bs)
+                            kc, vc, live = kf, vf, full_live
                         x = x + _diff_out(
                             cfg, p, w, l,
-                            attend(_q_heads(cfg, q), kh, vh, live))
+                            _attend_decode(cfg, _q_heads(cfg, q), kc, vc,
+                                           live))
                 x = _mlp(cfg, p, w, x)
             with scope("head"):
                 last = _ln(x, w["lnf_w"], w["lnf_b"], cfg.eps)

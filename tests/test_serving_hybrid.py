@@ -15,6 +15,7 @@ of order 1) leave ten times that and are far under what a missing term gives
 
 import importlib
 import os
+import re
 import sys
 import threading
 
@@ -166,6 +167,105 @@ def test_flash_kernel_path_agrees_with_the_reference(world, n):
     st = world["state"][LENS.index(n)]
     assert _rel(kv.ssm[1, 0, table.slot], st["ssm"][0]) <= STATE_TOL
     kv.free_sequence(1)
+    kv.assert_idle()
+
+
+def _padded(tables, rows, blocks):
+    """A batch's tables as ``decode_step`` pads them: (rows, blocks)."""
+    out = np.zeros((rows, blocks), np.int32)
+    for i, t in enumerate(tables):
+        out[i, :len(t)] = t
+    return out
+
+
+def test_the_decode_program_makes_no_copy_of_a_whole_window_layer(world):
+    """The rings are read as whole blocks with the layer inside the gather's
+    index: no op of the lowered decode program yields an array of one whole
+    window layer, ``(ring pool rows, kv_dim)`` (what ``pool[layer]`` first
+    makes)."""
+    model, kv = world["model"], world["kv"]
+    b, l = 8, 64
+    i32 = np.int32
+    text = model._decode_fn(b, l).lower(
+        model._params, kv.full.k_pool, kv.full.v_pool, kv.window.k_pool,
+        kv.window.v_pool, kv.ssm, kv.conv, np.zeros(b, i32),
+        np.zeros(b, i32), np.zeros((b, l // BS), i32),
+        np.zeros((b, kv.ring_blocks), i32), np.zeros(b, i32)).as_text()
+    layer = tuple(kv.window.k_pool.shape[1:])
+    shapes = {tuple(int(d) for d in s.split("x"))
+              for s in re.findall(r"tensor<(\d+(?:x\d+)*)x[a-z]", text)}
+    assert tuple(kv.window.k_pool.shape) in shapes     # the pools go through
+    whole = {s for s in shapes if s[-2:] == layer
+             and np.prod(s) == np.prod(layer)}
+    assert not whole, whole
+
+
+def test_the_block_read_equals_the_row_by_row_read(world, monkeypatch):
+    """One decode step whose batch holds a ring that has wrapped (37 rows),
+    a row inside the window (9) and padded rows (position 0): the K and V
+    each attention layer reads equal, on its live positions, what the
+    row-by-row read gives (each context position's pool row through the
+    table), element for element; the live positions are the window's and the
+    causal ones."""
+    import jax
+
+    from brpc_tpu.serving import hybrid_model
+
+    model, kv = _stand(weights=world["host"])
+    prompts = [world["prompts"][LENS.index(n)] for n in (37, 9)]
+    tables = [kv.alloc_sequence(i + 1, len(p)) for i, p in enumerate(prompts)]
+    first = [model.prefill(p, t) for p, t in zip(prompts, tables)]
+    tables = [kv.extend_sequence(i + 1, len(p) + 1)
+              for i, p in enumerate(prompts)]
+    pos = np.array([len(p) for p in prompts], np.int32)
+    ring_rows, window = kv.ring_blocks * BS, M["sliding_window"]
+    assert pos[0] > ring_rows and pos[1] < window
+    seen = []
+    attend = hybrid_model._attend_decode
+
+    def record(cfg, qh, kc, vc, live):
+        seen.append(tuple(np.asarray(a) for a in (kc, vc, live)))
+        return attend(cfg, qh, kc, vc, live)
+
+    monkeypatch.setattr(hybrid_model, "_attend_decode", record)
+    with jax.disable_jit():
+        model.decode_step(np.array(first, np.int32), pos, tables)
+    b_bucket, l_bucket = model._decode_buckets(len(pos), tables)
+    assert b_bucket > len(pos)
+    pos = np.pad(pos, (0, b_bucket - len(pos)))
+    tabs = _padded(tables, b_bucket, l_bucket // BS)
+    rings = _padded([t.window for t in tables], b_bucket, kv.ring_blocks)
+
+    def rows_read(pool, layer, table, n):
+        """Position t of the context at pool row ``table[t // BS] * BS + t
+        % BS``, gathered one row at a time."""
+        t = np.arange(n)
+        return np.asarray(pool)[layer][table[:, t // BS] * BS + t % BS]
+
+    t = np.arange(l_bucket)
+    n_ring = min(ring_rows, l_bucket)
+    ring_live = np.asarray(hybrid_model._ring_live(pos, ring_rows, window))
+    want = [("window", 0), ("window", 1), ("full", 0), ("cross", 0)]
+    assert len(seen) == len(want)
+    for (kind, layer), (kc, vc, live) in zip(want, seen):
+        if kind == "window":
+            pools, table, n = (kv.window.k_pool, kv.window.v_pool), rings, \
+                n_ring
+            assert not ring_live[:, n:].any()
+            np.testing.assert_array_equal(live, ring_live[:, :n])
+        else:
+            pools, table, n = (kv.full.k_pool, kv.full.v_pool), tabs, l_bucket
+            np.testing.assert_array_equal(live, t[None, :] <= pos[:, None])
+        assert live[:2].sum(1).tolist() == (
+            [window, pos[1] + 1] if kind == "window" else list(pos[:2] + 1))
+        assert live[2:].sum(1).tolist() == [1] * (b_bucket - 2)  # position 0
+        for got, pool in zip((kc, vc), pools):
+            ref = rows_read(pool, layer, table, n)
+            assert got.shape == ref.shape
+            assert np.abs(ref[live[:2].nonzero()]).max() > 0
+            np.testing.assert_array_equal(got[live], ref[live])
+    for i in (1, 2):
+        kv.free_sequence(i)
     kv.assert_idle()
 
 

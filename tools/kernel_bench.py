@@ -6,6 +6,7 @@ Run standalone (owns the chip):
     python tools/kernel_bench.py paged_decode   # only the named benches
     python tools/kernel_bench.py ssm_scan       # the prefill's selective scan
     python tools/kernel_bench.py cca_mix        # the zaya lane's decode-side mix
+    python tools/kernel_bench.py sambay_decode  # SambaY's decode program, both reads
     python tools/kernel_bench.py mla_paged_decode   # latent pages' decode kernel
     python tools/kernel_bench.py mla_chunk      # a later chunk's latent attention
     python tools/kernel_bench.py ring_hops      # needs four chips
@@ -784,6 +785,103 @@ def bench_cca_mix(peak: dict):
           f"that roofline)", flush=True)
 
 
+def bench_sambay_decode(peak: dict):
+    """SambaY's whole decode program alone (``SambaYModel._decode_fn``) at
+    the `reason-steady` cell's shape: the configuration's 16 layers at the
+    published widths, float32 weights drawn on the device, its pools as
+    deployed (16384 full-layer blocks, rings for 64 sequences), 8 rows whose
+    rings have wrapped, at contexts of 1024 and 2048. Both forms of the
+    context read side by side: the block form the program has (whole blocks,
+    the layer inside the gather's index, attention over K where the gather
+    puts it) and the row form it replaced (``pool[layer]``, then one row a
+    position, then ``_attend_rows``' heads-before-K einsums), swapped in
+    here alone. Against the least time of the block's own byte count
+    (``blocks/sambay/work.py:decode_step_bytes``: every weight once, the
+    live K/V rows and state) over the HBM peak. Read by no metric."""
+    import functools
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.serving import hybrid_cache, hybrid_model as hm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    from blocks.sambay.work import decode_step_bytes
+
+    with open(os.path.join(bench, "configs",
+                           "phi4-mini-flash-l16-serve.json")) as f:
+        args = json.load(f)["runner_args"]
+    margs = {k: v for k, v in args["model"].items() if k != "rehearsal"}
+    kargs = {k: v for k, v in args["kv"].items() if k != "rehearsal"}
+    cfg = hm.SambaYConfig(**margs)
+    kv = cfg.cache(hybrid_cache.HybridCacheConfig(**kargs,
+                                                  window=cfg.window))
+    model = hm.SambaYModel.__new__(hm.SambaYModel)
+    model._init_programs(cfg, kv)
+    key = jax.random.key(0)
+    w = {name: 0.02 * jax.random.normal(jax.random.fold_in(key, i), shape,
+                                        jnp.float32)
+         for i, (name, shape, _how) in enumerate(cfg.weight_specs())}
+    wbytes = sum(v.size * 4 for v in w.values())
+    bs, rows = kv.block_size, 8
+    rng = np.random.default_rng(0)
+
+    def rows_context(pool, layer, blocks, block_size):
+        t = jnp.arange(blocks.shape[1] * block_size)
+        return pool[layer][blocks[:, t // block_size] * block_size
+                           + t % block_size]
+
+    def rows_attend(cfg, qh, kc, vc, live):
+        kh, vh = hm._kv_heads(cfg, kc, vc)
+        return jax.vmap(functools.partial(hm._attend_rows, cfg))(
+            qh[:, None], kh, vh, live[:, None, :])[:, 0]
+
+    forms = {"block form": (hm._block_context, hm._attend_decode),
+             "row form (before)": (rows_context, rows_attend)}
+    # the pools and slots, donated to each launch and taken back from it;
+    # the last entry: the tokens the next launch reads
+    state = [kv.full.k_pool, kv.full.v_pool, kv.window.k_pool,
+             kv.window.v_pool, kv.ssm, kv.conv, jnp.ones(rows, jnp.int32)]
+    for context in (1024, 2048):
+        pos = np.sort(rng.integers(context // 2, context, rows)).astype(
+            np.int32)
+        blocks = rng.permutation(np.arange(1, kv.num_blocks + 1))[
+            :rows * context // bs].reshape(rows, -1).astype(np.int32)
+        rings = rng.permutation(np.arange(1, kv.window.num_blocks + 1))[
+            :rows * kv.ring_blocks].reshape(rows, -1).astype(np.int32)
+        host = (pos, blocks, rings, np.arange(1, rows + 1, dtype=np.int32))
+        least = decode_step_bytes(pos + 1, margs) / peak["hbm_bytes_per_s"]
+        line = []
+        for label, read in forms.items():
+            hm._block_context, hm._attend_decode = read
+            try:
+                fn = model._decode_fn(rows, context).lower(
+                    w, *state, *host).compile()
+            finally:
+                hm._block_context, hm._attend_decode = forms["block form"]
+
+            def run(n, fn=fn):
+                for _ in range(n):
+                    state[:] = fn(w, *state, *host)
+                jax.device_get(state[-1][:1])
+
+            secs = _marginal(run, 4, 24)
+            line.append(f"{label} {secs * 1e3:.3f} ms "
+                        f"({least / secs * 100:.1f}% of that least time)")
+        print(f"# sambay_decode {rows} rows x {context} context "
+              f"(positions {pos.min()}-{pos.max()}), {cfg.n_layers} layers, "
+              f"{wbytes / 1e9:.2f} GB of float32 weights: least time "
+              f"{least * 1e3:.3f} ms (weights once + live K/V and state "
+              f"over the HBM peak; the weights alone "
+              f"{wbytes / peak['hbm_bytes_per_s'] * 1e3:.3f} ms); "
+              + "; ".join(line), flush=True)
+
+
 def bench_mla_paged_decode(peak: dict):
     """The absorbed decode attention over latent pages alone, at the widths
     of the `agent-steady` cell (20 heads over rows of 576 values allocated
@@ -1052,6 +1150,7 @@ def main():
                "paged_decode": bench_paged_decode,
                "ssm_scan": bench_ssm_scan,
                "cca_mix": bench_cca_mix,
+               "sambay_decode": bench_sambay_decode,
                "mla_paged_decode": bench_mla_paged_decode,
                "mla_chunk": bench_mla_chunk,
                "train_step_mfu": bench_train_step_mfu}
