@@ -669,14 +669,15 @@ def serving_service(server, http: HttpMessage):
         from brpc_tpu.serving.engine import active_engines
     except ImportError:
         return 200, CONTENT_TEXT, "serving plane not loaded\n"
-    snaps = [e.snapshot() for e in active_engines()]
+    engines = active_engines()
+    snaps = [e.snapshot() for e in engines]
     if http.query.get("format", "") == "json":
         return 200, CONTENT_JSON, json.dumps(
             {"engines": snaps}, indent=2) + "\n"
     if not snaps:
         return 200, CONTENT_TEXT, "no serving engine running\n"
     out = []
-    for i, s in enumerate(snaps):
+    for i, (engine, s) in enumerate(zip(engines, snaps)):
         kv = s["kv"]
         out.append(f"[engine {i}] max_batch={s['max_batch']} "
                    f"token_budget={s['token_budget']}")
@@ -746,60 +747,8 @@ def serving_service(server, http: HttpMessage):
                 f"evict_scanned={pfx['evict_scanned']} "
                 f"hit_ratio={pfx['hit_ratio']:.2f}"
                 + ("" if pfx.get("enabled", True) else " (disabled)"))
-        # decode attention: which path the launches took, and how much of
-        # the padded bucket (what the gather copies) the rows' lengths
-        # cover (what the paged kernel reads)
-        dec = s.get("decode")
-        if dec:
-            out.append(
-                f"  decode: launches paged={dec['decode_launches_paged']} "
-                f"gather={dec['decode_launches_gather']} "
-                f"pages live={dec['decode_pages_live']} "
-                f"bucket={dec['decode_pages_bucket']} "
-                f"live_share={dec['live_share']:.2f}")
-        # the expert layer: how loaded this chip's experts are, and the
-        # share of them (so of their weights) a decode step reaches
-        moe = s.get("moe")
-        if moe:
-            parts = []
-            for phase in ("decode", "prefill"):
-                c = moe[phase]
-                n = max(1, c["layer_launches"])
-                parts.append(
-                    f"{phase} pairs={c['pairs']} "
-                    f"experts_hit={c['experts_hit']} "
-                    f"layer_launches={c['layer_launches']} "
-                    f"pairs_max_expert={c['pairs_max_expert']} "
-                    f"(pairs/launch={c['pairs'] / n:.1f} hit_share="
-                    f"{c['experts_hit'] / n / moe['experts_held']:.2f})"
-                    + (f" kernel_layers={c['kernel_layers']} "
-                       f"blocked_layers={c['blocked_layers']}"
-                       if "kernel_layers" in c else "")
-                    # rows a router sent to an output that computes nothing
-                    + (f" skipped={c['skipped']}" if "skipped" in c else ""))
-            out.append(f"  moe: held={moe['experts_held']} "
-                       + " | ".join(parts))
-        # the selective scan: prefill launches that ran the kernel, and the
-        # rows (padded rows x Mamba layers) they handed it
-        scan = s.get("scan")
-        if scan:
-            out.append(f"  scan: launches={scan['launches']} "
-                       f"rows={scan['rows']}")
-        # latent attention: the live latent rows a launch read (x layers;
-        # a decode step fetches each once), the context rows later chunks
-        # of a prompt built K and V of again, and the rows of their context
-        # buckets the kernel never read, of the buckets' rows
-        mla = s.get("mla")
-        if mla:
-            dec, pre = mla["decode"], mla["prefill"]
-            out.append(
-                f"  mla: decode launches={dec['launches']} "
-                f"latent_rows={dec['latent_rows']} | prefill "
-                f"launches={pre['launches']} "
-                f"latent_rows={pre['latent_rows']} "
-                f"expanded_rows={pre['expanded_rows']} "
-                f"skipped_rows={pre['skipped_rows']} "
-                f"bucket_rows={pre['bucket_rows']}")
+        # what the model counts: its own families, in its own lines
+        out.extend(engine.counter_lines(s))
         # speculative decoding: draft/verify economics — how many tokens
         # each verify launch commits and how many rows it wastes
         sp = s.get("spec")

@@ -44,6 +44,7 @@ CreditLedger audits window teardown.
 from __future__ import annotations
 
 import collections
+import copy
 import math
 import threading
 import time
@@ -63,7 +64,7 @@ from brpc_tpu.rpc import native_transport as _native_transport
 from brpc_tpu.serving import qos as _qos
 from brpc_tpu.serving import speculative as _spec
 from brpc_tpu.serving.kv_cache import KVCacheFull, PagedKVCache
-from brpc_tpu.serving.model import TinyTransformer
+from brpc_tpu.serving.model import COUNTER_FAMILIES, TinyTransformer
 
 _fault.register("serving.decode.stall",
                 "stall the serving engine's decode step (delay_ms=)")
@@ -1299,58 +1300,22 @@ class ServingEngine:
             self._finish(seq, code, reason)
 
     # ------------------------------------------------------------ visibility
-    def _decode_snapshot(self) -> Optional[Dict[str, object]]:
-        """The model's decode-attention counters (serving/model.py), for
-        a model that keeps them: launches by path, and pages the rows'
-        lengths covered against pages of the padded buckets."""
-        counters = getattr(self.model, "decode_counters", None)
-        if counters is None:
-            return None
-        out = dict(counters)
-        out["live_share"] = round(
-            out["decode_pages_live"] / max(1, out["decode_pages_bucket"]),
-            4)
+    def _counters(self) -> Dict[str, object]:
+        """The model's counter families by name (its ``counters()``: what
+        each model keeps, serving/model.py and the hybrid lane's modules),
+        copied; each of ``COUNTER_FAMILIES`` the model keeps none of is
+        None."""
+        out = dict.fromkeys(COUNTER_FAMILIES)
+        counters = getattr(self.model, "counters", None)
+        if counters is not None:
+            out.update(copy.deepcopy(counters()))
         return out
 
-    def _moe_snapshot(self) -> Optional[Dict[str, object]]:
-        """The model's expert-layer counters (serving/moe_model.py), for a
-        model that keeps them: ``experts_held`` and, for decode and prefill
-        apart, the token-expert pairs this chip computed, the distinct held
-        experts hit, the most pairs of one expert (summed over
-        layer-launches) and the layer-launches; for prefill also the
-        attention layers that took the flash kernel and the blocked scan
-        (``kernel_layers``, ``blocked_layers``); where the router has an
-        output that computes nothing (serving/zaya_model.py), the rows it
-        sent there (``skipped``)."""
-        counters = getattr(self.model, "moe_counters", None)
-        if counters is None:
-            return None
-        return {k: dict(v) if isinstance(v, dict) else v
-                for k, v in counters.items()}
-
-    def _scan_snapshot(self) -> Optional[Dict[str, int]]:
-        """The model's selective-scan counters (serving/hybrid_model.py),
-        for a model with Mamba layers: the prefill ``launches`` that ran
-        the ``ssm_scan`` kernel and the ``rows`` handed to it (a launch's
-        padded rows times its Mamba layers)."""
-        counters = getattr(self.model, "scan_counters", None)
-        return dict(counters) if counters is not None else None
-
-    def _mla_snapshot(self) -> Optional[Dict[str, object]]:
-        """The model's latent-attention counters (serving/glm_model.py),
-        for a model whose pages hold one latent row a token: for decode and
-        prefill apart the ``launches``, the live ``latent_rows`` they read
-        (a launch's live context rows times its layers: what the decode
-        kernel fetches, once each) and, for prefill, the ``expanded_rows``
-        (context rows whose K and V a later chunk built again), the
-        ``skipped_rows`` (rows of a later chunk's context bucket past its
-        last row's tile, which the kernel never read) and the
-        ``bucket_rows`` (the later chunks' context buckets), each times the
-        layers."""
-        counters = getattr(self.model, "mla_counters", None)
-        if counters is None:
-            return None
-        return {phase: dict(c) for phase, c in counters.items()}
+    def counter_lines(self, snap: Dict[str, object]) -> List[str]:
+        """``/serving``'s lines of the counter families in ``snap`` (a
+        :meth:`snapshot` of this engine), as the model formats them."""
+        lines = getattr(self.model, "counter_lines", None)
+        return lines(snap) if lines is not None else []
 
     def _host_snapshot(self, loop_spans) -> Dict[str, object]:
         """The host side of the process, every number cumulative since
@@ -1445,10 +1410,7 @@ class ServingEngine:
             "kv": kv,
             "prefix": (self.prefix.snapshot()
                        if self.prefix is not None else None),
-            "decode": self._decode_snapshot(),
-            "moe": self._moe_snapshot(),
-            "scan": self._scan_snapshot(),
-            "mla": self._mla_snapshot(),
+            **self._counters(),
             "spec": (dict(self.spec_stats.snapshot(),
                           k_max=self.config.spec_k)
                      if self.spec_stats is not None else None),

@@ -34,7 +34,7 @@ case); the programs pad what they write and ask to the pool's own width.
   head width)``, its output ``(P_h c) Wuv_h``. All heads read each live row
   ONCE a layer, in place, as key and value: ``pallas_ops.mla_paged_decode``.
 
-**The chip's share** of the expert layer is ``moe_model.expert_layer``'s: the
+**The chip's share** of the expert layer is the lane's ``expert_layer``'s: the
 layer is told which experts it holds (``expert_rank``-th run of
 ``n_routed_experts`` of ``num_routed_experts``), routes over all of them and
 computes its own experts' part; the shared expert, attention, the router, the
@@ -44,8 +44,9 @@ Layer 0 is apart (its MLP is dense); layers ``1 .. n - 1`` are ONE
 ``fori_loop`` over stacked weights (the body compiles once), the experts of
 all layers in one (layers x held, ...) stack the grouped matmul indexes.
 Each launch returns, beside its tokens and in the same sync, the expert
-layers' counters (``moe_counters``: ``ServingEngine.snapshot()["moe"]``) and
-the latent rows it read (``mla_counters``: ``snapshot()["mla"]``).
+layers' counters (the model's ``moe`` counter family, ``moe_counters``) and
+the latent rows it read (its ``mla`` family, ``mla_counters``, which this
+module keeps and formats for ``/serving``).
 
 Storage: weights and pages bfloat16, the router and its bias float32;
 matmul operands rounded to bfloat16 on the TPU (exact elsewhere), sums
@@ -55,26 +56,26 @@ norms' statistics, rotary and softmax float32. Greedy argmax.
 
 from __future__ import annotations
 
-import functools
 import math
-from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
-from brpc_tpu.serving.hybrid_model import (HybridServingModel, _rms,
-                                           decode_buckets)
-from brpc_tpu.serving.jamba_model import _pow2_floor, attend_chunk_blocked
-from brpc_tpu.serving.model import _next_pow2
-from brpc_tpu.serving.moe_model import (_mm, _operand_dtype, expert_layer,
-                                        rope, route, shared_experts)
-from brpc_tpu.serving.zaya_model import draw_array
+from brpc_tpu.serving.hybrid_model import (HybridServingModel,
+                                           _block_context, _f32, _mm,
+                                           _next_pow2, _operand_dtype, _rms,
+                                           attend_chunk_blocked,
+                                           context_floors, draw_array,
+                                           expert_counts, expert_layer,
+                                           fill_stack, rope, route,
+                                           shared_experts)
 
 CHUNK_FLOOR = 512             # rows of the smallest padded chunk of a prompt
-DECODE_CONTEXT_FLOOR = 8192   # rows of the narrowest decode block table
+# rows of the narrowest decode block table (the kernel walks a row's own
+# pages: the table's width costs scalar memory, not time)
+DECODE_CONTEXT_FLOOR = 8192
 PREFILL_CONTEXT_FLOOR = 4096  # rows of a later chunk's smallest context
-COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
 MLA_COUNTERS = ("latent_rows", "expanded_rows", "skipped_rows",
                 "bucket_rows")
 
@@ -130,10 +131,9 @@ class GlmMoeLiteConfig:
         self.n_layers = num_hidden_layers
         self.vocab = vocab_size
         self.max_context = max_context
-        self.decode_context_floor = min(_pow2_floor(max_context),
-                                        DECODE_CONTEXT_FLOOR)
-        self.prefill_context_floor = min(_pow2_floor(max_context),
-                                         PREFILL_CONTEXT_FLOOR)
+        self.decode_context_floor, self.prefill_context_floor = \
+            context_floors(max_context, DECODE_CONTEXT_FLOOR,
+                           PREFILL_CONTEXT_FLOOR)
         self.seed = seed
         self.attn = attn            # as ModelConfig.attn
 
@@ -205,16 +205,12 @@ class GlmMoeLiteConfig:
         return out
 
 
-def _f32(x):
-    import jax.numpy as jnp
-
-    return x.astype(jnp.float32)
-
-
 class GlmMoeLiteModel(HybridServingModel):
     """Weights + the chunk and decode programs over a HybridStateCache."""
 
     CONTINUES_PREFILL = True
+    DECODE_ROWS_BY_8 = True
+    MOE_COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
 
     def __init__(self, config: GlmMoeLiteConfig, kv: HybridStateCache,
                  weights: Optional[Dict[str, np.ndarray]] = None):
@@ -222,12 +218,9 @@ class GlmMoeLiteModel(HybridServingModel):
         (``l3.wqa``, ``l3.e2.wg``, ..., ``embed``, ``head``) that replace
         what the recipe gives (tests); everything else is drawn from
         ``config.seed``."""
-        import jax
         import jax.numpy as jnp
 
         self._init_programs(config, kv)
-        self.moe_counters = {"experts_held": config.held}
-        self.reset_moe_counters()
         # per phase: launches, the live latent rows they read (summed over
         # the launch's rows, x layers) and, for prefill, the context rows a
         # later chunk built K and V of again, the rows of its context bucket
@@ -235,29 +228,15 @@ class GlmMoeLiteModel(HybridServingModel):
         self.mla_counters = {
             phase: dict.fromkeys(("launches",) + MLA_COUNTERS, 0)
             for phase in ("decode", "prefill")}
-        cfg, dev = config, self.store.device
-        given = dict(weights or {})
+        cfg = config
         d, ff, n = cfg.d_model, cfg.d_ff, cfg.n_layers
-        h, r = cfg.n_heads, cfg.kv_lora
-
-        def host(name, stream, shape, const, spread):
-            if name not in given:
-                return draw_array(cfg.seed, stream, shape, const, spread)
-            got = np.asarray(given[name], np.float32)  # tpulint: disable=no-per-token-host-sync
-            if got.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {got.shape} != {shape}")
-            return got
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def fill(buf, part, *start):
-            return jax.lax.dynamic_update_slice(buf, part.astype(buf.dtype),
-                                                start)
+        h, r, ns = cfg.n_heads, cfg.kv_lora, cfg.n_shared
 
         def by_head(wkvb, lo, hi):
-            """(r, h x (nope + v)) -> (1, h, r, hi - lo): the columns ``[lo,
+            """(r, h x (nope + v)) -> (h, r, hi - lo): the columns ``[lo,
             hi)`` of every head's ``[k_nope | v]``."""
             w3 = wkvb.reshape(r, h, cfg.nope + cfg.v_dim)[:, :, lo:hi]
-            return w3.transpose(1, 0, 2)[None]
+            return w3.transpose(1, 0, 2)
 
         bf = jnp.bfloat16
         shapes = {
@@ -266,90 +245,62 @@ class GlmMoeLiteModel(HybridServingModel):
             "wuk": (n, h, r, cfg.nope), "wuv": (n, h, r, cfg.v_dim),
             "wo": (n, h * cfg.v_dim, d), "ln1": (n, d), "ln2": (n, d),
             "q_ln": (n, cfg.q_lora), "kv_ln": (n, r),
-            "s_wgu": (n - 1, d, 2 * cfg.n_shared * ff),
-            "s_wd": (n - 1, cfg.n_shared * ff, d),
+            "s_wgu": (n - 1, d, 2 * ns * ff),
+            "s_wd": (n - 1, ns * ff, d),
             # (expert layers x held, d, gate | up), (..., ff, d)
             "e_wgu": ((n - 1) * cfg.held, d, 2 * ff),
             "e_wd": ((n - 1) * cfg.held, ff, d),
             "d_wgu": (d, 2 * cfg.d_dense), "d_wd": (cfg.d_dense, d)}
-        # drawn a layer ahead on threads (numpy frees the interpreter),
-        # staged ONE array at a time into the stacks the programs read:
-        # set-up, not a step loop
-        with ThreadPoolExecutor(4) as pool:
-            def start(layer):
-                return {a[0]: pool.submit(host, *a)
-                        for a in cfg.arrays(layer)}
-
-            def take(drawn, name):
-                return jax.device_put(drawn.pop(name).result(), dev)  # tpulint: disable=no-per-op-step-dispatch
-
-            drawn, ahead = start(None), start(0)
-            # embedding and head first, float32 on their way to bfloat16,
-            # before the stacks take their share of the device
-            for name in ("embed", "head", "lnf"):
-                self._stage(name, take(drawn, name).astype(bf))
-            st = {k: jnp.zeros(s, bf) for k, s in shapes.items()}
-            st["router"] = jnp.zeros((n - 1, d, cfg.n_experts), jnp.float32)
-            st["r_bias"] = jnp.zeros((n - 1, cfg.n_experts), jnp.float32)
-            for l in range(n):
-                drawn, p = ahead, f"l{l}."
-                ahead = start(l + 1) if l + 1 < n else {}
-                st["wqkva"] = fill(st["wqkva"], take(drawn, p + "wqa")[None],
-                                   l, 0, 0)
-                st["wqkva"] = fill(st["wqkva"], take(drawn, p + "wkva")[None],
-                                   l, 0, cfg.q_lora)
-                wkvb = take(drawn, p + "wkvb")
-                st["wuk"] = fill(st["wuk"], by_head(wkvb, 0, cfg.nope),
-                                 l, 0, 0, 0)
-                st["wuv"] = fill(st["wuv"], by_head(
-                    wkvb, cfg.nope, cfg.nope + cfg.v_dim), l, 0, 0, 0)
-                del wkvb
-                for k in ("wqb", "wo"):
-                    st[k] = fill(st[k], take(drawn, p + k)[None], l, 0, 0)
-                for k in ("ln1", "ln2", "q_ln", "kv_ln"):
-                    st[k] = fill(st[k], take(drawn, p + k)[None], l, 0)
-                if l == 0:
-                    st["d_wgu"] = fill(st["d_wgu"], take(drawn, p + "wg"),
-                                       0, 0)
-                    st["d_wgu"] = fill(st["d_wgu"], take(drawn, p + "wu"),
-                                       0, cfg.d_dense)
-                    st["d_wd"] = fill(st["d_wd"], take(drawn, p + "wd"), 0, 0)
-                    continue
-                m = l - 1       # of the expert layers
-                st["router"] = fill(st["router"],
-                                    take(drawn, p + "router")[None], m, 0, 0)
-                st["r_bias"] = fill(st["r_bias"],
-                                    take(drawn, p + "r_bias")[None], m, 0)
-                # shared experts: gates side by side, then ups; downs stacked
-                ns = cfg.n_shared
-                for i in range(ns):
-                    q = f"{p}s{i}."
-                    st["s_wgu"] = fill(st["s_wgu"], take(drawn, q + "wg")[None],
-                                       m, 0, i * ff)
-                    st["s_wgu"] = fill(st["s_wgu"], take(drawn, q + "wu")[None],
-                                       m, 0, (ns + i) * ff)
-                    st["s_wd"] = fill(st["s_wd"], take(drawn, q + "wd")[None],
-                                      m, i * ff, 0)
-                for i in range(cfg.held):
-                    at, q = m * cfg.held + i, f"{p}e{cfg.expert_lo + i}."
-                    st["e_wgu"] = fill(st["e_wgu"], take(drawn, q + "wg")[None],
-                                       at, 0, 0)
-                    st["e_wgu"] = fill(st["e_wgu"], take(drawn, q + "wu")[None],
-                                       at, 0, ff)
-                    st["e_wd"] = fill(st["e_wd"], take(drawn, q + "wd")[None],
-                                      at, 0, 0)
+        for l, take in self._drawn_layers(cfg.arrays, draw_array, weights):
+            if l is None:
+                # embedding and head first, float32 on their way to
+                # bfloat16, before the stacks take their share of the device
+                for name in ("embed", "head", "lnf"):
+                    self._stage(name, take(name).astype(bf))
+                st = {k: jnp.zeros(s, bf) for k, s in shapes.items()}
+                st["router"] = jnp.zeros((n - 1, d, cfg.n_experts),
+                                         jnp.float32)
+                st["r_bias"] = jnp.zeros((n - 1, cfg.n_experts), jnp.float32)
+                continue
+            p = f"l{l}."
+            st["wqkva"] = fill_stack(st["wqkva"], take(p + "wqa"), l)
+            st["wqkva"] = fill_stack(st["wqkva"], take(p + "wkva"), l, 0,
+                                     cfg.q_lora)
+            wkvb = take(p + "wkvb")
+            st["wuk"] = fill_stack(st["wuk"], by_head(wkvb, 0, cfg.nope), l)
+            st["wuv"] = fill_stack(st["wuv"], by_head(
+                wkvb, cfg.nope, cfg.nope + cfg.v_dim), l)
+            del wkvb
+            for k in ("wqb", "wo", "ln1", "ln2", "q_ln", "kv_ln"):
+                st[k] = fill_stack(st[k], take(p + k), l)
+            if l == 0:
+                st["d_wgu"] = fill_stack(st["d_wgu"], take(p + "wg"))
+                st["d_wgu"] = fill_stack(st["d_wgu"], take(p + "wu"), 0,
+                                         cfg.d_dense)
+                st["d_wd"] = fill_stack(st["d_wd"], take(p + "wd"))
+                continue
+            m = l - 1       # of the expert layers
+            st["router"] = fill_stack(st["router"], take(p + "router"), m)
+            st["r_bias"] = fill_stack(st["r_bias"], take(p + "r_bias"), m)
+            # shared experts: gates side by side, then ups; downs stacked
+            for i in range(ns):
+                q = f"{p}s{i}."
+                st["s_wgu"] = fill_stack(st["s_wgu"], take(q + "wg"), m, 0,
+                                         i * ff)
+                st["s_wgu"] = fill_stack(st["s_wgu"], take(q + "wu"), m, 0,
+                                         (ns + i) * ff)
+                st["s_wd"] = fill_stack(st["s_wd"], take(q + "wd"), m,
+                                        i * ff)
+            for i in range(cfg.held):
+                at, q = m * cfg.held + i, f"{p}e{cfg.expert_lo + i}."
+                st["e_wgu"] = fill_stack(st["e_wgu"], take(q + "wg"), at)
+                st["e_wgu"] = fill_stack(st["e_wgu"], take(q + "wu"), at, 0,
+                                         ff)
+                st["e_wd"] = fill_stack(st["e_wd"], take(q + "wd"), at)
         for name, arr in st.items():
             self._stage(name, arr)
 
     # ------------------------------------------------------------- buckets
-    def _decode_buckets(self, n_rows: int, tables):
-        """Rows to a multiple of 8; the block table as wide as a power of
-        two of blocks from the floor up (the kernel walks a row's own pages:
-        the table's width costs scalar memory, not time)."""
-        return (-(-n_rows // 8) * 8,
-                decode_buckets(n_rows, tables, self.kv.block_size,
-                               self.config.decode_context_floor)[1])
-
     def _chunk_buckets(self, n: int, end: int, start: int):
         """Rows to a power of two from ``CHUNK_FLOOR``; a prompt's first
         launch is its own context, a later one reads a power of two of rows
@@ -363,24 +314,45 @@ class GlmMoeLiteModel(HybridServingModel):
                       2 * c)
 
     # ------------------------------------------------------------ counters
-    def reset_moe_counters(self) -> None:
-        for phase in ("decode", "prefill"):
-            self.moe_counters[phase] = dict.fromkeys(
-                COUNTERS + ("layer_launches",), 0)
-
     def _note_counters(self, phase: str, tail) -> None:
-        """``tail``: per expert layer its ``COUNTERS``, then the rows of
+        """``tail``: per expert layer its ``MOE_COUNTERS``, then the rows of
         ``MLA_COUNTERS`` a layer."""
         tail = np.asarray(tail, np.int64)
-        c = self.moe_counters[phase]
-        per_layer = tail[:-len(MLA_COUNTERS)].reshape(-1, len(COUNTERS))
-        c["layer_launches"] += len(per_layer)
-        for name, total in zip(COUNTERS, per_layer.sum(axis=0)):
-            c[name] += int(total)
         m = self.mla_counters[phase]
         m["launches"] += 1
         for name, rows in zip(MLA_COUNTERS, tail[-len(MLA_COUNTERS):]):
             m[name] += int(rows) * self.config.n_layers
+        super()._note_counters(phase, tail[:-len(MLA_COUNTERS)])
+
+    def counters(self) -> Dict[str, object]:
+        """The lane's families and ``mla``: for decode and prefill apart the
+        ``launches``, the live ``latent_rows`` they read (a launch's live
+        context rows times its layers: what the decode kernel fetches, once
+        each) and, for prefill, the ``expanded_rows`` (context rows whose K
+        and V a later chunk built again), the ``skipped_rows`` (rows of a
+        later chunk's context bucket past its last row's tile, which the
+        kernel never read) and the ``bucket_rows`` (the later chunks'
+        context buckets), each times the layers."""
+        return dict(super().counters(), mla=self.mla_counters)
+
+    def counter_lines(self, snap) -> List[str]:
+        out = super().counter_lines(snap)
+        # latent attention: the live latent rows a launch read (x layers;
+        # a decode step fetches each once), the context rows later chunks
+        # of a prompt built K and V of again, and the rows of their context
+        # buckets the kernel never read, of the buckets' rows
+        mla = snap.get("mla")
+        if mla:
+            dec, pre = mla["decode"], mla["prefill"]
+            out.append(
+                f"  mla: decode launches={dec['launches']} "
+                f"latent_rows={dec['latent_rows']} | prefill "
+                f"launches={pre['launches']} "
+                f"latent_rows={pre['latent_rows']} "
+                f"expanded_rows={pre['expanded_rows']} "
+                f"skipped_rows={pre['skipped_rows']} "
+                f"bucket_rows={pre['bucket_rows']}")
+        return out
 
     # ---- what both programs share of a layer
     def _down(self, w, i, x, pos):
@@ -430,8 +402,7 @@ class GlmMoeLiteModel(HybridServingModel):
         routed, cnt = expert_layer(cfg, hn, idx, wts, w["e_wgu"], w["e_wd"],
                                    tile, first=m * cfg.held)
         y = routed + shared_experts(cfg, hn, w["s_wgu"][m], w["s_wd"][m])
-        return y, jnp.stack([jnp.sum(cnt), jnp.sum(cnt > 0),
-                             jnp.max(cnt)]).astype(jnp.int32)
+        return y, expert_counts(cnt).astype(jnp.int32)
 
     def _layers(self, w, x, fk, live, tile: int, attend):
         """Every layer over rows ``x``: ``attend(i, x, fk) -> (attention's
@@ -454,7 +425,8 @@ class GlmMoeLiteModel(HybridServingModel):
 
         return jax.lax.fori_loop(
             1, cfg.n_layers, layer,
-            (x, fk, jnp.zeros((cfg.n_layers - 1, len(COUNTERS)), jnp.int32)))
+            (x, fk, jnp.zeros((cfg.n_layers - 1, len(self.MOE_COUNTERS)),
+                              jnp.int32)))
 
     def _out(self, nxt, counts, *mla):
         import jax.numpy as jnp
@@ -527,8 +499,7 @@ class GlmMoeLiteModel(HybridServingModel):
                     else:
                         # rows [0, start + length) back through the table,
                         # whole blocks at a time, this chunk's among them
-                        ctx = fk.reshape(len(fk), -1, bs, fk.shape[-1])[
-                            i, table].reshape(l_bucket, fk.shape[-1])
+                        ctx = _block_context(fk, i, table, bs)
                         a = attend_later(w, i, q, ctx, start, length)
                     return _mm(a.reshape(c_bucket, h * cfg.v_dim),
                                w["wo"][i]), fk

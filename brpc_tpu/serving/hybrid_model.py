@@ -1,6 +1,19 @@
-"""A SambaY decoder-hybrid-decoder on the serving plane: Mamba-1 layers,
-sliding-window and full differential attention, gated memory units over ONE
-shared K/V (arXiv:2507.06607; the architecture of Phi-4-mini-flash-reasoning).
+"""The hybrid serving lane's shared module, and SambaY's model.
+
+**The lane.** Every model over a
+:class:`~brpc_tpu.serving.hybrid_cache.HybridStateCache` -- SambaY here,
+Command A+ (``moe_model``), Jamba (``jamba_model``), ZAYA1 (``zaya_model``)
+and GLM-4.7-Flash (``glm_model``) -- subclasses :class:`HybridServingModel`
+and imports from this module alone (beside ``hybrid_cache`` and ``tpu/``):
+the launch and its spans, the buckets, the staging pipeline, the expert
+counters and their ``/serving`` lines, and every layer function more than
+one model calls. A model writes its configuration, the layout of its stacks,
+its draw recipe, its two programs and the names of its counters
+(docs/serving.md "Adding an architecture").
+
+**SambaY** is a decoder-hybrid-decoder: Mamba-1 layers, sliding-window and
+full differential attention, gated memory units over ONE shared K/V
+(arXiv:2507.06607; the architecture of Phi-4-mini-flash-reasoning).
 
 Layers ``0 .. N/2 + 1`` are the self-decoder: (Mamba, window attention)
 pairs, then the memory Mamba layer ``N/2`` and the one FULL attention layer
@@ -10,8 +23,7 @@ full layer's K/V and keep no state of their own. Every layer is followed by a
 gated SiLU MLP; LayerNorm with bias; tied head; no positional encoding.
 ``benchmark/blocks/sambay/reference.py`` states each equation.
 
-Two programs over different layers, both against a
-:class:`~brpc_tpu.serving.hybrid_cache.HybridStateCache`:
+Two programs over different layers:
 
 - ``prefill``: the self-decoder over all rows of the prompt -- one scan
   kernel per Mamba layer (``ssm_scan``), window attention (the flash
@@ -41,19 +53,41 @@ from __future__ import annotations
 import functools
 import math
 import threading
-from typing import List, Sequence
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.profiling.registry import wait_span as _wait_span
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
-from brpc_tpu.serving.model import _next_pow2
 
 NEG = -1e30
 # rows a chunk of a prompt is a multiple of (``PREFILL_GRANULE``), and the
 # smallest padded chunk: whole row blocks of the scan kernel
 SCAN_CHUNK = 128
+QUERY_BLOCK = 128    # query rows a step of a blocked attention
+
+
+# ---------------------------------------------------------------- buckets
+def _next_pow2(n: int) -> int:
+    p = 1
+    while p < n:
+        p <<= 1
+    return p
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (max(1, n).bit_length() - 1)
+
+
+def context_floors(max_context: int, decode: int, prefill: int):
+    """A configuration's (``decode_context_floor``,
+    ``prefill_context_floor``): the smallest context bucket of a decode
+    program and of a later prefill chunk, the model's own floors cut to the
+    largest power of two within ``max_context``."""
+    cap = _pow2_floor(max_context)
+    return min(cap, decode), min(cap, prefill)
 
 
 class SambaYConfig:
@@ -88,6 +122,9 @@ class SambaYConfig:
         self.d_inner = expand * hidden_size
         self.dt_rank = dt_rank or -(-hidden_size // 16)
         self.max_context = max_context
+        # a decode program reads the full layer's context from a window's
+        # rows up (a step reads every weight, and the full layer is ONE)
+        self.decode_context_floor = sliding_window
         self.seed = seed
         self.attn = attn            # as ModelConfig.attn
         # ``mamba`` | ``window`` | ``full`` | ``gmu`` | ``cross`` by layer
@@ -202,17 +239,298 @@ def prefill_bucket(s: int, window: int) -> int:
     return -(-s // window) * window
 
 
-def decode_buckets(n_rows: int, tables, block_size: int, window: int):
-    """The decode program's padded (rows, full-layer context): rows to 8, 16,
-    ...; context to a power of two of blocks from the window up. A step reads
-    every weight whatever its rows, and the full layer is ONE layer, so the
-    padding costs little and few programs compile."""
+def decode_buckets(n_rows: int, tables, block_size: int, floor: int):
+    """The decode program's padded (rows, context): rows to 8, 16, ...;
+    context to a power of two of blocks from ``floor`` rows up. A step reads
+    every weight whatever its rows, so the padding costs little and few
+    programs compile."""
     blocks = max(len(t) for t in tables)
     return (max(8, _next_pow2(n_rows)),
-            max(window, _next_pow2(blocks) * block_size))
+            max(floor, _next_pow2(blocks) * block_size))
 
 
-# ------------------------------------------------------------ layer functions
+
+
+# ---------------------------------------------------------------- weights
+def draw_matrix(seed: int, stream: int, shape, fan_in: int) -> np.ndarray:
+    """One matrix of the recipe: ``Generator(Philox(key=[seed, stream]))``,
+    ``standard_normal`` float32 in row-major order, times
+    ``0.5 / sqrt(fan_in)``."""
+    rng = np.random.Generator(np.random.Philox(key=[seed, stream]))
+    out = rng.standard_normal(shape, dtype=np.float32)
+    out *= np.float32(0.5 / math.sqrt(fan_in))
+    return out
+
+
+def draw_array(seed: int, stream, shape, const, spread) -> np.ndarray:
+    """One float32 array of the recipe: ``Generator(Philox(key=[seed,
+    stream]))``'s ``standard_normal`` float32 in row-major order, times the
+    spread, plus the constant; the constant alone where ``stream`` is None."""
+    if stream is None:
+        return np.broadcast_to(np.float32(const), shape).copy()
+    rng = np.random.Generator(np.random.Philox(key=[seed, stream]))
+    out = rng.standard_normal(shape, dtype=np.float32)
+    out *= np.asarray(spread, np.float32)
+    out += np.asarray(const, np.float32)
+    return out
+
+
+def _handed(weights, name: str, shape) -> np.ndarray:
+    """A test's array for weight ``name``, float32 on the host, its shape
+    checked."""
+    got = np.asarray(weights[name], np.float32)  # tpulint: disable=no-per-token-host-sync
+    if got.shape != tuple(shape):
+        raise ValueError(f"weight {name}: {got.shape} != {shape}")
+    return got
+
+
+@functools.lru_cache(maxsize=None)
+def _filler():
+    import jax
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def fill(buf, part, *start):
+        part = part.reshape((1,) * (buf.ndim - part.ndim) + part.shape)
+        start = start + (0,) * (buf.ndim - len(start))
+        return jax.lax.dynamic_update_slice(buf, part.astype(buf.dtype),
+                                            start)
+
+    return fill
+
+
+def fill_stack(buf, part, *start):
+    """``buf`` (donated) with ``part`` written in, cast to ``buf``'s dtype,
+    at ``start``: ``part`` gains leading axes of one where it has fewer than
+    ``buf``, and ``start`` zeros for the axes it leaves out."""
+    return _filler()(buf, part, *start)
+
+
+# ----------------------------------------------------- shared layer functions
+def _f32(x):
+    import jax.numpy as jnp
+
+    return x.astype(jnp.float32)
+
+
+def _operand_dtype():
+    """What a matmul's operands are rounded to: the TPU's default precision
+    rounds both to bfloat16, so they are cast (the weights ARE bfloat16:
+    nothing is converted on the way in); elsewhere products are exact."""
+    import jax.numpy as jnp
+
+    from brpc_tpu.tpu.pallas_ops import _on_tpu
+
+    return jnp.bfloat16 if _on_tpu() else jnp.float32
+
+
+def _mm(a, b, spec: Optional[str] = None):
+    """``a @ b`` (or ``einsum(spec, a, b)``) on rounded operands, summed in
+    float32."""
+    import jax.numpy as jnp
+
+    dt = _operand_dtype()
+    a, b = a.astype(dt), b.astype(dt)
+    if spec is None:
+        return jnp.matmul(a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def rope(x, pos, theta: float):
+    """``rope_gptj``: pairs ``(2j, 2j + 1)`` of the last axis turned by
+    ``pos * theta^(-2j / hd)``, the pairs left interleaved. x (rows, heads,
+    hd) float32, pos (rows,). As ``x cos + swap(x) sin`` at the full head
+    width, ``swap(x)[2j] = -x[2j + 1]``, ``swap(x)[2j + 1] = x[2j]`` a
+    product with the fixed signed pair-swap matrix (exact at ``highest``:
+    every column has one entry, 1 or -1): no slice strides the lanes."""
+    import jax
+    import jax.numpy as jnp
+
+    hd = x.shape[-1]
+    inv = np.repeat(theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd), 2)
+    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv, jnp.float32)
+    swap = np.zeros((hd, hd), np.float32)
+    even = np.arange(0, hd, 2)
+    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+    turned = jnp.matmul(x, jnp.asarray(swap),
+                        precision=jax.lax.Precision.HIGHEST)
+    return (x * jnp.cos(ang)[:, None, :]
+            + turned * jnp.sin(ang)[:, None, :])
+
+
+def route(cfg, h, w_router, live, bias=None, scale=None):
+    """``sigmoid(h Wr)`` in float32 at ``highest``, the ``top_k`` largest,
+    weights renormalised over them. ``bias`` (experts,): a stored selection
+    bias added to the scores for the CHOICE only (the weights are the
+    chosen experts' scores without it); ``scale``: a factor on the
+    renormalised weights. Returns (rows, k) expert ids (published indices;
+    ``-1`` for a row that is not live) and weights."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(h, w_router,
+                               precision=jax.lax.Precision.HIGHEST))
+    if bias is None:
+        top, idx = jax.lax.top_k(s, cfg.top_k)
+    else:
+        _, idx = jax.lax.top_k(s + bias, cfg.top_k)
+        top = jnp.take_along_axis(s, idx, axis=-1)
+    wts = top / jnp.sum(top, axis=-1, keepdims=True)
+    return (jnp.where(live[:, None], idx, -1),
+            wts if scale is None else wts * scale)
+
+
+def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int, first=None):
+    """This chip's part of ``sum_e w_e E_e(h)``: the pairs (row, expert)
+    whose expert is held here, sorted by expert, each expert's run padded
+    to whole tiles of ``tile`` rows, through the grouped matmul (gate and up
+    as one product, then down), and gathered back to their rows. h (R, d)
+    float32; idx, wts (R, k) from :func:`route`; wgu (held, d, 2 ff), wd
+    (held, ff, d). An index that is nobody's here (another chip's expert,
+    an output that computes nothing, ``-1``) is left out. ``first``: where
+    held expert 0 lies in ``wgu`` and ``wd`` when they stack the experts of
+    many layers (layers x held, ...), so that the kernel indexes the stack
+    and no layer is sliced out of it. Returns (R, d) float32 and the pairs
+    held by expert (held,) int32."""
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.tpu import pallas_ops
+
+    r, k = idx.shape
+    n = cfg.held
+    with jax.named_scope("moe_dispatch"):
+        local = idx - cfg.expert_lo
+        here = (idx >= 0) & (local >= 0) & (local < n)
+        key = jnp.where(here, local, n).reshape(-1)          # (R k,)
+        order = jnp.argsort(key, stable=True)
+        cnt = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                      dtype=jnp.int32)
+        padded = -(-cnt // tile) * tile
+        p_end, u_end = jnp.cumsum(padded), jnp.cumsum(cnt)
+        p_start, u_start = p_end - padded, u_end - cnt
+        # every pair here fits: R k pairs, and at most tile - 1 pads a run
+        rows = -(-(r * k + n * (tile - 1)) // tile) * tile
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            p_end, jnp.arange(rows // tile) * tile, side="right"), n - 1)
+        # the sorted, padded rows: row p of expert e's run is its off-th pair
+        p = jnp.arange(rows)
+        e_of = tile_expert[p // tile]
+        off = p - p_start[e_of]
+        pair = order[jnp.minimum(u_start[e_of] + off, r * k - 1)]
+        x = jnp.where((off < cnt[e_of])[:, None], h[pair // k], 0.0)
+        # where each pair's row went: its rank in the sorted order
+        rank = jnp.zeros(r * k, jnp.int32).at[order].set(
+            jnp.arange(r * k, dtype=jnp.int32))
+        e_pair = jnp.minimum(key, n - 1)
+        dest = jnp.where(here.reshape(-1),
+                         p_start[e_pair] + rank - u_start[e_pair], 0)
+    with jax.named_scope("experts"):
+        used = p_end[-1] // tile
+        if first is not None:
+            tile_expert = tile_expert + first
+        gmm = functools.partial(pallas_ops.moe_grouped_matmul,
+                                tile_expert=tile_expert, tiles_used=used,
+                                block_rows=tile)
+        gu = gmm(x.astype(_operand_dtype()), wgu)
+        act = jax.nn.silu(gu[:, :cfg.d_ff]) * gu[:, cfg.d_ff:]
+        y = gmm(act.astype(_operand_dtype()), wd)
+    with jax.named_scope("moe_dispatch"):
+        got = y[dest].reshape(r, k, -1)
+        out = jnp.sum(jnp.where(here[..., None], wts[..., None] * got, 0.0),
+                      axis=1)
+    return out, cnt
+
+
+def expert_counts(cnt, *more):
+    """What a launch returns of one expert layer, from the pairs held by
+    expert ``cnt``: the pairs computed here, the held experts hit and the
+    most pairs of one expert (``HybridServingModel.MOE_COUNTERS``' first
+    three), then ``more``."""
+    import jax.numpy as jnp
+
+    return jnp.stack([jnp.sum(cnt), jnp.sum(cnt > 0), jnp.max(cnt), *more])
+
+
+def shared_experts(cfg, h, wgu, wd):
+    """The mean of the shared experts' outputs: their gate and up
+    projections side by side, their down projections stacked, so the sum
+    over them is one product's."""
+    import jax
+
+    with jax.named_scope("shared_experts"):
+        gu = _mm(h, wgu)
+        half = cfg.n_shared * cfg.d_ff
+        return _mm(jax.nn.silu(gu[:, :half]) * gu[:, half:], wd) \
+            / cfg.n_shared
+
+
+def attend_chunk_blocked(cfg, q, k, v, start):
+    """Causal attention of a chunk's rows over the context: query ``i`` (row
+    ``start + i``) over keys ``0 .. start + i``, a block of query rows at a
+    time so that the scores held are heads x block x context. q (C, H, hd);
+    k, v (L, G, hd)."""
+    import jax
+    import jax.numpy as jnp
+
+    c, g = q.shape[0], k.shape[1]
+    qb = min(QUERY_BLOCK, c)
+    qh = q.reshape(c // qb, qb, g, cfg.n_heads // g, cfg.head_dim)
+    k_pos = jnp.arange(k.shape[0])[None, :]
+
+    def one(args):
+        qc, i0 = args
+        sc = _mm(qc, k, "qgjd,kgd->gjqk") / math.sqrt(cfg.head_dim)
+        live = k_pos <= (start + i0 + jnp.arange(qb))[:, None]
+        prob = jax.nn.softmax(jnp.where(live, sc, NEG), axis=-1)
+        return _mm(prob, v, "gjqk,kgd->qgjd")
+
+    out = jax.lax.map(one, (qh, jnp.arange(c // qb) * qb))
+    return out.reshape(c, cfg.n_heads, cfg.head_dim)
+
+
+def attend_chunk_flash(cfg, q, k, v, start):
+    """The same through the flash carry kernel, a query head a pass (a loop
+    over the heads: one kernel, the key/value head read in place), with the
+    chunk's first row as the kernel's run-time offset; tiles past a query
+    block's rows are skipped. Operands as :func:`_mm` rounds them."""
+    import jax
+    import jax.numpy as jnp
+
+    from brpc_tpu.tpu import pallas_ops
+
+    c, g, hd = q.shape[0], k.shape[1], cfg.head_dim
+    dt = _operand_dtype()
+    per = cfg.n_heads // g
+    m0 = jnp.full((c, 1), pallas_ops.NEG_INF, jnp.float32)
+    l0 = jnp.zeros((c, 1), jnp.float32)
+    acc0 = jnp.zeros((c, hd), jnp.float32)
+    kh, vh = k.astype(dt).transpose(1, 0, 2), v.astype(dt).transpose(1, 0, 2)
+
+    def head(args):
+        qh, j = args
+        _m, l, acc = pallas_ops.flash_attention_carry(
+            qh, kh[j // per], vh[j // per], m0, l0, acc0, start, 0,
+            causal=True, block_q=min(512, c), block_k=512)
+        return acc / l
+
+    out = jax.lax.map(head, (q.astype(dt).transpose(1, 0, 2),
+                             jnp.arange(cfg.n_heads)))
+    return out.transpose(1, 0, 2)
+
+
+def _block_context(pool, layer: int, blocks, block_size: int):
+    """Rows of ONE layer of a pool read through block tables, whole blocks
+    at a time (a block is ``block_size`` contiguous rows, gathered three
+    times as fast as its rows one by one, measured), the layer inside the
+    gather's index: ``pool[layer]`` first is a copy of the whole layer.
+    ``blocks`` (..., n) -> (..., n * block_size, width), position p of a
+    context at its row p."""
+    layers, _, width = pool.shape
+    return pool.reshape(layers, -1, block_size, width)[layer, blocks] \
+        .reshape(blocks.shape[:-1] + (-1, width))
+
+
+# ------------------------------------ layer functions (SambaY's; and Mamba's)
 def _ln(x, w, b, eps):
     import jax
     import jax.numpy as jnp
@@ -337,26 +655,29 @@ def _attend_rows(cfg, qh, kh, vh, live):
 
 
 def _attend_flash(cfg, qh, kh, vh):
-    """Full causal attention of one sequence through the flash kernel: it
-    takes one (S, hd) head with v as wide as q, so each softmax map runs on
-    the two hd-wide halves of its v."""
-    import jax
+    """Full causal attention of one sequence in ONE call of the batched
+    flash forward (``pallas_ops.flash_attention_mha``), which takes v as
+    wide as q: each softmax map runs on the two hd-wide halves of its v.
+    The K/V heads are (pair, map, v half); every query pair of a pair reads
+    its map's head in place, so only q is repeated, over the v halves."""
     import jax.numpy as jnp
 
     from brpc_tpu.tpu import pallas_ops
 
     s, g, per, _, hd = qh.shape
-    shape = (g, per, 2, 2, s, hd)          # pair, query pair, map, v half
-    q = jnp.broadcast_to(qh.transpose(1, 2, 3, 0, 4)[:, :, :, None], shape)
-    k = jnp.broadcast_to(kh.transpose(1, 2, 0, 3)[:, None, :, None], shape)
+    heads = (g, 2, 2)                      # pair, map, v half
+    q = jnp.broadcast_to(qh.transpose(1, 3, 2, 0, 4)[:, :, None],
+                         heads + (per, s, hd))
+    k = jnp.broadcast_to(kh.transpose(1, 2, 0, 3)[:, :, None],
+                         heads + (s, hd))
     v = jnp.broadcast_to(vh.reshape(s, g, 2, hd).transpose(1, 2, 0, 3)
-                         [:, None, None], shape)
-    out = jax.vmap(functools.partial(pallas_ops.flash_attention,
-                                     causal=True))(
-        q.reshape(-1, s, hd), k.reshape(-1, s, hd), v.reshape(-1, s, hd))
-    # (g, per, 2, 2, s, hd) -> (s, g, per, 2, 2 hd)
-    return out.reshape(shape).transpose(4, 0, 1, 2, 3, 5).reshape(
-        s, g, per, 2, 2 * hd)
+                         [:, None], heads + (s, hd))
+    out = pallas_ops.flash_attention_mha(
+        q.reshape(1, -1, s, hd), k.reshape(1, -1, s, hd),
+        v.reshape(1, -1, s, hd), causal=True)
+    # (g, map, v half, per, s, hd) -> (s, g, per, map, 2 hd)
+    return out.reshape(heads + (per, s, hd)).transpose(4, 0, 3, 1, 2, 5) \
+        .reshape(s, g, per, 2, 2 * hd)
 
 
 def _attend_banded(cfg, qh, kh, vh):
@@ -385,17 +706,6 @@ def _attend_banded(cfg, qh, kh, vh):
     a = jax.lax.map(one, (qh.reshape((nb, win) + qh.shape[1:]),
                           two_bands(kh), two_bands(vh), jnp.arange(nb)))
     return a.reshape((s,) + a.shape[2:])
-
-
-def _block_context(pool, layer: int, blocks, block_size: int):
-    """A decode batch's rows of ONE layer of a pool, whole blocks at a time
-    (a block is ``block_size`` contiguous rows, gathered three times as fast
-    as its rows one by one, measured), the layer inside the gather's index:
-    ``pool[layer]`` first is a slice of the whole layer. ``blocks`` (B, n)
-    -> (B, n * block_size, width), position p of the context at row p."""
-    layers, _, width = pool.shape
-    return pool.reshape(layers, -1, block_size, width)[layer, blocks] \
-        .reshape(blocks.shape[0], -1, width)
 
 
 def _attend_decode(cfg, qh, kc, vc, live):
@@ -440,19 +750,20 @@ def _ring_live(pos, ring_rows: int, window: int):
 class HybridServingModel:
     """What every model over a :class:`HybridStateCache` shares: the launch
     over the manager's six device arrays, the padded host arguments, the
-    ``prep`` / ``launch`` / ``sync`` spans and the interface
+    ``prep`` / ``launch`` / ``sync`` spans, the buckets, the staging
+    pipeline, the counters and the interface
     :class:`~brpc_tpu.serving.engine.ServingEngine` drives. A subclass
     stages ``self._params`` / ``self._handles`` and gives the two programs:
 
     - ``_prefill_fn(s_bucket, use_flash)`` -> jitted ``impl(w, fk, fv, wk,
       wv, ssm, conv, tokens, table, ring_table, slot, length)``
-    - ``_decode_fn(b_bucket, l_bucket)`` -> jitted ``impl(w, fk, fv, wk, wv,
-      ssm, conv, tokens, pos, tables, ring_tables, slots)``
+    - ``_decode_fn(b_bucket, l_bucket)`` -> jitted ``impl(w, fk, fv, wk,
+      wv, ssm, conv, tokens, pos, tables, ring_tables, slots)``
 
     each returning the six arrays and ONE int32 array whose first entries
     are the next tokens (one for prefill, ``b_bucket`` for decode); what
     follows them, if anything, reaches ``_note_counters`` from the same
-    host sync.
+    host sync: a row of ``MOE_COUNTERS`` an expert layer.
 
     A model whose prefill can go on from what its slot and pages hold
     declares ``CONTINUES_PREFILL`` and gives, in place of ``_prefill_fn``,
@@ -463,7 +774,11 @@ class HybridServingModel:
       ``l_bucket`` context rows read through ``table``
 
     and :class:`~brpc_tpu.serving.engine.ServingEngine` then prefills a long
-    prompt a chunk a step (``prefill_suffix`` with ``start > 0``)."""
+    prompt a chunk a step (``prefill_suffix`` with ``start > 0``).
+
+    The configuration gives ``decode_context_floor`` (and, for a model that
+    continues a prefill, ``prefill_context_floor``): the smallest context
+    bucket of each program."""
 
     FUSED_STEP = True     # decode_step: one launch, one host sync
     # prefill_suffix(start > 0) goes on from the slot's scan state and conv
@@ -472,6 +787,14 @@ class HybridServingModel:
     CONTINUES_PREFILL = False
     # rows an engine's chunk of a prompt is a multiple of
     PREFILL_GRANULE = SCAN_CHUNK
+    # a decode program's rows: a power of two from 8, or (True) the next
+    # multiple of 8 (a step then gathers no padded row's context in vain)
+    DECODE_ROWS_BY_8 = False
+    # what a launch returns per expert layer after its tokens, by name
+    # (``moe_counters``; none: no expert layer) ...
+    MOE_COUNTERS = ()
+    # ... and what the host adds up of a launched prefill program's build
+    PREFILL_MOE_COUNTERS = ()
 
     def _init_programs(self, config, kv: HybridStateCache) -> None:
         self.config = config
@@ -482,10 +805,15 @@ class HybridServingModel:
         self._decode_cache = {}
         self._params, self._handles, self.param_nbytes = {}, [], 0
         # the prefill launches that ran the scan kernel and the rows handed
-        # to it (a launch's padded rows x its Mamba layers); read by
-        # ``ServingEngine.snapshot()["scan"]``; None without Mamba layers
+        # to it (a launch's padded rows x its Mamba layers); None without
+        # Mamba layers
         self.scan_counters = ({"launches": 0, "rows": 0}
                               if config.count("mamba") else None)
+        # the expert layers': ``experts_held`` and a dict a phase
+        self.moe_counters = None
+        if self.MOE_COUNTERS:
+            self.moe_counters = {"experts_held": config.held}
+            self.reset_moe_counters()
 
     def _stage(self, name: str, arr) -> None:
         """Hold one device array as a weight, registered with the store."""
@@ -493,6 +821,40 @@ class HybridServingModel:
         self._params[name] = arr
         self._handles.append(handle)
         self.param_nbytes += nbytes
+
+    def _drawn_layers(self, recipe, draw, weights=None):
+        """The staging pipeline of a model whose arrays each have a stream
+        of their own. ``recipe(layer)`` lists ``(name, *args)`` of every
+        array of ``layer`` (of no layer where it is None: the embedding and
+        the like), the array's shape second of ``args``; ``draw(seed,
+        *args)`` draws one on the host, or ``weights[name]`` stands in for
+        it (tests; its shape checked). Yields ``(layer, take)`` for None and
+        then every layer in order while the NEXT one's arrays are drawn on
+        four threads (numpy frees the interpreter); ``take(name)`` is the
+        array on the device, float32. The caller stages ONE array at a time
+        (into its stacks: :func:`fill_stack`): set-up, not a step loop."""
+        import jax
+
+        given, dev = weights or {}, self.store.device
+        n, seed = self.config.n_layers, self.config.seed
+
+        def host(name, *args):
+            return (_handed(given, name, args[1]) if name in given
+                    else draw(seed, *args))
+
+        with ThreadPoolExecutor(4) as pool:
+            def start(layer):
+                return {a[0]: pool.submit(host, *a) for a in recipe(layer)}
+
+            ahead = start(None)
+            for layer in [None] + list(range(n)):
+                drawn, nxt = ahead, 0 if layer is None else layer + 1
+                ahead = start(nxt) if nxt < n else {}
+
+                def take(name, drawn=drawn):
+                    return jax.device_put(drawn.pop(name).result(), dev)  # tpulint: disable=no-per-op-step-dispatch
+
+                yield layer, take
 
     def _use_flash(self) -> bool:
         if self.config.attn in ("flash", "reference"):
@@ -502,12 +864,82 @@ class HybridServingModel:
         return _on_tpu()
 
     def _decode_buckets(self, n_rows: int, tables):
-        return decode_buckets(n_rows, tables, self.kv.block_size,
-                              self.config.window)
+        """The decode program's padded (rows, context): rows as
+        ``DECODE_ROWS_BY_8`` says, context as :func:`decode_buckets` from
+        the configuration's floor."""
+        rows, ctx = decode_buckets(n_rows, tables, self.kv.block_size,
+                                   self.config.decode_context_floor)
+        return (-(-n_rows // 8) * 8 if self.DECODE_ROWS_BY_8 else rows, ctx)
+
+    # ------------------------------------------------------------ counters
+    def reset_moe_counters(self) -> None:
+        for phase, more in (("decode", ()),
+                            ("prefill", self.PREFILL_MOE_COUNTERS)):
+            self.moe_counters[phase] = dict.fromkeys(
+                self.MOE_COUNTERS + ("layer_launches",) + more, 0)
 
     def _note_counters(self, phase: str, tail) -> None:
-        """``tail``: what a launch returned after its tokens (host array)."""
+        """``tail``: what a launch returned after its tokens (host array),
+        a row of ``MOE_COUNTERS`` an expert layer, summed here."""
+        if not self.MOE_COUNTERS:
+            return
+        c = self.moe_counters[phase]
+        per_layer = np.asarray(tail, np.int64).reshape(
+            -1, len(self.MOE_COUNTERS))
+        c["layer_launches"] += len(per_layer)
+        for name, total in zip(self.MOE_COUNTERS, per_layer.sum(axis=0)):
+            c[name] += int(total)
 
+    def counters(self) -> Dict[str, object]:
+        """The counter families this model keeps, by name, for
+        ``ServingEngine.snapshot()`` (which copies them), None where the
+        model has no such layer. ``moe``: ``experts_held`` and, for decode
+        and prefill apart, the token-expert pairs this chip computed, the
+        distinct held experts hit, the most pairs of one expert (summed
+        over layer-launches), the ``layer_launches`` and whatever else the
+        class counts (``moe_model``: the attention layers of the launched
+        prefill programs that took the flash kernel and the blocked scan;
+        ``zaya_model``: the rows sent to the output that computes nothing).
+        ``scan``: the prefill ``launches`` that ran the ``ssm_scan`` kernel
+        and the ``rows`` handed to it (a launch's padded rows times its
+        Mamba layers)."""
+        return {"moe": self.moe_counters, "scan": self.scan_counters}
+
+    def counter_lines(self, snap) -> List[str]:
+        """``/serving``'s lines of this model's families in ``snap`` (an
+        engine's snapshot)."""
+        out = []
+        # the expert layer: how loaded this chip's experts are, and the
+        # share of them (so of their weights) a decode step reaches
+        moe = snap.get("moe")
+        if moe:
+            parts = []
+            for phase in ("decode", "prefill"):
+                c = moe[phase]
+                n = max(1, c["layer_launches"])
+                parts.append(
+                    f"{phase} pairs={c['pairs']} "
+                    f"experts_hit={c['experts_hit']} "
+                    f"layer_launches={c['layer_launches']} "
+                    f"pairs_max_expert={c['pairs_max_expert']} "
+                    f"(pairs/launch={c['pairs'] / n:.1f} hit_share="
+                    f"{c['experts_hit'] / n / moe['experts_held']:.2f})"
+                    + (f" kernel_layers={c['kernel_layers']} "
+                       f"blocked_layers={c['blocked_layers']}"
+                       if "kernel_layers" in c else "")
+                    # rows a router sent to an output that computes nothing
+                    + (f" skipped={c['skipped']}" if "skipped" in c else ""))
+            out.append(f"  moe: held={moe['experts_held']} "
+                       + " | ".join(parts))
+        # the selective scan: prefill launches that ran the kernel, and the
+        # rows (padded rows x Mamba layers) they handed it
+        scan = snap.get("scan")
+        if scan:
+            out.append(f"  scan: launches={scan['launches']} "
+                       f"rows={scan['rows']}")
+        return out
+
+    # -------------------------------------------------------------- launch
     def _launch(self, fn, writes, *args):
         """One launch over the manager's six device arrays (donated in,
         installed again as they come back). ``writes``: (table, first row,
@@ -679,7 +1111,6 @@ class HybridServingModel:
         return ((np.arange(length, dtype=np.int64) * 31 + 7)
                 % (v - 1)).astype(np.int32) + 1
 
-
 class SambaYModel(HybridServingModel):
     """Weights + the prefill and decode programs over a HybridStateCache."""
 
@@ -696,9 +1127,7 @@ class SambaYModel(HybridServingModel):
             # one matrix at a time ON PURPOSE (set-up, not a step loop): a
             # stacked transfer would hold the 8.8 GB twice
             host = (_draw(rng, shape, how) if weights is None else
-                    np.asarray(weights[name], np.float32))  # tpulint: disable=no-per-token-host-sync
-            if host.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {host.shape} != {shape}")
+                    _handed(weights, name, shape))
             self._stage(name, jax.device_put(host, self.store.device))  # tpulint: disable=no-per-op-step-dispatch
 
     # ------------------------------------------------------------- prefill

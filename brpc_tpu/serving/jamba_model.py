@@ -39,20 +39,20 @@ the norms' statistics and the softmax float32. Greedy argmax.
 
 from __future__ import annotations
 
-import functools
 import math
-from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional
 
 import numpy as np
 
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
 from brpc_tpu.serving.hybrid_model import (NEG, HybridServingModel,
-                                           _mamba_inputs, _rms, conv_windows,
-                                           decode_buckets, ssm_scan)
-from brpc_tpu.serving.moe_model import _mm, _operand_dtype, draw_matrix
+                                           _block_context, _f32,
+                                           _mamba_inputs, _mm, _rms,
+                                           attend_chunk_blocked,
+                                           attend_chunk_flash, context_floors,
+                                           conv_windows, draw_matrix,
+                                           fill_stack, ssm_scan)
 
-QUERY_BLOCK = 128             # query rows a step of the blocked attention
 DECODE_CONTEXT_FLOOR = 2048   # rows of the smallest decode context bucket
 PREFILL_CONTEXT_FLOOR = 8192  # rows of a later chunk's smallest context
 
@@ -94,10 +94,9 @@ class JambaConfig:
         self.eps = rms_norm_eps
         self.vocab = vocab_size
         self.max_context = max_context
-        self.decode_context_floor = min(_pow2_floor(max_context),
-                                        DECODE_CONTEXT_FLOOR)
-        self.prefill_context_floor = min(_pow2_floor(max_context),
-                                         PREFILL_CONTEXT_FLOOR)
+        self.decode_context_floor, self.prefill_context_floor = \
+            context_floors(max_context, DECODE_CONTEXT_FLOOR,
+                           PREFILL_CONTEXT_FLOOR)
         self.seed = seed
         self.attn = attn            # as ModelConfig.attn
 
@@ -143,30 +142,41 @@ class JambaConfig:
                 "wo": (d, d), "ln1": (d,), "ln2": (d,), "wg": (d, ff),
                 "wu": (d, ff), "wd": (ff, d)}
 
-    def drawn(self, layer: Optional[int] = None):
-        """(name, stream id, shape, fan-in) of every DRAWN array of
-        ``layer`` (the embedding where it is None)."""
+    def arrays(self, layer: Optional[int] = None):
+        """(name, stream id or None, shape, fan-in or name) of every array
+        of ``layer`` (the embedding and the final norm where it is None):
+        drawn by ``draw_matrix`` from its stream, or where the stream is
+        None the family's :meth:`constant` of that name."""
+        d, sh = self.d_model, self.shapes()
         if layer is None:
             # 0.1 / sqrt(d): with rows as long as the layers' outputs a
             # tied head returns the token it was given
-            return [("embed", 10 ** 6, (self.vocab, self.d_model),
-                     25 * self.d_model)]
-        sh, base = self.shapes(), 1000 * layer
+            return [("embed", 10 ** 6, (self.vocab, d), 25 * d),
+                    ("lnf", None, (d,), "lnf")]
+        base, p = 1000 * layer, f"l{layer}."
         if self.kinds[layer] == "mamba":
             mixer = [("win", 0, 0), ("conv_w", 1, 0), ("conv_b", 2, 25),
                      ("wx", 3, 0), ("wdt", 4, 0), ("wout", 5, 0)]
+            fixed = [k for k in MAMBA if k not in {m[0] for m in mixer}]
         else:
-            mixer = [("wq", 0, 0), ("wk", 1, 0), ("wv", 2, 0), ("wo", 3, 0)]
-        return [(f"l{layer}.{name}", base + sid, sh[name],
-                 fan or sh[name][0])
-                for name, sid, fan in mixer + [("wg", 10, 0), ("wu", 11, 0),
-                                               ("wd", 12, 0)]]
+            mixer, fixed = [("wq", 0, 0), ("wk", 1, 0), ("wv", 2, 0),
+                            ("wo", 3, 0)], []
+        return ([(p + name, base + sid, sh[name], fan or sh[name][0])
+                 for name, sid, fan in mixer + [("wg", 10, 0), ("wu", 11, 0),
+                                                ("wd", 12, 0)]]
+                + [(p + k, None, sh[k], k) for k in fixed + ["ln1", "ln2"]])
 
-    def constant(self, name: str) -> np.ndarray:
-        """One layer's array of a stack that is not drawn: ``A_log =
-        log(1..d_state)`` in every channel, a ``dt`` bias whose softplus
-        runs 1e-3..1e-1 over the channels, ``D`` and every norm weight 1."""
-        shape = self.shapes()[name]
+    def draw(self, seed: int, stream, shape, fan_in) -> np.ndarray:
+        """One array of :meth:`arrays`' recipe."""
+        if stream is None:
+            return self.constant(fan_in, shape)
+        return draw_matrix(seed, stream, shape, fan_in)
+
+    @staticmethod
+    def constant(name: str, shape) -> np.ndarray:
+        """An array that is not drawn: ``A_log = log(1..d_state)`` in every
+        channel, a ``dt`` bias whose softplus runs 1e-3..1e-1 over the
+        channels, ``D`` and every norm weight 1."""
         if name == "a_log":
             return np.broadcast_to(np.log(np.arange(
                 1, shape[0] + 1, dtype=np.float64))[:, None],
@@ -178,17 +188,7 @@ class JambaConfig:
         return np.ones(shape, np.float32)
 
 
-def _pow2_floor(n: int) -> int:
-    return 1 << (max(1, n).bit_length() - 1)
-
-
 # ------------------------------------------------------------ layer functions
-def _f32(x):
-    import jax.numpy as jnp
-
-    return x.astype(jnp.float32)
-
-
 def _mamba_weights(cfg, w, i):
     """Layer ``i`` (of the Mamba layers) out of the stacks, as
     ``_mamba_inputs`` names them; the vectors and the conv's weights in
@@ -209,60 +209,6 @@ def _mlp(cfg, w, l, x):
                        w["wd"][l])
 
 
-def attend_chunk_blocked(cfg, q, k, v, start):
-    """Causal attention of a chunk's rows over the context: query ``i`` (row
-    ``start + i``) over keys ``0 .. start + i``, a block of query rows at a
-    time so that the scores held are heads x block x context. q (C, H, hd);
-    k, v (L, G, hd)."""
-    import jax
-    import jax.numpy as jnp
-
-    c, g = q.shape[0], k.shape[1]
-    qb = min(QUERY_BLOCK, c)
-    qh = q.reshape(c // qb, qb, g, cfg.n_heads // g, cfg.head_dim)
-    k_pos = jnp.arange(k.shape[0])[None, :]
-
-    def one(args):
-        qc, i0 = args
-        sc = _mm(qc, k, "qgjd,kgd->gjqk") / math.sqrt(cfg.head_dim)
-        live = k_pos <= (start + i0 + jnp.arange(qb))[:, None]
-        prob = jax.nn.softmax(jnp.where(live, sc, NEG), axis=-1)
-        return _mm(prob, v, "gjqk,kgd->qgjd")
-
-    out = jax.lax.map(one, (qh, jnp.arange(c // qb) * qb))
-    return out.reshape(c, cfg.n_heads, cfg.head_dim)
-
-
-def attend_chunk_flash(cfg, q, k, v, start):
-    """The same through the flash carry kernel, a query head a pass (a loop
-    over the heads: one kernel, the key/value head read in place), with the
-    chunk's first row as the kernel's run-time offset; tiles past a query
-    block's rows are skipped. Operands as :func:`_mm` rounds them."""
-    import jax
-    import jax.numpy as jnp
-
-    from brpc_tpu.tpu import pallas_ops
-
-    c, g, hd = q.shape[0], k.shape[1], cfg.head_dim
-    dt = _operand_dtype()
-    per = cfg.n_heads // g
-    m0 = jnp.full((c, 1), pallas_ops.NEG_INF, jnp.float32)
-    l0 = jnp.zeros((c, 1), jnp.float32)
-    acc0 = jnp.zeros((c, hd), jnp.float32)
-    kh, vh = k.astype(dt).transpose(1, 0, 2), v.astype(dt).transpose(1, 0, 2)
-
-    def head(args):
-        qh, j = args
-        _m, l, acc = pallas_ops.flash_attention_carry(
-            qh, kh[j // per], vh[j // per], m0, l0, acc0, start, 0,
-            causal=True, block_q=min(512, c), block_k=512)
-        return acc / l
-
-    out = jax.lax.map(head, (q.astype(dt).transpose(1, 0, 2),
-                             jnp.arange(cfg.n_heads)))
-    return out.transpose(1, 0, 2)
-
-
 class JambaModel(HybridServingModel):
     """Weights + the chunk and decode programs over a HybridStateCache."""
 
@@ -274,71 +220,30 @@ class JambaModel(HybridServingModel):
         ..., ``embed``, ``lnf``) that replace what the recipe gives
         (tests); everything else is drawn from ``config.seed`` or set to the
         family's constant."""
-        import jax
         import jax.numpy as jnp
 
         self._init_programs(config, kv)
-        cfg, dev = config, self.store.device
-        given = dict(weights or {})
-        shapes = cfg.shapes()
-
-        def handed(name, shape):
-            got = np.asarray(given[name], np.float32)  # tpulint: disable=no-per-token-host-sync
-            if got.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {got.shape} != {shape}")
-            return got
-
-        def host(name, sid, shape, fan_in):
-            if name in given:
-                return handed(name, shape)
-            return draw_matrix(cfg.seed, sid, shape, fan_in)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def fill(buf, part, i):
-            return jax.lax.dynamic_update_index_in_dim(
-                buf, part.astype(buf.dtype), i, 0)
-
+        cfg, shapes = config, config.shapes()
         n_of = {"m.": cfg.count("mamba"), "a.": cfg.count("full"),
                 "": cfg.n_layers}
         stacks = {pre + k: jnp.zeros((n_of[pre],) + shapes[k], jnp.bfloat16)
                   for pre, names in (("m.", MAMBA), ("a.", ATTN),
                                      ("", EVERY)) for k in names}
-        # drawn a layer ahead on threads (numpy frees the interpreter),
-        # staged ONE array at a time into the stacks the programs read:
-        # set-up, not a step loop
-        with ThreadPoolExecutor(4) as pool:
-            def start(layer):
-                return {m[0]: pool.submit(host, *m)
-                        for m in cfg.drawn(layer)}
-
-            drawn, ahead = start(None), start(0)
-            self._stage("embed", jax.device_put(
-                drawn.pop("embed").result(), dev).astype(jnp.bfloat16))
-            seen = {"mamba": 0, "full": 0}
-            for l, kind in enumerate(cfg.kinds):
-                drawn = ahead
-                ahead = start(l + 1) if l + 1 < cfg.n_layers else {}
-                pre, names = (("m.", MAMBA) if kind == "mamba"
-                              else ("a.", ATTN))
-                for stack, k, i in ([(pre + k, k, seen[kind])
-                                     for k in names]
-                                    + [(k, k, l) for k in EVERY]):
-                    name = f"l{l}.{k}"
-                    part = (drawn.pop(name).result() if name in drawn
-                            else handed(name, shapes[k]) if name in given
-                            else cfg.constant(k))
-                    stacks[stack] = fill(stacks[stack],
-                                         jax.device_put(part, dev), i)  # tpulint: disable=no-per-op-step-dispatch
-                seen[kind] += 1
+        seen = {"mamba": 0, "full": 0}
+        for l, take in self._drawn_layers(cfg.arrays, cfg.draw, weights):
+            if l is None:
+                for name in ("embed", "lnf"):
+                    self._stage(name, take(name).astype(jnp.bfloat16))
+                continue
+            kind = cfg.kinds[l]
+            pre, names = ("m.", MAMBA) if kind == "mamba" else ("a.", ATTN)
+            for stack, k, i in ([(pre + k, k, seen[kind]) for k in names]
+                                + [(k, k, l) for k in EVERY]):
+                stacks[stack] = fill_stack(stacks[stack],
+                                           take(f"l{l}.{k}"), i)
+            seen[kind] += 1
         for name, arr in stacks.items():
             self._stage(name, arr)
-        self._stage("lnf", jax.device_put(
-            np.asarray(given.get("lnf", np.ones(cfg.d_model)), np.float32),  # tpulint: disable=no-per-token-host-sync
-            dev).astype(jnp.bfloat16))
-
-    def _decode_buckets(self, n_rows: int, tables):
-        return decode_buckets(n_rows, tables, self.kv.block_size,
-                              self.config.decode_context_floor)
 
     # ---- what both programs share of a layer
     def _mamba(self, w, i, l, x, scan):
@@ -387,14 +292,6 @@ class JambaModel(HybridServingModel):
                 for j in range(n):
                     carry = attention(w, i0 + j, l0 + j, carry)
         return carry
-
-    def _context(self, pool, layer: int, blocks):
-        """Rows of one layer of a pool, whole BLOCKS at a time, the layer
-        inside the gather's index (``pool[layer]`` first would copy the
-        layer)."""
-        bs = self.kv.block_size
-        return pool.reshape(len(pool), -1, bs, self.config.kv_dim)[
-            layer, blocks]
 
     # --------------------------------------------------------------- chunk
     def _chunk_fn(self, c_bucket: int, l_bucket: int, use_flash: bool):
@@ -452,9 +349,9 @@ class JambaModel(HybridServingModel):
                     # chunk's among them
                     return attend_rows(
                         cfg, q,
-                        self._context(fk, i, table).reshape(
+                        _block_context(fk, i, table, bs).reshape(
                             l_bucket, cfg.n_kv_heads, -1),
-                        self._context(fv, i, table).reshape(
+                        _block_context(fv, i, table, bs).reshape(
                             l_bucket, cfg.n_kv_heads, -1), start)
 
                 x = self._attention(w, i, l, x, attend)
@@ -521,9 +418,9 @@ class JambaModel(HybridServingModel):
                     nonlocal fk, fv
                     fk = fk.at[i, full_write].set(k)
                     fv = fv.at[i, full_write].set(v)
-                    kh = self._context(fk, i, tables).reshape(
+                    kh = _block_context(fk, i, tables, bs).reshape(
                         b_bucket, l_bucket, g, hd)
-                    vh = self._context(fv, i, tables).reshape(
+                    vh = _block_context(fv, i, tables, bs).reshape(
                         b_bucket, l_bucket, g, hd)
                     sc = _mm(q.reshape(b_bucket, g, per, hd), kh,
                              "bgjd,bkgd->bgjk") / math.sqrt(hd)

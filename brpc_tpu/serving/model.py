@@ -37,7 +37,16 @@ import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.profiling.registry import wait_span as _wait_span
+from brpc_tpu.serving.hybrid_model import _next_pow2
 from brpc_tpu.serving.kv_cache import PagedKVCache
+
+# the counter families every ``ServingEngine.snapshot()`` carries, None where
+# its model keeps none: the toy block's ``decode`` (here) and the hybrid
+# lane's ``moe``, ``scan`` and ``mla`` (serving/hybrid_model.py,
+# serving/glm_model.py). A model reports these and any others by name
+# through ``counters()`` and formats their ``/serving`` lines through
+# ``counter_lines(snapshot)``; the engine names none of them.
+COUNTER_FAMILIES = ("decode", "moe", "scan", "mla")
 
 
 class ModelConfig:
@@ -66,13 +75,6 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.d_model
-
-
-def _next_pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p <<= 1
-    return p
 
 
 def _prefill_bucket(s: int) -> int:
@@ -247,7 +249,7 @@ class TinyTransformer:
         self._decode_fns = {}     # the jitted programs those keys share
         # what the decode launches of this instance ran and read: pages
         # the rows' lengths cover against pages of the padded bucket (what
-        # the gather copies); ServingEngine.snapshot() and /serving show it
+        # the gather copies); ``counters()`` reports them
         self.decode_counters = {"decode_launches_paged": 0,
                                 "decode_launches_gather": 0,
                                 "decode_pages_live": 0,
@@ -603,6 +605,32 @@ class TinyTransformer:
             res.append(out[off:off + c])
             off += c
         return res
+
+    # ------------------------------------------------------------ counters
+    def counters(self):
+        """The counter families this model keeps, by name, for
+        ``ServingEngine.snapshot()`` (which copies them): ``decode``, the
+        decode launches by attention path, the pages the rows' lengths
+        covered against the pages of the padded buckets, and their ratio
+        ``live_share``."""
+        c = dict(self.decode_counters)
+        c["live_share"] = round(
+            c["decode_pages_live"] / max(1, c["decode_pages_bucket"]), 4)
+        return {"decode": c}
+
+    def counter_lines(self, snap) -> List[str]:
+        """``/serving``'s line of the ``decode`` family in ``snap`` (an
+        engine's snapshot): which path the launches took, and how much of
+        the padded bucket (what the gather copies) the rows' lengths cover
+        (what the paged kernel reads)."""
+        dec = snap.get("decode")
+        if not dec:
+            return []
+        return [f"  decode: launches paged={dec['decode_launches_paged']} "
+                f"gather={dec['decode_launches_gather']} "
+                f"pages live={dec['decode_pages_live']} "
+                f"bucket={dec['decode_pages_bucket']} "
+                f"live_share={dec['live_share']:.2f}"]
 
     # ------------------------------------------------------------- helpers
     def close(self) -> None:

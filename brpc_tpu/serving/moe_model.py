@@ -29,15 +29,17 @@ sync a decode step):
   chunks of ``MOE_CHUNK`` rows.
 - ``decode_step``: one fused launch for the batch over ring rows and pages.
 
-The routed product is ONE routine for both, :func:`expert_layer`: pairs
-sorted by expert, each expert's run padded to whole tiles, no pair dropped,
-static shapes by bucket, through ``pallas_ops.moe_grouped_matmul`` (a tile
-streams one expert's weights; an expert no row hit is never read). Each
-launch returns, beside its tokens and in the same sync, per layer the pairs
-computed here, the distinct held experts hit and the most pairs of one
-expert: ``moe_counters``, which ``ServingEngine.snapshot()["moe"]`` reads
-(prefill's also how many layers of the launched programs took the kernel and
-how many the blocked scan, summed on the host).
+The routed product is ONE routine for both, the lane's
+:func:`~brpc_tpu.serving.hybrid_model.expert_layer`: pairs sorted by expert,
+each expert's run padded to whole tiles, no pair dropped, static shapes by
+bucket, through ``pallas_ops.moe_grouped_matmul`` (a tile streams one
+expert's weights; an expert no row hit is never read). Each launch returns,
+beside its tokens and in the same sync, per layer the pairs computed here,
+the distinct held experts hit and the most pairs of one expert: the model's
+``moe`` counter family (``moe_counters``; prefill's also how many layers of
+the launched programs took the kernel and how many the blocked scan, summed
+on the host), which ``ServingEngine.snapshot()`` copies and ``/serving``
+prints as the model formats it.
 
 Storage: weights and K/V pools bfloat16 (K stored rotated); the router's
 weights, the residual stream, softmax, router and every sum float32; matmuls
@@ -49,21 +51,21 @@ from __future__ import annotations
 
 import functools
 import math
-from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional, Sequence
 
 import numpy as np
 
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
-from brpc_tpu.serving.hybrid_model import (NEG, HybridServingModel, _ln,
-                                           _ring_live, decode_buckets)
+from brpc_tpu.serving.hybrid_model import (NEG, QUERY_BLOCK,
+                                           HybridServingModel,
+                                           _block_context, _ln, _mm,
+                                           _operand_dtype, _ring_live,
+                                           draw_matrix, expert_counts,
+                                           expert_layer, fill_stack, rope,
+                                           route, shared_experts)
 
-QUERY_BLOCK = 128    # query rows a step of the blocked window attention
 MOE_CHUNK = 1024     # rows a pass of the expert layer in prefill
 DECODE_CONTEXT_FLOOR = 1024   # rows of the smallest decode context bucket
-COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
-# how a launched prefill program's attention layers were built (host sums)
-LAYER_PATHS = ("kernel_layers", "blocked_layers")
 
 
 class Cohere2MoeConfig:
@@ -164,62 +166,7 @@ class Cohere2MoeConfig:
         return out
 
 
-def draw_matrix(seed: int, stream: int, shape, fan_in: int) -> np.ndarray:
-    """One matrix of the recipe: ``Generator(Philox(key=[seed, stream]))``,
-    ``standard_normal`` float32 in row-major order, times
-    ``0.5 / sqrt(fan_in)``."""
-    rng = np.random.Generator(np.random.Philox(key=[seed, stream]))
-    out = rng.standard_normal(shape, dtype=np.float32)
-    out *= np.float32(0.5 / math.sqrt(fan_in))
-    return out
-
-
 # ------------------------------------------------------------ layer functions
-def _operand_dtype():
-    """What a matmul's operands are rounded to: the TPU's default precision
-    rounds both to bfloat16, so they are cast (the weights ARE bfloat16:
-    nothing is converted on the way in); elsewhere products are exact."""
-    import jax.numpy as jnp
-
-    from brpc_tpu.tpu.pallas_ops import _on_tpu
-
-    return jnp.bfloat16 if _on_tpu() else jnp.float32
-
-
-def _mm(a, b, spec: Optional[str] = None):
-    """``a @ b`` (or ``einsum(spec, a, b)``) on rounded operands, summed in
-    float32."""
-    import jax.numpy as jnp
-
-    dt = _operand_dtype()
-    a, b = a.astype(dt), b.astype(dt)
-    if spec is None:
-        return jnp.matmul(a, b, preferred_element_type=jnp.float32)
-    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
-
-
-def rope(x, pos, theta: float):
-    """``rope_gptj``: pairs ``(2j, 2j + 1)`` of the last axis turned by
-    ``pos * theta^(-2j / hd)``, the pairs left interleaved. x (rows, heads,
-    hd) float32, pos (rows,). As ``x cos + swap(x) sin`` at the full head
-    width, ``swap(x)[2j] = -x[2j + 1]``, ``swap(x)[2j + 1] = x[2j]`` a
-    product with the fixed signed pair-swap matrix (exact at ``highest``:
-    every column has one entry, 1 or -1): no slice strides the lanes."""
-    import jax
-    import jax.numpy as jnp
-
-    hd = x.shape[-1]
-    inv = np.repeat(theta ** (-np.arange(0, hd, 2, dtype=np.float64) / hd), 2)
-    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv, jnp.float32)
-    swap = np.zeros((hd, hd), np.float32)
-    even = np.arange(0, hd, 2)
-    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
-    turned = jnp.matmul(x, jnp.asarray(swap),
-                        precision=jax.lax.Precision.HIGHEST)
-    return (x * jnp.cos(ang)[:, None, :]
-            + turned * jnp.sin(ang)[:, None, :])
-
-
 def _softmax_out(sc, live, v, spec):
     import jax
     import jax.numpy as jnp
@@ -275,196 +222,54 @@ def attend_blocked(cfg, q, k, v, window: int):
     return out.reshape(s, cfg.n_heads, hd)
 
 
-def route(cfg, h, w_router, live, bias=None, scale=None):
-    """``sigmoid(h Wr)`` in float32 at ``highest``, the ``top_k`` largest,
-    weights renormalised over them. ``bias`` (experts,): a stored selection
-    bias added to the scores for the CHOICE only (the weights are the
-    chosen experts' scores without it); ``scale``: a factor on the
-    renormalised weights. Returns (rows, k) expert ids (published indices;
-    ``-1`` for a row that is not live) and weights."""
-    import jax
-    import jax.numpy as jnp
-
-    s = jax.nn.sigmoid(jnp.dot(h, w_router,
-                               precision=jax.lax.Precision.HIGHEST))
-    if bias is None:
-        top, idx = jax.lax.top_k(s, cfg.top_k)
-    else:
-        _, idx = jax.lax.top_k(s + bias, cfg.top_k)
-        top = jnp.take_along_axis(s, idx, axis=-1)
-    wts = top / jnp.sum(top, axis=-1, keepdims=True)
-    return (jnp.where(live[:, None], idx, -1),
-            wts if scale is None else wts * scale)
-
-
-def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int, first=None):
-    """This chip's part of ``sum_e w_e E_e(h)``: the pairs (row, expert)
-    whose expert is held here, sorted by expert, each expert's run padded
-    to whole tiles of ``tile`` rows, through the grouped matmul (gate and up
-    as one product, then down), and gathered back to their rows. h (R, d)
-    float32; idx, wts (R, k) from :func:`route`; wgu (held, d, 2 ff), wd
-    (held, ff, d). An index that is nobody's here (another chip's expert,
-    an output that computes nothing, ``-1``) is left out. ``first``: where
-    held expert 0 lies in ``wgu`` and ``wd`` when they stack the experts of
-    many layers (layers x held, ...), so that the kernel indexes the stack
-    and no layer is sliced out of it. Returns (R, d) float32 and the pairs
-    held by expert (held,) int32."""
-    import jax
-    import jax.numpy as jnp
-
-    from brpc_tpu.tpu import pallas_ops
-
-    r, k = idx.shape
-    n = cfg.held
-    with jax.named_scope("moe_dispatch"):
-        local = idx - cfg.expert_lo
-        here = (idx >= 0) & (local >= 0) & (local < n)
-        key = jnp.where(here, local, n).reshape(-1)          # (R k,)
-        order = jnp.argsort(key, stable=True)
-        cnt = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
-                      dtype=jnp.int32)
-        padded = -(-cnt // tile) * tile
-        p_end, u_end = jnp.cumsum(padded), jnp.cumsum(cnt)
-        p_start, u_start = p_end - padded, u_end - cnt
-        # every pair here fits: R k pairs, and at most tile - 1 pads a run
-        rows = -(-(r * k + n * (tile - 1)) // tile) * tile
-        tile_expert = jnp.minimum(jnp.searchsorted(
-            p_end, jnp.arange(rows // tile) * tile, side="right"), n - 1)
-        # the sorted, padded rows: row p of expert e's run is its off-th pair
-        p = jnp.arange(rows)
-        e_of = tile_expert[p // tile]
-        off = p - p_start[e_of]
-        pair = order[jnp.minimum(u_start[e_of] + off, r * k - 1)]
-        x = jnp.where((off < cnt[e_of])[:, None], h[pair // k], 0.0)
-        # where each pair's row went: its rank in the sorted order
-        rank = jnp.zeros(r * k, jnp.int32).at[order].set(
-            jnp.arange(r * k, dtype=jnp.int32))
-        e_pair = jnp.minimum(key, n - 1)
-        dest = jnp.where(here.reshape(-1),
-                         p_start[e_pair] + rank - u_start[e_pair], 0)
-    with jax.named_scope("experts"):
-        used = p_end[-1] // tile
-        if first is not None:
-            tile_expert = tile_expert + first
-        gmm = functools.partial(pallas_ops.moe_grouped_matmul,
-                                tile_expert=tile_expert, tiles_used=used,
-                                block_rows=tile)
-        gu = gmm(x.astype(_operand_dtype()), wgu)
-        act = jax.nn.silu(gu[:, :cfg.d_ff]) * gu[:, cfg.d_ff:]
-        y = gmm(act.astype(_operand_dtype()), wd)
-    with jax.named_scope("moe_dispatch"):
-        got = y[dest].reshape(r, k, -1)
-        out = jnp.sum(jnp.where(here[..., None], wts[..., None] * got, 0.0),
-                      axis=1)
-    return out, cnt
-
-
-def shared_experts(cfg, h, wgu, wd):
-    """The mean of the shared experts' outputs: their gate and up
-    projections side by side, their down projections stacked, so the sum
-    over them is one product's."""
-    import jax
-
-    with jax.named_scope("shared_experts"):
-        gu = _mm(h, wgu)
-        half = cfg.n_shared * cfg.d_ff
-        return _mm(jax.nn.silu(gu[:, :half]) * gu[:, half:], wd) \
-            / cfg.n_shared
-
-
 class Cohere2MoeModel(HybridServingModel):
     """Weights + the prefill and decode programs over a HybridStateCache."""
 
+    DECODE_ROWS_BY_8 = True   # a step gathers every padded row's context
+    MOE_COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
+    # how a launched prefill program's attention layers were built
+    PREFILL_MOE_COUNTERS = ("kernel_layers", "blocked_layers")
+
     def __init__(self, config: Cohere2MoeConfig, kv: HybridStateCache,
                  weights: Optional[Dict[str, np.ndarray]] = None):
-        """``weights``: host arrays by ``config.matrices()``'s names
-        (tests); drawn from ``config.seed`` where it is left out."""
-        import jax
+        """``weights``: host arrays by ``config.matrices()``'s names that
+        replace what the recipe draws (tests); the rest is drawn from
+        ``config.seed``."""
         import jax.numpy as jnp
 
         self._init_programs(config, kv)
-        self.moe_counters = {"experts_held": config.held}
-        self.reset_moe_counters()
-        cfg = config
-        dev = self.store.device
-        d, ff = cfg.d_model, cfg.d_ff
-
-        def host(name, sid, shape, fan_in):
-            if weights is None:
-                return draw_matrix(cfg.seed, sid, shape, fan_in)
-            got = np.asarray(weights[name], np.float32)  # tpulint: disable=no-per-token-host-sync
-            if got.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {got.shape} != {shape}")
-            return got
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def fill(buf, part, *start):
-            return jax.lax.dynamic_update_slice(buf, part.astype(buf.dtype),
-                                                start)
-
-        # drawn a layer ahead on threads (numpy frees the interpreter),
-        # staged ONE matrix at a time into the arrays the programs read:
-        # set-up, not a step loop
-        with ThreadPoolExecutor(4) as pool:
-            def start(layer):
-                return {m[0]: pool.submit(host, *m)
-                        for m in cfg.matrices(layer)}
-
-            def take(drawn, name):
-                return jax.device_put(drawn.pop(name).result(), dev)  # tpulint: disable=no-per-op-step-dispatch
-
-            drawn, ahead = start(None), start(0)
-            self._stage("embed", take(drawn, "embed").astype(jnp.bfloat16))
-            for l in range(cfg.n_layers):
-                drawn, p = ahead, f"l{l}."
-                ahead = start(l + 1) if l + 1 < cfg.n_layers else {}
-                for name in ("wq", "wk", "wv", "wo"):
-                    self._stage(p + name,
-                                take(drawn, p + name).astype(jnp.bfloat16))
-                self._stage(p + "router", take(drawn, p + "router"))
-                self._stage(p + "ln_w", jnp.ones((d,), jnp.float32))
-                # shared experts: gates side by side, then ups; downs stacked
-                ns = cfg.n_shared
-                wgu = jnp.zeros((d, 2 * ns * ff), jnp.bfloat16)
-                wd = jnp.zeros((ns * ff, d), jnp.bfloat16)
-                for i in range(ns):
-                    wgu = fill(wgu, take(drawn, f"{p}s{i}.wg"), 0, i * ff)
-                    wgu = fill(wgu, take(drawn, f"{p}s{i}.wu"), 0,
-                               (ns + i) * ff)
-                    wd = fill(wd, take(drawn, f"{p}s{i}.wd"), i * ff, 0)
-                self._stage(p + "s_wgu", wgu)
-                self._stage(p + "s_wd", wd)
-                # held experts: (held, d, gate | up) and (held, ff, d)
-                wgu = jnp.zeros((cfg.held, d, 2 * ff), jnp.bfloat16)
-                wd = jnp.zeros((cfg.held, ff, d), jnp.bfloat16)
-                for i in range(cfg.held):
-                    e = cfg.expert_lo + i
-                    wgu = fill(wgu, take(drawn, f"{p}e{e}.wg")[None], i, 0, 0)
-                    wgu = fill(wgu, take(drawn, f"{p}e{e}.wu")[None], i, 0,
-                               ff)
-                    wd = fill(wd, take(drawn, f"{p}e{e}.wd")[None], i, 0, 0)
-                self._stage(p + "e_wgu", wgu)
-                self._stage(p + "e_wd", wd)
-            self._stage("lnf_w", jnp.ones((d,), jnp.float32))
-
-    def _decode_buckets(self, n_rows: int, tables):
-        """Rows to a multiple of 8 (a step gathers every padded row's
-        context), the context as the hybrid lane's from its own floor."""
-        return (-(-n_rows // 8) * 8,
-                decode_buckets(n_rows, tables, self.kv.block_size,
-                               self.config.decode_context_floor)[1])
-
-    def reset_moe_counters(self) -> None:
-        for phase, more in (("decode", ()), ("prefill", LAYER_PATHS)):
-            self.moe_counters[phase] = dict.fromkeys(
-                COUNTERS + ("layer_launches",) + more, 0)
-
-    def _note_counters(self, phase: str, tail) -> None:
-        c = self.moe_counters[phase]
-        per_layer = np.asarray(tail, np.int64).reshape(-1, len(COUNTERS))
-        c["layer_launches"] += len(per_layer)
-        for name, total in zip(COUNTERS, per_layer.sum(axis=0)):
-            c[name] += int(total)
+        cfg, bf = config, jnp.bfloat16
+        d, ff, ns = cfg.d_model, cfg.d_ff, cfg.n_shared
+        for l, take in self._drawn_layers(cfg.matrices, draw_matrix,
+                                          weights):
+            if l is None:
+                self._stage("embed", take("embed").astype(bf))
+                continue
+            p = f"l{l}."
+            for name in ("wq", "wk", "wv", "wo"):
+                self._stage(p + name, take(p + name).astype(bf))
+            self._stage(p + "router", take(p + "router"))
+            self._stage(p + "ln_w", jnp.ones((d,), jnp.float32))
+            # shared experts: gates side by side, then ups; downs stacked
+            wgu = jnp.zeros((d, 2 * ns * ff), bf)
+            wd = jnp.zeros((ns * ff, d), bf)
+            for i in range(ns):
+                wgu = fill_stack(wgu, take(f"{p}s{i}.wg"), 0, i * ff)
+                wgu = fill_stack(wgu, take(f"{p}s{i}.wu"), 0, (ns + i) * ff)
+                wd = fill_stack(wd, take(f"{p}s{i}.wd"), i * ff)
+            self._stage(p + "s_wgu", wgu)
+            self._stage(p + "s_wd", wd)
+            # held experts: (held, d, gate | up) and (held, ff, d)
+            wgu = jnp.zeros((cfg.held, d, 2 * ff), bf)
+            wd = jnp.zeros((cfg.held, ff, d), bf)
+            for i in range(cfg.held):
+                e = cfg.expert_lo + i
+                wgu = fill_stack(wgu, take(f"{p}e{e}.wg"), i)
+                wgu = fill_stack(wgu, take(f"{p}e{e}.wu"), i, 0, ff)
+                wd = fill_stack(wd, take(f"{p}e{e}.wd"), i)
+            self._stage(p + "e_wgu", wgu)
+            self._stage(p + "e_wd", wd)
+        self._stage("lnf_w", jnp.ones((d,), jnp.float32))
 
     # ---- what both programs share of a layer
     def _qkv(self, p, w, h, pos, rotate: bool):
@@ -491,12 +296,6 @@ class Cohere2MoeModel(HybridServingModel):
                                    w[p + "e_wd"], tile)
         return routed + shared_experts(cfg, h, w[p + "s_wgu"],
                                        w[p + "s_wd"]), cnt
-
-    @staticmethod
-    def _counted(cnt):
-        import jax.numpy as jnp
-
-        return jnp.stack([jnp.sum(cnt), jnp.sum(cnt > 0), jnp.max(cnt)])
 
     # ------------------------------------------------------------- prefill
     def _prefill_fn(self, s_bucket: int, use_flash: bool):
@@ -558,7 +357,7 @@ class Cohere2MoeModel(HybridServingModel):
                 y, cnt = jax.lax.map(
                     ffn, (h.reshape(-1, chunk, cfg.d_model),
                           live.reshape(-1, chunk)))
-                counts.append(self._counted(jnp.sum(cnt, axis=0)))
+                counts.append(expert_counts(jnp.sum(cnt, axis=0)))
                 x = x + att + y.reshape(s_bucket, cfg.d_model)
             with scope("head"):
                 last = _ln(x[length - 1], w["lnf_w"], 0.0, cfg.eps)
@@ -569,8 +368,8 @@ class Cohere2MoeModel(HybridServingModel):
             return fk, fv, wk, wv, ssm, conv, out
 
         program = jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
-        built = dict(zip(LAYER_PATHS, (sum(kernel),
-                                       cfg.n_layers - sum(kernel))))
+        built = dict(zip(self.PREFILL_MOE_COUNTERS,
+                         (sum(kernel), cfg.n_layers - sum(kernel))))
 
         @functools.wraps(program)
         def launch(*args):
@@ -612,15 +411,6 @@ class Cohere2MoeModel(HybridServingModel):
             # length at least) routes nowhere
             live = pos > 0
 
-            def context(pool, layer: int, blocks):
-                """A batch's rows of one layer of a pool, whole BLOCKS at a
-                time (a block is 16 contiguous rows: gathered three times
-                as fast as its rows one by one, measured), the layer inside
-                the gather's index: ``pool[layer]`` first is a copy of the
-                whole layer, 404 MB of the rings a window layer."""
-                return pool.reshape(len(pool), -1, bs, cfg.kv_dim)[
-                    layer, blocks].reshape(b_bucket, -1, cfg.kv_dim)
-
             def attend(q, kc, vc, mask):
                 qh = q.reshape(b_bucket, g, per, hd)
                 kh = kc.reshape(b_bucket, -1, g, hd)
@@ -641,18 +431,20 @@ class Cohere2MoeModel(HybridServingModel):
                     if kind == "window":
                         wk = wk.at[i_w, ring_write].set(k)
                         wv = wv.at[i_w, ring_write].set(v)
-                        a = attend(q, context(wk, i_w, ring_blocks),
-                                   context(wv, i_w, ring_blocks), ring_live)
+                        a = attend(q, _block_context(wk, i_w, ring_blocks, bs),
+                                   _block_context(wv, i_w, ring_blocks, bs),
+                                   ring_live)
                         i_w += 1
                     else:
                         fk = fk.at[i_f, full_write].set(k)
                         fv = fv.at[i_f, full_write].set(v)
-                        a = attend(q, context(fk, i_f, tables),
-                                   context(fv, i_f, tables), full_live)
+                        a = attend(q, _block_context(fk, i_f, tables, bs),
+                                   _block_context(fv, i_f, tables, bs),
+                                   full_live)
                         i_f += 1
                     att = _mm(a.reshape(b_bucket, cfg.q_dim), w[p + "wo"])
                 y, cnt = self._ffn(p, w, h, live, tile=16)
-                counts.append(self._counted(cnt))
+                counts.append(expert_counts(cnt))
                 x = x + att + y
             with scope("head"):
                 last = _ln(x, w["lnf_w"], 0.0, cfg.eps)

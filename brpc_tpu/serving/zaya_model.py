@@ -28,12 +28,13 @@ the other hybrid lanes:
 - ``decode_step``: one fused launch for the batch: per layer a tail shift,
   the row appended and the context gathered whole blocks at a time.
 
-The routed product is :func:`~brpc_tpu.serving.moe_model.expert_layer` at
-``k = 1`` with every expert held; a row the router sends to output
-``num_experts`` (the skip) is nobody's and computes nothing. Each launch
-returns, beside its tokens and in the same sync, per layer the pairs
-computed, the experts hit, the most pairs of one expert and the rows
-skipped: ``moe_counters``, read by ``ServingEngine.snapshot()["moe"]``.
+The routed product is the lane's
+:func:`~brpc_tpu.serving.hybrid_model.expert_layer` at ``k = 1`` with every
+expert held; a row the router sends to output ``num_experts`` (the skip) is
+nobody's and computes nothing. Each launch returns, beside its tokens and in
+the same sync, per layer the pairs computed, the experts hit, the most pairs
+of one expert and the rows skipped: the model's ``moe`` counter family
+(``moe_counters``).
 
 Storage: weights and K/V pools bfloat16, the router's arrays float32; matmul
 operands rounded to bfloat16 on the TPU (exact elsewhere), sums float32; both
@@ -46,21 +47,21 @@ from __future__ import annotations
 
 import functools
 import math
-from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional
 
 import numpy as np
 
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
-from brpc_tpu.serving.hybrid_model import (NEG, HybridServingModel, _rms,
-                                           conv_windows, decode_buckets)
-from brpc_tpu.serving.jamba_model import (_pow2_floor, attend_chunk_blocked,
-                                          attend_chunk_flash)
-from brpc_tpu.serving.moe_model import _mm, expert_layer
+from brpc_tpu.serving.hybrid_model import (NEG, HybridServingModel,
+                                           _block_context, _f32, _mm, _rms,
+                                           attend_chunk_blocked,
+                                           attend_chunk_flash, context_floors,
+                                           conv_windows, draw_array,
+                                           expert_counts, expert_layer,
+                                           fill_stack)
 
 DECODE_CONTEXT_FLOOR = 2048   # rows of the smallest decode context bucket
 PREFILL_CONTEXT_FLOOR = 4096  # rows of a later chunk's smallest context
-COUNTERS = ("pairs", "experts_hit", "pairs_max_expert", "skipped")
 
 # the stacks a layer reads, each (layers, ...): bfloat16, and the router's
 # float32; the experts' two stacks are (layers x experts, ...)
@@ -110,10 +111,9 @@ class ZayaConfig:
         self.n_layers = num_hidden_layers
         self.vocab = vocab_size
         self.max_context = max_context
-        self.decode_context_floor = min(_pow2_floor(max_context),
-                                        DECODE_CONTEXT_FLOOR)
-        self.prefill_context_floor = min(_pow2_floor(max_context),
-                                         PREFILL_CONTEXT_FLOOR)
+        self.decode_context_floor, self.prefill_context_floor = \
+            context_floors(max_context, DECODE_CONTEXT_FLOOR,
+                           PREFILL_CONTEXT_FLOOR)
         self.seed = seed
         self.attn = attn            # as ModelConfig.attn
 
@@ -223,26 +223,7 @@ class ZayaConfig:
         return out
 
 
-def draw_array(seed: int, stream, shape, const, spread) -> np.ndarray:
-    """One float32 array of the recipe: ``Generator(Philox(key=[seed,
-    stream]))``'s ``standard_normal`` float32 in row-major order, times the
-    spread, plus the constant; the constant alone where ``stream`` is None."""
-    if stream is None:
-        return np.broadcast_to(np.float32(const), shape).copy()
-    rng = np.random.Generator(np.random.Philox(key=[seed, stream]))
-    out = rng.standard_normal(shape, dtype=np.float32)
-    out *= np.asarray(spread, np.float32)
-    out += np.asarray(const, np.float32)
-    return out
-
-
 # ------------------------------------------------------------ layer functions
-def _f32(x):
-    import jax.numpy as jnp
-
-    return x.astype(jnp.float32)
-
-
 def rope_half(x, pos, rot: int, theta: float):
     """Rotate-half rotary on the first ``rot`` dims of a head: dims ``j`` and
     ``j + rot / 2`` turn by ``pos * theta^(-2j / rot)``, the dims from
@@ -349,6 +330,8 @@ class ZayaModel(HybridServingModel):
     """Weights + the chunk and decode programs over a HybridStateCache."""
 
     CONTINUES_PREFILL = True
+    # and the rows a router sent to the output that computes nothing
+    MOE_COUNTERS = ("pairs", "experts_hit", "pairs_max_expert", "skipped")
 
     def __init__(self, config: ZayaConfig, kv: HybridStateCache,
                  weights: Optional[Dict[str, np.ndarray]] = None):
@@ -356,99 +339,45 @@ class ZayaModel(HybridServingModel):
         (``l3.wq``, ``l3.e2.wg``, ..., ``embed``, ``lnf``) that replace
         what the recipe gives (tests); everything else is drawn from
         ``config.seed``."""
-        import jax
         import jax.numpy as jnp
 
         self._init_programs(config, kv)
-        self.moe_counters = {"experts_held": config.held}
-        self.reset_moe_counters()
-        cfg, dev = config, self.store.device
-        given = dict(weights or {})
-        sh = cfg.shapes()
-        d, ff, n_e = cfg.d_model, cfg.d_ff, cfg.n_experts
-
-        def host(name, stream, shape, const, spread):
-            if name not in given:
-                return draw_array(cfg.seed, stream, shape, const, spread)
-            got = np.asarray(given[name], np.float32)  # tpulint: disable=no-per-token-host-sync
-            if got.shape != tuple(shape):
-                raise ValueError(f"weight {name}: {got.shape} != {shape}")
-            return got
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def fill(buf, part, *start):
-            return jax.lax.dynamic_update_slice(buf, part.astype(buf.dtype),
-                                                start)
-
-        # drawn a layer ahead on threads (numpy frees the interpreter),
-        # staged ONE array at a time into the stacks the programs read:
-        # set-up, not a step loop
-        with ThreadPoolExecutor(4) as pool:
-            def start(layer):
-                return {a[0]: pool.submit(host, *a)
-                        for a in cfg.arrays(layer)}
-
-            def take(drawn, name):
-                return jax.device_put(drawn.pop(name).result(), dev)  # tpulint: disable=no-per-op-step-dispatch
-
-            drawn, ahead = start(None), start(0)
-            # the embedding first, float32 on its way to bfloat16, BEFORE
-            # the stacks take their share of the device (8.4 GB at the
-            # published sizes): the device's peak is then the programs',
-            # not the staging's
-            for name in ("embed", "lnf"):
-                self._stage(name, take(drawn, name).astype(jnp.bfloat16))
-            n = cfg.n_layers
-            wide = sum(sh[k][1] for k in PROJ)
-            stacks = {k: jnp.zeros(
-                (n,) + ((d, wide) if k == "wqkv" else sh[k]), jnp.bfloat16)
-                for k in STACKS}
-            stacks.update({k: jnp.zeros((n,) + sh[k], jnp.float32)
-                           for k in ROUTER})
-            # (layers x experts, d, gate | up), (layers x experts, ff, d)
-            stacks["e_wgu"] = jnp.zeros((n * n_e, d, 2 * ff), jnp.bfloat16)
-            stacks["e_wd"] = jnp.zeros((n * n_e, ff, d), jnp.bfloat16)
-            for l in range(n):
-                drawn, p = ahead, f"l{l}."
-                ahead = start(l + 1) if l + 1 < n else {}
-                col = 0
-                for k in PROJ:
-                    stacks["wqkv"] = fill(stacks["wqkv"],
-                                          take(drawn, p + k)[None], l, 0, col)
-                    col += sh[k][1]
-                for k in STACKS[1:] + ROUTER:
-                    part = take(drawn, p + k)[None]
-                    stacks[k] = fill(stacks[k], part, l,
-                                     *(0,) * (part.ndim - 1))
-                for e in range(n_e):
-                    i, q = l * n_e + e, f"{p}e{e}."
-                    stacks["e_wgu"] = fill(stacks["e_wgu"],
-                                           take(drawn, q + "wg")[None],
-                                           i, 0, 0)
-                    stacks["e_wgu"] = fill(stacks["e_wgu"],
-                                           take(drawn, q + "wu")[None],
-                                           i, 0, ff)
-                    stacks["e_wd"] = fill(stacks["e_wd"],
-                                          take(drawn, q + "wd")[None],
-                                          i, 0, 0)
+        cfg, sh, bf = config, config.shapes(), jnp.bfloat16
+        d, ff, n_e, n = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_layers
+        for l, take in self._drawn_layers(cfg.arrays, draw_array, weights):
+            if l is None:
+                # the embedding first, float32 on its way to bfloat16,
+                # BEFORE the stacks take their share of the device (8.4 GB
+                # at the published sizes): the device's peak is then the
+                # programs', not the staging's
+                for name in ("embed", "lnf"):
+                    self._stage(name, take(name).astype(bf))
+                wide = sum(sh[k][1] for k in PROJ)
+                stacks = {k: jnp.zeros(
+                    (n,) + ((d, wide) if k == "wqkv" else sh[k]), bf)
+                    for k in STACKS}
+                stacks.update({k: jnp.zeros((n,) + sh[k], jnp.float32)
+                               for k in ROUTER})
+                # (layers x experts, d, gate | up), (layers x experts, ff, d)
+                stacks["e_wgu"] = jnp.zeros((n * n_e, d, 2 * ff), bf)
+                stacks["e_wd"] = jnp.zeros((n * n_e, ff, d), bf)
+                continue
+            p, col = f"l{l}.", 0
+            for k in PROJ:
+                stacks["wqkv"] = fill_stack(stacks["wqkv"], take(p + k),
+                                            l, 0, col)
+                col += sh[k][1]
+            for k in STACKS[1:] + ROUTER:
+                stacks[k] = fill_stack(stacks[k], take(p + k), l)
+            for e in range(n_e):
+                i, q = l * n_e + e, f"{p}e{e}."
+                stacks["e_wgu"] = fill_stack(stacks["e_wgu"], take(q + "wg"),
+                                             i)
+                stacks["e_wgu"] = fill_stack(stacks["e_wgu"], take(q + "wu"),
+                                             i, 0, ff)
+                stacks["e_wd"] = fill_stack(stacks["e_wd"], take(q + "wd"), i)
         for name, arr in stacks.items():
             self._stage(name, arr)
-
-    def _decode_buckets(self, n_rows: int, tables):
-        return decode_buckets(n_rows, tables, self.kv.block_size,
-                              self.config.decode_context_floor)
-
-    def reset_moe_counters(self) -> None:
-        for phase in ("decode", "prefill"):
-            self.moe_counters[phase] = dict.fromkeys(
-                COUNTERS + ("layer_launches",), 0)
-
-    def _note_counters(self, phase: str, tail) -> None:
-        c = self.moe_counters[phase]
-        per_layer = np.asarray(tail, np.int64).reshape(-1, len(COUNTERS))
-        c["layer_launches"] += len(per_layer)
-        for name, total in zip(COUNTERS, per_layer.sum(axis=0)):
-            c[name] += int(total)
 
     # ---- what both programs share of a layer
     @staticmethod
@@ -485,17 +414,8 @@ class ZayaModel(HybridServingModel):
         y, cnt = expert_layer(cfg, h, idx, p_e, w["e_wgu"], w["e_wd"], tile,
                               first=i * cfg.held)
         x = residual_scale(wl["res_m"], x, y)
-        counted = jnp.stack([jnp.sum(cnt), jnp.sum(cnt > 0), jnp.max(cnt),
-                             jnp.sum(idx == cfg.n_experts)])
+        counted = expert_counts(cnt, jnp.sum(idx == cfg.n_experts))
         return x, r, counts.at[i].set(counted.astype(jnp.int32))
-
-    def _context(self, pool, layer, blocks):
-        """Rows of one layer of a pool, whole BLOCKS at a time, the layer
-        inside the gather's index (``pool[layer]`` first would copy the
-        layer)."""
-        bs = self.kv.block_size
-        return pool.reshape(len(pool), -1, bs, self.config.kv_dim)[
-            layer, blocks]
 
     def _start(self, w, tokens):
         import jax.numpy as jnp
@@ -503,7 +423,8 @@ class ZayaModel(HybridServingModel):
         cfg = self.config
         return (_f32(w["embed"][tokens]),
                 jnp.zeros((len(tokens), cfg.d_router), jnp.float32),
-                jnp.zeros((cfg.n_layers, len(COUNTERS)), jnp.int32))
+                jnp.zeros((cfg.n_layers, len(self.MOE_COUNTERS)),
+                          jnp.int32))
 
     # --------------------------------------------------------------- chunk
     def _chunk_fn(self, c_bucket: int, l_bucket: int, use_flash: bool):
@@ -554,8 +475,10 @@ class ZayaModel(HybridServingModel):
                     # chunk's among them
                     return attend_rows(
                         cfg, q,
-                        self._context(fk, i, table).reshape(l_bucket, g, hd),
-                        self._context(fv, i, table).reshape(l_bucket, g, hd),
+                        _block_context(fk, i, table, bs).reshape(
+                            l_bucket, g, hd),
+                        _block_context(fv, i, table, bs).reshape(
+                            l_bucket, g, hd),
                         start)
 
                 x, r, counts = self._layer(w, i, x, r, counts, live, row,
@@ -624,10 +547,8 @@ class ZayaModel(HybridServingModel):
                     # product would lay every gathered context out anew
                     # (two copies of it a layer, read in the compiled
                     # program)
-                    kc = self._context(fk, i, tables).reshape(
-                        b_bucket, l_bucket, g * hd)
-                    vc = self._context(fv, i, tables).reshape(
-                        b_bucket, l_bucket, g * hd)
+                    kc = _block_context(fk, i, tables, bs)
+                    vc = _block_context(fv, i, tables, bs)
                     qw = (q.reshape(b_bucket, g, per, 1, hd)
                           * own).reshape(b_bucket, g * per, g * hd)
                     sc = _mm(qw, kc, "bjd,bkd->bjk") / math.sqrt(hd)

@@ -161,100 +161,6 @@ def attention_reference(q, k, v, causal: bool = False):
     return jnp.einsum("qk,kd->qd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                  causal: bool, bq: int, bk: int, nk: int):
-    import jax.experimental.pallas as pl
-
-    qi = pl.program_id(0)
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    def _accumulate(masked: bool):
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        scale = 1.0 / float(q.shape[-1]) ** 0.5
-        s = _dot_f32(q, k, trans_b=True) * scale
-        if masked:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (bq, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + _dot_f32(p.astype(v.dtype), v)
-        m_scr[:] = m_new
-
-    if causal:
-        _causal_three_way(qi * bq + bq - 1 >= ki * bk,
-                          qi * bq >= ki * bk + bk - 1,
-                          _accumulate)
-    else:
-        _accumulate(False)
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        # fully-masked rows (l == 0) normalize to zeros, not NaNs
-        l = l_scr[:]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc_scr[:] / safe).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "block_q", "block_k",
-                                    "interpret"))
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = None):
-    """Single-head flash attention over (S, D) tensors; vmap for heads/
-    batch. Sequence length must divide by the block sizes (pad upstream —
-    the ring-attention layer already block-aligns its shards)."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if interpret is None:
-        interpret = not _on_tpu()
-    sq, d = q.shape
-    sk = k.shape[0]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    if sq % bq or sk % bk:
-        raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks "
-                         f"({bq},{bk})")
-    nq, nk = sq // bq, sk // bk
-    kernel = functools.partial(_flash_kernel, causal=causal, bq=bq, bk=bk,
-                               nk=nk)
-    params = (None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary")))
-    return pl.pallas_call(
-        kernel,
-        grid=(nq, nk),
-        in_specs=[
-            pl.BlockSpec((bq, d), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((bk, d), lambda qi, ki: (ki, 0)),
-            pl.BlockSpec((bk, d), lambda qi, ki: (ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((bq, d), lambda qi, ki: (qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=params,
-        interpret=interpret,
-        name="flash_attention_fwd",
-    )(q, k, v)
-
-
 # ---------------------------------------------------------------------------
 # Batched (B*H-grid) flash attention with a Pallas backward pass.
 #
@@ -549,8 +455,7 @@ def _tri_row(t):
 
 def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                              m_scr, l_scr, acc_scr, *,
-                             b: int, bn: int, diag_split: bool,
-                             one_tile: bool = False):
+                             b: int, bn: int, one_tile: bool = False):
     import jax.experimental.pallas as pl
 
     if one_tile:
@@ -577,17 +482,15 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _update(j, rows, s, v):
-        """Online-softmax update of scratch rows `rows` with scores s."""
-        m_prev = m_scr[j, rows]
+    def _update(j, s, v):
+        """Online-softmax update of head j's scratch with scores s."""
+        m_prev = m_scr[j]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[j, rows] = (l_scr[j, rows] * alpha
-                          + jnp.sum(p, axis=-1, keepdims=True))
-        acc_scr[j, rows] = (acc_scr[j, rows] * alpha
-                            + _dot_f32(p.astype(v.dtype), v))
-        m_scr[j, rows] = m_new
+        l_scr[j] = l_scr[j] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[j] = acc_scr[j] * alpha + _dot_f32(p.astype(v.dtype), v)
+        m_scr[j] = m_new
 
     def _accumulate(masked: bool):
         # query heads a K/V head of the block (rows first: one)
@@ -597,35 +500,14 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             k = tile(k_ref, j // share)
             v = tile(v_ref, j // share)
             scale = 1.0 / float(q.shape[-1]) ** 0.5
-            if not masked:
-                _update(j, slice(None),
-                        _dot_f32(q, k, trans_b=True) * scale, v)
-            elif not diag_split:
+            s = _dot_f32(q, k, trans_b=True) * scale
+            if masked:
                 # on-diagonal tile: triangular mask with RELATIVE
                 # positions (qi*b + r >= ki*b + c, qi == ki -> r >= c)
-                s = _dot_f32(q, k, trans_b=True) * scale
                 r_pos = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
                 c_pos = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
                 s = jnp.where(r_pos >= c_pos, s, NEG_INF)
-                _update(j, slice(None), s, v)
-            else:
-                # 2x2 diagonal decomposition: the upper-right quadrant is
-                # fully masked and never computed (25% of the diagonal
-                # tile's MXU work); the two on-diagonal half-tiles get
-                # the half-size triangular mask
-                h = b // 2
-                r = jax.lax.broadcasted_iota(jnp.int32, (h, h), 0)
-                c = jax.lax.broadcasted_iota(jnp.int32, (h, h), 1)
-                tri = r >= c
-                q0, q1 = q[0:h], q[h:b]
-                s00 = _dot_f32(q0, k[0:h], trans_b=True) * scale
-                _update(j, slice(0, h),
-                        jnp.where(tri, s00, NEG_INF), v[0:h])
-                s10 = _dot_f32(q1, k[0:h], trans_b=True) * scale
-                s11 = _dot_f32(q1, k[h:b], trans_b=True) * scale
-                s1 = jnp.concatenate(
-                    [s10, jnp.where(tri, s11, NEG_INF)], axis=1)
-                _update(j, slice(h, b), s1, v)
+            _update(j, s, v)
 
     @pl.when(ki != qi)
     def _():
@@ -649,10 +531,8 @@ def _flash_fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                                    m_scr[j] + jnp.log(safe))
 
 
-@functools.partial(jax.jit, static_argnames=("b", "interpret", "bn",
-                                             "diag_split"))
-def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
-                      diag_split: bool = False):
+@functools.partial(jax.jit, static_argnames=("b", "interpret", "bn"))
+def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1):
     """Causal forward over [N, S, D] via the triangular grid; bq = bk = b.
     Returns (o, lse). Causal masking uses absolute positions aligned at 0
     (the non-ring case); ring hops keep the (qi, ki) kernels. k, v may hold
@@ -669,8 +549,7 @@ def _flash_fwd_folded(q, k, v, b: int, interpret: bool, bn: int = 1,
     kn, kb = _kv_block(n, k.shape[0], bn)
     nq = sq // b
     steps = nq * (nq + 1) // 2
-    kernel = functools.partial(_flash_fwd_folded_kernel, b=b, bn=bn,
-                               diag_split=diag_split)
+    kernel = functools.partial(_flash_fwd_folded_kernel, b=b, bn=bn)
     params = (None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary")))
 
@@ -798,7 +677,7 @@ def flash_attention_rows(q, k, v, n_heads: int, head_dim: int,
         vmem_limit_bytes=_ROWS_VMEM_LIMIT))
     out, _ = pl.pallas_call(
         functools.partial(_flash_fwd_folded_kernel, b=b, bn=bn,
-                          diag_split=False, one_tile=nq == 1),
+                          one_tile=nq == 1),
         grid=(n_heads // bn, nq * (nq + 1) // 2),
         in_specs=[
             pl.BlockSpec((b, bn * d), rows(q_at)),

@@ -412,7 +412,8 @@ def test_counters_reach_the_snapshot_and_the_serving_page():
 def test_a_model_without_the_counters_has_no_decode_line():
     engine = ServingEngine.__new__(ServingEngine)
     engine.model = types.SimpleNamespace()
-    assert engine._decode_snapshot() is None
+    assert engine._counters()["decode"] is None
+    assert engine.counter_lines(engine._counters()) == []
 
 
 # -------------------------------------- compiled for the chip, no chip here

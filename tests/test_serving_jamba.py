@@ -536,7 +536,7 @@ def test_a_model_without_mamba_layers_has_no_scan_part():
 
     eng = E.__new__(E)
     eng.model = NoScan()
-    assert eng._scan_snapshot() is None
+    assert eng._counters()["scan"] is None
 
 
 def test_cancel_mid_prompt_frees_slot_and_pages(world, long_prompt, engine):

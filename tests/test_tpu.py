@@ -504,7 +504,7 @@ class TestFlashAttention:
         import jax
 
         from brpc_tpu.tpu.pallas_ops import (attention_reference,
-                                             flash_attention)
+                                             flash_attention_mha)
 
         key = jax.random.PRNGKey(0)
         kq, kk, kv = jax.random.split(key, 3)
@@ -512,8 +512,10 @@ class TestFlashAttention:
         q = jax.random.normal(kq, (S, D), dtype=jnp.float32)
         k = jax.random.normal(kk, (S, D), dtype=jnp.float32)
         v = jax.random.normal(kv, (S, D), dtype=jnp.float32)
-        out = flash_attention(q, k, v, causal=causal, block_q=64,
-                              block_k=64, interpret=True)
+        # one sequence, one head
+        out = flash_attention_mha(q[None, None], k[None, None],
+                                  v[None, None], causal=causal, block_q=64,
+                                  block_k=64, interpret=True)[0, 0]
         ref = attention_reference(q, k, v, causal=causal)
         assert jnp.allclose(out, ref, atol=2e-3), float(
             jnp.abs(out - ref).max())
@@ -573,11 +575,10 @@ class TestFlashAttention:
         head the ungrouped program gives the same bits, in every forward of
         the family, two heads a step (one K/V head under both, or two whole
         groups) included; and a K/V head a query head IS the ungrouped
-        program, equal to the single-head kernel a head."""
+        program, equal to the same call one head at a time."""
         import jax
 
-        from brpc_tpu.tpu.pallas_ops import (flash_attention,
-                                             flash_attention_mha)
+        from brpc_tpu.tpu.pallas_ops import flash_attention_mha
 
         kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
         q = jax.random.normal(kq, (2, 4, 128, 32), jnp.float32)
@@ -589,9 +590,10 @@ class TestFlashAttention:
             rep = flash_attention_mha(q, jnp.repeat(k, 4 // g, axis=1),
                                       jnp.repeat(v, 4 // g, axis=1), **kw)
             assert np.array_equal(np.asarray(got), np.asarray(rep))
-        one = jax.vmap(jax.vmap(lambda a, b, c: flash_attention(
-            a, b, c, causal=causal, block_q=bq, block_k=bk,
-            interpret=True)))(q, k.repeat(4, axis=1), v.repeat(4, axis=1))
+        one = jax.vmap(jax.vmap(lambda a, b, c: flash_attention_mha(
+            a[None, None], b[None, None], c[None, None], causal=causal,
+            block_q=bq, block_k=bk, interpret=True)[0, 0]))(
+            q, k.repeat(4, axis=1), v.repeat(4, axis=1))
         assert np.array_equal(np.asarray(got), np.asarray(one))
 
     def test_grouped_heads_must_divide(self):
@@ -604,12 +606,12 @@ class TestFlashAttention:
     def test_block_misalignment_rejected(self):
         import jax
 
-        from brpc_tpu.tpu.pallas_ops import flash_attention
+        from brpc_tpu.tpu.pallas_ops import flash_attention_mha
 
-        q = jnp.zeros((100, 32))
+        q = jnp.zeros((1, 1, 100, 32))
         with pytest.raises(ValueError):
-            flash_attention(q, q, q, block_q=64, block_k=64,
-                            interpret=True)
+            flash_attention_mha(q, q, q, block_q=64, block_k=64,
+                                interpret=True)
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_mha_gradients_match_reference(self, causal):
@@ -653,12 +655,14 @@ class TestFlashAttention:
         if jax.default_backend() != "tpu":
             pytest.skip("no TPU backend")
         from brpc_tpu.tpu.pallas_ops import (attention_reference,
-                                             flash_attention)
+                                             flash_attention_mha)
 
         key = jax.random.PRNGKey(2)
         q, k, v = (jax.random.normal(kk, (256, 128), dtype=jnp.float32)
                    for kk in jax.random.split(key, 3))
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+        out = flash_attention_mha(q[None, None], k[None, None],
+                                  v[None, None], causal=True,
+                                  interpret=False)[0, 0]
         ref = attention_reference(q, k, v, causal=True)
         assert jnp.allclose(out, ref, atol=2e-2), float(
             jnp.abs(out - ref).max())

@@ -17,7 +17,7 @@ import jax  # noqa: E402
 class TestKernelsOnChip:
     def test_flash_attention_mxu(self, tpu_device):
         from brpc_tpu.tpu.pallas_ops import (attention_reference,
-                                             flash_attention)
+                                             flash_attention_mha)
 
         rng = np.random.default_rng(0)
         S, D = 1024, 128
@@ -25,7 +25,10 @@ class TestKernelsOnChip:
         k = jnp.asarray(rng.normal(size=(S, D)), dtype=jnp.bfloat16)
         v = jnp.asarray(rng.normal(size=(S, D)), dtype=jnp.bfloat16)
         for causal in (False, True):
-            out = flash_attention(q, k, v, causal=causal, interpret=False)
+            # one sequence, one head
+            out = flash_attention_mha(q[None, None], k[None, None],
+                                      v[None, None], causal=causal,
+                                      interpret=False)[0, 0]
             ref = attention_reference(q, k, v, causal=causal)
             np.testing.assert_allclose(
                 np.asarray(out, dtype=np.float32),
@@ -83,8 +86,8 @@ class TestKernelsOnChip:
     def test_flash_carry_matches_one_shot(self, tpu_device):
         # carry form seeded with the identity state + one pass + normalize
         # == the one-shot kernel (the ring-hop contract)
-        from brpc_tpu.tpu.pallas_ops import (NEG_INF, flash_attention,
-                                             flash_attention_carry)
+        from brpc_tpu.tpu.pallas_ops import (NEG_INF, flash_attention_carry,
+                                             flash_attention_mha)
 
         rng = np.random.default_rng(1)
         S, D = 512, 128
@@ -97,7 +100,9 @@ class TestKernelsOnChip:
         m, l, acc = flash_attention_carry(q, k, v, m0, l0, a0, 0, 0,
                                           causal=True, interpret=False)
         out = acc / jnp.where(l == 0, 1.0, l)
-        ref = flash_attention(q, k, v, causal=True, interpret=False)
+        ref = flash_attention_mha(q[None, None], k[None, None],
+                                  v[None, None], causal=True, block_q=128,
+                                  block_k=128, interpret=False)[0, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-5)
 

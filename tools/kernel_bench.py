@@ -150,13 +150,12 @@ def _bench_toy_prefill(peak: dict, lengths=(384, 896, 1536)):
     """The toy serving block's prefill attention alone (``tiny-w2048-serve``:
     16 heads of 128, float32, one prompt's packed projection (S, 6144)) at a
     short, a middle and the longest bucket of ``prefill-closed``: the
-    rows-first call (PR 40) beside the kernel it replaced (the single-head
-    forward vmapped over transposed heads, its copies included), and the
-    least time the benchmark's ``flash_prefill_roofline`` divides by. A call
+    rows-first call beside the heads-first call of the same folded
+    forward (``flash_attention_mha`` over transposed heads, its copies
+    included), and the least time the benchmark's ``flash_prefill_roofline``
+    divides by. A call
     writes its output over the q columns of the next call's rows; ``chain``
     is that write alone, already taken off the other two."""
-    import functools
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -174,13 +173,12 @@ def _bench_toy_prefill(peak: dict, lengths=(384, 896, 1536)):
         return pallas_ops.flash_attention_rows(
             x, x, x, H, D, heads_at=(0, H, 2 * H), interpret=False)
 
-    def vmapped(x):
-        q, k, v = (part.reshape(-1, H, D).transpose(1, 0, 2)
+    def heads_first(x):
+        q, k, v = (part.reshape(-1, H, D).transpose(1, 0, 2)[None]
                    for part in jnp.split(x, 3, axis=-1))
-        out = jax.vmap(functools.partial(pallas_ops.flash_attention,
-                                         causal=True, interpret=False))(
-                                             q, k, v)
-        return out.transpose(1, 0, 2).reshape(-1, W)
+        out = pallas_ops.flash_attention_mha(q, k, v, causal=True,
+                                             interpret=False)
+        return out[0].transpose(1, 0, 2).reshape(-1, W)
 
     def chain(x):
         return x[:, W:2 * W]
@@ -190,7 +188,7 @@ def _bench_toy_prefill(peak: dict, lengths=(384, 896, 1536)):
                         * 0.5, dtype=jnp.float32)
         sec = {}
         for name, f in (("chain", chain), ("rows_first", rows_first),
-                        ("vmapped", vmapped)):
+                        ("heads_first", heads_first)):
             loop = jax.jit(lambda x, n, f=f: jax.lax.fori_loop(
                 0, n, lambda i, x: x.at[:, :W].set(f(x)), x))
             sec[name] = _marginal(
@@ -198,12 +196,13 @@ def _bench_toy_prefill(peak: dict, lengths=(384, 896, 1536)):
                 16, 128)
         least = work.flash_prefill_least_s(
             {"prefill": [S]}, {"d_model": W, "n_layers": 1}, peak)
-        new, old = (sec[k] - sec["chain"] for k in ("rows_first", "vmapped"))
+        new, old = (sec[k] - sec["chain"]
+                    for k in ("rows_first", "heads_first"))
         b, bn = pallas_ops._rows_tiles(S, H, D, 4, (0, H, 2 * H))
         print(f"# kernel flash_attention fwd TOY PREFILL H={H} D={D} f32 "
               f"S={S}: rows-first (tile {b}, {bn} heads a step) "
               f"{new * 1e3:7.4f} ms ({100 * least / new:5.1f}% of roofline), "
-              f"vmapped single-head {old * 1e3:7.4f} ms "
+              f"heads-first {old * 1e3:7.4f} ms "
               f"({100 * least / old:5.1f}%), least {least * 1e3:7.4f} ms, "
               f"chain {sec['chain'] * 1e3:7.4f} ms", flush=True)
 
