@@ -702,8 +702,12 @@ def serving_service(server, http: HttpMessage):
         # the host side on the CPU clock since start: CPU seconds by
         # thread role (lane.* the native lane's threads, runtime what the
         # process holds beyond the listed ones), the mean wait of the
-        # lane's events for the poller, the collector's pauses
+        # lane's events for the poller, the collector's pauses, and the
+        # frames the writer thread wrote (batches, frames, mean lag from
+        # hand-off to a batch's last write, most batches behind) beside
+        # those the loop wrote itself
         host = s["host"]
+        batches, frames, lag_us, backlog = host["flush"]
         out.append(
             "  host: cpu_s " + " ".join(
                 f"{role}={cpu / 1e6:.2f}" for role, (_n, cpu) in sorted(
@@ -712,7 +716,10 @@ def serving_service(server, http: HttpMessage):
                 f"{kind}={wait / max(1, n):.0f} (n={n}, max={worst:.0f})"
                 for kind, (n, wait, worst) in host["lane_wait"].items())
             + "; gc={} pause_ms={:.1f} max_ms={:.1f}".format(
-                host["gc"][0], host["gc"][1] / 1e3, host["gc"][2] / 1e3))
+                host["gc"][0], host["gc"][1] / 1e3, host["gc"][2] / 1e3)
+            + f"; flush batches={batches} frames={frames} "
+            f"lag_us={lag_us / max(1, batches):.0f} backlog_max={backlog} "
+            f"frames_inline={host['frames_inline']}")
         out.append(f"  kv: {kv['blocks_used']}/{kv['blocks_total']} blocks "
                    f"used ({kv['used_ratio']:.0%}), "
                    f"watermark={kv['watermark']:.0%}, "

@@ -27,7 +27,9 @@ that waits for something BY DESIGN (nothing to run, a full pool, the
 device), reads it at its two ends and keeps the CPU time used inside it.
 The thread's CPU time (:func:`cpu_by_role`) less that of its waits is the
 CPU its working spans cost, and their wall time less that is how long
-they stood off the CPU.
+they stood off the CPU. A thread may name, with :func:`on_wait`, work to
+hand on as each of its wait_spans opens: the moment it stops needing the
+interpreter.
 
 Spans nest; :func:`set_phase` keeps ONE span open per nesting level and
 swaps it, so a thread's timeline is flat there: at every instant exactly
@@ -60,7 +62,7 @@ import sys
 import threading
 from time import (clock_gettime_ns, perf_counter_ns, process_time_ns,
                   thread_time_ns)
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 get_ident = threading.get_ident
 get_native_id = threading.get_native_id
@@ -82,6 +84,8 @@ ROLE_USER = "user"          # anything unregistered (main thread, app threads)
 
 _roles: Dict[int, str] = {}
 _phases: Dict[int, str] = {}
+# ident -> what the thread calls as each of its wait_spans opens (on_wait)
+_wait_hooks: Dict[int, Callable[[], None]] = {}
 # the same roles by the kernel's thread id: an ``ident`` goes to the next
 # thread at once, with whatever role its last owner left there, and the CPU
 # table must not read a new thread under a dead one's role
@@ -308,6 +312,9 @@ class wait_span(span):
     __slots__ = ("_c0",)
 
     def __enter__(self):
+        hook = _wait_hooks.get(get_ident())
+        if hook is not None:     # the enclosing span's work, not the wait's
+            hook()
         span.__enter__(self)
         self._c0 = thread_time_ns()
         return self
@@ -317,6 +324,17 @@ class wait_span(span):
         waits[self.name] = (waits.get(self.name, 0)
                             + thread_time_ns() - self._c0)
         return span.__exit__(self, *exc)
+
+
+def on_wait(fn: Optional[Callable[[], None]]) -> None:
+    """Call ``fn`` on this thread as each of its :class:`wait_span` s opens,
+    before the span does (None: no longer). The serving loop hands the
+    step's stream frames to its writer thread there, so that the writer
+    takes the interpreter while the loop waits for the device."""
+    if fn is None:
+        _wait_hooks.pop(get_ident(), None)
+    else:
+        _wait_hooks[get_ident()] = fn
 
 
 def set_phase(name: Optional[str], **ids) -> Optional[str]:
@@ -522,7 +540,7 @@ def prune(live_idents) -> None:
         st = _threads.pop(ident, None)
         if st is not None:
             _retire_spans(role_of(ident), st)
-    for d in (_roles, _phases):
+    for d in (_roles, _phases, _wait_hooks):
         for ident in [i for i in d if i not in live]:
             d.pop(ident, None)
 
@@ -532,6 +550,7 @@ def reset_for_test() -> None:
     _roles.clear()
     _roles_by_nid.clear()
     _phases.clear()
+    _wait_hooks.clear()
     _threads.clear()
     _retired_spans.clear()
     _retired_cpu.clear()

@@ -32,6 +32,12 @@ step; per-token streaming writes fan out of that single sync (tpulint's
 Streaming: a request that arrived with stream settings gets TokenDelta
 frames as steps complete, so TTFT is a stream-arrival time, decoupled
 from the RPC response (which carries the full token list at completion).
+A prefill's first-token frame is written on the loop thread; a decode
+step's frames, and from then on everything that completes those
+sequences' RPCs, go as one batch a step to the engine's writer thread
+(serving/frame_writer.py) as the loop next waits (in the next step's
+``model.sync``, or with nothing to run), and the writer writes them while
+the device runs that step.
 
 Fault points: ``serving.decode.stall`` (injects latency into the step
 loop) and ``serving.kv.exhaust`` (forces admission rejections). A tunnel
@@ -45,6 +51,7 @@ from __future__ import annotations
 
 import collections
 import copy
+import functools
 import math
 import threading
 import time
@@ -63,6 +70,7 @@ from brpc_tpu.rpc import errors
 from brpc_tpu.rpc import native_transport as _native_transport
 from brpc_tpu.serving import qos as _qos
 from brpc_tpu.serving import speculative as _spec
+from brpc_tpu.serving.frame_writer import FrameWriter, write_frame
 from brpc_tpu.serving.kv_cache import KVCacheFull, PagedKVCache
 from brpc_tpu.serving.model import COUNTER_FAMILIES, TinyTransformer
 
@@ -89,6 +97,20 @@ _engines_lock = threading.Lock()
 
 def _ns_to_us(ns: int) -> float:
     return round(ns / 1000.0, 1)
+
+
+def _reply(done, resp) -> None:
+    try:
+        done(resp)
+    except Exception:
+        pass
+
+
+def _close_stream(seq, stream_id: int) -> None:
+    if not seq.stream_lost:
+        from brpc_tpu.rpc.stream import stream_close
+
+        stream_close(stream_id)
 
 
 def active_engines() -> List["ServingEngine"]:
@@ -208,6 +230,11 @@ class Sequence:
         # speculative decoding: per-sequence adaptive draft-length
         # controller, created lazily by the engine when spec_k > 0
         self.spec = None
+        # the stream's frames go through the engine's writer from the
+        # first decode frame on (set by the loop), and a write there that
+        # failed marks the stream lost (set by the writer)
+        self.flushed = False
+        self.stream_lost = False
 
     @property
     def pos(self) -> int:
@@ -260,6 +287,12 @@ class ServingEngine:
         self.prefill_chunks = 0       # launches that were part of a prompt
         self.prefill_chunk_rows = 0   # rows those launches prefilled
         self._thread: Optional[threading.Thread] = None
+        # a step's frames and the completions behind them (loop-owned),
+        # handed to the writer thread as the loop next waits
+        self._outbox: list = []
+        self._writer = FrameWriter()
+        self._waited = False          # the step has waited (on_wait)
+        self.frames_inline = 0        # frames written on the loop thread
         self.running = False
         self.steps = 0
         self.tokens_generated = 0
@@ -324,6 +357,7 @@ class ServingEngine:
             hooks = global_series().post_tick_hooks
             if self._qos_governor not in hooks:
                 hooks.append(self._qos_governor)
+        self._writer.start()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="brpc-serving-engine")
         self._thread.start()
@@ -365,6 +399,8 @@ class ServingEngine:
         # fan a retriable error to anything still in flight, then prove
         # the pool whole — the CreditLedger teardown discipline
         self._abort_all_locked_out(abort_code, "engine stopped")
+        self._hand_off()
+        self._writer.stop()
         with self._cv:
             self._adopted.clear()
             self._recover_index.clear()
@@ -613,15 +649,7 @@ class ServingEngine:
             else:
                 self._adopted.pop(seq.seq_id, None)
         if deferred is not None:
-            code, reason = deferred
-            try:
-                if code != 0 and cntl is not None:
-                    cntl.set_failed(code, reason)
-                    done(None)
-                else:
-                    done(self._response_for(seq))
-            except Exception:
-                pass
+            self._complete(seq, done, *deferred)
             return 0, seq
         if replay and stream_id:
             # catch the client up on tokens decoded before it attached
@@ -636,6 +664,10 @@ class ServingEngine:
         _prof.register_current_thread("serving")
         self._spans = _prof.thread_spans()
         self._waits = _prof.thread_waits()
+        # the step's batch goes to the writer as the loop next waits (for
+        # the device, for work, for the pool): the writer then takes the
+        # interpreter while the loop has no use for it
+        _prof.on_wait(self._on_wait)
         try:
             while True:
                 # admit's own time: the wait for the lock (an RPC thread
@@ -660,6 +692,7 @@ class ServingEngine:
                     continue
                 with _span("engine.step", step=self.steps,
                            batch=len(self._running)) as sp:
+                    self._waited = False
                     try:
                         with self.pool_gate:
                             self._step(admitted)
@@ -668,9 +701,14 @@ class ServingEngine:
                             self._finish(seq, errors.EINTERNAL,
                                          f"step failed: {e}")
                         self._running, self._prefilling = [], None
+                    if not self._waited:
+                        # a step that never waited (a model whose call
+                        # opens no wait span) hands its batch on here
+                        self._hand_off()
                 self.last_step_us = sp.elapsed_ns / 1000.0
                 g_serving_step.record(self.last_step_us)
         finally:
+            _prof.on_wait(None)
             _prof.unregister_current_thread()
 
     def _has_work(self) -> bool:
@@ -963,6 +1001,8 @@ class ServingEngine:
             if end == whole:
                 with _span("engine.commit", batch=1):
                     self._append_token(seq, first)
+                    self._stream_delta(seq, [first],
+                                       seq.state == STATE_DONE)
         rspan = getattr(seq.cntl, "span", None)
         if rspan is not None:
             rspan.add_phase("prefill_us", sp.elapsed_ns / 1000.0)
@@ -1039,6 +1079,8 @@ class ServingEngine:
             else:
                 for s, tok in zip(batch, nxt):
                     self._append_token(s, int(tok))
+                    self._stream_delta(s, [int(tok)],
+                                       s.state == STATE_DONE, defer=True)
             for s in batch:
                 rspan = getattr(s.cntl, "span", None)
                 if rspan is not None:
@@ -1066,14 +1108,14 @@ class ServingEngine:
             a, committed = _spec.accept_longest_prefix(d, m)
             ncommit = 0
             for tok in committed:
-                self._append_token(s, tok, stream=False)
+                self._append_token(s, tok)
                 ncommit += 1
                 if s.state == STATE_DONE:
                     break
             accepted_sent = min(ncommit, a)
             self._stream_delta(s, committed[:ncommit],
                                s.state == STATE_DONE,
-                               accepted=accepted_sent)
+                               accepted=accepted_sent, defer=True)
             # rejected rows wrote K/V past the committed context; drop
             # whole tail blocks now, let next step's writes mask the rest
             self.kv.truncate_sequence(s.seq_id, s.context_len())
@@ -1105,8 +1147,7 @@ class ServingEngine:
         _spec.note_step(step_drafted, step_accepted)
 
     # ----------------------------------------------------------- completion
-    def _append_token(self, seq: Sequence, tok: int,
-                      stream: bool = True) -> None:
+    def _append_token(self, seq: Sequence, tok: int) -> None:
         now = time.monotonic()
         if not seq.out_tokens:
             seq.t_first_token = now
@@ -1119,8 +1160,6 @@ class ServingEngine:
         g_serving_tokens.put(1)
         finished = (len(seq.out_tokens) >= seq.max_new_tokens
                     or (seq.stop_token and tok == seq.stop_token))
-        if stream:
-            self._stream_delta(seq, [tok], finished)
         if finished:
             seq.finish_reason = ("stop_token"
                                  if seq.stop_token and tok == seq.stop_token
@@ -1128,23 +1167,49 @@ class ServingEngine:
             seq.state = STATE_DONE
 
     def _stream_delta(self, seq: Sequence, toks: List[int],
-                      done: bool, accepted: int = 0) -> None:
+                      done: bool, accepted: int = 0,
+                      defer: bool = False) -> None:
+        """One TokenDelta of ``seq``: into the step's batch for the writer
+        (``defer``: a decode commit's frame, and every frame of a sequence
+        that has had one deferred, so that its frames keep their order),
+        else written here."""
         if not seq.stream_id:
             return
-        from brpc_tpu.proto import serving_pb2
-        from brpc_tpu.rpc.stream import stream_write
-
-        delta = serving_pb2.TokenDelta(
-            seq_id=seq.seq_id, tokens=toks,
-            step=len(seq.out_tokens), done=done, accepted=accepted)
-        rc = stream_write(seq.stream_id, delta.SerializeToString())
-        if rc != 0:
+        frame = (seq, seq.stream_id, toks, len(seq.out_tokens), done,
+                 accepted)
+        if defer or seq.flushed:
+            seq.flushed = True
+            self._outbox.append(frame)
+            return
+        self.frames_inline += 1
+        if not write_frame(frame):
             seq.stream_id = 0  # stream died; finish via the RPC response
+
+    def _behind_frames(self, seq: Sequence, fn, *args) -> None:
+        """Run ``fn(*args)``, a step of completing ``seq``'s RPC, after
+        ``seq``'s frames: on the writer where they went there, else here."""
+        if seq.flushed:
+            self._outbox.append(functools.partial(fn, *args))
+        else:
+            fn(*args)
+
+    def _on_wait(self) -> None:
+        """The loop thread's wait hook (``registry.on_wait``)."""
+        self._waited = True
+        self._hand_off()
+
+    def _hand_off(self) -> None:
+        """The step's batch to the writer thread."""
+        if self._outbox:
+            batch, self._outbox = self._outbox, []
+            self._writer.hand_off(batch)
 
     def _reap_finished(self) -> None:
         still: List[Sequence] = []
         with _span("engine.reap") as sp:
             for seq in self._running:
+                if seq.stream_lost:
+                    seq.stream_id = 0   # the writer found the stream dead
                 sock = getattr(seq.cntl, "_srv_socket", None)
                 if sock is not None and getattr(sock, "failed", False):
                     # tunnel/connection died mid-generation: retriable
@@ -1199,10 +1264,7 @@ class ServingEngine:
             prompt_len=len(seq.prompt), steps=len(seq.out_tokens),
             ttft_us=ttft_us, finish_reason="handoff",
             handoff_shard=self.migrator.dest_shard)
-        try:
-            done(resp)
-        except Exception:
-            pass
+        self._behind_frames(seq, _reply, done, resp)
 
     def _drain_migrate(self) -> None:
         """stop()-path recovery: move live chains to the survivor. The
@@ -1240,9 +1302,7 @@ class ServingEngine:
         else:
             g_serving_aborted.put(1)
         if seq.stream_id and code != 0:
-            from brpc_tpu.rpc.stream import stream_close
-
-            stream_close(seq.stream_id)
+            self._behind_frames(seq, _close_stream, seq, seq.stream_id)
             seq.stream_id = 0
         with self._cv:
             if seq.adopted and seq.done is None and not seq._attached:
@@ -1252,8 +1312,12 @@ class ServingEngine:
                 return
             self._adopted.pop(seq.seq_id, None)
         done, seq.done = seq.done, None
-        if done is None:
-            return
+        if done is not None:
+            self._behind_frames(seq, self._complete, seq, done, code,
+                                reason)
+
+    def _complete(self, seq: Sequence, done, code: int, reason: str) -> None:
+        """Complete ``seq``'s RPC: its error, or its response."""
         try:
             if code != 0 and seq.cntl is not None:
                 seq.cntl.set_failed(code, reason)
@@ -1323,7 +1387,8 @@ class ServingEngine:
         profile"): the loop thread's spans with their long closes, the CPU
         time inside those of them that wait by design, every thread's spans
         and CPU time by role (the native lane's threads too), the wait of
-        the lane's events for the poller, and the collector's pauses."""
+        the lane's events for the poller, the collector's pauses, and the
+        frames written by the writer thread and by the loop."""
         us = _ns_to_us
         return {
             "wall_us": us(time.perf_counter_ns()),
@@ -1352,6 +1417,10 @@ class ServingEngine:
             # [collections, pause_us, max_us]
             "gc": [g if i == 0 else us(g)
                    for i, g in enumerate(_prof.gc_pauses())],
+            # the writer thread's: [batches, frames, lag_us_sum,
+            # backlog_max], and the frames the loop wrote itself
+            "flush": self._writer.snapshot(),
+            "frames_inline": self.frames_inline,
         }
 
     def snapshot(self) -> Dict[str, object]:

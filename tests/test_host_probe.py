@@ -59,6 +59,25 @@ def test_the_windows_differences_by_hand():
     assert d["gc"] == [2, 10_000.0, 6_000.0]
 
 
+@pytest.mark.parametrize("writer", [True, False],
+                         ids=["writer", "older_program"])
+def test_the_writers_counters_over_the_window(writer):
+    """``flush`` differences the writer's counters and keeps the
+    program's ``backlog_max``; a program without the writer has none."""
+    h0, h1 = dict(H0), dict(H1)
+    if writer:
+        h0.update(flush=[10, 50, 2_000.0, 0], frames_inline=4)
+        h1.update(flush=[210, 1_250, 42_000.0, 1], frames_inline=9)
+    d = _tool()._difference(h0, h1, steps=200)
+    if not writer:
+        assert "flush" not in d
+        return
+    assert d["flush"] == {"batches": 200, "frames": 1_200,
+                          "lag_us_sum": 40_000.0, "backlog_max": 1,
+                          "frames_inline": 5, "frames_a_step": 6.0,
+                          "lag_us_a_batch": 200.0}
+
+
 def test_an_untraced_run_prints_the_six_counters(tmp_path):
     out = subprocess.run(
         [sys.executable, TOOL, "--tag", "t", "--out", str(tmp_path),
@@ -83,6 +102,8 @@ def test_an_untraced_run_prints_the_six_counters(tmp_path):
         <= probe["host"]["contender_cpu_share"]
     assert probe["steps"] > 0 and 0.9 < probe["loop_self_over_wall"] <= 1.0
     assert {"engine.idle", "model.sync"} <= set(probe["waits_cpu_ms_a_step"])
+    # the decode frames went through the writer thread
+    assert probe["flush"]["frames"] > 0 and probe["flush"]["batches"] > 0
     kept = json.load(open(tmp_path / "t.json"))
     assert set(kept) == {"host0", "host1", "steps", "probe"}
     assert kept["host1"]["wall_us"] > kept["host0"]["wall_us"]

@@ -43,9 +43,10 @@ LOOP_SPANS = ["engine.idle", "engine.pool_wait", "engine.admit",
               "engine.commit", "engine.reap", "engine.prefix_commit",
               "model.prefill",
               "model.decode", "model.prep", "model.launch", "model.sync"]
-ALL_SPANS = RPC_SPANS + ["engine.submit"] + LOOP_SPANS
+WRITER_SPANS = ["engine.flush"]
+ALL_SPANS = RPC_SPANS + ["engine.submit"] + LOOP_SPANS + WRITER_SPANS
 HOST_KEYS = {"wall_us", "loop", "waits", "spans", "threads", "lane_wait",
-             "gc"}
+             "gc", "flush", "frames_inline"}
 
 
 # ------------------------------------------------------------ the primitive
@@ -98,6 +99,31 @@ class TestPrimitive:
         waits = _prof.thread_waits()
         assert waits == {"model.sync": reads[1] - reads[0]}
         assert 0 <= waits["model.sync"] < mine - 15_000_000
+
+    def test_a_threads_wait_hook_runs_as_each_of_its_waits_opens(self):
+        """``on_wait``: called before the wait opens (the enclosing span's
+        phase), on the thread that named it only, until it names None."""
+        me = threading.get_ident()
+        seen = []
+        _prof.on_wait(lambda: seen.append(_prof.phase_of(me)))
+        try:
+            with _prof.span("engine.step"):
+                with _prof.span("model.launch"):
+                    pass
+                with _prof.wait_span("model.sync"):
+                    pass
+
+            def elsewhere():
+                with _prof.wait_span("engine.idle"):
+                    pass
+            other = threading.Thread(target=elsewhere)
+            other.start()
+            other.join()
+        finally:
+            _prof.on_wait(None)
+        with _prof.wait_span("engine.idle"):
+            pass
+        assert seen == ["step"]
 
     def test_a_wait_that_spins_is_on_the_cpu_and_one_that_sleeps_is_not(
             self):
@@ -1137,6 +1163,26 @@ class TestProfilerTrace:
         assert {n for _lo, _hi, n in leaves} >= {
             "engine.idle", "engine.pool_wait", "engine.admit", "model.prep",
             "model.launch", "model.sync", "rpc.stream_write"}
+
+    def test_decode_frames_are_written_on_the_writer_threads_line(
+            self, trace):
+        """A prefill's first-token frame stays on the loop's line (the
+        case above); every later frame is written on the writer thread's
+        line, inside its ``engine.flush``, and so are the responses of
+        the sequences that had frames there."""
+        loop = {e.line for e in trace if e.name == "engine.step"}
+        flushes = [e for e in trace if e.name == "engine.flush"]
+        lines = {e.line for e in flushes}
+        assert len(lines) == 1 and not lines & loop
+        writes = [e for e in trace if e.name == "rpc.stream_write"]
+        on_writer = [w for w in writes if w.line in lines]
+        assert all(any(f.holds(w) for f in flushes) for w in on_writer)
+        # one frame a token: the first from the loop, the rest from here
+        assert len(on_writer) == sum(new - 1 for _p, new in TRACED)
+        assert sum(1 for w in writes if w.line in loop) == len(TRACED)
+        assert sum(1 for e in trace if e.name == "rpc.respond"
+                   and e.line in lines) == sum(1 for _p, new in TRACED
+                                               if new > 1)
 
     def test_loop_thread_holds_only_the_loops_spans(self, trace):
         names = {e.name for e in _loop_line(trace)}

@@ -16,6 +16,10 @@ prints, after the run's own line, ONE line ``HOSTPROBE {...}`` with:
   instant of the loop lies in a span: 0.995 or more);
 - ``threads_cpu_s``: CPU seconds by thread role in the window;
 - ``lane_wait``, ``gc``: the window's differences;
+- ``flush``: the writer thread's ``[batches, frames, lag_us_sum,
+  backlog_max]`` and ``frames_inline``, the frames the loop thread wrote
+  itself, over the window (``backlog_max`` is the program's, since
+  start); with ``frames_a_step`` and ``lag_us_a_batch``;
 - ``mla_prefill``: where the program counts latent attention
   (``snapshot()["mla"]``), the window's difference of its prefill counters
   and ``skipped_share``, the rows of the later chunks' context buckets that
@@ -88,7 +92,25 @@ def _difference(h0, h1, steps):
                       for kind, rec in h1["lane_wait"].items()},
         "gc": [b - a for a, b in zip(h0["gc"], h1["gc"])][:2]
         + [h1["gc"][2]],
+        **_flush(h0, h1, steps),
     }
+
+
+def _flush(h0, h1, steps):
+    """The writer thread's counters over the window (a program without
+    the writer has none: nothing)."""
+    if "flush" not in h1:
+        return {}
+    batches, frames, lag_us = (b - a for a, b in
+                               zip(h0["flush"][:3], h1["flush"][:3]))
+    return {"flush": {"batches": batches, "frames": frames,
+                      "lag_us_sum": round(lag_us, 1),
+                      "backlog_max": h1["flush"][3],
+                      "frames_inline": (h1["frames_inline"]
+                                        - h0["frames_inline"]),
+                      "frames_a_step": round(frames / max(1, steps), 3),
+                      "lag_us_a_batch": round(lag_us / max(1, batches),
+                                              1)}}
 
 
 def main(argv=None) -> int:
