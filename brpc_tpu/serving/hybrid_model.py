@@ -441,6 +441,38 @@ def expert_layer(cfg, h, idx, wts, wgu, wd, tile: int, first=None):
     return out, cnt
 
 
+# the ``decode`` counter family of a model whose decode attention may read
+# the pages in place (``pallas_ops.paged_decode_attention``): its launches by
+# attention path, the pages the rows' positions cover (what the kernel
+# fetches) and the pages of the tables the launches were handed (what a
+# gather of the padded context copies); counted on the host
+DECODE_COUNTERS = ("decode_launches_paged", "decode_launches_gather",
+                   "decode_pages_live", "decode_pages_bucket")
+
+
+def decode_family(counters: Dict[str, int]) -> Dict[str, object]:
+    """The ``decode`` family as ``counters()`` reports it: the counts and
+    ``live_share``, the live pages over the tables' pages."""
+    c = dict(counters)
+    c["live_share"] = round(
+        c["decode_pages_live"] / max(1, c["decode_pages_bucket"]), 4)
+    return c
+
+
+def decode_lines(snap) -> List[str]:
+    """``/serving``'s line of the ``decode`` family in ``snap`` (an engine's
+    snapshot): which path the launches took, and how much of the tables
+    the rows' positions cover."""
+    dec = snap.get("decode")
+    if not dec:
+        return []
+    return [f"  decode: launches paged={dec['decode_launches_paged']} "
+            f"gather={dec['decode_launches_gather']} "
+            f"pages live={dec['decode_pages_live']} "
+            f"bucket={dec['decode_pages_bucket']} "
+            f"live_share={dec['live_share']:.2f}"]
+
+
 def expert_counts(cnt, *more):
     """What a launch returns of one expert layer, from the pairs held by
     expert ``cnt``: the pairs computed here, the held experts hit and the

@@ -37,15 +37,17 @@ import numpy as np
 
 from brpc_tpu.profiling.registry import span as _span
 from brpc_tpu.profiling.registry import wait_span as _wait_span
-from brpc_tpu.serving.hybrid_model import _next_pow2
+from brpc_tpu.serving.hybrid_model import (DECODE_COUNTERS, _next_pow2,
+                                           decode_family, decode_lines)
 from brpc_tpu.serving.kv_cache import PagedKVCache
 
 # the counter families every ``ServingEngine.snapshot()`` carries, None where
-# its model keeps none: the toy block's ``decode`` (here) and the hybrid
-# lane's ``moe``, ``scan`` and ``mla`` (serving/hybrid_model.py,
-# serving/glm_model.py). A model reports these and any others by name
-# through ``counters()`` and formats their ``/serving`` lines through
-# ``counter_lines(snapshot)``; the engine names none of them.
+# its model keeps none: ``decode`` (the toy block's, here, and
+# Command A+'s, serving/moe_model.py) and the hybrid lane's ``moe``,
+# ``scan`` and ``mla`` (serving/hybrid_model.py, serving/glm_model.py). A
+# model reports these and any others by name through ``counters()`` and
+# formats their ``/serving`` lines through ``counter_lines(snapshot)``; the
+# engine names none of them.
 COUNTER_FAMILIES = ("decode", "moe", "scan", "mla")
 
 
@@ -250,10 +252,7 @@ class TinyTransformer:
         # what the decode launches of this instance ran and read: pages
         # the rows' lengths cover against pages of the padded bucket (what
         # the gather copies); ``counters()`` reports them
-        self.decode_counters = {"decode_launches_paged": 0,
-                                "decode_launches_gather": 0,
-                                "decode_pages_live": 0,
-                                "decode_pages_bucket": 0}
+        self.decode_counters = dict.fromkeys(DECODE_COUNTERS, 0)
 
         # ---- weights: pack host-side once, stream into HBM by handle
         flat, self._offsets = self._init_weights(config)
@@ -613,24 +612,14 @@ class TinyTransformer:
         decode launches by attention path, the pages the rows' lengths
         covered against the pages of the padded buckets, and their ratio
         ``live_share``."""
-        c = dict(self.decode_counters)
-        c["live_share"] = round(
-            c["decode_pages_live"] / max(1, c["decode_pages_bucket"]), 4)
-        return {"decode": c}
+        return {"decode": decode_family(self.decode_counters)}
 
     def counter_lines(self, snap) -> List[str]:
         """``/serving``'s line of the ``decode`` family in ``snap`` (an
         engine's snapshot): which path the launches took, and how much of
         the padded bucket (what the gather copies) the rows' lengths cover
         (what the paged kernel reads)."""
-        dec = snap.get("decode")
-        if not dec:
-            return []
-        return [f"  decode: launches paged={dec['decode_launches_paged']} "
-                f"gather={dec['decode_launches_gather']} "
-                f"pages live={dec['decode_pages_live']} "
-                f"bucket={dec['decode_pages_bucket']} "
-                f"live_share={dec['live_share']:.2f}"]
+        return decode_lines(snap)
 
     # ------------------------------------------------------------- helpers
     def close(self) -> None:

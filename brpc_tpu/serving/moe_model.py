@@ -27,7 +27,14 @@ sync a decode step):
   scans query blocks of ``QUERY_BLOCK`` rows over ``window + QUERY_BLOCK``
   keys, so nothing holds heads x rows x rows. The expert layer runs over
   chunks of ``MOE_CHUNK`` rows.
-- ``decode_step``: one fused launch for the batch over ring rows and pages.
+- ``decode_step``: one fused launch for the batch. A layer's attention is
+  ONE call of ``pallas_ops.paged_decode_attention`` over the pools in
+  place, a query head's group reading its key/value head's columns: the
+  full layer's pages as far as each row's position, a window layer's ring
+  of rows through its ring table, masked to the window, the kernel's grid
+  the rows' live steps alone (``fetch_plan``). Nothing is gathered, and
+  every decode program takes the served context's whole table: ONE
+  program a row bucket.
 
 The routed product is ONE routine for both, the lane's
 :func:`~brpc_tpu.serving.hybrid_model.expert_layer`: pairs sorted by expert,
@@ -39,7 +46,10 @@ the distinct held experts hit and the most pairs of one expert: the model's
 ``moe`` counter family (``moe_counters``; prefill's also how many layers of
 the launched programs took the kernel and how many the blocked scan, summed
 on the host), which ``ServingEngine.snapshot()`` copies and ``/serving``
-prints as the model formats it.
+prints as the model formats it. Its ``decode`` family (the toy block's
+names, ``hybrid_model.DECODE_COUNTERS``) counts, from the positions the
+host hands each decode step, the pages the attention kernel fetched
+against the pages of the tables it was handed, summed over the layers.
 
 Storage: weights and K/V pools bfloat16 (K stored rotated); the router's
 weights, the residual stream, softmax, router and every sum float32; matmuls
@@ -51,21 +61,21 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from brpc_tpu.serving.hybrid_cache import HybridStateCache
-from brpc_tpu.serving.hybrid_model import (NEG, QUERY_BLOCK,
-                                           HybridServingModel,
-                                           _block_context, _ln, _mm,
-                                           _operand_dtype, _ring_live,
-                                           draw_matrix, expert_counts,
-                                           expert_layer, fill_stack, rope,
-                                           route, shared_experts)
+from brpc_tpu.serving.hybrid_model import (DECODE_COUNTERS, NEG,
+                                           QUERY_BLOCK, HybridServingModel,
+                                           _ln, _mm, _next_pow2,
+                                           _operand_dtype, decode_family,
+                                           decode_lines, draw_matrix,
+                                           expert_counts, expert_layer,
+                                           fill_stack, rope, route,
+                                           shared_experts)
 
 MOE_CHUNK = 1024     # rows a pass of the expert layer in prefill
-DECODE_CONTEXT_FLOOR = 1024   # rows of the smallest decode context bucket
 
 
 class Cohere2MoeConfig:
@@ -114,9 +124,11 @@ class Cohere2MoeConfig:
         self.logit_scale = float(logit_scale)
         self.vocab = vocab_size
         self.max_context = max_context
-        # the smallest context bucket of a decode program: short contexts
-        # read that many ring rows and pages, not a whole window's
-        self.decode_context_floor = min(sliding_window, DECODE_CONTEXT_FLOOR)
+        # every decode program takes the served context's whole table: the
+        # attention kernel walks a row's own pages alone, and a program is
+        # seconds of set-up (its kernels lowered on the host whatever the
+        # compile cache holds), so ONE program a row bucket
+        self.decode_context_floor = _next_pow2(max_context)
         self.seed = seed
         self.attn = attn            # as ModelConfig.attn
 
@@ -225,7 +237,9 @@ def attend_blocked(cfg, q, k, v, window: int):
 class Cohere2MoeModel(HybridServingModel):
     """Weights + the prefill and decode programs over a HybridStateCache."""
 
-    DECODE_ROWS_BY_8 = True   # a step gathers every padded row's context
+    # a padded row costs its rows of every projection and one step of each
+    # layer's attention kernel
+    DECODE_ROWS_BY_8 = True
     MOE_COUNTERS = ("pairs", "experts_hit", "pairs_max_expert")
     # how a launched prefill program's attention layers were built
     PREFILL_MOE_COUNTERS = ("kernel_layers", "blocked_layers")
@@ -238,6 +252,8 @@ class Cohere2MoeModel(HybridServingModel):
         import jax.numpy as jnp
 
         self._init_programs(config, kv)
+        # decode attention's pages, counted on the host (counters())
+        self.decode_counters = dict.fromkeys(DECODE_COUNTERS, 0)
         cfg, bf = config, jnp.bfloat16
         d, ff, ns = cfg.d_model, cfg.d_ff, cfg.n_shared
         for l, take in self._drawn_layers(cfg.matrices, draw_matrix,
@@ -384,39 +400,34 @@ class Cohere2MoeModel(HybridServingModel):
         import jax
         import jax.numpy as jnp
 
+        from brpc_tpu.tpu import pallas_ops
+
         cfg = self.config
         bs, ring = self.kv.block_size, self.kv.config.ring_blocks
         ring_rows = ring * bs
         # every row's position lies under l_bucket: a ring that has not
-        # wrapped holds position p at ring row p
+        # wrapped holds position p at ring row p, so no row reads a ring
+        # row past this
         ring_read = min(ring_rows, l_bucket)
         scope = jax.named_scope
         rows = jnp.arange(b_bucket)
         pool_dt = self.kv.full.k_pool.dtype
-        g, per, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, \
-            cfg.head_dim
+        attend = functools.partial(
+            pallas_ops.paged_decode_attention, n_heads=cfg.n_heads,
+            n_kv_heads=cfg.n_kv_heads, block_size=bs, fetch_plan=True)
 
         def impl(w, fk, fv, wk, wv, ssm, conv, tokens, pos, tables,
                  ring_tables, slots):
             del slots           # no recurrent state: the row is the slot
-            t = jnp.arange(l_bucket)
-            full_live = (t[None, :] <= pos[:, None])[:, None, None, :]
             full_write = tables[rows, pos // bs] * bs + pos % bs
-            ring_live = _ring_live(pos, ring_rows, cfg.window)[
-                :, None, None, :ring_read]
             at = pos % ring_rows
             ring_write = ring_tables[rows, at // bs] * bs + at % bs
-            ring_blocks = ring_tables[:, :ring_read // bs]
-            # a padded row (position 0: a decode row's is its prompt's
-            # length at least) routes nowhere
+            ring_pages = ring_tables[:, :ring_read // bs]
+            # the positions 0 .. pos a row attends to; a padded row
+            # (position 0: a decode row's is its prompt's length at least)
+            # fetches one page, its output unread, and routes nowhere
+            length = pos + 1
             live = pos > 0
-
-            def attend(q, kc, vc, mask):
-                qh = q.reshape(b_bucket, g, per, hd)
-                kh = kc.reshape(b_bucket, -1, g, hd)
-                vh = vc.reshape(b_bucket, -1, g, hd)
-                sc = _mm(qh, kh, "bgjd,bkgd->bgjk") / math.sqrt(hd)
-                return _softmax_out(sc, mask, vh, "bgjk,bkgd->bgjd")
 
             x = w["embed"][tokens].astype(jnp.float32)
             counts = []
@@ -427,22 +438,20 @@ class Cohere2MoeModel(HybridServingModel):
                 with scope("window_attention" if kind == "window"
                            else "full_attention"):
                     q, k, v = self._qkv(p, w, h, pos, kind == "window")
+                    q = q.reshape(b_bucket, cfg.q_dim)
                     k, v = k.astype(pool_dt), v.astype(pool_dt)
                     if kind == "window":
                         wk = wk.at[i_w, ring_write].set(k)
                         wv = wv.at[i_w, ring_write].set(v)
-                        a = attend(q, _block_context(wk, i_w, ring_blocks, bs),
-                                   _block_context(wv, i_w, ring_blocks, bs),
-                                   ring_live)
+                        a = attend(q, wk, wv, i_w, ring_pages, length,
+                                   ring=(ring_rows, cfg.window))
                         i_w += 1
                     else:
                         fk = fk.at[i_f, full_write].set(k)
                         fv = fv.at[i_f, full_write].set(v)
-                        a = attend(q, _block_context(fk, i_f, tables, bs),
-                                   _block_context(fv, i_f, tables, bs),
-                                   full_live)
+                        a = attend(q, fk, fv, i_f, tables, length)
                         i_f += 1
-                    att = _mm(a.reshape(b_bucket, cfg.q_dim), w[p + "wo"])
+                    att = _mm(a, w[p + "wo"])
                 y, cnt = self._ffn(p, w, h, live, tile=16)
                 counts.append(expert_counts(cnt))
                 x = x + att + y
@@ -455,3 +464,31 @@ class Cohere2MoeModel(HybridServingModel):
             return fk, fv, wk, wv, ssm, conv, out
 
         return jax.jit(impl, donate_argnums=(1, 2, 3, 4, 5, 6))
+
+    def decode_step(self, tokens: np.ndarray, positions: np.ndarray,
+                    tables) -> np.ndarray:
+        """The lane's decode step, and the ``decode`` family counted from
+        the host's positions: the pages each row's attention fetched in
+        each layer (its positions' in the full layer, its ring's rows' in a
+        window layer) and the pages of the tables handed over."""
+        out = super().decode_step(tokens, positions, tables)
+        b_bucket, l_bucket = self._decode_buckets(len(tokens), tables)
+        bs, ring = self.kv.block_size, self.kv.ring_blocks
+        n = np.asarray(positions, np.int64) + 1
+        full, window = self.config.count("full"), self.config.count("window")
+        c = self.decode_counters
+        c["decode_launches_paged"] += 1
+        c["decode_pages_live"] += int(
+            full * (-(-n // bs)).sum()
+            + window * (-(-np.minimum(n, ring * bs) // bs)).sum())
+        c["decode_pages_bucket"] += b_bucket * (
+            full * (l_bucket // bs) + window * min(ring, l_bucket // bs))
+        return out
+
+    def counters(self) -> Dict[str, object]:
+        """The lane's families and ``decode`` (``decode_family``)."""
+        return dict(super().counters(),
+                    decode=decode_family(self.decode_counters))
+
+    def counter_lines(self, snap) -> List[str]:
+        return super().counter_lines(snap) + decode_lines(snap)

@@ -979,24 +979,49 @@ def flash_attention_carry(q, k, v, m, l, acc, q_start, k_start,
 # scalar-prefetched; each grid step takes `pages` pages of one row, every
 # page through a BlockSpec of its own over the SAME pool operand whose
 # index_map reads the table, so Mosaic's pipeline fetches a page straight
-# from the pool (128 KB contiguous at block 16 x kv_dim 2048 float32) and
-# prefetches the next row's first pages behind the current row's last. A
-# page past the row's length keeps the index it had one step earlier: the
-# pipeline fetches nothing for an unchanged index, and `pl.when` skips the
-# chunk. Scores for all heads come from ONE MXU product against the
-# block-diagonal query (H, D) (one cross-lane reduction a head and
-# position otherwise); the P V product yields (H, D) whose diagonal blocks
-# are the heads' outputs. Matmul operands are rounded as the backend's
-# default precision rounds the gather body's einsums: to bfloat16 on the
-# TPU (one MXU pass), not at all in the interpreted kernel on the CPU;
-# sums and the online softmax are float32 everywhere.
+# from the pool and prefetches the next row's first pages behind the
+# current row's last. A step takes 128 positions (the MXU's tile of keys),
+# 8 pages at block 16, at any row width: each page is a BlockSpec lowered
+# into every program that holds the kernel, and 32 of them a step (512
+# positions at bfloat16 rows of 1024) tripled the kernel's lowering, which
+# the host redoes at every set-up (PERF.md section 6). A page past the
+# row's length keeps the index it had one step earlier: the pipeline
+# fetches nothing for an unchanged index, and `pl.when` skips the chunk
+# (~1 us a step on a v5e: PERF.md section 5, `chat-steady`).
+# ``fetch_plan``: the grid is the rows' live steps alone, one dimension
+# whose size is computed on the device: a plan of (row, chunk) pairs,
+# scalar-prefetched beside the table, a row's chunks in order. A caller
+# whose table spans the whole served context (8192 positions, 64 steps a
+# row) walks a short row's few steps and no others.
+# A table's last chunk may be ragged: its columns past the table repeat
+# an earlier page of the row, past the row's length, masked.
+#
+# Heads. With a key/value head a query head, the scores of all heads come
+# from ONE MXU product against the block-diagonal query (H, D) (one
+# cross-lane reduction a head and position otherwise); the P V product
+# yields (H, D) whose diagonal blocks are the heads' outputs. With grouped
+# heads (H query heads over G key/value heads), each key/value head's
+# group of H / G query heads is one product against that head's columns
+# of the chunk, (H / G, hd) x (hd, T), and (H / G, T) x (T, hd) after it.
+#
+# A ring (``ring=(ring_rows, window)``): the table is a window layer's
+# ring, ring row r holding the newest position p <= pos with p = r (mod
+# ring_rows) (K was rotated when written, so where a row lies in the ring
+# is nothing to the softmax). A row at ``pos = length - 1`` fetches ring
+# rows 0 .. min(length, ring_rows) - 1 and masks those whose position lies
+# at or below pos - window.
+#
+# Matmul operands are rounded as the backend's default precision rounds the
+# gather body's einsums: to bfloat16 on the TPU (one MXU pass), not at all
+# in the interpreted kernel on the CPU; sums and the online softmax are
+# float32 everywhere.
 # ---------------------------------------------------------------------------
 _PAGED_CHUNK = 128   # positions a grid step: the MXU's tile of keys
 # the scalar-prefetched block table (rows x pages, int32) lives in SMEM,
 # 1 MiB on a v5e with the kernel's own scalars beside it
 PAGED_TABLE_BYTES = 512 << 10
 # a caller's tables come in multiples of this many pages (2048 positions at
-# block 16): a dead column costs one skipped grid step a chunk (~0.35 us),
+# block 16): a dead column costs one skipped grid step a chunk (~1 us),
 # a table width of its own costs a compiled program
 PAGED_TABLE_PAGES = 128
 
@@ -1007,21 +1032,60 @@ def paged_table_pages(n_pages: int) -> int:
     return -(-n_pages // PAGED_TABLE_PAGES) * PAGED_TABLE_PAGES
 
 
-def _paged_decode_kernel(layer_ref, table_ref, len_ref, q_ref, *refs,
-                         pages: int, bs: int, n_heads: int, nj: int):
+def _paged_decode_kernel(layer_ref, table_ref, len_ref, *refs, pages: int,
+                         bs: int, n_heads: int, nj: int, group: int, ring,
+                         plan: bool):
     import jax.experimental.pallas as pl
 
     del layer_ref, table_ref   # read by the index maps
+    if plan:
+        row_ref, chunk_ref, q_ref, *refs = refs
+        b = row_ref[pl.program_id(0)]
+        j = chunk_ref[pl.program_id(0)]
+    else:
+        q_ref, *refs = refs
+        b = pl.program_id(0)
+        j = pl.program_id(1)
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, kbuf, vbuf, m_scr, l_scr, acc_scr = refs[2 * pages:]
-    b = pl.program_id(0)
-    j = pl.program_id(1)
     length = len_ref[b]
-    H, D = acc_scr.shape
-    hd = D // n_heads
+    H, D = acc_scr.shape     # grouped heads: D is one head's width
+    hd = D // n_heads if group == 1 else D
     T = pages * bs
-    own = (jax.lax.broadcasted_iota(jnp.int32, (H, D), 1) // hd
-           == jax.lax.broadcasted_iota(jnp.int32, (H, D), 0))
+    if group == 1:
+        own = (jax.lax.broadcasted_iota(jnp.int32, (H, D), 1) // hd
+               == jax.lax.broadcasted_iota(jnp.int32, (H, D), 0))
+    # the positions (ring rows) the row reads
+    fetch = length if ring is None else jnp.minimum(length, ring[0])
+
+    def live(rows):
+        """(rows, T): the positions of this chunk the row attends to."""
+        r = j * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+        ok = r < fetch
+        if ring is not None:
+            n, window = ring
+            # ring row r holds position pos - ((pos - r) mod n)
+            age = jax.lax.rem(length - 1, n) - r
+            ok = ok & (jnp.where(age < 0, age + n, age) < window)
+        return ok
+
+    def update(heads, s, cols):
+        """The online softmax of query rows ``heads`` over the chunk's
+        scores ``s``, against V's columns ``cols``. A chunk with no live
+        position (a ring's, under a window much narrower than the ring)
+        leaves m at NEG_INF and counts in l and acc that the first live
+        chunk scales by exp(NEG_INF - m) = 0; after a live chunk its
+        scores' exp underflow to the correct 0 (without a ring the first
+        chunk always holds position 0)."""
+        m_prev = m_scr[heads]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[heads] = l_scr[heads] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+        acc_scr[heads] = acc_scr[heads] * alpha + _dot_f32(
+            p.astype(vbuf.dtype), vbuf[:, cols])
+        m_scr[heads] = m_new
 
     @pl.when(j == 0)
     def _init():
@@ -1029,50 +1093,61 @@ def _paged_decode_kernel(layer_ref, table_ref, len_ref, q_ref, *refs,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * T < length)
+    @pl.when(j * T < fetch)
     def _chunk():
         for i in range(pages):
             kbuf[i * bs:(i + 1) * bs, :] = k_refs[i][:].astype(kbuf.dtype)
             vbuf[i * bs:(i + 1) * bs, :] = v_refs[i][:].astype(vbuf.dtype)
-        q = jnp.broadcast_to(q_ref[0], (H, D))
-        qbd = jnp.where(own, q, 0.0).astype(kbuf.dtype)
-        s = _dot_f32(qbd, kbuf[:], trans_b=True) / float(hd) ** 0.5
-        pos = j * T + jax.lax.broadcasted_iota(jnp.int32, (H, T), 1)
-        # the first chunk always holds position 0, so m is finite from
-        # there on and a masked score's exp underflows to the correct 0
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + _dot_f32(p.astype(vbuf.dtype),
-                                                   vbuf[:])
-        m_scr[:] = m_new
+        every = slice(None)
+        if group == 1:
+            q = jnp.broadcast_to(q_ref[0], (H, D))
+            qbd = jnp.where(own, q, 0.0).astype(kbuf.dtype)
+            s = _dot_f32(qbd, kbuf[:], trans_b=True) / float(hd) ** 0.5
+            update(every, jnp.where(live(H), s, NEG_INF), every)
+            return
+        ok = live(group)
+        for g in range(n_heads // group):
+            heads = slice(g * group, (g + 1) * group)
+            cols = slice(g * hd, (g + 1) * hd)
+            q = q_ref[0, heads, :].astype(kbuf.dtype)
+            s = _dot_f32(q, kbuf[:, cols], trans_b=True) / float(hd) ** 0.5
+            update(heads, jnp.where(ok, s, NEG_INF), cols)
 
-    @pl.when(j == nj - 1)
+    # a plan's row ends at its last live chunk, a grid's at the table's end
+    @pl.when((j + 1) * T >= fetch if plan else j == nj - 1)
     def _finish():
-        out = jnp.where(own, acc_scr[:] / l_scr[:], 0.0)
-        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+        out = acc_scr[:] / l_scr[:]
+        if group == 1:
+            out = jnp.sum(jnp.where(own, out, 0.0), axis=0, keepdims=True)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_heads", "block_size", "interpret",
-                                    "operand_dtype"))
+                                    "operand_dtype", "n_kv_heads", "ring",
+                                    "fetch_plan"))
 def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
                            n_heads: int, block_size: int,
-                           interpret: bool = None, operand_dtype=None):
+                           interpret: bool = None, operand_dtype=None,
+                           n_kv_heads: int = None, ring=None,
+                           fetch_plan: bool = False):
     """Attention of ONE query row per sequence over its own paged prefix.
 
-    q: (B, D) float32, heads side by side (D = n_heads * head_dim);
-    k_pool, v_pool: the WHOLE pools, (layers, slots, D), a page being
-    ``block_size`` consecutive slots; layer: int32 scalar (an operand, so
-    every layer of a program runs the same kernel); block_tables:
-    (B, pages) int32 physical block ids; lengths: (B,) int32, each >= 1,
-    the positions ``0 .. length - 1`` a row attends to (pages past it are
-    neither fetched nor computed). ``operand_dtype``: what the two
-    products' operands are rounded to; by default the backend's own
-    default precision (see above). Returns (B, D)."""
+    q: (B, n_heads * head_dim), heads side by side; k_pool, v_pool: the
+    WHOLE pools, (layers, slots, n_kv_heads * head_dim), a page being
+    ``block_size`` consecutive slots; query head h reads key/value head
+    ``h // (n_heads / n_kv_heads)`` (``n_kv_heads``: ``n_heads`` where it
+    is None); layer: int32 scalar (an operand, so every layer of a program
+    runs the same kernel); block_tables: (B, pages) int32 physical block
+    ids; lengths: (B,) int32, each >= 1, the positions ``0 .. length - 1``
+    a row attends to (pages past it are neither fetched nor computed).
+    ``ring``: None, or ``(ring_rows, window)`` where the tables are window
+    rings (see above): a row fetches ``min(length, ring_rows)`` ring rows
+    and attends to the positions ``length - window .. length - 1`` among
+    them. ``fetch_plan``: walk the rows' live steps alone (see above), not
+    every column of the table. ``operand_dtype``: what the two products'
+    operands are rounded to; by default the backend's own default
+    precision (see above). Returns (B, n_heads * head_dim) in q's dtype."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1080,23 +1155,41 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
         interpret = not _on_tpu()
     if operand_dtype is None:
         operand_dtype = jnp.float32 if interpret else jnp.bfloat16
-    B, D = q.shape
-    n_layers, slots, _ = k_pool.shape
+    B, Dq = q.shape
+    n_layers, slots, D = k_pool.shape
+    kv_heads = n_kv_heads or n_heads
+    group, hd = n_heads // kv_heads, Dq // n_heads
+    if n_heads % kv_heads or D != kv_heads * hd:
+        raise ValueError(f"{n_heads} query heads of {hd} over pools of "
+                         f"{D}: not {kv_heads} key/value heads")
     bs = block_size
     n_pages = block_tables.shape[1]
     pages = min(max(1, _PAGED_CHUNK // bs), n_pages)
-    if n_pages % pages:
-        raise ValueError(f"table width {n_pages} must divide into chunks "
-                         f"of {pages} pages")
-    nj = n_pages // pages
+    nj = -(-n_pages // pages)
+    # the table and a plan's two columns of up to B * nj steps, in SMEM
+    if (block_tables.size + (2 * B * nj if fetch_plan else 0)) * 4 \
+            > PAGED_TABLE_BYTES:
+        raise ValueError(f"a table of {B} x {n_pages} pages does not fit "
+                         f"the kernel's {PAGED_TABLE_BYTES} bytes of SMEM")
+    lengths = lengths.astype(jnp.int32)
     # (layers, blocks, bs, D): a bitcast where bs is a multiple of the
-    # float32 sublane tile (8)
+    # sublane tile (8 float32 rows, 16 bfloat16)
     k4 = k_pool.reshape(n_layers, slots // bs, bs, D)
     v4 = v_pool.reshape(n_layers, slots // bs, bs, D)
 
+    def step(*ids):
+        """(row, chunk, layer_ref, table_ref, len_ref) of a grid step."""
+        if not fetch_plan:
+            return ids
+        i, layer_ref, table_ref, len_ref, row_ref, chunk_ref = ids
+        return row_ref[i], chunk_ref[i], layer_ref, table_ref, len_ref
+
     def page_spec(i):
-        def index(b, j, layer_ref, table_ref, len_ref):
-            live = (len_ref[b] + bs - 1) // bs
+        def index(*ids):
+            b, j, layer_ref, table_ref, len_ref = step(*ids)
+            n = len_ref[b] if ring is None \
+                else jnp.minimum(len_ref[b], ring[0])
+            live = (n + bs - 1) // bs
             # the last step at which page j * pages + i is live; later
             # steps repeat its index so nothing is fetched for them
             last = jnp.maximum(live - 1 - i, 0) // pages
@@ -1104,37 +1197,56 @@ def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
             return (layer_ref[0], table_ref[b * n_pages + page], 0, 0)
         return pl.BlockSpec((None, None, bs, D), index)
 
-    def row(b, j, *_):
-        return (b, 0, 0)
+    def row(*ids):
+        return (step(*ids)[0], 0, 0)
 
+    # a key/value head a query head: the row as it is, heads side by side
+    # (the block-diagonal form); grouped: a query head a sublane row
+    width = (1, Dq) if group == 1 else (n_heads, hd)
     kernel = functools.partial(_paged_decode_kernel, pages=pages, bs=bs,
-                               n_heads=n_heads, nj=nj)
+                               n_heads=n_heads, nj=nj, group=group,
+                               ring=ring, plan=fetch_plan)
+    scalars = [jnp.asarray(layer, jnp.int32).reshape(1),
+               block_tables.astype(jnp.int32).reshape(-1), lengths]
+    if fetch_plan:
+        # step i of the plan: row b's chunk c, a row's chunks in order
+        fetch = lengths if ring is None else jnp.minimum(lengths, ring[0])
+        steps = (fetch + pages * bs - 1) // (pages * bs)
+        ends = jnp.cumsum(steps)
+        i = jnp.arange(B * nj, dtype=jnp.int32)
+        rows = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1),
+                           B - 1)
+        scalars += [rows.astype(jnp.int32),
+                    (i - (ends - steps)[rows]).astype(jnp.int32)]
+        grid, semantics = (ends[-1],), ("arbitrary",)
+    else:
+        grid, semantics = (B, nj), ("parallel", "arbitrary")
     params = (None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary")))
+        dimension_semantics=semantics))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, nj),
-            in_specs=([pl.BlockSpec((1, 1, D), row)]
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
+            in_specs=([pl.BlockSpec((1,) + width, row)]
                       + [page_spec(i) for i in range(pages)] * 2),
-            out_specs=pl.BlockSpec((1, 1, D), row),
+            out_specs=pl.BlockSpec((1,) + width, row),
             scratch_shapes=[
                 pltpu.VMEM((pages * bs, D), operand_dtype),
                 pltpu.VMEM((pages * bs, D), operand_dtype),
                 pltpu.VMEM((n_heads, 1), jnp.float32),
                 pltpu.VMEM((n_heads, 1), jnp.float32),
-                pltpu.VMEM((n_heads, D), jnp.float32),
+                pltpu.VMEM((n_heads, Dq if group == 1 else hd),
+                           jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B,) + width, q.dtype),
         compiler_params=params,
         interpret=interpret,
         name="paged_decode_attention",
-    )(jnp.asarray(layer, jnp.int32).reshape(1),
-      block_tables.astype(jnp.int32).reshape(-1),
-      lengths.astype(jnp.int32),
-      q[:, None, :], *([k4] * pages), *([v4] * pages))
-    return out[:, 0, :]
+    )(*scalars,
+      q[:, None, :] if group == 1 else q.reshape((B,) + width),
+      *([k4] * pages), *([v4] * pages))
+    return out[:, 0, :] if group == 1 else out.reshape(B, Dq)
 
 
 # ---------------------------------------------------------------------------

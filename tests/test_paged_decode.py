@@ -175,13 +175,248 @@ def test_kernel_bfloat16_operands_are_the_chips_rounding(name):
 
 
 def test_kernel_refuses_a_table_it_cannot_chunk():
+    """A table of any width is walked a chunk at a time, its last chunk
+    ragged where the width does not divide into chunks
+    (``test_kernel_takes_a_table_whose_last_chunk_is_ragged``); what it
+    cannot walk is a table its scalar memory does not hold."""
     rng = np.random.default_rng(0)
     kpool, vpool = _pools(rng, 1, 16, BS, 64)
-    with pytest.raises(ValueError, match="chunks"):
+    rows = pallas_ops.PAGED_TABLE_BYTES // (128 * 4)
+    with pytest.raises(ValueError, match="SMEM"):
         pallas_ops.paged_decode_attention(
-            jnp.zeros((1, 64)), kpool, vpool, jnp.int32(0),
-            jnp.zeros((1, 12), jnp.int32), jnp.ones((1,), jnp.int32),
+            jnp.zeros((2 * rows, 64)), kpool, vpool, jnp.int32(0),
+            jnp.zeros((2 * rows, 128), jnp.int32),
+            jnp.ones((2 * rows,), jnp.int32),
             n_heads=4, block_size=BS, interpret=True)
+
+
+# ------------------------------------ grouped key/value heads, window rings
+def gather_decode_attention(q, k_pool, v_pool, layer, block_tables,
+                            lengths, n_heads, block_size, n_kv_heads=None,
+                            ring=None, **_):
+    """The path the grouped kernel replaced in ``Cohere2MoeModel``'s
+    decode: each row's context gathered out of the pool whole blocks at a
+    time, padded to the table (``_block_context``), the grouped einsum and
+    ``_softmax_out``; a ring's rows masked by ``_ring_live``. It takes the
+    kernel's arguments, so it can stand in for it."""
+    import math
+
+    from brpc_tpu.serving.hybrid_model import _block_context, _mm, _ring_live
+    from brpc_tpu.serving.moe_model import _softmax_out
+
+    g = n_kv_heads or n_heads
+    B, hd = q.shape[0], q.shape[1] // n_heads
+    kc = _block_context(k_pool, layer, block_tables, block_size)
+    vc = _block_context(v_pool, layer, block_tables, block_size)
+    pos = jnp.asarray(lengths) - 1
+    if ring is None:
+        live = jnp.arange(kc.shape[1])[None, :] <= pos[:, None]
+    else:
+        live = _ring_live(pos, *ring)[:, :kc.shape[1]]
+    qh = q.reshape(B, g, n_heads // g, hd)
+    kh, vh = (c.reshape(B, -1, g, hd) for c in (kc, vc))
+    sc = _mm(qh, kh, "bgjd,bkgd->bgjk") / math.sqrt(hd)
+    out = _softmax_out(sc, live[:, None, None, :], vh, "bgjk,bkgd->bgjd")
+    return out.reshape(B, -1)
+
+
+def _grouped(rng, lengths, n_pages, heads, kv_heads, hd, dtype, padded=0,
+             layers=2):
+    """Pools of ``dtype`` rows of ``kv_heads * hd``, shuffled tables (every
+    page of a row live: a ring is allocated whole), the last ``padded``
+    rows a padded row's (length 1, table of noughts), and queries."""
+    dim = kv_heads * hd
+    live = [n_pages] * (len(lengths) - padded)
+    blocks = n_pages * len(live) + 2
+    kpool, vpool = (jnp.asarray(rng.standard_normal(
+        (layers, (blocks + 1) * BS, dim)), dtype) for _ in range(2))
+    tables = np.zeros((len(lengths), n_pages), np.int32)
+    tables[:len(live)] = _tables(rng, [n * BS for n in live], n_pages,
+                                 blocks, BS)
+    q = jnp.asarray(rng.standard_normal((len(lengths), heads * hd)),
+                    jnp.float32)
+    return q, kpool, vpool, jnp.asarray(tables), \
+        jnp.asarray(lengths, jnp.int32)
+
+
+def _against_the_gather(lengths, n_pages, heads, kv_heads, hd, dtype,
+                        padded=0, ring=None, seed=0, fetch_plan=False):
+    rng = np.random.default_rng(seed)
+    q, kpool, vpool, tables, lens = _grouped(
+        rng, lengths, n_pages, heads, kv_heads, hd, dtype, padded)
+    got = pallas_ops.paged_decode_attention(
+        q, kpool, vpool, jnp.int32(1), tables, lens, n_heads=heads,
+        n_kv_heads=kv_heads, block_size=BS, interpret=True, ring=ring,
+        fetch_plan=fetch_plan)
+    want = gather_decode_attention(q, kpool, vpool, 1, tables, lens, heads,
+                                   BS, kv_heads, ring)
+    n = len(lengths) - padded     # a padded row's output is unread
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+BF = jnp.bfloat16
+GROUPED_CASES = {
+    # lengths (the last `padded` rows padded), table pages, heads, key/value
+    # heads, head size, pool dtype, padded rows. Page edges at 16, step
+    # edges at 128
+    "128_over_8_page_and_step_edges": (
+        [1, 16, 17, 127, 129, 512], 32, 128, 8, 128, BF, 0),
+    "128_over_8_padded_rows": ([300, 33, 1, 1], 32, 128, 8, 128, BF, 2),
+    "4_over_2_page_edges": ([1, 15, 16, 17, 40, 64], 4, 4, 2, 16,
+                            jnp.float32, 0),
+    "4_over_2_padded_rows": ([9, 64, 1, 1, 1], 4, 4, 2, 16, BF, 3),
+}
+
+
+# the grid walks every column of the table, or the rows' live steps alone
+WALKS = pytest.mark.parametrize("fetch_plan", [False, True],
+                                ids=["grid", "plan"])
+
+
+@WALKS
+@pytest.mark.parametrize("name", sorted(GROUPED_CASES))
+def test_grouped_heads_equal_the_gather_and_the_grouped_einsum(name,
+                                                               fetch_plan):
+    lengths, n_pages, heads, kv_heads, hd, dtype, padded = \
+        GROUPED_CASES[name]
+    _against_the_gather(lengths, n_pages, heads, kv_heads, hd, dtype,
+                        padded, fetch_plan=fetch_plan)
+
+
+RING_CASES = {
+    # positions, ring pages (the table), (ring rows, window), heads,
+    # key/value heads, head size, pool dtype
+    "wrapped": ([64, 70, 100, 127, 200, 1000], 4, (64, 48), 4, 2, 16,
+                jnp.float32),
+    "not_wrapped": ([0, 5, 47, 48, 63], 4, (64, 48), 4, 2, 16, jnp.float32),
+    "ring_rows_over_window": ([10, 16, 17, 63, 64, 65, 150], 4, (64, 16),
+                              4, 2, 16, BF),
+    # Command A+'s widths, a ring of 10 pages: the last of two steps ragged
+    "two_steps_the_last_ragged": ([100, 127, 159, 160, 287, 375], 10,
+                                  (160, 128), 128, 8, 128, BF),
+    # a window far narrower than the ring: one of the two steps holds no
+    # live row (the first, the second, neither)
+    "a_step_with_no_live_row": ([484, 375, 405, 63], 16, (256, 64),
+                                128, 8, 128, BF),
+    # a context bucket under the ring: the table holds the rows the
+    # positions reach
+    "table_narrower_than_the_ring": ([3, 33, 63], 4, (128, 96), 4, 2, 16,
+                                     jnp.float32),
+}
+
+
+@WALKS
+@pytest.mark.parametrize("name", sorted(RING_CASES))
+def test_a_ring_equals_the_gather_masked_to_the_window(name, fetch_plan):
+    pos, n_pages, ring, heads, kv_heads, hd, dtype = RING_CASES[name]
+    _against_the_gather([p + 1 for p in pos], n_pages, heads, kv_heads, hd,
+                        dtype, ring=ring, seed=len(name),
+                        fetch_plan=fetch_plan)
+
+
+def _paged_call(heads, kv_heads, hd, dtype, rows, n_pages, ring=None,
+                fetch_plan=False):
+    """The kernel's pallas_call as traced for the chip (nothing runs)."""
+    S = jax.ShapeDtypeStruct
+    pool = S((2, 64 * BS, kv_heads * hd), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, n: (
+        pallas_ops.paged_decode_attention(
+            q, k, v, jnp.int32(0), t, n, n_heads=heads, n_kv_heads=kv_heads,
+            block_size=BS, interpret=False, ring=ring,
+            fetch_plan=fetch_plan)))(
+        S((rows, heads * hd), jnp.float32), pool, pool,
+        S((rows, n_pages), jnp.int32), S((rows,), jnp.int32))
+    (call,) = [e for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"]
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("heads,kv_heads,hd,dtype", [
+    (32, 16, 128, jnp.float32),         # rows of 8 KiB (the toy's width)
+    (128, 8, 128, BF),                  # Command A+'s rows of 2 KiB
+], ids=["rows_of_8_kib", "rows_of_2_kib"])
+def test_a_grid_step_takes_128_positions_whatever_the_rows_width(
+        heads, kv_heads, hd, dtype):
+    """A step takes 128 positions (8 pages) at any row width: the grid
+    walks a 64-page table in 8 steps, and rows that end on either side of
+    a step's edge, or two steps on, equal the gather's."""
+    mapping = _paged_call(heads, kv_heads, hd, dtype, 4, 64)
+    assert tuple(mapping.grid) == (4, 8)
+    _against_the_gather([1, 127, 129, 391], 32, heads, kv_heads, hd, dtype,
+                        seed=heads)
+
+
+def test_a_fetch_plan_walks_the_live_steps_of_a_wide_table():
+    """With ``fetch_plan`` the grid is ONE dimension whose size is an
+    operand (the rows' live steps, summed on the device), beside the table
+    the plan's row and chunk of each step: a table of 512 pages (Command
+    A+'s served context) costs a short row its own steps, and rows of 1 to
+    5 steps, a padded row among them, over a table of 40 pages equal the
+    gather's."""
+    from jax._src.pallas import core as pallas_core
+
+    mapping = _paged_call(128, 8, 128, BF, 8, 512, fetch_plan=True)
+    assert len(mapping.grid) == 1
+    assert mapping.grid[0] is pallas_core.dynamic_grid_dim
+    assert mapping.num_dynamic_grid_bounds == 1
+    assert mapping.num_index_operands == 5
+    _against_the_gather([1, 129, 640, 100, 1], 40, 4, 2, 16, jnp.float32,
+                        padded=1, fetch_plan=True, seed=9)
+
+
+def test_kernel_takes_a_table_whose_last_chunk_is_ragged():
+    """A ring of 257 pages (Command A+'s: ceil(4096 / 16) + 1) walked 8
+    pages a step: 33 steps, the last of ONE page; compiled nowhere."""
+    mapping = _paged_call(128, 8, 128, BF, 8, 257, ring=(4112, 4096))
+    assert tuple(mapping.grid) == (8, 33)
+    # and at a width that runs here: 12 pages at 8 a step
+    rng = np.random.default_rng(3)
+    lengths = [1, 8 * BS, 8 * BS + 1, 12 * BS]
+    q, kpool, vpool, tables, lens = _grouped(
+        rng, lengths, 12, 16, 16, 128, jnp.float32)
+    assert tuple(_paged_call(16, 16, 128, jnp.float32, 4, 12).grid) == (4, 2)
+    got = pallas_ops.paged_decode_attention(
+        q, kpool, vpool, jnp.int32(1), tables, lens, n_heads=16,
+        block_size=BS, interpret=True)
+    want = _oracle(q, kpool, vpool, 1, np.asarray(tables), lengths, 16, BS)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_a_key_value_head_a_query_head_is_the_toys_kernel():
+    """``n_kv_heads == n_heads`` is the call the toy model makes, bit for
+    bit; at ``chat-steady``'s widths (16 heads of 128, float32 rows of
+    2048, tables of 128 pages) a step takes 8 pages (128 positions), the
+    row as one block (1, 2048) of block-diagonal heads."""
+    name = "ragged_1_bs-1_bs_bs+1_full"
+    lengths, n_pages, n_heads, hd, bs = KERNEL_CASES[name]
+    rng = np.random.default_rng(5)
+    blocks = sum(-(-n // bs) for n in lengths) + 1
+    kpool, vpool = _pools(rng, 2, blocks, bs, n_heads * hd)
+    tables = jnp.asarray(_tables(rng, lengths, n_pages, blocks, bs))
+    q = jnp.asarray(rng.standard_normal((len(lengths), n_heads * hd)),
+                    jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    toy, same = (np.asarray(pallas_ops.paged_decode_attention(
+        q, kpool, vpool, jnp.int32(1), tables, lens, n_heads=n_heads,
+        block_size=bs, interpret=True, **kw))
+        for kw in ({}, {"n_kv_heads": n_heads}))
+    np.testing.assert_array_equal(toy, same)
+    mapping = _paged_call(16, 16, 128, jnp.float32, 8, 128)
+    assert tuple(mapping.grid) == (8, 16)
+    blocks = [tuple(getattr(d, "block_size", d) for d in m.block_shape)
+              for m in mapping.block_mappings]
+    assert blocks[0] == blocks[-1] == (1, 1, 2048)
+    assert len(blocks) == 2 + 2 * 8
 
 
 # ----------------------------------------------- the decode program, paged
@@ -459,6 +694,23 @@ for rows, pages in json.loads(sys.argv[1]):
     except Exception as e:
         out[f"{rows}x{pages}"] = {"error": str(e)[:500]}
 
+# the toy's kernel as `chat-steady` serves it (8 rows, tables of 128 pages):
+# the Mosaic module's text without debug locations, by sha256
+import base64, hashlib, re
+from jax._src.lib import tpu  # registers the Mosaic dialect
+from jaxlib.mlir import ir
+
+hlo = jax.jit(f).lower(S((8, d)), pool, pool, S((), jnp.int32),
+                       S((8, 128), jnp.int32), S((8,), jnp.int32)).as_text()
+config = json.loads(re.findall(r'backend_config = "([^"]*)"',
+                               hlo)[0].replace("\\22", '"'))
+with ir.Context() as ctx:
+    ctx.allow_unregistered_dialects = True
+    module = ir.Module.parse(base64.b64decode(
+        config["custom_call_config"]["body"]))
+    out["toy_kernel"] = hashlib.sha256(module.operation.get_asm(
+        enable_debug_info=False).encode()).hexdigest()
+
 
 # the grouped matmul of the expert layer (serving/moe_model.py) at the
 # sparse-expert cell's widths: (rows, tile, K, N) over 16 held experts
@@ -536,6 +788,33 @@ for rows, ctx_rows in json.loads(sys.argv[5]):
             "temp": c.memory_analysis().temp_size_in_bytes}
     except Exception as e:
         out[f"mlachunk{rows}x{ctx_rows}"] = {"error": str(e)[:500]}
+
+
+# decode attention over grouped heads (serving/moe_model.py) at the
+# sparse-expert cell's widths: 128 query heads over 8 of 128, bfloat16
+# pools of the full layer (16384 blocks) and of three window layers' rings
+# (48 of 257 blocks); (rows, table pages, ring rows or 0)
+full = S((1, (16384 + 1) * bs, 1024), bf)
+rings = S((3, (48 * 257 + 1) * bs, 1024), bf)
+for rows, pages, ring_rows in json.loads(sys.argv[6]):
+    ring = (ring_rows, 4096) if ring_rows else None
+    pool = rings if ring else full
+
+    def gq(q, kpool, vpool, layer, tables, lengths, ring=ring):
+        return pallas_ops.paged_decode_attention(
+            q, kpool, vpool, layer, tables, lengths, n_heads=128,
+            n_kv_heads=8, block_size=bs, interpret=False, ring=ring,
+            fetch_plan=True)
+
+    try:
+        c = jax.jit(gq).lower(
+            S((rows, 128 * 128)), pool, pool, S((), jnp.int32),
+            S((rows, pages), jnp.int32), S((rows,), jnp.int32)).compile()
+        out[f"gqa{rows}x{pages}x{ring_rows}"] = {
+            "custom_call": "tpu_custom_call" in c.as_text(),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+    except Exception as e:
+        out[f"gqa{rows}x{pages}x{ring_rows}"] = {"error": str(e)[:500]}
 print(json.dumps(out))
 """
 # decode gate-and-up and down at 32 rows (8 x 32 pairs + 16 x 15 pads),
@@ -554,6 +833,9 @@ _MLA_SHAPES = [(16, 512), (32, 2048)]
 # later chunks of `agent-steady`: the smallest bucket, and a full chunk at
 # the narrowest and the widest context
 _MLA_CHUNK_SHAPES = [(512, 4096), (2048, 4096), (2048, 32768)]
+# steps of `rag-steady`: the full layer at the widest context bucket, and a
+# window layer's ring (257 pages: the last step ragged) and a short bucket's
+_GQA_SHAPES = [(8, 512, 0), (32, 512, 0), (8, 257, 4112), (8, 64, 4112)]
 
 
 @pytest.fixture(scope="module")
@@ -574,7 +856,7 @@ def compiled_for_v5e():
         [sys.executable, "-c", _COMPILE_SCRIPT,
          json.dumps(_COMPILE_SHAPES), json.dumps(_GMM_SHAPES),
          json.dumps(_SCAN_SHAPES), json.dumps(_MLA_SHAPES),
-         json.dumps(_MLA_CHUNK_SHAPES)],
+         json.dumps(_MLA_CHUNK_SHAPES), json.dumps(_GQA_SHAPES)],
         env=env, capture_output=True, text=True, timeout=600)
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert proc.returncode == 0 and lines, proc.stderr[-2000:]
@@ -595,6 +877,20 @@ def test_kernel_compiles_for_the_v5e_at_the_cells_shapes(compiled_for_v5e,
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < 64 << 20
+
+
+# the toy's kernel lowered before it took grouped heads, rings and a fetch
+# plan (the parent of the change that added them, at this JAX and libtpu)
+TOY_KERNEL_SHA256 = \
+    "16c84137238cb7707a3ea8657b7e71f487fbc969f16ae41be9e4e2a7ea1b8cf4"
+
+
+def test_the_toys_kernel_is_the_mosaic_module_it_was(compiled_for_v5e):
+    """``chat-steady``'s call (one key/value head a query head, no ring, a
+    grid over every column of tables 128 pages wide) lowers to the same
+    Mosaic module, debug locations aside, as before the kernel took grouped
+    heads, rings and a fetch plan."""
+    assert compiled_for_v5e["toy_kernel"] == TOY_KERNEL_SHA256
 
 
 @pytest.mark.parametrize("rows,tile,k,n", _GMM_SHAPES)
@@ -647,3 +943,17 @@ def test_mla_chunk_attention_compiles_for_the_v5e_at_the_cells_shapes(
     assert "error" not in got, got
     assert got["custom_call"]
     assert got["temp"] < ctx_rows * 256 * 2
+
+
+@pytest.mark.parametrize("rows,pages,ring_rows", _GQA_SHAPES)
+def test_grouped_paged_decode_compiles_for_the_v5e_at_the_cells_shapes(
+        compiled_for_v5e, rows, pages, ring_rows):
+    """Mosaic accepts ``paged_decode_attention`` over grouped heads at the
+    sparse-expert cell's widths (128 query heads over 8 of 128, bfloat16
+    rows of 1024) on the full layer's pages and on a
+    window layer's ring, within its VMEM, and XLA hands it the pools
+    without a copy."""
+    got = compiled_for_v5e[f"gqa{rows}x{pages}x{ring_rows}"]
+    assert "error" not in got, got
+    assert got["custom_call"]
+    assert got["temp"] < 64 << 20

@@ -404,9 +404,11 @@ def test_prefill_counts_the_layers_the_kernel_and_the_blocked_scan_took(
 
 def test_a_decode_step_gathers_its_blocks_and_copies_no_layer_of_a_pool(
         world):
-    """``pool[layer]`` ahead of the gather is a copy of the whole layer (on
-    the chip 404 MB of the rings, six times a step): the layer rides in the
-    gather's index, and no slice takes a pool for its operand."""
+    """``pool[layer]`` is a copy of the whole layer (on the chip 404 MB of
+    the rings, six times a step), and a gathered context a copy of every
+    padded row's: a layer's attention is ONE call of the paged kernel over
+    the whole pools, the layer an operand, and nothing gathers a pool's
+    blocks or slices a pool."""
     import jax
 
     model, kv = _stand(weights=world["host"])
@@ -420,11 +422,55 @@ def test_a_decode_step_gathers_its_blocks_and_copies_no_layer_of_a_pool(
     pools = {kv.full.k_pool.shape, kv.window.k_pool.shape}
     eqns = list(_eqns(jaxpr.jaxpr))
     for e in eqns:
-        if e.primitive.name in ("slice", "dynamic_slice", "squeeze"):
+        if e.primitive.name in ("slice", "dynamic_slice", "squeeze",
+                                "gather"):
+            assert e.invars[0].aval.shape[-2:] != (BS, M["head_dim"] * 2), e
             assert e.invars[0].aval.shape not in pools, e
-    reads = [e for e in eqns if e.primitive.name == "gather"
-             and e.invars[0].aval.shape[-2:] == (BS, M["head_dim"] * 2)]
-    assert len(reads) == 2 * len(model.config.kinds)
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"] == "paged_decode_attention"]
+    assert len(calls) == len(model.config.kinds)
+
+
+def test_decode_through_the_kernel_serves_the_gather_paths_64_tokens(
+        world, monkeypatch):
+    """The paged kernel (interpreted here) and the gather it replaced
+    (standing in for it: ``test_paged_decode.gather_decode_attention``)
+    answer the same 64 greedy tokens a prompt, three rows a step over
+    wrapped rings, through one program whatever the context; and the
+    ``decode`` family counts, from the host's positions, the pages of each
+    row's positions in the full layer and of its ring's rows in each
+    window layer against the pages of the tables handed over."""
+    from test_paged_decode import gather_decode_attention
+
+    from brpc_tpu.tpu import pallas_ops
+
+    answers = []
+    for gather in (False, True):
+        if gather:
+            monkeypatch.setattr(pallas_ops, "paged_decode_attention",
+                                gather_decode_attention)
+        model, kv = _stand(weights=world["host"])
+        answers.append(_serve(model, kv, world["prompts"], 64))
+        counted = model.counters()["decode"]
+        # contexts of 38-133 rows: ONE decode program, over the served
+        # context's table (max_context 256)
+        assert list(model._decode_cache) == [(8, 256)]
+        model.close()
+    assert answers[0] == answers[1]
+    assert answers[0] != [[o[0]] * 64 for o in answers[0]]
+    ring = 2 * BS                      # ceil(16 / 16) + 1 blocks
+    live = 0
+    for step in range(63):
+        n = np.array([len(p) + step + 1 for p in world["prompts"]])
+        live += int((-(-n // BS)).sum()
+                    + 3 * (-(-np.minimum(n, ring) // BS)).sum())
+    # 8 rows a launch, tables of 16 pages and rings of 2 in each layer
+    bucket = 63 * 8 * (256 // BS + 3 * 2)
+    assert counted == {"decode_launches_paged": 63,
+                       "decode_launches_gather": 0,
+                       "decode_pages_live": live,
+                       "decode_pages_bucket": bucket,
+                       "live_share": round(live / bucket, 4)}
 
 
 def test_grouped_matmul_reads_one_expert_a_tile_and_zeroes_unused_tiles():
@@ -553,7 +599,8 @@ def test_generate_through_the_engine_serves_the_same_tokens(world):
     for i in range(len(LENS)):
         assert got[i] == world["outs"][i]
     snap = eng.snapshot()
-    assert snap["prefix"] is None and snap["decode"] is None
+    # the ``decode`` family is the paged kernel's (below)
+    assert snap["prefix"] is None
     moe = snap["moe"]
     assert moe["experts_held"] == M["num_experts"]
     assert moe["decode"]["layer_launches"] == eng.steps * 4
@@ -566,11 +613,18 @@ def test_generate_through_the_engine_serves_the_same_tokens(world):
                                                         "blocked_layers"}
     assert (moe["prefill"]["kernel_layers"],
             moe["prefill"]["blocked_layers"]) == (0, 12)
+    dec = snap["decode"]
+    assert dec["decode_launches_paged"] == eng.steps
+    assert dec["decode_launches_gather"] == 0
+    assert 0 < dec["decode_pages_live"] < dec["decode_pages_bucket"]
     from brpc_tpu.builtin.services import serving_service
     from brpc_tpu.policy.http_protocol import HttpMessage
     page = serving_service(None, HttpMessage())[2]
     assert "moe: held=4 decode pairs=" in page and "hit_share=" in page
     assert "kernel_layers=0 blocked_layers=12" in page
+    assert (f"decode: launches paged={dec['decode_launches_paged']} "
+            f"gather=0 pages live={dec['decode_pages_live']} "
+            f"bucket={dec['decode_pages_bucket']} live_share=") in page
     eng.stop()
     kv.assert_idle("engine stopped")
 

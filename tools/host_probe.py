@@ -23,7 +23,11 @@ prints, after the run's own line, ONE line ``HOSTPROBE {...}`` with:
 - ``mla_prefill``: where the program counts latent attention
   (``snapshot()["mla"]``), the window's difference of its prefill counters
   and ``skipped_share``, the rows of the later chunks' context buckets that
-  the kernel never read over the buckets' rows.
+  the kernel never read over the buckets' rows;
+- ``decode``: where the program counts its decode launches by attention
+  path (``snapshot()["decode"]``), the window's difference of those counts
+  and of the pages the rows' positions cover and the tables' pages, and
+  ``live_share``, the one over the other.
 
 With ``--out DIR`` the two ``host`` groups are written to ``DIR/<tag>.json``.
 Run it from the root of the checkout to measure, which may be another than
@@ -171,6 +175,13 @@ def main(argv=None) -> int:
         if mla.get("bucket_rows"):
             mla["skipped_share"] = mla["skipped_rows"] / mla["bucket_rows"]
         probe["mla_prefill"] = mla
+    dec0, dec1 = a.get("decode"), b.get("decode")
+    if dec0 and dec1:                    # decode launches by path, pages
+        dec = {k: dec1[k] - dec0.get(k, 0) for k in dec1
+               if k != "live_share"}
+        dec["live_share"] = (dec["decode_pages_live"]
+                             / max(1, dec["decode_pages_bucket"]))
+        probe["decode"] = dec
     if args.out:
         os.makedirs(args.out, exist_ok=True)
         with open(os.path.join(args.out, args.tag + ".json"), "w") as f:

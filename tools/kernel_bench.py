@@ -636,6 +636,105 @@ def bench_paged_decode(peak: dict):
     case("upper bucket as served", 16, 1024, chat + [880, 45, 512],
          table_pages=128)
     case("two short rows as served", 2, 512, [80, 300], table_pages=128)
+    _bench_paged_grouped(peak)
+
+
+def _bench_paged_grouped(peak: dict):
+    """The same kernel at the `rag-steady` cell's widths (Command A+: 128
+    query heads over 8 key/value heads of 128, bfloat16 pools, 8 rows), as
+    the model calls it: tables of the served context (512 pages, 8192
+    rows) for the full layer, a window layer's ring of 257 pages (4112
+    rows, window 4096), the grid the rows' live steps (``fetch_plan``).
+    Beside it the same kernel walking every column of the table, and the
+    path the kernel replaced in ``Cohere2MoeModel``'s decode: the context
+    gathered whole blocks at a time, padded to the batch's context bucket
+    (``_block_context``), then the grouped einsum and ``_softmax_out``.
+    Each against the bytes of the live rows (pages the kernel fetches)
+    over the HBM peak."""
+    import functools
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from brpc_tpu.serving.hybrid_model import _block_context, _mm, _ring_live
+    from brpc_tpu.serving.moe_model import _softmax_out
+    from brpc_tpu.tpu.pallas_ops import paged_decode_attention
+
+    H, G, hd, bs, rows, table = 128, 8, 128, 16, 8, 512
+    ring = (4112, 4096)
+    key = jax.random.key(1)
+    hbm = peak["hbm_bytes_per_s"]
+    blocks = rows * table + 1
+    kpool, vpool = (jax.random.normal(
+        jax.random.fold_in(key, i), (3, (blocks + 1) * bs, G * hd),
+        jnp.bfloat16) for i in (1, 2))
+    ids = np.random.default_rng(1).permutation(np.arange(1, blocks + 1))
+    tables = jnp.asarray(ids[:rows * table].reshape(rows, table), jnp.int32)
+    q0 = jax.random.normal(jax.random.fold_in(key, 3), (rows, H * hd),
+                           jnp.float32)
+
+    def gather(q, kp, vp, layer, tab, lens, ring):
+        kc = _block_context(kp, layer, tab, bs)
+        vc = _block_context(vp, layer, tab, bs)
+        pos = lens - 1
+        if ring is None:
+            live = jnp.arange(kc.shape[1])[None, :] <= pos[:, None]
+        else:
+            live = _ring_live(pos, *ring)[:, :kc.shape[1]]
+        kh, vh = (c.reshape(rows, -1, G, hd) for c in (kc, vc))
+        sc = _mm(q.reshape(rows, G, H // G, hd), kh,
+                 "bgjd,bkgd->bgjk") / math.sqrt(hd)
+        return _softmax_out(sc, live[:, None, None, :], vh,
+                            "bgjk,bkgd->bgjd").reshape(rows, -1)
+
+    def chained(call):
+        @functools.partial(jax.jit, static_argnames=("n",))
+        def loop(q, kp, vp, n: int):
+            def body(i, qc):
+                return call(qc, kp, vp, i % 3)
+            return jax.lax.fori_loop(0, n, body, q)
+
+        def run(n):
+            jax.device_get(loop(q0, kpool, vpool, n)[0, :1])
+
+        return _marginal(run, 12, 120)
+
+    def case(label, lengths, bucket, ring=None):
+        lengths = np.asarray(lengths, np.int32)
+        lens = jnp.asarray(lengths)
+        tab = tables[:, :-(-ring[0] // bs)] if ring else tables
+        # the gather read the ring's rows under the batch's context bucket
+        gt = tab[:, :min(bucket, ring[0] if ring else bucket) // bs]
+        fetched = np.minimum(lengths, ring[0]) if ring else lengths
+        live_bytes = 2 * 2 * G * hd * float((-(-fetched // bs)).sum() * bs)
+        t = {walk: chained(lambda q, kp, vp, l, plan=plan:
+                           paged_decode_attention(
+                               q, kp, vp, l, tab, lens, n_heads=H,
+                               n_kv_heads=G, block_size=bs, interpret=False,
+                               ring=ring, fetch_plan=plan))
+             for walk, plan in (("plan", True), ("grid", False))}
+        t["gather"] = chained(lambda q, kp, vp, l: gather(q, kp, vp, l, gt,
+                                                          lens, ring))
+        print(f"# kernel paged_decode_attention {label} (Command A+, {rows}"
+              f" rows, 128 over 8 heads of 128, bfloat16, table "
+              f"{tab.shape[1]} pages): {t['plan'] * 1e6:8.1f} us a layer = "
+              f"{live_bytes / t['plan'] / 1e9:6.1f} GB/s of live pages "
+              f"({live_bytes / t['plan'] / hbm * 100:.0f}% of the HBM "
+              f"peak); every column walked {t['grid'] * 1e6:8.1f} us; the "
+              f"gather + grouped einsum at a {bucket}-row bucket "
+              f"{t['gather'] * 1e6:8.1f} us", flush=True)
+
+    wide = [512, 6912, 1210, 905, 2403, 700, 3101, 1499]
+    case("full layer, rows of 512-6912", wide, 8192)
+    case("window ring, rows of 512-6912", wide, 8192, ring)
+    # `rag-steady`'s batch: ~3.8 live rows of ~1 200, the rest padded
+    rag = [1100, 1350, 900, 1500, 1, 1, 1, 1]
+    case("full layer, 4 rows of 900-1500 and 4 padded", rag, 2048)
+    case("window ring, 4 rows of 900-1500 and 4 padded", rag, 2048, ring)
+    # what a step the grid walks past a row's end costs: rows of one page
+    case("full layer, 8 rows of one page", [16] * 8, 1024)
 
 
 def bench_ssm_scan(peak: dict):
